@@ -11,13 +11,15 @@ import logging
 import signal
 import sys
 
-from linkerd_tpu.admin.server import AdminServer
-from linkerd_tpu.linker import DEFAULT_ADMIN_PORT, load_linker
-
 log = logging.getLogger("linkerd_tpu")
 
 
 async def amain(config_text: str) -> None:
+    # imported here, not at module level: linkerd_tpu.linker pulls in
+    # jax, and main() must place the compile cache before that happens
+    from linkerd_tpu.admin.server import AdminServer
+    from linkerd_tpu.linker import DEFAULT_ADMIN_PORT, load_linker
+
     linker = load_linker(config_text)
     await linker.start()
 
@@ -87,6 +89,8 @@ def main() -> None:
         raise SystemExit(64)
     with open(sys.argv[1], "r", encoding="utf-8") as f:
         text = f.read()
+    from linkerd_tpu.compile_cache import place_compile_cache
+    log.info("jax compile cache: %s", place_compile_cache())
     asyncio.run(amain(text))
 
 

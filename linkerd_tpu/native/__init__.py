@@ -22,19 +22,44 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+_SRC_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), os.pardir, os.pardir, "native"))
+# generated by build.py on every build, so never newer than its output
+_GENERATED = {"huffman_table.h"}
+
+
 def _build() -> bool:
-    build_py = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                            "native", "build.py")
-    build_py = os.path.abspath(build_py)
+    build_py = os.path.join(_SRC_DIR, "build.py")
     if not os.path.exists(build_py):
         return False
     try:
         subprocess.run([sys.executable, build_py], check=True,
                        capture_output=True, timeout=120)
         return True
-    except Exception as e:  # noqa: BLE001 - fall back to pure python
-        log.debug("native build failed: %s", e)
+    except subprocess.CalledProcessError as e:
+        # callers fall back to pure python, but a compiler that refused
+        # the sources must say why where an operator will see it
+        log.warning("native build failed (rc=%s):\n%s", e.returncode,
+                    (e.stderr or b"").decode("utf-8", "replace")[-4000:])
         return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+        log.warning("native build failed: %s", e)
+        return False
+
+
+def _stale() -> bool:
+    """The .so is older than a C++ source next to this checkout — the
+    rule ``native/build.py`` ``build_h2bench`` uses for its binary. A
+    build product can outlive the revision it was built from (it is
+    git-ignored, and a copied tree carries it along)."""
+    try:
+        built = os.path.getmtime(_SO_PATH)
+        return any(
+            os.path.getmtime(os.path.join(_SRC_DIR, name)) > built
+            for name in os.listdir(_SRC_DIR)
+            if name.endswith((".cpp", ".h")) and name not in _GENERATED)
+    except OSError:
+        return False  # no .so yet, or no source tree to rebuild from
 
 
 def ensure_built() -> bool:
@@ -42,10 +67,11 @@ def ensure_built() -> bool:
     startup (linker/namerd assembly) — NEVER from the data path: the
     compile shells out to g++ and would freeze the event loop.
 
-    A stale .so (from an older source revision, missing newer symbols)
-    is rebuilt once: lib() refuses to load it, so we retry the build."""
+    A stale .so — older than its sources, or (from an older revision)
+    missing newer symbols so that lib() refuses to load it — is rebuilt
+    once."""
     global _tried
-    if not os.path.exists(_SO_PATH):
+    if _lib is None and (not os.path.exists(_SO_PATH) or _stale()):
         _build()
     _tried = False  # allow lib() to (re)load
     if lib() is None and os.path.exists(_SO_PATH):

@@ -8,8 +8,11 @@ scale (hundreds to a few thousand rows of 32 features) the model is far too
 small to be MXU-bound — HBM traffic and kernel-launch overhead dominate — so
 the fusion is the win (see /opt/skills/guides/pallas_guide.md).
 
-Falls back transparently to the plain XLA path (`models.anomaly.anomaly_scores`)
-when Mosaic can't compile (e.g. CPU tests run with ``interpret=True``).
+``best_scorer`` selects by the platform the parameters live on: this
+kernel on ``tpu``, the plain XLA path (``models.anomaly.anomaly_scores``)
+everywhere else. There is no probe and no fallback: a kernel that Mosaic
+refuses on the chip is an error the caller sees, not a quieter scorer.
+CPU tests run the kernel with ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -117,24 +120,17 @@ def fused_anomaly_scores(
     return out[:orig_b, 0]
 
 
-@functools.lru_cache(maxsize=16)
-def fused_available(cfg: AnomalyModelConfig = AnomalyModelConfig()) -> bool:
-    """Probe whether the fused kernel compiles+runs for THIS config on the
-    current backend (cached per config)."""
-    try:
-        from linkerd_tpu.models.anomaly import init_params
-        params = init_params(jax.random.key(0), cfg)
-        x = jnp.zeros((256, cfg.in_dim), jnp.float32)
-        got = jax.jit(lambda p, v: fused_anomaly_scores(p, v, cfg))(params, x)
-        ref = anomaly_scores(params, x, cfg)
-        return bool(jnp.allclose(got, ref, atol=2e-2))
-    except Exception:  # noqa: BLE001 — any Mosaic/lowering error means "no"
-        return False
+def scorer_kind(platform: str) -> str:
+    """Which implementation ``best_scorer`` builds for parameters on
+    ``platform``: ``"fused_pallas"`` on a TPU, ``"xla"`` elsewhere."""
+    return "fused_pallas" if platform == "tpu" else "xla"
 
 
-def best_scorer(cfg: AnomalyModelConfig = AnomalyModelConfig(),
+def best_scorer(cfg: AnomalyModelConfig, platform: str,
                 donate: bool = False):
-    """Return a jitted scorer: the fused kernel when available, else XLA.
+    """Return the jitted scorer for parameters living on ``platform``
+    (``jax.Device.platform``): the fused kernel on ``tpu``, plain XLA
+    elsewhere. A compile error propagates to the caller.
 
     The returned fn is ``(params, x, mu=None, var=None) -> scores``:
     with mu/var, ``normalize_features`` runs on device ahead of the
@@ -147,16 +143,15 @@ def best_scorer(cfg: AnomalyModelConfig = AnomalyModelConfig(),
     step's temporaries/outputs instead of allocating fresh device
     memory per micro-batch. Donated buffers raise on re-read.
     """
+    score_rows = (fused_anomaly_scores
+                  if scorer_kind(platform) == "fused_pallas"
+                  else anomaly_scores)
 
-    def _norm(v, mu, var):
-        return v if mu is None else normalize_features(v, mu, var)
+    def score(p, v, mu=None, var=None):
+        if mu is not None:
+            v = normalize_features(v, mu, var)
+        return score_rows(p, v, cfg)
 
-    if fused_available(cfg):
-        fn = lambda p, v, mu=None, var=None: \
-            fused_anomaly_scores(p, _norm(v, mu, var), cfg)  # noqa: E731
-    else:
-        fn = lambda p, v, mu=None, var=None: \
-            anomaly_scores(p, _norm(v, mu, var), cfg)  # noqa: E731
     if donate:
-        return jax.jit(fn, donate_argnums=(1,))
-    return jax.jit(fn)
+        return jax.jit(score, donate_argnums=(1,))
+    return jax.jit(score)

@@ -305,7 +305,7 @@ class InProcessScorer(Scorer):
         import jax
         import optax
         from linkerd_tpu.models.anomaly import AnomalyModelConfig, init_params
-        from linkerd_tpu.ops.scoring import best_scorer
+        from linkerd_tpu.ops.scoring import best_scorer, scorer_kind
 
         self.cfg = AnomalyModelConfig(recon_weight=recon_weight)
         self._opt = optax.adam(learning_rate)
@@ -330,19 +330,32 @@ class InProcessScorer(Scorer):
                                            donate=True)
             self._train_step = make_train_step(self.mesh, self._opt, self.cfg)
             self._batch_multiple = self.mesh.shape["data"]
+            self.score_path = "mesh"
         else:
             params = init_params(jax.random.key(seed), self.cfg)
             # honor an explicit device choice (e.g. pin to the second
             # chip); jit follows the committed placement of the params
             self.params = jax.device_put(params, devices[0])
-            self._opt_state = self._opt.init(self.params)
-            self._scorer = best_scorer(self.cfg, donate=True)
+            # committed like the params: adam's fresh step count comes
+            # back uncommitted, and the first train step would compile
+            # once for it and again for its own committed outputs
+            self._opt_state = jax.device_put(
+                self._opt.init(self.params), devices[0])
+            # selected by where the params live, never probed: a kernel
+            # Mosaic refuses on the chip raises out of the first score
+            self.score_path = scorer_kind(devices[0].platform)
+            self._scorer = best_scorer(self.cfg, devices[0].platform,
+                                       donate=True)
             self._train_step = self._mk_train_step()
         self.fit_steps = fit_steps
         self._devices = devices
         # cumulative train steps; checkpointed so a restored model resumes
         # its lineage, not a fresh step count
         self._step = 0
+        # fit() calls per compiled shape ("<padded rows>", "+mask" when
+        # padding rows are masked out): with the dispatcher's per-bucket
+        # score counts, every program this scorer made XLA compile
+        self._fit_batches: Dict[str, int] = {}
         # Running feature normalization (updated on non-anomalous training
         # rows): without it the autoencoder's reconstruction error is
         # dominated by raw feature scale and tanh() saturates for normal
@@ -379,6 +392,27 @@ class InProcessScorer(Scorer):
         self._dispatcher = RingDispatcher(self.cfg.in_dim,
                                           self._bucket_target)
         self._place_norm()
+
+    def device_state(self) -> dict:
+        """What this scorer actually runs on, as JAX reports it, plus the
+        score path it built and the batch shapes it has dispatched —
+        the ``device`` block of /model.json. ``chip_smoke.py`` and the
+        bench read the platform from here: a linker that came up on the
+        CPU says so instead of serving under TPU names unnoticed."""
+        import jax
+
+        d0 = self._devices[0]
+        return {
+            "platform": d0.platform,
+            "device_kind": d0.device_kind,
+            "count": len(jax.devices()),
+            "score_path": self.score_path,
+            "mesh": (dict(self.mesh.shape)
+                     if self.mesh is not None else None),
+            "score_batches": {str(b): n for b, n in
+                              sorted(self._dispatcher.batches.items())},
+            "fit_batches": dict(self._fit_batches),
+        }
 
     def _place_norm(self) -> None:
         """Refresh the device mirrors of the normalization stats: tiny
@@ -678,6 +712,8 @@ class InProcessScorer(Scorer):
                     if len(xn) != n else None)
 
         mu_d, var_d = self._mu_d, self._var_d  # consistent pair (see score)
+        shape = f"{len(xn)}+mask" if row_mask is not None else str(len(xn))
+        self._fit_batches[shape] = self._fit_batches.get(shape, 0) + 1
 
         def run() -> float:
             loss = float("nan")
@@ -1188,9 +1224,14 @@ class JaxAnomalyTelemeter(Telemeter):
 
     # -- Telemeter --------------------------------------------------------
     def _mk_inprocess(self) -> "InProcessScorer":
-        return InProcessScorer(
+        scorer = InProcessScorer(
             learning_rate=self.cfg.learningRate,
             recon_weight=self.cfg.reconWeight)
+        d = scorer.device_state()
+        log.info("in-process scorer on %s (%s, %d visible), score path %s",
+                 d["platform"], d["device_kind"], d["count"],
+                 d["score_path"])
+        return scorer
 
     def set_sidecar_activity(self, activity) -> None:
         """Install the namer lookup Activity backing a path-form
@@ -1238,16 +1279,13 @@ class JaxAnomalyTelemeter(Telemeter):
                     self._scorer = resilient
                 else:
                     # line-rate default: in-process primary, sidecar
-                    # DEMOTED to the fallback tier behind the breaker
-                    try:
-                        primary = self._mk_inprocess()
-                    except Exception as e:  # noqa: BLE001 — no local
-                        # device/toolchain: the sidecar carries the load
-                        log.warning("in-process scorer unavailable (%r); "
-                                    "sidecar serves as the only tier", e)
-                        self._scorer = resilient
-                    else:
-                        self._scorer = TieredScorer(primary, resilient)
+                    # DEMOTED to the fallback tier behind the breaker.
+                    # A linker configured for an in-process primary that
+                    # cannot build one (no device, chip held by another
+                    # process) fails here, at start — sidecarTier:
+                    # primary is how to run without a local device
+                    self._scorer = TieredScorer(self._mk_inprocess(),
+                                                resilient)
             else:
                 self._scorer = self._mk_inprocess()
             if self._span_sink is not None:
@@ -1842,6 +1880,12 @@ class JaxAnomalyTelemeter(Telemeter):
         tier_fn = getattr(self._scorer, "tier_state", None)
         if tier_fn is not None:
             out["tiers"] = tier_fn()
+        device_fn = getattr(self._scorer, "device_state", None)
+        if device_fn is not None:
+            # platform / device_kind / count as JAX reports them, the
+            # score path built (fused kernel, XLA, or mesh shape) and
+            # the batch buckets dispatched so far
+            out["device"] = device_fn()
         if self._scorer_pool is not None:
             out["scorer_pool"] = self._scorer_pool.status()
         if self.distill is not None:

@@ -118,6 +118,8 @@ class RingDispatcher:
         self._bucket_fn = bucket_fn
         self.depth = max(1, depth)
         self._slots: Dict[int, List[_Slot]] = {}
+        # batches dispatched per padded bucket (event-loop thread only)
+        self.batches: Dict[int, int] = {}
         self._waiters: List[Tuple[int, asyncio.AbstractEventLoop,
                                   asyncio.Future]] = []
         self._lock = threading.Lock()
@@ -246,6 +248,7 @@ class RingDispatcher:
         except BaseException:
             self._release(slot)
             raise
+        self.batches[bucket] = self.batches.get(bucket, 0) + 1
         fut = loop.create_future()
         self._ensure_thread()
         self._queue.put((result, n, loop, fut, slot))
@@ -538,6 +541,10 @@ class TieredScorer:
     @property
     def _step(self):
         return getattr(self.primary, "_step", None)
+
+    def device_state(self) -> Optional[dict]:
+        fn = getattr(self.primary, "device_state", None)
+        return fn() if fn is not None else None
 
     async def _tiered(self, what: str, primary_call, fallback_call):
         admitted, probe = self.primary_breaker.acquire()

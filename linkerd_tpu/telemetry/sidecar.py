@@ -188,8 +188,8 @@ class GrpcScorerClient:
     def __init__(self, address: str, timeout_s: float = 5.0,
                  first_timeout_s: float = 60.0):
         # The first call on each RPC gets a long deadline to absorb the
-        # sidecar's XLA compile (~20-40s on TPU); afterwards the short
-        # steady-state deadline keeps failure detection responsive.
+        # sidecar's XLA compile; afterwards the short steady-state
+        # deadline keeps failure detection responsive.
         self.address = address
         self.timeout_s = timeout_s
         self.first_timeout_s = first_timeout_s
@@ -206,10 +206,10 @@ class GrpcScorerClient:
 
     @staticmethod
     def _bucket(rpc: str, rows: int) -> tuple:
-        # Each power-of-two bucket is a distinct XLA compilation (~20-40s
-        # on TPU). Warm state is keyed by (rpc, bucket) so the first call
-        # into any bucket gets the long deadline while compiled buckets
-        # keep the short one.
+        # Each power-of-two bucket is a distinct XLA compilation. Warm
+        # state is keyed by (rpc, bucket) so the first call into any
+        # bucket gets the long deadline while compiled buckets keep the
+        # short one.
         return (rpc, bucket_rows(rows))
 
     def _deadline(self, key: tuple) -> float:
@@ -317,6 +317,9 @@ def main() -> None:
              "withdraws on shutdown")
     parser.add_argument("--announce-name", default="l5d-scorer")
     args = parser.parse_args()
+    # before ScorerSidecar() builds the InProcessScorer and imports jax
+    from linkerd_tpu.compile_cache import place_compile_cache
+    place_compile_cache()
 
     async def amain() -> None:
         from linkerd_tpu.core import Path

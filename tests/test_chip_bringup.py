@@ -1,0 +1,274 @@
+"""Bring-up invariants for the accelerator tier: the scorer is selected by
+platform and never probed, failures on that path propagate, the compile
+cache is placed from outside, and ``chip_smoke.py`` cannot pass without a
+chip. (Sorts before test_multicore.py on purpose: tier-1 is time-boxed.)"""
+
+import ast
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from linkerd_tpu.models.anomaly import (
+    AnomalyModelConfig, anomaly_scores, init_params,
+)
+from linkerd_tpu.ops import scoring
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SMOKE = os.path.join(REPO, "chip_smoke.py")
+CFG = AnomalyModelConfig()
+
+
+def _run(argv, env=None, cwd=REPO, timeout=120):
+    return subprocess.run(argv, env=env, cwd=cwd, capture_output=True,
+                          text=True, timeout=timeout)
+
+
+def _clean_env(**extra):
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(extra)
+    return env
+
+
+class TestScorerSelection:
+    def test_cpu_gets_xla_without_touching_pallas(self, monkeypatch):
+        def no_kernel(*a, **k):
+            raise AssertionError("pallas_call reached on a cpu platform")
+
+        monkeypatch.setattr(scoring.pl, "pallas_call", no_kernel)
+        assert scoring.scorer_kind("cpu") == "xla"
+        params = init_params(jax.random.key(0), CFG)
+        x = jax.random.normal(jax.random.key(1), (8, CFG.in_dim))
+        got = scoring.best_scorer(CFG, "cpu")(params, x)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(anomaly_scores(params, x, CFG)),
+            atol=1e-6)
+
+    def test_kernel_error_on_tpu_platform_propagates(self, monkeypatch):
+        class MosaicRefused(Exception):
+            pass
+
+        def refuse(*a, **k):
+            raise MosaicRefused("kernel does not compile")
+
+        monkeypatch.setattr(scoring, "fused_anomaly_scores", refuse)
+        assert scoring.scorer_kind("tpu") == "fused_pallas"
+        params = init_params(jax.random.key(0), CFG)
+        x = jnp.zeros((4, CFG.in_dim), jnp.float32)
+        with pytest.raises(MosaicRefused):
+            scoring.best_scorer(CFG, "tpu")(params, x)
+
+    def test_selection_contains_no_exception_handler(self):
+        with open(scoring.__file__, "r", encoding="utf-8") as f:
+            tree = ast.parse(f.read())
+        assert not [n for n in ast.walk(tree) if isinstance(n, ast.Try)]
+        assert not hasattr(scoring, "fused_available")
+
+    def test_kernel_compiles_for_v5e_with_the_real_mosaic(self):
+        """libtpu's compile-only client needs no chip: lower the fused
+        kernel for a v5e device and run Mosaic's own compile on it (tier-1
+        otherwise only ever runs the kernel with interpret=True)."""
+        code = (
+            "import jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models.anomaly import (\n"
+            "    AnomalyModelConfig, init_params)\n"
+            "from linkerd_tpu.ops.scoring import best_scorer\n"
+            "cfg = AnomalyModelConfig()\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,"
+            " sharding=sh)\n"
+            "params = jax.tree.map(S, init_params(jax.random.key(0), cfg))\n"
+            "vec = S(jnp.zeros((cfg.in_dim,)))\n"
+            "for rows in (8, 1024):\n"
+            "    best_scorer(cfg, 'tpu', donate=True).lower(\n"
+            "        params, S(jnp.zeros((rows, cfg.in_dim))), vec, vec\n"
+            "    ).compile()\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], env=_clean_env(
+            JAX_PLATFORMS="cpu", TPU_ACCELERATOR_TYPE="v5litepod-4",
+            TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+
+
+class TestFailLoud:
+    def test_inprocess_primary_that_cannot_build_fails_at_start(
+            self, monkeypatch):
+        from linkerd_tpu.telemetry.anomaly import (
+            JaxAnomalyConfig, JaxAnomalyTelemeter,
+        )
+        from linkerd_tpu.telemetry.metrics import MetricsTree
+
+        class NoDevice(Exception):
+            pass
+
+        def no_device(self):
+            raise NoDevice("chip held by another process")
+
+        monkeypatch.setattr(JaxAnomalyTelemeter, "_mk_inprocess", no_device)
+        tele = JaxAnomalyTelemeter(
+            JaxAnomalyConfig(sidecarAddress="127.0.0.1:1"), MetricsTree())
+        with pytest.raises(NoDevice):
+            tele._ensure_scorer()  # no quiet demotion to the sidecar
+
+    def test_device_block_reports_what_ran(self):
+        import asyncio
+
+        from linkerd_tpu.telemetry.anomaly import InProcessScorer
+
+        scorer = InProcessScorer(devices=[jax.devices()[0]])
+        try:
+            asyncio.run(scorer.warmup())
+            state = scorer.device_state()
+        finally:
+            scorer.close()
+        assert state["platform"] == "cpu"
+        assert state["score_path"] == "xla" and state["mesh"] is None
+        assert state["count"] == len(jax.devices())
+        assert state["score_batches"] == {"4": 2}
+        assert state["fit_batches"] == {"4": 1}
+
+    def test_bench_phase_child_that_raised_exits_nonzero(
+            self, monkeypatch, capsys):
+        import bench
+        import linkerd_tpu.compile_cache as cc
+
+        def boom():
+            raise RuntimeError("phase blew up")
+
+        monkeypatch.setattr(cc, "place_compile_cache", lambda: "unused")
+        monkeypatch.setattr(bench, "static_analysis_bench", boom)
+        monkeypatch.setattr(sys, "argv",
+                            ["bench.py", "--phase", "static_analysis"])
+        with pytest.raises(SystemExit) as exc:
+            bench.main()
+        assert exc.value.code == 1
+        frag = bench._last_phase_fragment(capsys.readouterr().out)
+        assert "phase blew up" in frag["static_analysis_error"]
+
+
+class TestCompileCachePlacement:
+    CODE = ("from linkerd_tpu.compile_cache import place_compile_cache\n"
+            "import os\n"
+            "a = place_compile_cache(); b = place_compile_cache()\n"
+            "assert a == b == os.environ['JAX_COMPILATION_CACHE_DIR']\n"
+            "print(a)\n"
+            "print(os.environ['JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS'])")
+
+    def test_environment_variable_is_honoured(self, tmp_path):
+        proc = _run([sys.executable, "-c", self.CODE], env=_clean_env(
+            JAX_COMPILATION_CACHE_DIR=str(tmp_path / "elsewhere")))
+        assert proc.returncode == 0, proc.stderr
+        assert proc.stdout.split() == [str(tmp_path / "elsewhere"), "0"]
+
+    def test_default_is_one_fixed_path_in_the_checkout(self, tmp_path):
+        outs = [_run([sys.executable, "-c", self.CODE],
+                     env=_clean_env(PYTHONPATH=REPO), cwd=cwd).stdout.split()
+                for cwd in (REPO, str(tmp_path))]  # cwd must not matter
+        assert outs[0] == outs[1] == [os.path.join(REPO, ".jax_cache"), "0"]
+
+    def test_placement_after_jax_import_is_an_error(self):
+        from linkerd_tpu.compile_cache import place_compile_cache
+        with pytest.raises(RuntimeError, match="before the first"):
+            place_compile_cache()
+
+    def test_no_other_cache_dir_setting_in_the_tree(self):
+        hits = []
+        for root, dirs, files in os.walk(REPO):
+            dirs[:] = [d for d in dirs
+                       if not d.startswith(".") and d != "chiprun_out"]
+            for name in files:
+                path = os.path.join(root, name)
+                if name.endswith(".py"):
+                    with open(path, "r", encoding="utf-8") as f:
+                        if "compilation_cache_dir" in f.read().lower():
+                            hits.append(os.path.relpath(path, REPO))
+        assert sorted(hits) == ["linkerd_tpu/compile_cache.py",
+                                "tests/test_chip_bringup.py"]
+
+
+class TestChipSmoke:
+    def test_without_a_chip_it_fails_and_names_the_platform(self):
+        proc = _run([sys.executable, SMOKE],
+                    env=_clean_env(JAX_PLATFORMS="cpu"), timeout=170)
+        assert proc.returncode != 0
+        assert "platform is 'cpu'" in proc.stderr
+        assert not [ln for ln in proc.stdout.splitlines()
+                    if ln.startswith("{")]  # no result line
+
+    def test_alone_in_a_directory_it_fails(self, tmp_path):
+        import shutil
+        shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+        proc = _run([sys.executable, "chip_smoke.py"], cwd=str(tmp_path),
+                    env=_clean_env(), timeout=60)
+        assert proc.returncode != 0
+        assert not proc.stdout.strip()
+
+    def test_parent_imports_no_jax_and_no_telemetry(self):
+        with open(SMOKE, "r", encoding="utf-8") as f:
+            tree = ast.parse(f.read())
+
+        def module_level(nodes):
+            for node in nodes:
+                if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
+                                     ast.Lambda)):
+                    continue  # runs only when called (the child legs)
+                yield node
+                yield from module_level(ast.iter_child_nodes(node))
+
+        names = []
+        for node in module_level(tree.body):
+            if isinstance(node, ast.Import):
+                names += [a.name for a in node.names]
+            elif isinstance(node, ast.ImportFrom):
+                names.append(node.module or "")
+        assert names, "expected stdlib imports at module level"
+        for name in names:
+            assert name.split(".")[0] not in ("jax", "jaxlib", "numpy")
+            assert not name.startswith("linkerd_tpu.telemetry")
+
+
+class TestNativeStaleness:
+    def test_so_older_than_a_source_is_stale(self, tmp_path, monkeypatch):
+        from linkerd_tpu import native
+
+        so = tmp_path / "libl5d_native.so"
+        so.write_bytes(b"")
+        newest = max(
+            os.path.getmtime(os.path.join(native._SRC_DIR, n))
+            for n in os.listdir(native._SRC_DIR)
+            if n.endswith((".cpp", ".h")) and n not in native._GENERATED)
+        monkeypatch.setattr(native, "_SO_PATH", str(so))
+        os.utime(so, (newest - 10, newest - 10))
+        assert native._stale()
+        os.utime(so, (newest + 10, newest + 10))
+        assert not native._stale()
+        monkeypatch.setattr(native, "_SO_PATH", str(tmp_path / "missing"))
+        assert not native._stale()  # absent is "not built", not "stale"
+
+    def test_compiler_failure_is_logged_at_warning(self, monkeypatch,
+                                                   caplog):
+        from linkerd_tpu import native
+
+        def refuse(*a, **k):
+            raise subprocess.CalledProcessError(
+                1, a[0], stderr=b"fastpath.cpp:1: error: expected ';'")
+
+        monkeypatch.setattr(native.subprocess, "run", refuse)
+        with caplog.at_level("WARNING", logger=native.log.name):
+            assert native._build() is False
+        assert "expected ';'" in caplog.text

@@ -184,7 +184,7 @@ class TestDeviceNormalization:
         var = x.var(axis=0)
         ref = anomaly_scores(params, jnp.asarray(
             self._host_norm(x, mu, var), jnp.float32), CFG)
-        scorer = best_scorer(CFG)
+        scorer = best_scorer(CFG, jax.devices()[0].platform)
         got = scorer(params, jnp.asarray(x), jnp.asarray(mu),
                      jnp.asarray(var))
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

@@ -594,7 +594,8 @@ class TestMetricsScope:
 class TestJaxHotpath:
     """Per-call device seams reachable from the score dispatch path:
     device_put / to_thread / asarray readback must not creep back into
-    the line-rate path (the 39.95 ms regression shape of BENCH_r04)."""
+    the line-rate path (the per-call seam shape COMPONENTS.md §2.11
+    removed)."""
 
     def test_device_put_and_to_thread_in_score_fire(self, tmp_path):
         got = findings_of(tmp_path, {

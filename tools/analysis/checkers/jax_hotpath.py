@@ -4,8 +4,9 @@ The line-rate scoring contract (COMPONENTS.md §2.11): the score
 dispatch path pays ONE host memcpy into a persistent staging buffer and
 rides JAX async dispatch; readback happens on the single drainer
 thread. Three call shapes silently reintroduce the old per-call seam
-and its 8x latency (BENCH_r04's 39.95 ms ``score_batch_p50_ms`` vs the
-≤5 ms bar):
+and its latency (39.95 ms ``score_batch_p50_ms`` vs the ≤5 ms bar in a
+pre-round record taken through a shared remote chip; on a local chip:
+not measured):
 
 - ``jax.device_put`` — a fresh per-call host→device transfer instead of
   the staging ring;
