@@ -56,14 +56,6 @@ def scorer_throughput() -> dict:
     async def drive() -> tuple:
         await scorer.score(host_batches[0])  # warm / compile
         await scorer.score(micro_batches[0])
-        # seam measurement phase: phase-split timing ON for 20 batches
-        # (transfer_GBps / device_step_ms), then OFF so the headline
-        # latency/throughput loops keep the ring dispatch path
-        scorer.timing_enabled = True
-        for i in range(20):
-            await scorer.score(host_batches[i % len(host_batches)])
-        scorer.timing_enabled = False
-        await scorer.score(host_batches[0])  # back on the ring path
         # per-batch e2e latency at the serving micro-batch size:
         # sequential score() calls, the shape a single accrual-policy
         # consumer sees (VERDICT r3 item 4)
@@ -85,22 +77,7 @@ def scorer_throughput() -> dict:
         return time.perf_counter() - t0, lats
 
     dt, lats = asyncio.run(drive())
-    # seam efficiency (ROADMAP item 3): host<->device transfer bandwidth
-    # and pure device-step time, from the scorer's own timing hooks —
-    # the same decomposition the scorer-path trace spans annotate
-    tt = dict(scorer.timing_totals)
-    seam = {}
-    if tt.get("calls"):
-        transfer_s = tt["transfer_ms"] / 1e3
-        seam["transfer_GBps"] = (
-            round(tt["bytes"] / transfer_s / 1e9, 3)
-            if transfer_s > 0 else None)
-        seam["device_step_ms"] = round(tt["device_ms"] / tt["calls"], 3)
-        seam["transfer_ms_avg"] = round(tt["transfer_ms"] / tt["calls"], 3)
-        seam["dispatch_queue_ms_avg"] = round(
-            tt["queue_ms"] / tt["calls"], 3)
     out = {
-        **seam,
         "rows_per_s": batch * n_iters / dt,
         "rows_per_s_async4": round(batch * n_iters / dt, 1),
         "score_batch_p50_ms": round(lats[len(lats) // 2], 3),

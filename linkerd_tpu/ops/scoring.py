@@ -116,6 +116,7 @@ def fused_anomaly_scores(
         out_specs=pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, 1), jnp.float32),
         interpret=interpret,
+        name="anomaly_score_fused",
     )(x, *flat_args)
     return out[:orig_b, 0]
 
@@ -149,8 +150,10 @@ def best_scorer(cfg: AnomalyModelConfig, platform: str,
 
     def score(p, v, mu=None, var=None):
         if mu is not None:
-            v = normalize_features(v, mu, var)
-        return score_rows(p, v, cfg)
+            with jax.named_scope("normalize"):
+                v = normalize_features(v, mu, var)
+        with jax.named_scope("score_rows"):
+            return score_rows(p, v, cfg)
 
     if donate:
         return jax.jit(score, donate_argnums=(1,))

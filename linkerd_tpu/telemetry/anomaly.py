@@ -38,6 +38,7 @@ from linkerd_tpu.lifecycle import LifecycleConfig
 from linkerd_tpu.models.features import FEATURE_DIM, FeatureVector, featurize_batch
 from linkerd_tpu.protocol.http.message import Request, Response
 from linkerd_tpu.router.service import Filter, Service
+from linkerd_tpu.telemetry import phases
 from linkerd_tpu.telemetry.metrics import MetricsTree
 from linkerd_tpu.telemetry.telemeter import Telemeter
 
@@ -250,10 +251,10 @@ class Scorer:
     ``asyncio.to_thread``) or async (gRPC sidecar).
 
     ``last_timing``: per-call decomposition of the most recent score()
-    ({queue_ms, transfer_ms, device_ms, bytes} in-process; {rpc_ms} for
-    the sidecar) — the source for scorer-span annotations and the
-    bench's transfer_GBps / device_step_ms seam metrics. None until the
-    first scored batch; backends without instrumentation leave it None."""
+    ({queue_ms, transfer_ms, device_ms, hop_ms, bytes} in-process, read
+    off the ring path's phase record; {rpc_ms} for the sidecar) — the
+    source for scorer-span annotations. None until the first scored
+    batch; backends without instrumentation leave it None."""
 
     last_timing: Optional[dict] = None
 
@@ -370,28 +371,28 @@ class InProcessScorer(Scorer):
         self._var = np.ones(self.cfg.in_dim, np.float32)
         self._norm_momentum = 0.2
         self._norm_initialized = False
-        # score-path timing decomposition (worker-thread writes are
-        # GIL-atomic dict swaps; readers snapshot last_timing whole).
-        # OFF by default: the phase-split adds two device barriers per
-        # batch, forfeiting transfer/compute overlap — only pay it when
-        # a consumer exists (span sink installed, or bench seam metrics)
-        self.timing_enabled = False
-        # with timing on, only every Nth batch pays the instrumented
-        # (two-barrier, thread-hop) path; the rest ride the line-rate
-        # ring and span tags reuse the last sampled decomposition.
-        # 1 = time every batch (the bench's seam phase sets this).
-        self.timing_sample_every = 1
-        self._timing_i = 0
-        self.last_timing: Optional[dict] = None
-        self.timing_totals = {"calls": 0, "queue_ms": 0.0,
-                              "transfer_ms": 0.0, "device_ms": 0.0,
-                              "bytes": 0}
         # persistent double-buffered staging ring (the line-rate
         # dispatch path; see class docstring)
         from linkerd_tpu.telemetry.linerate import RingDispatcher
         self._dispatcher = RingDispatcher(self.cfg.in_dim,
                                           self._bucket_target)
         self._place_norm()
+
+    @property
+    def last_timing(self) -> Optional[dict]:
+        """The newest score call's phases on the ring path, as the scorer
+        spans tag them (COMPONENTS.md, scorer-path spans). ``device_ms``
+        is the drainer's wait for the device, transfer included."""
+        rec = self._dispatcher.last
+        if rec is None:
+            return None
+        return {"queue_ms": rec.ms(phases.SLOT_WAIT),
+                "transfer_ms": rec.ms(phases.STAGE, phases.PUT,
+                                      phases.READBACK),
+                "device_ms": rec.ms(phases.DEVICE_WAIT),
+                "hop_ms": rec.ms(phases.HOP),
+                "bytes": (rec.counts.get("put.bytes", 0)
+                          + rec.counts.get("readback.bytes", 0))}
 
     def device_state(self) -> dict:
         """What this scorer actually runs on, as JAX reports it, plus the
@@ -460,11 +461,14 @@ class InProcessScorer(Scorer):
         def step(params, opt_state, x, labels, mask, row_mask=None,
                  mu=None, var=None):
             if mu is not None:
-                x = normalize_features(x, mu, var)
-            loss, grads = jax.value_and_grad(loss_fn)(
-                params, x, labels, mask, cfg, row_mask)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+                with jax.named_scope("normalize"):
+                    x = normalize_features(x, mu, var)
+            with jax.named_scope("loss_grad"):
+                loss, grads = jax.value_and_grad(loss_fn)(
+                    params, x, labels, mask, cfg, row_mask)
+            with jax.named_scope("adam"):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             return params, opt_state, loss
 
         return step
@@ -597,27 +601,7 @@ class InProcessScorer(Scorer):
         the thousands would lose mantissa bits if cast to bf16 before
         subtracting mu) and the sharded path normalizes each batch shard
         on its own device."""
-        return self._pad_rows(np.asarray(x, np.float32))  # l5d: ignore[jax-hotpath] — host-side dtype cast of the input batch, not a device readback
-
-    def _batch_placement(self):
-        """Device placement for an input batch: the data-axis sharding
-        when meshed, the pinned device otherwise."""
-        if self.mesh is not None:
-            from linkerd_tpu.parallel.mesh import batch_sharding
-            return batch_sharding(self.mesh)
-        return self._devices[0]
-
-    def _note_timing(self, queue_ms: float, transfer_ms: float,
-                     device_ms: float, nbytes: int) -> None:
-        self.last_timing = {"queue_ms": queue_ms,
-                            "transfer_ms": transfer_ms,
-                            "device_ms": device_ms, "bytes": nbytes}
-        t = self.timing_totals
-        t["calls"] += 1
-        t["queue_ms"] += queue_ms
-        t["transfer_ms"] += transfer_ms
-        t["device_ms"] += device_ms
-        t["bytes"] += nbytes
+        return self._pad_rows(np.asarray(x, np.float32))
 
     async def score(self, x: np.ndarray) -> np.ndarray:
         """Score [n, D] -> [n] through the donated staging ring. The
@@ -628,81 +612,40 @@ class InProcessScorer(Scorer):
         never mutates the captured (immutable) device arrays, so an
         in-flight donated batch always completes against a consistent
         model."""
-        if self.timing_enabled:
-            self._timing_i += 1
-            if self.timing_sample_every <= 1 \
-                    or self._timing_i % self.timing_sample_every == 1:
-                return await self._score_timed(x)
-        xf = np.asarray(x, np.float32)  # l5d: ignore[jax-hotpath] — host-side dtype cast of the input batch, not a device readback
         params = self.params
         mu_d, var_d = self._mu_d, self._var_d
         if self.mesh is not None:
             from linkerd_tpu.parallel.mesh import shard_batch
             mesh = self.mesh
 
-            def step(staging: np.ndarray):
+            def put(staging: np.ndarray):
                 # per-device shard feed; the assembled array is donated
-                xd = shard_batch(mesh, staging)
-                return self._scorer(params, xd, mu_d, var_d)
+                return shard_batch(mesh, staging)
         else:
             dev = self._devices[0]
 
-            def step(staging: np.ndarray):
+            def put(staging: np.ndarray):
                 import jax
-                xd = jax.device_put(staging, dev)  # l5d: ignore[jax-hotpath] — async placement of the persistent staging buffer; donated to the step, never re-read
-                return self._scorer(params, xd, mu_d, var_d)
+                return jax.device_put(staging, dev)  # l5d: ignore[jax-hotpath] — async placement of the persistent staging buffer; donated to the step, never re-read
 
-        return await self._dispatcher.dispatch(xf, step)
+        def step(xd):
+            return self._scorer(params, xd, mu_d, var_d)
 
-    async def _score_timed(self, x: np.ndarray) -> np.ndarray:
-        """Instrumented scoring: explicit transfer/step/readback phases
-        so the seam cost is measurable (transfer_GBps, device-step-ms)
-        and scorer spans can split queue/device/transfer out. Pays two
-        device barriers per batch — opt-in via ``timing_enabled`` only;
-        the line-rate path is ``score`` above."""
-        n = len(x)
-        t_submit = time.monotonic()
-        xn = self._prep(x)
-        # capture the (mu, var) pair BEFORE dispatching to the worker
-        # thread: a concurrent fit() repoints both mirrors, and reading
-        # them from the thread could tear the pair (new mu, old var)
-        mu_d, var_d = self._mu_d, self._var_d
-        params = self.params
-
-        def run() -> np.ndarray:
-            import jax
-            t0 = time.monotonic()
-            xd = jax.block_until_ready(  # l5d: ignore[jax-hotpath] — instrumented path: the barriers ARE the measurement
-                jax.device_put(xn, self._batch_placement()))  # l5d: ignore[jax-hotpath] — instrumented path: fresh per-call transfer, measured deliberately
-            t1 = time.monotonic()
-            import warnings
-
-            from linkerd_tpu.telemetry.linerate import (
-                _DONATION_DECLINED_MSG,
-            )
-            with warnings.catch_warnings():
-                # first-compile of a bucket may happen here instead of
-                # on the ring path; same expected donation-decline note
-                warnings.filterwarnings(
-                    "ignore", message=_DONATION_DECLINED_MSG)
-                r = jax.block_until_ready(  # l5d: ignore[jax-hotpath] — instrumented path: device-step barrier, measured deliberately
-                    self._scorer(params, xd, mu_d, var_d))
-            t2 = time.monotonic()
-            out = np.asarray(r, dtype=np.float32)[:n]  # l5d: ignore[jax-hotpath] — instrumented path: host readback timed deliberately
-            t3 = time.monotonic()
-            self._note_timing(
-                queue_ms=(t0 - t_submit) * 1e3,
-                transfer_ms=(t1 - t0 + t3 - t2) * 1e3,
-                device_ms=(t2 - t1) * 1e3,
-                nbytes=xn.nbytes + out.nbytes)
-            return out
-
-        return await asyncio.to_thread(run)  # l5d: ignore[jax-hotpath] — opt-in instrumented path only; the serving path is the donated ring dispatch
+        return await self._dispatcher.dispatch(x, step, put)
 
     async def fit(self, x: np.ndarray, labels: np.ndarray,
                   mask: np.ndarray) -> float:
+        rec = phases.Call(phases.FIT)
+        try:
+            return await self._fit(x, labels, mask, rec)
+        finally:
+            rec.close()
+
+    async def _fit(self, x, labels, mask, rec: phases.Call) -> float:
         n = len(x)
+        rec.count("fit.calls")
         self._update_norm(x, labels, mask)
+        rec.mark(phases.UPDATE_NORM)
         xn = self._prep(x)
         labels = self._pad_rows(np.asarray(labels, np.float32))
         mask = self._pad_rows(np.asarray(mask, np.float32))
@@ -714,17 +657,28 @@ class InProcessScorer(Scorer):
         mu_d, var_d = self._mu_d, self._var_d  # consistent pair (see score)
         shape = f"{len(xn)}+mask" if row_mask is not None else str(len(xn))
         self._fit_batches[shape] = self._fit_batches.get(shape, 0) + 1
+        # the host arrays each step is handed, and so ships
+        shipped = sum(a.nbytes for a in (xn, labels, mask, row_mask)
+                      if a is not None)
+        rec.mark(phases.PREP)
 
         def run() -> float:
+            rec.mark(phases.THREAD_HOP)
             loss = float("nan")
             for _ in range(self.fit_steps):
                 self.params, self._opt_state, loss = self._train_step(
                     self.params, self._opt_state, xn, labels, mask,
                     row_mask, mu_d, var_d)
+                rec.count("fit.shipped_bytes", shipped)
+                rec.mark(phases.STEP)
             self._step += self.fit_steps
-            return float(loss)
+            loss = float(loss)
+            rec.mark(phases.LOSS_WAIT)
+            return loss
 
-        return await asyncio.to_thread(run)
+        loss = await asyncio.to_thread(run)
+        rec.mark(phases.RETURN_HOP)
+        return loss
 
     def close(self) -> None:
         self._dispatcher.close()
@@ -1199,28 +1153,15 @@ class JaxAnomalyTelemeter(Telemeter):
         if rows > 0:
             self._wake.set()
 
-    # with a span sink installed, 1-in-N batches pay the instrumented
-    # two-barrier timing path; the other N-1 keep the line-rate ring
-    # and span tags reuse the last sampled decomposition
-    TIMING_SAMPLE_EVERY = 16
-
     def set_tracer(self, tracer) -> None:
         """Install the linker's span sink (called after telemeter
         assembly — the broadcast tracer is built FROM telemeters, so it
-        cannot exist when this one is constructed). With a sink in
-        place the scorer's phase-split timing pays for itself, so it is
-        switched on — SAMPLED, so the serving path stays on the
-        donated ring."""
+        cannot exist when this one is constructed). The scorer spans
+        take their tags from the scorer's ``last_timing``: the ring
+        path's own phase record, which every batch writes."""
         self._span_sink = tracer
         if self.control is not None and tracer is not None:
             self.control.set_tracer(tracer)
-        if self._scorer is not None and tracer is not None:
-            self._enable_sampled_timing(self._scorer)
-
-    def _enable_sampled_timing(self, scorer) -> None:
-        scorer.timing_enabled = True
-        if hasattr(scorer, "timing_sample_every"):
-            scorer.timing_sample_every = self.TIMING_SAMPLE_EVERY
 
     # -- Telemeter --------------------------------------------------------
     def _mk_inprocess(self) -> "InProcessScorer":
@@ -1288,11 +1229,6 @@ class JaxAnomalyTelemeter(Telemeter):
                                                 resilient)
             else:
                 self._scorer = self._mk_inprocess()
-            if self._span_sink is not None:
-                # spans consume the decomposition: turn on phase-split
-                # timing (a no-op attribute on backends without it),
-                # sampled so the line-rate path keeps the ring
-                self._enable_sampled_timing(self._scorer)
         return self._scorer
 
     def _set_degraded(self, degraded: bool) -> None:

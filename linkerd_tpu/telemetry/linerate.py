@@ -39,6 +39,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from linkerd_tpu.telemetry import phases
+
 log = logging.getLogger(__name__)
 
 # On backends/shapes where XLA cannot fold the donated [B, D] input
@@ -95,16 +97,21 @@ class _Slot:
 class RingDispatcher:
     """Persistent double-buffered score dispatch.
 
-    ``dispatch(x, step)`` copies ``x`` (float32 [n, D]) into a
-    preallocated staging buffer for the padded batch bucket, hands the
-    buffer to ``step`` (which places it on device and invokes the
-    DONATING jitted score step — async dispatch, no barrier), and
-    returns an awaitable resolved by the background drainer thread once
-    readback completes. Two slots per bucket: batch N fills slot B
-    while slot A's transfer+compute+readback chain is in flight.
+    ``dispatch(x, step, put)`` copies ``x`` ([n, D], cast to float32 by
+    the copy) into a preallocated staging buffer for the padded batch
+    bucket, hands the buffer to ``put`` (which places it on device),
+    hands that to ``step`` (which invokes the DONATING jitted score step
+    — async dispatch, no barrier), and returns an awaitable resolved by
+    the background drainer thread once readback completes. Two slots per
+    bucket: batch N fills slot B while slot A's
+    transfer+compute+readback chain is in flight.
 
-    Donation rules: ``step`` receives the staging buffer and must hand
-    its device copy to a step compiled with ``donate_argnums`` —
+    Every call is one ``phases.Call`` record, stamped at each of those
+    boundaries on the loop and on the drainer and closed when the call
+    resolves or fails; ``last`` is the newest one closed.
+
+    Donation rules: ``step`` must hand the device copy of the staging
+    buffer to a step compiled with ``donate_argnums`` —
     neither the dispatcher nor any caller may re-read the device array
     after dispatch (JAX deletes donated buffers; re-reads raise).
     Staging rows beyond ``n`` may hold stale rows from earlier batches;
@@ -120,6 +127,8 @@ class RingDispatcher:
         self._slots: Dict[int, List[_Slot]] = {}
         # batches dispatched per padded bucket (event-loop thread only)
         self.batches: Dict[int, int] = {}
+        # the phase record of the newest call closed
+        self.last: Optional[phases.Call] = None
         self._waiters: List[Tuple[int, asyncio.AbstractEventLoop,
                                   asyncio.Future]] = []
         self._lock = threading.Lock()
@@ -144,16 +153,26 @@ class RingDispatcher:
             item = self._queue.get()
             if item is None:
                 return
-            result, n, loop, fut, slot = item
+            result, n, loop, fut, slot, rec = item
+            rec.mark(phases.QUEUE_WAIT)
             out: Optional[np.ndarray] = None
             err: Optional[BaseException] = None
             try:
-                # the ONLY blocking readback on the score path, and it
-                # blocks this drainer thread, never the event loop
-                out = np.asarray(result, dtype=np.float32)[:n].copy()
+                # the ONLY blocking wait and readback on the score path,
+                # and they block this drainer thread, never the event
+                # loop. The barrier adds none: the readback blocked here
+                # already; it only divides that one wait in two
+                ready = getattr(result, "block_until_ready", None)
+                if ready is not None:
+                    ready()
+                rec.mark(phases.DEVICE_WAIT)
+                scores = np.asarray(result, dtype=np.float32)
+                rec.count("readback.bytes", scores.nbytes)
+                out = scores[:n].copy()
             except BaseException as e:  # noqa: BLE001 — surfaced via fut
                 err = e
             self._release(slot)
+            rec.mark(phases.READBACK)
             try:
                 if err is None:
                     loop.call_soon_threadsafe(self._resolve, fut, out)
@@ -186,7 +205,7 @@ class RingDispatcher:
                 return s
         return None
 
-    async def _acquire(self, bucket: int) -> _Slot:
+    async def _acquire(self, bucket: int, rec: phases.Call) -> _Slot:
         loop = asyncio.get_running_loop()
         while True:
             waiter: Optional[asyncio.Future] = None
@@ -197,6 +216,7 @@ class RingDispatcher:
                     self._waiters.append((bucket, loop, waiter))
             if slot is not None:
                 return slot
+            rec.counts["slot.waits"] = 1  # once, however often it is woken
             await waiter  # backpressure: both slots in flight
 
     def _release(self, slot: _Slot) -> None:
@@ -227,32 +247,56 @@ class RingDispatcher:
 
     # -- dispatch ---------------------------------------------------------
     async def dispatch(self, x: np.ndarray,
-                       step: Callable[[np.ndarray], object]) -> np.ndarray:
+                       step: Callable[[object], object],
+                       put: Callable[[np.ndarray], object]) -> np.ndarray:
         """Score one batch through the donated ring; returns f32 [n]."""
         if self._closed:
             raise RuntimeError("dispatcher closed")
+        rec = phases.Call(phases.SCORE)
+        try:
+            return await self._dispatch(x, step, put, rec)
+        finally:
+            # a raised call closes too, with the stamps it got to
+            self.last = rec.close()
+
+    async def _dispatch(self, x, step, put, rec: phases.Call) -> np.ndarray:
         n = len(x)
         loop = asyncio.get_running_loop()
         bucket = int(self._bucket_fn(n))
-        slot = await self._acquire(bucket)
+        slot = await self._acquire(bucket, rec)
+        rec.mark(phases.SLOT_WAIT)
         if self._closed:  # re-check: close() may have raced the acquire
             self._release(slot)
             raise RuntimeError("dispatcher closed")
         try:
             np.copyto(slot.staging[:n], x, casting="unsafe")
+            rec.mark(phases.STAGE)
+            xd = put(slot.staging)
+            rec.mark(phases.PUT)
             with warnings.catch_warnings():
                 warnings.filterwarnings(
                     "ignore", message=_DONATION_DECLINED_MSG)
                 # async dispatch; the step donates the device copy
-                result = step(slot.staging)
+                result = step(xd)
         except BaseException:
             self._release(slot)
             raise
         self.batches[bucket] = self.batches.get(bucket, 0) + 1
+        rec.count("score.calls")
+        rec.count("put.bytes", slot.staging.nbytes)
         fut = loop.create_future()
         self._ensure_thread()
-        self._queue.put((result, n, loop, fut, slot))
-        return await fut
+        # stamped ahead of the hand-over: past it the record is the
+        # drainer's to stamp
+        rec.mark(phases.LAUNCH)
+        self._queue.put((result, n, loop, fut, slot, rec))
+        try:
+            return await fut
+        finally:
+            # cancelled here, the call made no hop: the drainer still
+            # holds it
+            if not fut.cancelled():
+                rec.mark(phases.HOP)
 
     def close(self) -> None:
         self._closed = True
@@ -269,7 +313,7 @@ class RingDispatcher:
                 break
             if item is None:
                 continue
-            _result, _n, loop, fut, slot = item
+            _result, _n, loop, fut, slot, _rec = item
             self._release(slot)
             try:
                 loop.call_soon_threadsafe(
@@ -519,24 +563,6 @@ class TieredScorer:
     @property
     def last_timing(self):
         return getattr(self.primary, "last_timing", None)
-
-    @property
-    def timing_enabled(self) -> bool:
-        return bool(getattr(self.primary, "timing_enabled", False))
-
-    @timing_enabled.setter
-    def timing_enabled(self, v: bool) -> None:
-        if hasattr(self.primary, "timing_enabled"):
-            self.primary.timing_enabled = v
-
-    @property
-    def timing_sample_every(self) -> int:
-        return int(getattr(self.primary, "timing_sample_every", 1))
-
-    @timing_sample_every.setter
-    def timing_sample_every(self, v: int) -> None:
-        if hasattr(self.primary, "timing_sample_every"):
-            self.primary.timing_sample_every = v
 
     @property
     def _step(self):

@@ -1,0 +1,97 @@
+"""The phase clock of the accelerator tier's host path: an in-memory log
+of where each ``score`` and ``fit`` call spent its time, written on the
+path that serves, always on.
+
+A call is one ``Call``: stamped at entry, then ``mark(name)`` at each
+boundary (one stamp ends phase ``name`` and begins the next, so the
+phases tile the call) and ``count(name, n)`` for what it moved, then
+``close()``, which appends a frozen copy to the process-wide log (the
+newest ``LOG_CAPACITY`` calls). Times are ``time.monotonic()``. The log
+outlives the scorer, so a reader that no longer holds one picks calls by
+when they began.
+
+Counts, each on the call that made it: ``score.calls``, ``put.bytes``
+(handed to ``device_put``: the whole padded bucket), ``slot.waits`` (a
+dispatch that found no free slot), ``readback.bytes``; ``fit.calls``,
+``fit.shipped_bytes`` (host arrays handed to the train step, over its
+steps).
+
+Score call (``RingDispatcher.dispatch``), in order: SLOT_WAIT, STAGE,
+PUT, LAUNCH on the event loop; QUEUE_WAIT, DEVICE_WAIT, READBACK on the
+drainer; HOP back onto the loop, up to the awaiting coroutine having its
+result. Fit call (``InProcessScorer.fit``): UPDATE_NORM, PREP on the
+loop; THREAD_HOP, STEP x ``fit_steps``, LOSS_WAIT on the worker thread;
+RETURN_HOP back onto the loop.
+"""
+
+from __future__ import annotations
+
+import collections
+import copy
+import threading
+import time
+from typing import Dict, Iterator, List, Sequence, Tuple
+
+LOG_CAPACITY = 4096
+
+SCORE, FIT = "score", "fit"
+SLOT_WAIT, STAGE = "dispatch.slot_wait", "dispatch.stage"
+PUT, LAUNCH = "dispatch.put", "dispatch.launch"
+QUEUE_WAIT, DEVICE_WAIT = "drain.queue_wait", "drain.device_wait"
+READBACK, HOP = "drain.readback", "drain.hop"
+UPDATE_NORM, PREP = "fit.update_norm", "fit.prep"
+THREAD_HOP, STEP = "fit.thread_hop", "fit.step"
+LOSS_WAIT, RETURN_HOP = "fit.loss_wait", "fit.return_hop"
+SCORE_PHASES = (SLOT_WAIT, STAGE, PUT, LAUNCH, QUEUE_WAIT, DEVICE_WAIT,
+                READBACK, HOP)
+
+_log: "collections.deque[Call]" = collections.deque(maxlen=LOG_CAPACITY)
+_lock = threading.Lock()
+
+
+class Call:
+    """One ``score`` or ``fit`` call. One thread writes it at a time: the
+    hand-overs between loop, drainer and worker order the writes."""
+
+    __slots__ = ("kind", "t0", "marks", "counts")
+
+    def __init__(self, kind: str):
+        self.kind = kind
+        self.marks: Sequence[Tuple[str, float]] = []
+        self.counts: Dict[str, int] = {}
+        self.t0 = time.monotonic()
+
+    def mark(self, name: str) -> None:
+        self.marks.append((name, time.monotonic()))
+
+    def count(self, name: str, n: int = 1) -> None:
+        self.counts[name] = self.counts.get(name, 0) + n
+
+    def close(self) -> "Call":
+        """Log the call as it stands and return what was logged: a copy
+        no one writes. A drainer or worker whose awaiter was cancelled
+        goes on stamping this one, which no one reads any more."""
+        done = copy.copy(self)
+        done.marks, done.counts = tuple(self.marks), dict(self.counts)
+        with _lock:
+            _log.append(done)
+        return done
+
+    def spans(self) -> Iterator[Tuple[str, float, float]]:
+        """``(name, start, end)`` of the phases, in the order stamped: the
+        first begins at ``t0``, each at the end of the one before."""
+        start = self.t0
+        for name, t in self.marks:
+            yield (name, start, t)
+            start = t
+
+    def ms(self, *names: str) -> float:
+        """Milliseconds this call spent in the named phases."""
+        return 1e3 * sum(end - start for name, start, end in self.spans()
+                         if name in names)
+
+
+def records() -> List[Call]:
+    """The log as it stands, oldest first."""
+    with _lock:
+        return list(_log)

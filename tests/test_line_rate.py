@@ -30,6 +30,11 @@ def run(coro):
     return asyncio.run(asyncio.wait_for(coro, 120))
 
 
+def put(staging):
+    """Stub placement for a stub step: the staging buffer as it is."""
+    return staging
+
+
 class TestRingDispatcher:
     def test_dispatch_returns_scores_and_reuses_staging(self):
         calls = []
@@ -41,9 +46,10 @@ class TestRingDispatcher:
         async def go():
             d = RingDispatcher(4, lambda n: 8)
             try:
-                out1 = await d.dispatch(np.ones((3, 4), np.float32), step)
+                out1 = await d.dispatch(np.ones((3, 4), np.float32), step,
+                                        put)
                 out2 = await d.dispatch(
-                    np.full((3, 4), 2.0, np.float32), step)
+                    np.full((3, 4), 2.0, np.float32), step, put)
                 assert out1.shape == (3,) and (out1 == 4.0).all()
                 assert (out2 == 8.0).all()
                 # double-buffered: two dispatches of one bucket use the
@@ -79,11 +85,11 @@ class TestRingDispatcher:
 
             try:
                 t1 = asyncio.ensure_future(
-                    d.dispatch(np.ones((2, 2), np.float32), step))
+                    d.dispatch(np.ones((2, 2), np.float32), step, put))
                 t2 = asyncio.ensure_future(
-                    d.dispatch(np.ones((2, 2), np.float32), step))
+                    d.dispatch(np.ones((2, 2), np.float32), step, put))
                 t3 = asyncio.ensure_future(
-                    d.dispatch(np.ones((2, 2), np.float32), step))
+                    d.dispatch(np.ones((2, 2), np.float32), step, put))
                 await asyncio.sleep(0.05)
                 # only two slots exist: the third dispatch must wait
                 assert len(inflight) == 2
@@ -108,7 +114,7 @@ class TestRingDispatcher:
                     # leaked slot would deadlock the later attempts
                     with pytest.raises(RuntimeError):
                         await d.dispatch(np.ones((2, 2), np.float32),
-                                         boom)
+                                         boom, put)
             finally:
                 d.close()
 
@@ -120,7 +126,7 @@ class TestRingDispatcher:
             d.close()
             with pytest.raises(RuntimeError):
                 await d.dispatch(np.ones((1, 2), np.float32),
-                                 lambda s: s)
+                                 lambda s: s, put)
 
         run(go())
 
@@ -139,14 +145,14 @@ class TestDonationSafety:
             dev = jax.devices()[0]
             captured = []
 
-            def step(staging):
+            def place(staging):
                 xd = jax.device_put(staging, dev)
                 captured.append(xd)
-                return donating(xd)
+                return xd
 
             try:
                 out = await d.dispatch(
-                    np.ones((4, 4), np.float32), step)
+                    np.ones((4, 4), np.float32), donating, place)
                 assert (out == 2.0).all()
                 (xd,) = captured
                 assert xd.is_deleted()
@@ -725,31 +731,6 @@ class TestFastpathNativeFeed:
                     np.zeros((4, 2 * NATIVE_ROW_WIDTH), np.float32)[:, ::2])
         finally:
             eng.close()
-
-
-class TestSampledTiming:
-    def test_span_sink_timing_is_sampled_not_per_batch(self):
-        """With a span sink installed, only 1-in-N batches pay the
-        instrumented two-barrier path; the rest stay on the ring. The
-        FIRST batch is always sampled so span tags exist immediately."""
-
-        async def go():
-            tele = JaxAnomalyTelemeter(
-                JaxAnomalyConfig(trainEveryBatches=0), MetricsTree())
-            tele.set_tracer(lambda span: None)  # any sink-shaped object
-            scorer = tele._ensure_scorer()
-            assert scorer.timing_enabled
-            assert scorer.timing_sample_every == \
-                JaxAnomalyTelemeter.TIMING_SAMPLE_EVERY
-            x = np.zeros((8, scorer.cfg.in_dim), np.float32)
-            for _ in range(8):
-                await scorer.score(x)
-            # exactly one timed call in the first 8 (the first)
-            assert scorer.timing_totals["calls"] == 1
-            assert scorer.last_timing is not None
-            tele.close()
-
-        run(go())
 
 
 class TestTieredFit:

@@ -20,9 +20,9 @@ The rule flags these calls inside functions REACHABLE from the score
 dispatch roots (``score``, ``dispatch*``, ``drain_once``,
 ``_score_and_publish``) through same-module call edges, including
 nested defs/lambdas (closures handed to the dispatcher execute on the
-path). Deliberate uses — the opt-in instrumented timing path, the
-staging-buffer placement inside the dispatcher's step closure, host-side
-dtype casts that are not readbacks — carry the usual justified
+path). Deliberate uses — the staging-buffer placement inside the
+dispatcher's put closure, host-side dtype casts that are not readbacks
+— carry the usual justified
 ``# l5d: ignore[jax-hotpath] — why``.
 """
 
