@@ -81,6 +81,39 @@ def normalize_features(x: jax.Array, mu: jax.Array, var: jax.Array) -> jax.Array
     return (x - mu) * jax.lax.rsqrt(var + 1e-2)
 
 
+def running_norm(norm, x: jax.Array, labels: jax.Array, mask: jax.Array,
+                 row_mask: Optional[jax.Array] = None, *, momentum: float):
+    """The running normalization statistics after one fit's batch:
+    ``norm`` is ``(mu, var, initialised)``, the result the same triple.
+
+    Mean and variance are taken over the rows NOT labelled anomalous
+    (``mask == 0`` or ``labels == 0``; ``row_mask`` leaves a padded
+    batch's padding rows out as well), in float32 and in two passes — the
+    variance about the batch's own mean, because raw columns run into the
+    thousands and E[x^2] - E[x]^2 would cancel — plus 1e-6. The first
+    batch with such a row sets the pair, later ones blend in with
+    ``momentum``; a batch with none leaves pair and flag as they were.
+    Everything, the flag included, is decided on the device: the caller
+    never waits to learn it. Jitted by ``InProcessScorer`` as a program of
+    its own, on the batch its train steps read; sharded over ``data`` the
+    sums reduce across the mesh."""
+    mu0, var0, initialized = norm
+    w = ((mask == 0.0) | (labels == 0.0)).astype(jnp.float32)
+    if row_mask is not None:
+        w = w * row_mask
+    w = w[:, None]
+    count = jnp.sum(w)
+    seen = count > 0
+    n = jnp.maximum(count, 1.0)
+    mu = jnp.sum(w * x, axis=0) / n
+    var = jnp.sum(w * jnp.square(x - mu), axis=0) / n + 1e-6
+    blend = seen & initialized
+    mu = jnp.where(blend, (1 - momentum) * mu0 + momentum * mu, mu)
+    var = jnp.where(blend, (1 - momentum) * var0 + momentum * var, var)
+    return (jnp.where(seen, mu, mu0), jnp.where(seen, var, var0),
+            initialized | seen)
+
+
 def _mlp(layers, x: jax.Array, dtype, final_act: bool) -> jax.Array:
     n = len(layers)
     for i, layer in enumerate(layers):

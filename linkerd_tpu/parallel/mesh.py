@@ -73,9 +73,10 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-def batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Batch rows over the data axis; feature dim replicated."""
-    return NamedSharding(mesh, P("data", None))
+def batch_sharding(mesh: Mesh, ndim: int = 2) -> NamedSharding:
+    """Batch rows over the data axis; feature dim replicated (a fit's
+    labels and masks, ``ndim`` 1, have none)."""
+    return NamedSharding(mesh, P("data", *(None,) * (ndim - 1)))
 
 
 def shard_batch(mesh: Mesh, x: np.ndarray) -> jax.Array:
@@ -90,9 +91,9 @@ def shard_batch(mesh: Mesh, x: np.ndarray) -> jax.Array:
     its slice — transfers are per-shard and the assembly is metadata
     only. The batch dim must divide evenly over the data axis (callers
     pad via ``_pad_rows``; ``bucket_rows`` already rounds to a multiple
-    of the mesh's data size).
+    of the mesh's data size). A fit's per-row vectors go the same way.
     """
-    sh = batch_sharding(mesh)
+    sh = batch_sharding(mesh, x.ndim)
     idx_map = sh.addressable_devices_indices_map(x.shape)
     shards = [jax.device_put(x[idx], d) for d, idx in idx_map.items()]
     return jax.make_array_from_single_device_arrays(x.shape, sh, shards)

@@ -360,23 +360,27 @@ class InProcessScorer(Scorer):
         # Running feature normalization (updated on non-anomalous training
         # rows): without it the autoencoder's reconstruction error is
         # dominated by raw feature scale and tanh() saturates for normal
-        # AND anomalous traffic alike. The host keeps the authoritative
-        # numpy stats (cheap EWMA over a few rows); device mirrors feed
-        # the jitted steps, which apply models.anomaly.normalize_features
-        # on device — the z-score with its 1e-2 soft variance floor (a
-        # near-constant training dim must register novelty as a LARGE
-        # z-score, not a 1e3-sigma blowup; hard clipping cost ~0.15 AUC
-        # on the k8s-restart benchmark).
-        self._mu = np.zeros(self.cfg.in_dim, np.float32)
-        self._var = np.ones(self.cfg.in_dim, np.float32)
+        # AND anomalous traffic alike. The statistics live ON THE DEVICE
+        # as one triple ``(mu, var, initialised)``: a fit reduces its
+        # batch's mean and variance there (``_norm_step``) from the copy
+        # of the batch its train steps read, and the jitted steps apply
+        # models.anomaly.normalize_features there too — the z-score with
+        # its 1e-2 soft variance floor (a near-constant training dim must
+        # register novelty as a LARGE z-score, not a 1e3-sigma blowup;
+        # hard clipping cost ~0.15 AUC on the k8s-restart benchmark). The
+        # host never reads a batch; ``snapshot()`` fetches the triple.
+        # ONE attribute, stored once: ``score`` captures a fit's pair
+        # whole, never a new ``mu`` beside an old ``var``.
         self._norm_momentum = 0.2
-        self._norm_initialized = False
+        self._norm_step = self._mk_norm_step()
+        self._norm = self._put_norm(np.zeros(self.cfg.in_dim, np.float32),
+                                    np.ones(self.cfg.in_dim, np.float32),
+                                    False)
         # persistent double-buffered staging ring (the line-rate
         # dispatch path; see class docstring)
         from linkerd_tpu.telemetry.linerate import RingDispatcher
         self._dispatcher = RingDispatcher(self.cfg.in_dim,
                                           self._bucket_target)
-        self._place_norm()
 
     @property
     def last_timing(self) -> Optional[dict]:
@@ -415,11 +419,19 @@ class InProcessScorer(Scorer):
             "fit_batches": dict(self._fit_batches),
         }
 
-    def _place_norm(self) -> None:
-        """Refresh the device mirrors of the normalization stats: tiny
-        replicated arrays the jitted score/train steps consume so the
-        whole normalize->score pipeline runs on device (each data-axis
-        shard z-scores its own rows; the host never touches the batch)."""
+    def _put_rows(self, arr: np.ndarray):
+        """Place a host array whose leading axis is the batch's rows: on
+        the pinned device, or each data-axis shard on its own device.
+        Returns before the bytes move."""
+        if self.mesh is not None:
+            from linkerd_tpu.parallel.mesh import shard_batch
+            return shard_batch(self.mesh, arr)
+        import jax
+        return jax.device_put(arr, self._devices[0])  # l5d: ignore[jax-hotpath] — async placement: the score path's persistent staging buffer (donated to the step, never re-read) or a fit's batch, once a fit
+
+    def _put_norm(self, mu, var, initialized):
+        """The statistics triple as the jitted steps take it: tiny
+        arrays on the pinned device, replicated over the mesh."""
         import jax
 
         if self.mesh is not None:
@@ -427,27 +439,36 @@ class InProcessScorer(Scorer):
             placement = replicated(self.mesh)
         else:
             placement = self._devices[0]
-        self._mu_d = jax.device_put(self._mu, placement)
-        self._var_d = jax.device_put(self._var, placement)
+        return jax.device_put((np.asarray(mu, np.float32),
+                               np.asarray(var, np.float32),
+                               np.bool_(initialized)), placement)
 
-    def _update_norm(self, x: np.ndarray, labels: np.ndarray,
-                     mask: np.ndarray) -> None:
-        # learn the "normal" distribution: exclude rows labeled anomalous
-        normal = x[(mask == 0.0) | (labels == 0.0)]
-        if len(normal) == 0:
-            return
-        mu = normal.mean(axis=0)
-        var = normal.var(axis=0) + 1e-6
-        if not self._norm_initialized:
-            self._mu, self._var = mu, var
-            self._norm_initialized = True
-        else:
-            m = self._norm_momentum
-            self._mu = (1 - m) * self._mu + m * mu
-            self._var = (1 - m) * self._var + m * var
-        self._mu = np.asarray(self._mu, np.float32)
-        self._var = np.asarray(self._var, np.float32)
-        self._place_norm()
+    @property
+    def _norm_initialized(self) -> bool:
+        """Whether a fit has set the statistics yet. Blocking (reads the
+        device's flag): for snapshots and tests, not the serving path."""
+        return bool(self._norm[2])
+
+    def _mk_norm_step(self):
+        """The statistics program (``models.anomaly.running_norm``): a
+        jitted program of its own, apart from the train step, run once a
+        fit on the fit's resident batch."""
+        import jax
+        from linkerd_tpu.models.anomaly import running_norm
+
+        momentum = self._norm_momentum
+
+        # a def and not a partial: the program's name in a trace is
+        # ``jit_norm_step`` (a partial's is ``jit__unknown``)
+        def norm_step(norm, x, labels, mask, row_mask=None):
+            return running_norm(norm, x, labels, mask, row_mask,
+                                momentum=momentum)
+
+        if self.mesh is not None:
+            from linkerd_tpu.parallel.mesh import replicated
+            # XLA inserts the reduction over the data axis
+            return jax.jit(norm_step, out_shardings=replicated(self.mesh))
+        return jax.jit(norm_step)
 
     def _mk_train_step(self):
         import jax
@@ -507,13 +528,13 @@ class InProcessScorer(Scorer):
         from linkerd_tpu.lifecycle.store import ModelSnapshot
 
         params = jax.device_get(self.params)
+        mu, var, initialized = jax.device_get(self._norm)
         opt_leaves = [np.asarray(leaf) for leaf in
                       jax.tree_util.tree_leaves(
                           jax.device_get(self._opt_state))]
         return ModelSnapshot(
             params=params, opt_leaves=opt_leaves,
-            mu=self._mu.copy(), var=self._var.copy(),
-            norm_initialized=self._norm_initialized,
+            mu=mu, var=var, norm_initialized=bool(initialized),
             step=self._step, cfg=self.cfg)
 
     def restore(self, snap) -> None:
@@ -554,10 +575,8 @@ class InProcessScorer(Scorer):
                                              self._devices[0]))
             self.params = params
             self._opt_state = jax.tree_util.tree_unflatten(treedef, placed)
-        self._mu = np.asarray(snap.mu, np.float32).copy()
-        self._var = np.asarray(snap.var, np.float32).copy()
-        self._norm_initialized = bool(snap.norm_initialized)
-        self._place_norm()
+        self._norm = self._put_norm(snap.mu, snap.var,
+                                    snap.norm_initialized)
         self._step = int(snap.step)
 
     def swap(self, snap):
@@ -576,7 +595,7 @@ class InProcessScorer(Scorer):
         rows = max(rows, self._batch_multiple, 1)
         x = np.zeros((rows, self.cfg.in_dim), np.float32)
         params, opt_state = self.params, self._opt_state
-        mu, var, init = self._mu, self._var, self._norm_initialized
+        norm = self._norm
         step = self._step
         try:
             await self.score(x)
@@ -589,14 +608,13 @@ class InProcessScorer(Scorer):
             # startup-sequenced: warmup runs before the telemeter's drain
             # loop starts, so no concurrent fit/score exists to clobber
             self.params, self._opt_state = params, opt_state  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
-            self._mu, self._var, self._norm_initialized = mu, var, init  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
-            self._place_norm()
+            self._norm = norm  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
             self._step = step  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
 
     def _prep(self, x: np.ndarray) -> np.ndarray:
         """Pad + cast to the f32 transfer dtype. Raw features ship as-is:
-        normalization happens ON DEVICE inside the jitted step (mu/var
-        mirrors via _place_norm), fused into the first matmul's producer
+        normalization happens ON DEVICE inside the jitted step (under the
+        device's own mu/var), fused into the first matmul's producer
         — so f32 precision is kept through the z-score (raw latencies in
         the thousands would lose mantissa bits if cast to bf16 before
         subtracting mu) and the sharded path normalizes each batch shard
@@ -613,23 +631,16 @@ class InProcessScorer(Scorer):
         in-flight donated batch always completes against a consistent
         model."""
         params = self.params
-        mu_d, var_d = self._mu_d, self._var_d
-        if self.mesh is not None:
-            from linkerd_tpu.parallel.mesh import shard_batch
-            mesh = self.mesh
-
-            def put(staging: np.ndarray):
-                # per-device shard feed; the assembled array is donated
-                return shard_batch(mesh, staging)
-        else:
-            dev = self._devices[0]
-
-            def put(staging: np.ndarray):
-                import jax
-                return jax.device_put(staging, dev)  # l5d: ignore[jax-hotpath] — async placement of the persistent staging buffer; donated to the step, never re-read
+        mu_d, var_d, _ = self._norm     # one read: one fit's pair
 
         def step(xd):
             return self._scorer(params, xd, mu_d, var_d)
+
+        def put(staging: np.ndarray):
+            # per-device shard feed on the mesh; the array is donated. A
+            # call and not the bound method handed on: the jax-hotpath
+            # lint follows calls, and this placement is on its path
+            return self._put_rows(staging)
 
         return await self._dispatcher.dispatch(x, step, put)
 
@@ -642,34 +653,39 @@ class InProcessScorer(Scorer):
             rec.close()
 
     async def _fit(self, x, labels, mask, rec: phases.Call) -> float:
+        """The batch goes to the device ONCE: its statistics are reduced
+        there and its ``fit_steps`` train steps read the same resident
+        arrays. Placement, the statistics program's launch and the
+        repointing of ``_norm`` all return before a byte moves and all
+        happen before this coroutine first yields, so no call can begin
+        between a fit's start and its statistics; the device runs its
+        queue in order, so a score launched afterwards meets them."""
         n = len(x)
         rec.count("fit.calls")
-        self._update_norm(x, labels, mask)
-        rec.mark(phases.UPDATE_NORM)
         xn = self._prep(x)
         labels = self._pad_rows(np.asarray(labels, np.float32))
         mask = self._pad_rows(np.asarray(mask, np.float32))
-        # row_mask excludes the padding rows from BOTH loss terms so the
-        # sharded and single-chip paths train on the same objective
+        # row_mask excludes the padding rows from the statistics and from
+        # BOTH loss terms so the sharded and single-chip paths train on
+        # the same objective
         row_mask = (self._pad_rows(np.ones(n, np.float32))
                     if len(xn) != n else None)
-
-        mu_d, var_d = self._mu_d, self._var_d  # consistent pair (see score)
+        host = (xn, labels, mask, row_mask)
         shape = f"{len(xn)}+mask" if row_mask is not None else str(len(xn))
         self._fit_batches[shape] = self._fit_batches.get(shape, 0) + 1
-        # the host arrays each step is handed, and so ships
-        shipped = sum(a.nbytes for a in (xn, labels, mask, row_mask)
-                      if a is not None)
         rec.mark(phases.PREP)
+        batch = [None if a is None else self._put_rows(a) for a in host]
+        rec.count("fit.shipped_bytes",
+                  sum(a.nbytes for a in host if a is not None))
+        norm = self._norm = self._norm_step(self._norm, *batch)
+        rec.mark(phases.UPDATE_NORM)
 
         def run() -> float:
             rec.mark(phases.THREAD_HOP)
             loss = float("nan")
             for _ in range(self.fit_steps):
                 self.params, self._opt_state, loss = self._train_step(
-                    self.params, self._opt_state, xn, labels, mask,
-                    row_mask, mu_d, var_d)
-                rec.count("fit.shipped_bytes", shipped)
+                    self.params, self._opt_state, *batch, norm[0], norm[1])
                 rec.mark(phases.STEP)
             self._step += self.fit_steps
             loss = float(loss)

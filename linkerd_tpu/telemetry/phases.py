@@ -13,15 +13,20 @@ when they began.
 Counts, each on the call that made it: ``score.calls``, ``put.bytes``
 (handed to ``device_put``: the whole padded bucket), ``slot.waits`` (a
 dispatch that found no free slot), ``readback.bytes``; ``fit.calls``,
-``fit.shipped_bytes`` (host arrays handed to the train step, over its
-steps).
+``fit.shipped_bytes`` (the padded host arrays a fit places on the
+device: rows, labels, mask and, where rows were padded, the row mask;
+once a fit, whatever ``fit_steps``).
 
 Score call (``RingDispatcher.dispatch``), in order: SLOT_WAIT, STAGE,
 PUT, LAUNCH on the event loop; QUEUE_WAIT, DEVICE_WAIT, READBACK on the
 drainer; HOP back onto the loop, up to the awaiting coroutine having its
-result. Fit call (``InProcessScorer.fit``): UPDATE_NORM, PREP on the
-loop; THREAD_HOP, STEP x ``fit_steps``, LOSS_WAIT on the worker thread;
-RETURN_HOP back onto the loop.
+result. Fit call (``InProcessScorer.fit``): PREP (pad and cast), then
+UPDATE_NORM (the batch placed on the device, the statistics program
+launched, the scorer's statistics repointed: calls that return before a
+byte moves) on the loop, before the coroutine first yields; THREAD_HOP,
+STEP x ``fit_steps`` (each train-step call, on the resident batch),
+LOSS_WAIT (the one wait: transfer, statistics and steps) on the worker
+thread; RETURN_HOP back onto the loop.
 """
 
 from __future__ import annotations

@@ -302,3 +302,286 @@ class TestDrainBurst:
             assert len(tele.ring) == 8 * 32
 
         run(go())
+
+
+# -- a fit's statistics: reduced on the device from the one placed batch ------
+
+def scorer_on(placement: str, **kw) -> InProcessScorer:
+    """``one``: pinned to the first of the test mesh's eight CPU devices,
+    as the chip's cell runs; ``mesh``: over all eight, as a default
+    scorer in these tests does."""
+    import jax
+    return InProcessScorer(
+        devices=jax.devices()[:1] if placement == "one" else None, **kw)
+
+
+def feature_rows(seed: int, n: int, anomalous: float = 0.3):
+    """Seeded rows whose columns run from units into the thousands, a
+    third of them labelled, ``anomalous`` of those as anomalies."""
+    rng = np.random.default_rng(seed)
+    x = (rng.normal(size=(n, FEATURE_DIM)) * np.linspace(1, 900, FEATURE_DIM)
+         + np.linspace(-40, 6000, FEATURE_DIM)).astype(np.float32)
+    mask = (rng.random(n) < 1 / 3).astype(np.float32)
+    labels = ((rng.random(n) < anomalous) * mask).astype(np.float32)
+    return x, labels, mask
+
+
+def rule_in_float64(norm, x, labels, mask, momentum=0.2):
+    """The configuration's rule, reckoned apart in NumPy float64: mean and
+    variance of the rows not labelled anomalous; the first such batch sets
+    the pair, later ones blend in; a batch with no such row changes
+    nothing."""
+    normal = np.asarray(x, np.float64)[(mask == 0) | (labels == 0)]
+    if not len(normal):
+        return norm
+    mu, var = normal.mean(axis=0), normal.var(axis=0) + 1e-6
+    if norm is not None:
+        mu = (1 - momentum) * norm[0] + momentum * mu
+        var = (1 - momentum) * norm[1] + momentum * var
+    return mu, var
+
+
+def _first_batch():
+    return [feature_rows(1, 64)]
+
+
+def _second_batch():
+    return [feature_rows(1, 64), feature_rows(2, 64)]
+
+
+def _every_row_anomalous():
+    x, _, _ = feature_rows(3, 64)
+    return [feature_rows(1, 64),
+            (x * 50, np.ones(64, np.float32), np.ones(64, np.float32))]
+
+
+def _rows_that_pad():
+    # 50 rows pad to 64: fourteen rows of zeros that are no one's traffic
+    return [feature_rows(1, 50), feature_rows(2, 41)]
+
+
+def _anomalous_rows_left_out():
+    x, labels, mask = feature_rows(4, 64, anomalous=0.6)
+    x[labels == 1] *= 1e3        # would carry both moments if counted
+    assert 8 < labels.sum() < 40
+    return [feature_rows(1, 64), (x, labels, mask)]
+
+
+STATISTICS_CASES = {
+    "first_batch": _first_batch, "second_batch": _second_batch,
+    "every_row_anomalous": _every_row_anomalous,
+    "rows_that_pad": _rows_that_pad,
+    "anomalous_rows_left_out": _anomalous_rows_left_out}
+
+
+class TestFitStatistics:
+    @pytest.mark.parametrize("case", sorted(STATISTICS_CASES))
+    @pytest.mark.parametrize("placement", ["one", "mesh"])
+    def test_statistics_follow_the_rule(self, placement, case):
+        batches = STATISTICS_CASES[case]()
+
+        async def go():
+            scorer = scorer_on(placement, fit_steps=1)
+            try:
+                snaps = [scorer.snapshot()]
+                for x, labels, mask in batches:
+                    assert np.isfinite(await scorer.fit(x, labels, mask))
+                    snaps.append(scorer.snapshot())
+                return snaps
+            finally:
+                scorer.close()
+
+        snaps = run(go())
+        assert snaps[0].norm_initialized is False
+        assert (snaps[0].mu == 0).all() and (snaps[0].var == 1).all()
+        want = None
+        for rows in batches:
+            want = rule_in_float64(want, *rows)
+        got = snaps[-1]
+        assert got.norm_initialized is True
+        assert got.mu.dtype == got.var.dtype == np.float32
+        np.testing.assert_allclose(got.mu, want[0], rtol=1e-5)
+        np.testing.assert_allclose(got.var, want[1], rtol=1e-5)
+        if case == "every_row_anomalous":
+            # nothing to learn from: pair and flag as the fit found them
+            assert got.mu.tobytes() == snaps[-2].mu.tobytes()
+            assert got.var.tobytes() == snaps[-2].var.tobytes()
+
+    @pytest.mark.parametrize("placement", ["one", "mesh"])
+    def test_a_first_batch_of_anomalies_leaves_the_flag_unset(self,
+                                                              placement):
+        async def go():
+            scorer = scorer_on(placement, fit_steps=1)
+            try:
+                await scorer.fit(*_every_row_anomalous()[1])
+                return scorer._norm_initialized, scorer.snapshot()
+            finally:
+                scorer.close()
+
+        flag, snap = run(go())
+        assert flag is False and snap.norm_initialized is False
+        assert (snap.mu == 0).all() and (snap.var == 1).all()
+
+    @pytest.mark.parametrize("fit_steps", [1, 4])
+    @pytest.mark.parametrize("placement", ["one", "mesh"])
+    def test_a_fit_ships_its_batch_once(self, placement, fit_steps):
+        import time
+
+        from linkerd_tpu.telemetry import phases
+        x, labels, mask = feature_rows(5, 50)
+
+        async def go():
+            scorer = scorer_on(placement, fit_steps=fit_steps)
+            try:
+                t = time.monotonic()
+                await scorer.fit(x, labels, mask)
+                return t
+            finally:
+                scorer.close()
+
+        t = run(go())
+        (rec,) = [c for c in phases.records()
+                  if c.kind == phases.FIT and c.t0 >= t]
+        # 50 rows pad to 64: the rows, labels, mask and the row mask
+        assert rec.counts["fit.shipped_bytes"] == 64 * (FEATURE_DIM + 3) * 4
+        assert [n for n, _ in rec.marks].count(phases.STEP) == fit_steps
+
+    @pytest.mark.parametrize("placement", ["one", "mesh"])
+    def test_a_score_begun_once_fit_has_yielded_meets_its_statistics(
+            self, placement):
+        """Held to what the benchmark's comparison holds such a call to:
+        the parameters before the fit or after any of its steps, under the
+        fit's statistics; never the statistics from before it."""
+        import dataclasses
+        first, batch = feature_rows(6, 64), feature_rows(7, 64)
+        steps = 3
+
+        async def scores_under(twin, snap):
+            twin.restore(snap)
+            return await twin.score(batch[0])
+
+        async def go():
+            scorer = scorer_on(placement, fit_steps=steps)
+            twins = [scorer_on(placement, fit_steps=k)
+                     for k in range(1, steps + 1)]
+            try:
+                await scorer.fit(*first)
+                before = scorer.snapshot()
+                fit = asyncio.ensure_future(scorer.fit(*batch))
+                # first runs when fit has reached its first await
+                met = await asyncio.ensure_future(scorer.score(batch[0]))
+                await fit
+                after = scorer.snapshot()
+                new = [dataclasses.replace(before, mu=after.mu, var=after.var)]
+                for twin in twins:
+                    twin.restore(before)
+                    await twin.fit(*batch)
+                    new.append(twin.snapshot())
+                old = [dataclasses.replace(s, mu=before.mu, var=before.var)
+                       for s in new]
+                return (met, [await scores_under(twins[0], s) for s in new],
+                        [await scores_under(twins[0], s) for s in old], after,
+                        new[-1])
+            finally:
+                for s in [scorer] + twins:
+                    s.close()
+
+        met, under_new, under_old, after, twin_after = run(go())
+        # the twin's steps are the fit's own
+        assert twin_after.mu.tobytes() == after.mu.tobytes()
+        np.testing.assert_allclose(
+            twin_after.params["enc"][0]["w"], after.params["enc"][0]["w"],
+            rtol=0, atol=1e-7)
+        gap_new = [float(np.max(np.abs(met - s))) for s in under_new]
+        gap_old = [float(np.max(np.abs(met - s))) for s in under_old]
+        assert min(gap_new) < 1e-6, (gap_new, gap_old)
+        assert min(gap_old) > 1e-3, (gap_new, gap_old)
+
+    @pytest.mark.parametrize("placement", ["one", "mesh"])
+    def test_a_score_captures_one_fits_pair_whole(self, placement):
+        """``restore`` runs on a thread (the lifecycle's swap) and repoints
+        the statistics while the loop dispatches: a call takes mu and var
+        in one read of one attribute, so never one fit's mean beside
+        another's variance."""
+        import dataclasses
+        import sys
+        import threading
+
+        async def go():
+            scorer = scorer_on(placement, fit_steps=1)
+            base = scorer.snapshot()
+            d = scorer.cfg.in_dim
+            snaps = [dataclasses.replace(
+                base, mu=np.full(d, v, np.float32),
+                var=np.full(d, v, np.float32), norm_initialized=True)
+                for v in (2.0, 3.0)]
+            pairs, real, stop = [], scorer._scorer, threading.Event()
+
+            def recording(params, xd, mu, var):
+                pairs.append((mu, var))
+                return real(params, xd, mu, var)
+
+            def swapper():
+                i = 0
+                while not stop.is_set():
+                    scorer.restore(snaps[i % 2])
+                    i += 1
+
+            scorer._scorer = recording
+            thread = threading.Thread(target=swapper, daemon=True)
+            interval = sys.getswitchinterval()
+            sys.setswitchinterval(1e-5)
+            try:
+                thread.start()
+                x = np.zeros((8, d), np.float32)
+                for _ in range(150):
+                    await scorer.score(x)
+            finally:
+                stop.set()
+                sys.setswitchinterval(interval)
+                thread.join(30)
+                scorer.close()
+            assert not thread.is_alive()
+            return pairs
+
+        pairs = run(go())
+        assert len(pairs) == 150
+        tags = {(float(mu[0]), float(var[0])) for mu, var in pairs}
+        assert tags <= {(0.0, 1.0), (2.0, 2.0), (3.0, 3.0)}, tags
+        assert len(tags) > 1    # the thread did repoint them meanwhile
+
+    @pytest.mark.parametrize("placement", ["one", "mesh"])
+    def test_snapshot_restore_swap_warmup_keep_the_triple(self, placement):
+        def triple(snap):
+            return (snap.mu.tobytes(), snap.var.tobytes(),
+                    snap.norm_initialized)
+
+        async def go():
+            scorer = scorer_on(placement, fit_steps=1)
+            other = scorer_on(placement, seed=1, fit_steps=1)
+            try:
+                fresh = scorer.snapshot()
+                await scorer.warmup(4)
+                assert triple(scorer.snapshot()) == triple(fresh)
+                assert scorer._norm_initialized is False
+                await scorer.fit(*feature_rows(8, 64))
+                await other.fit(*feature_rows(9, 64))
+                mine, theirs = scorer.snapshot(), other.snapshot()
+                assert mine.norm_initialized and triple(mine) != triple(theirs)
+                # warm-up fits zeros in between and puts the triple back
+                await scorer.warmup(4)
+                assert triple(scorer.snapshot()) == triple(mine)
+                assert scorer._norm_initialized is True
+                displaced = scorer.swap(theirs)
+                assert triple(displaced) == triple(mine)
+                assert triple(scorer.snapshot()) == triple(theirs)
+                scorer.restore(displaced)
+                assert triple(scorer.snapshot()) == triple(mine)
+                scorer.restore(fresh)
+                assert triple(scorer.snapshot()) == triple(fresh)
+                assert scorer._norm_initialized is False
+            finally:
+                scorer.close()
+                other.close()
+
+        run(go())

@@ -104,6 +104,45 @@ class TestScorerSelection:
         assert proc.returncode == 0, proc.stderr[-2000:]
         assert "COMPILED TPU v5" in proc.stdout
 
+    def test_statistics_program_compiles_for_v5e_and_copies_no_batch(self):
+        """The fit's statistics program (``models.anomaly.running_norm``)
+        at the benchmark cell's 2,097,152 rows and at its set-up fit's
+        65,536, with and without a row mask, compiled by the TPU's own
+        compiler with no chip: two fused passes over the resident batch,
+        so no temporary of anything like the batch's size (302 MB)."""
+        code = (
+            "import functools, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models.anomaly import running_norm\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "f32 = functools.partial(S, jnp.float32)\n"
+            "step = jax.jit(functools.partial(running_norm, momentum=0.2))\n"
+            "norm = (f32(36), f32(36), S(jnp.bool_))\n"
+            "for rows in (65536, 2097152):\n"
+            "    for mask in ((), (f32(rows),)):\n"
+            "        m = step.lower(norm, f32(rows, 36), f32(rows),\n"
+            "                       f32(rows), *mask).compile(\n"
+            "                       ).memory_analysis()\n"
+            "        print('TEMP', m.temp_size_in_bytes)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], env=_clean_env(
+            JAX_PLATFORMS="cpu", TPU_ACCELERATOR_TYPE="v5litepod-4",
+            TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        temps = [int(line.split()[1]) for line in proc.stdout.splitlines()
+                 if line.startswith("TEMP")]
+        assert len(temps) == 4 and max(temps) < 32 * 2 ** 20, temps
+
 
 class TestFailLoud:
     def test_inprocess_primary_that_cannot_build_fails_at_start(

@@ -220,18 +220,17 @@ class TestFitPath:
         padded, exact = since(run(go()), phases.FIT)
         for c in (padded, exact):
             assert [n for n, _ in c.marks] == [
-                phases.UPDATE_NORM, phases.PREP, phases.THREAD_HOP,
+                phases.PREP, phases.UPDATE_NORM, phases.THREAD_HOP,
                 phases.STEP, phases.STEP, phases.STEP,
                 phases.LOSS_WAIT, phases.RETURN_HOP]
             assert_tiles(c)
-        # 6 rows pad to 8: x, labels, mask and the row mask ship, 3 times
+        # 6 rows pad to 8: x, labels, mask and the row mask ship, once a
+        # fit and not once a step
         assert padded.counts == {
-            "fit.calls": 1,
-            "fit.shipped_bytes": 3 * (8 * 36 * 4 + 3 * 8 * 4)}
+            "fit.calls": 1, "fit.shipped_bytes": 8 * 36 * 4 + 3 * 8 * 4}
         # 8 rows are a bucket: no row mask
         assert exact.counts == {
-            "fit.calls": 1,
-            "fit.shipped_bytes": 3 * (8 * 36 * 4 + 2 * 8 * 4)}
+            "fit.calls": 1, "fit.shipped_bytes": 8 * 36 * 4 + 2 * 8 * 4}
 
 
 class TestScorerSpanTags:
