@@ -287,3 +287,28 @@ def featurize_batch(fvs: Sequence[FeatureVector]) -> np.ndarray:
     for dim in _ABLATE:
         out[:, dim] = 0.0
     return out
+
+
+# -- event ids for the flow tier ----------------------------------------------
+
+# status class (5 and none) x log2-latency bucket x hashed destination
+# (column and sign) x (retry, exception) flags
+_EVENT_CODES = 6 * 16 * (2 * _PATH_HASH_DIM) * 4
+
+
+def event_ids(x: np.ndarray, vocab: int = 20480) -> np.ndarray:
+    """Feature rows ``[n, FEATURE_DIM]`` -> one event id a row, int32 in
+    ``[1, vocab)`` (0 is the flow model's start token): the row's status
+    class, the bucket of its latency (``floor(log2(1 + ms))``, 16 of
+    them), its hashed destination (column and sign) and its retry and
+    exception flags, as one code, folded onto the vocabulary's slice."""
+    x = np.asarray(x, np.float32)
+    status = np.where(x[:, 1:6].any(1), x[:, 1:6].argmax(1), 5)
+    latency = np.clip(x[:, 0] / np.log(2.0), 0, 15).astype(np.int64)
+    dst = x[:, _PATH_HASH_OFF:_PATH_HASH_OFF + _PATH_HASH_DIM]
+    col = np.abs(dst).argmax(1)
+    dst_code = 2 * col + (dst[np.arange(len(x)), col] < 0)
+    flags = 2 * ((x[:, 6] > 0) | (x[:, 7] > 0)) + (x[:, 13] > 0)
+    code = ((status * 16 + latency) * (2 * _PATH_HASH_DIM) + dst_code) * 4 \
+        + flags
+    return (1 + code % (vocab - 1)).astype(np.int32)

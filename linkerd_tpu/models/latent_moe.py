@@ -1,0 +1,517 @@
+"""A flow model: latent attention over a per-flow cache, and a sigmoid-routed
+mixture of experts beside a shared one (the DeepSeek-V3 family's block).
+
+An *event* is one token: an id in ``[1, vocab_slice)``; id 0 is the start
+token the program writes at position 0 of every flow. A *flow* is the
+events under one stream key, held in one of ``slots`` rows of the cache.
+The score of an event is how surprised the model is by it given the flow
+so far: ``1 - exp(-nll / ln vocab_slice)`` with ``nll`` the event's
+negative log-likelihood under the logits of the position before it.
+
+Per layer ``h += Attn(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``:
+
+- **Latent attention.** Queries through a low-rank pair (``wdq``, norm,
+  ``wuq``) into ``heads x (nope + rope)``; keys and values through ``wdkv``
+  into a ``kv_lora_rank`` latent (normed) and one rope key shared by all
+  heads. *The cache holds that latent and the rotated rope key, and
+  nothing else*: ``kv_lora_rank + rope`` values a position a layer.
+  Attention runs with ``wukv`` absorbed into the query and the output
+  (``q_nope . Wuk`` against the latent itself; the weighted latents through
+  ``Wuv``), so a cached position is never up-projected. RoPE is YaRN's,
+  rotate-half pairing.
+- **FFN.** The first ``first_k_dense_replace`` layers: dense SwiGLU. The
+  others: ``shared(x) + routed(x)``. The router scores all
+  ``n_routed_experts`` in float32 (``sigmoid``), selects the top
+  ``num_experts_per_tok`` of score + bias, weighs them by their scores
+  (without the bias) over their sum, times ``routed_scaling_factor``.
+  **This device holds the experts ``experts_held``** (a range) of every
+  layer: ``routed(x)`` sums over the token's selected experts that are
+  held here, the others add nothing, and nothing stands in for them. The
+  held pairs are sorted by expert into tiles of ``expert_tile`` rows, each
+  tile of one expert, and a loop over the tiles *that hold a pair* does
+  the grouped product: no capacity, no token dropped, work in proportion
+  to the pairs.
+
+The device step (``flow_step``) takes the call's rows as the host's
+``FlowTable`` laid them out (``telemetry/flowstate.py``): row ``i`` is
+``(cell, address, id)`` with ``cell = f * T + t`` its place in the call's
+``[F flows, T events]`` layout and ``address = slot * positions + pos``
+where it lies in the cache. Rows at and past ``n`` are padding and are
+masked out of the state by ``n``. A chunk that begins at position 1 begins
+a flow: the start token's cache entries (constants of the parameters,
+kept with the state: ``with_start``) are written at position 0 first.
+
+Parameters are bfloat16, drawn on the device from the seed (``init``):
+one draw a tensor, ``normal(fold_in(key(seed), crc32(name)))`` in float32
+times the tensor's scale, cast to bfloat16; an expert tensor folds the
+expert's index in once more, so an expert's weights are the same whichever
+device holds it. Compute is bfloat16 with float32 accumulation; the
+residual stream, the norms, the router and the softmaxes are float32.
+"""
+
+from __future__ import annotations
+
+import math
+import zlib
+from dataclasses import dataclass
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+Params = Dict[str, Any]
+
+
+@dataclass(frozen=True)
+class LatentMoEConfig:
+    hidden_size: int = 7168
+    num_attention_heads: int = 64
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    intermediate_size: int = 18432
+    moe_intermediate_size: int = 2048
+    n_routed_experts: int = 384         # the router's width: the whole layer's
+    n_shared_experts: int = 1
+    num_experts_per_tok: int = 8
+    routed_scaling_factor: float = 2.827
+    first_k_dense_replace: int = 1
+    rms_norm_eps: float = 1e-5
+    rope_theta: float = 50000.0
+    rope_factor: float = 64.0
+    rope_original_positions: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    layers: int = 5
+    experts_held: Tuple[int, int] = (0, 12)    # [lo, hi) of every layer
+    layer_share: int = 32               # devices that share each layer
+    vocab_slice: int = 20480
+    slots: int = 512
+    positions: int = 1024
+    expert_tile: int = 128
+
+    def __post_init__(self):
+        lo, hi = self.experts_held
+        if not 0 <= lo < hi <= self.n_routed_experts:
+            raise ValueError(f"experts_held {self.experts_held} outside "
+                             f"0..{self.n_routed_experts}")
+
+    @property
+    def entry_width(self) -> int:
+        """Values the cache holds a position a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @classmethod
+    def from_config(cls, cfg: dict) -> "LatentMoEConfig":
+        """From a configuration file of the benchmark (the published keys at
+        the top level; under ``model`` what is this repo's: the router's
+        width where the file's ``n_routed_experts`` is the count held
+        here, the held range, the share, the cache's size)."""
+        m, rope = cfg["model"], cfg["rope_scaling"]
+        if rope["type"] != "yarn" or rope["mscale"] != rope["mscale_all_dim"]:
+            # equal mscales leave cos and sin unscaled, as computed here
+            raise ValueError("only yarn with mscale == mscale_all_dim")
+        if cfg["scoring_func"] != "sigmoid" or cfg["n_group"] != 1:
+            raise ValueError("only sigmoid scores in one group are computed")
+        return cls(
+            hidden_size=cfg["hidden_size"],
+            num_attention_heads=cfg["num_attention_heads"],
+            q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
+            qk_nope_head_dim=cfg["qk_nope_head_dim"],
+            qk_rope_head_dim=cfg["qk_rope_head_dim"],
+            v_head_dim=cfg["v_head_dim"],
+            intermediate_size=cfg["intermediate_size"],
+            moe_intermediate_size=cfg["moe_intermediate_size"],
+            n_routed_experts=m["router_experts"],
+            n_shared_experts=cfg["n_shared_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            routed_scaling_factor=cfg["routed_scaling_factor"],
+            first_k_dense_replace=cfg["first_k_dense_replace"],
+            rms_norm_eps=cfg["rms_norm_eps"], rope_theta=cfg["rope_theta"],
+            rope_factor=rope["factor"],
+            rope_original_positions=rope["original_max_position_embeddings"],
+            rope_beta_fast=rope["beta_fast"], rope_beta_slow=rope["beta_slow"],
+            rope_mscale_all_dim=rope["mscale_all_dim"],
+            layers=cfg["num_hidden_layers"],
+            experts_held=tuple(m["experts_held"]),
+            layer_share=m["layer_share"], vocab_slice=cfg["vocab_size"],
+            slots=m["slots"], positions=m["positions"],
+            expert_tile=m.get("expert_tile", 128))
+
+
+# -- weights from the seed ----------------------------------------------------
+
+OUT_GAIN = 0.3      # output projections: residual norms stay near 1
+GAIN_SPREAD = 0.1   # a norm's learned gain: 1 + 0.1 * normal
+BIAS_SPREAD = 0.01  # the router's per-expert selection bias
+
+
+def tensor_table(cfg: LatentMoEConfig) -> Dict[str, tuple]:
+    """``{name: (shape, std, mean, per_expert)}`` of every tensor, in the
+    words of the configuration file's rule: a matrix ``[fan_in, fan_out]``
+    has std ``1/sqrt(fan_in)`` (output projections 0.3 of that), the
+    embedding std 1, a norm's gain 1 + 0.1 normal, the router's bias 0.01
+    normal. ``per_expert`` tensors are drawn expert by expert."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    qd = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    kvd = cfg.qk_nope_head_dim + cfg.v_head_dim
+    inter = cfg.moe_intermediate_size
+    shared = inter * cfg.n_shared_experts
+
+    def mat(i, o, gain=1.0):
+        return ((i, o), gain / math.sqrt(i), 0.0, False)
+
+    def gain(n):
+        return ((n,), GAIN_SPREAD, 1.0, False)
+
+    t = {"embed": ((cfg.vocab_slice, d), 1.0, 0.0, False),
+         "head": mat(d, cfg.vocab_slice), "final_norm": gain(d)}
+    for l in range(cfg.layers):
+        p = f"layers.{l}."
+        t.update({
+            p + "attn_norm": gain(d), p + "wdq": mat(d, cfg.q_lora_rank),
+            p + "q_norm": gain(cfg.q_lora_rank),
+            p + "wuq": mat(cfg.q_lora_rank, h * qd),
+            p + "wdkv": mat(d, cfg.entry_width),
+            p + "kv_norm": gain(cfg.kv_lora_rank),
+            p + "wukv": mat(cfg.kv_lora_rank, h * kvd),
+            p + "wo": mat(h * cfg.v_head_dim, d, OUT_GAIN),
+            p + "ffn_norm": gain(d)})
+        if l < cfg.first_k_dense_replace:
+            t.update({p + "w_gate": mat(d, cfg.intermediate_size),
+                      p + "w_up": mat(d, cfg.intermediate_size),
+                      p + "w_down": mat(cfg.intermediate_size, d, OUT_GAIN)})
+        else:
+            t.update({
+                p + "router": mat(d, cfg.n_routed_experts),
+                p + "router_bias": ((cfg.n_routed_experts,), BIAS_SPREAD,
+                                    0.0, False),
+                p + "shared_gate": mat(d, shared),
+                p + "shared_up": mat(d, shared),
+                p + "shared_down": mat(shared, d, OUT_GAIN),
+                p + "exp_gate": ((d, inter), 1 / math.sqrt(d), 0.0, True),
+                p + "exp_up": ((d, inter), 1 / math.sqrt(d), 0.0, True),
+                p + "exp_down": ((inter, d), OUT_GAIN / math.sqrt(inter),
+                                 0.0, True)})
+    return t
+
+
+def draw(key, tag, std, mean, experts, *, shape):
+    """One tensor by the rule. ``tag``: the crc32 of the tensor's name;
+    ``experts``: the indices of the experts to draw, stacked on a leading
+    axis, or None."""
+    k = jax.random.fold_in(key, tag)
+
+    def one(k):
+        return (mean + std * jax.random.normal(k, shape, jnp.float32)
+                ).astype(jnp.bfloat16)
+
+    if experts is None:
+        return one(k)
+    return jax.vmap(lambda e: one(jax.random.fold_in(k, e)))(experts)
+
+
+_draw = jax.jit(draw, static_argnames=("shape",))
+
+
+def name_tag(name: str) -> np.uint32:
+    return np.uint32(zlib.crc32(name.encode()))
+
+
+def init(key, cfg: LatentMoEConfig) -> Params:
+    """The parameters on the default device, tensor by tensor (each draw's
+    float32 scratch is freed before the next)."""
+    held = jnp.arange(*cfg.experts_held, dtype=jnp.uint32)
+    params: Params = {"layers": [{} for _ in range(cfg.layers)]}
+    for name, (shape, std, mean, per_expert) in tensor_table(cfg).items():
+        w = _draw(key, name_tag(name), np.float32(std), np.float32(mean),
+                  held if per_expert else None, shape=shape)
+        parts = name.split(".")
+        if parts[0] == "layers":
+            params["layers"][int(parts[1])][parts[2]] = w
+        else:
+            params[name] = w
+    return params
+
+
+def init_state(cfg: LatentMoEConfig):
+    """``(cache, length [slots], last_h [slots, hidden], start)``: the
+    latent cache, one array ``[slots, positions, entry]`` a layer, the
+    positions each slot holds, each slot's newest final hidden state (which
+    predicts the flow's next event), and what the start token leaves
+    behind: None until the first call has computed it (``with_start``)."""
+    return (tuple(jnp.zeros((cfg.slots, cfg.positions, cfg.entry_width),
+                            jnp.bfloat16) for _ in range(cfg.layers)),
+            jnp.zeros((cfg.slots,), jnp.int32),
+            jnp.zeros((cfg.slots, cfg.hidden_size), jnp.bfloat16),
+            None)
+
+
+def with_start(run, cfg: LatentMoEConfig, state, shape):
+    """The state with the start token's constants, which every flow begins
+    from: its cache entry in every layer ``[layers, entry]`` and its final
+    hidden state ``[hidden]`` (which predicts a flow's first event). They
+    are constants of the parameters, as the cache is a function of them,
+    and are made by the step's own program (``run(state, rows, n)``, at
+    the shape of the rows it is about to take, so nothing else compiles):
+    one call whose single event is the start token itself, which
+    ``flow_step`` takes as the call that makes them."""
+    cache, length, last_h, _ = state
+    blank = (np.zeros((cfg.layers, cfg.entry_width), jnp.bfloat16),
+             np.zeros((cfg.hidden_size,), jnp.bfloat16))
+    return run((cache, length, last_h, blank), np.zeros(shape, np.int32),
+               1)[1]
+
+
+# -- the block ----------------------------------------------------------------
+
+def _mm(x, w):
+    return jnp.dot(x.astype(jnp.bfloat16), w,
+                   preferred_element_type=jnp.float32)
+
+
+def _rms(x, gain, eps):
+    x = x.astype(jnp.float32)
+    return (x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
+            * gain.astype(jnp.float32))
+
+
+def _swiglu(x, gate, up, down):
+    return _mm(jax.nn.silu(_mm(x, gate)) * _mm(x, up), down)
+
+
+def yarn_inv_freq(cfg: LatentMoEConfig) -> np.ndarray:
+    """YaRN's blend of the plain and the interpolated frequencies by the
+    linear ramp between the two correction dimensions."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / cfg.rope_factor
+
+    def correction(rotations):
+        return (dim * math.log(cfg.rope_original_positions
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction(cfg.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def softmax_scale(cfg: LatentMoEConfig) -> float:
+    m = 0.1 * cfg.rope_mscale_all_dim * math.log(cfg.rope_factor) + 1.0
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _rope(x, cos, sin):
+    """Rotate-half pairing: (x[i], x[i + d/2]) turn together."""
+    a, b = jnp.split(x.astype(jnp.float32), 2, -1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+ATTENTION_BLOCK = 8     # flows attended at a time: bounds the score tensor
+
+
+def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
+               pos, cos, sin):
+    """``x [F, T, hidden]`` normed, ``cache [slots, positions, entry]``
+    this layer's. Each flow's slot is read whole, the chunk's ``count``
+    entries are set into it from position ``p0`` on (and the start
+    token's at position 0 where the flow ``begins``), the slot is written
+    back whole (a ``slot`` out of range: read clipped, write dropped) and
+    the chunk attends over it causally. Returns the output and the
+    cache."""
+    F, T, _ = x.shape
+    H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                     cfg.qk_rope_head_dim)
+    rank, eps, P = cfg.kv_lora_rank, cfg.rms_norm_eps, cfg.positions
+    q = _mm(_rms(_mm(x, lp["wdq"]), lp["q_norm"], eps), lp["wuq"]).reshape(
+        F, T, H, nope + rope)
+    q_rope = _rope(q[..., nope:], cos[:, :, None], sin[:, :, None])
+    ckr = _mm(x, lp["wdkv"])
+    entry = jnp.concatenate(
+        [_rms(ckr[..., :rank], lp["kv_norm"], eps),
+         _rope(ckr[..., rank:], cos, sin)], -1).astype(jnp.bfloat16)
+    kv = cache[jnp.minimum(slot, cache.shape[0] - 1)]       # [F, P, entry]
+    t = jnp.arange(P)[None] - p0[:, None]                   # [F, P]
+    mine = (t >= 0) & (t < count[:, None])
+    kv = jnp.where(mine[..., None], jnp.take_along_axis(
+        entry, jnp.clip(t, 0, T - 1)[..., None], 1), kv)
+    kv = kv.at[:, 0].set(jnp.where(begins[:, None], start_entry[None],
+                                   kv[:, 0]))
+    cache = cache.at[slot].set(kv, mode="drop")
+    wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
+    q_abs = jnp.einsum("fthd,chd->fthc", q[..., :nope].astype(jnp.bfloat16),
+                       wukv[..., :nope], preferred_element_type=jnp.float32)
+    qk = jnp.concatenate([q_abs, q_rope], -1).astype(jnp.bfloat16)
+
+    def attend(block):
+        qk, kv, pos = block
+        s = jnp.einsum("fthk,fpk->fhtp", qk, kv,
+                       preferred_element_type=jnp.float32
+                       ) * softmax_scale(cfg)
+        seen = jnp.arange(P)[None, None] <= pos[:, :, None]
+        p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), -1)
+        return jnp.einsum("fhtp,fpc->fhtc", p.astype(jnp.bfloat16),
+                          kv[..., :rank], preferred_element_type=jnp.float32
+                          ).astype(jnp.bfloat16)
+
+    nb = min(ATTENTION_BLOCK, F)
+    o = jax.lax.map(attend, jax.tree_util.tree_map(
+        lambda a: a.reshape(F // nb, nb, *a.shape[1:]), (qk, kv, pos)))
+    o = jnp.einsum("fhtc,chd->fthd", o.reshape(F, H, T, rank),
+                   wukv[..., nope:], preferred_element_type=jnp.float32)
+    return _mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache
+
+
+def route(lp, cfg, x):
+    """``x [N, hidden]`` float32 (already normed) -> the selected experts
+    ``[N, k]`` and their weights: float32 throughout, the matmul's inputs
+    being the bfloat16 values the experts see."""
+    xr = x.astype(jnp.bfloat16).astype(jnp.float32)
+    s = jax.nn.sigmoid(jnp.dot(xr, lp["router"].astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
+                           cfg.num_experts_per_tok)
+    sel = jnp.take_along_axis(s, idx, -1)
+    return idx, sel / sel.sum(-1, keepdims=True) * cfg.routed_scaling_factor
+
+
+def routed_experts(lp, cfg, x, valid):
+    """The held experts' part of the routed sum for ``x [N, hidden]``:
+    ``(out [N, hidden] float32, tokens per held expert [G])``. Tokens not
+    ``valid`` (padding) are routed nowhere."""
+    N, D = x.shape
+    lo, hi = cfg.experts_held
+    G, k, M = hi - lo, cfg.num_experts_per_tok, cfg.expert_tile
+    idx, w = route(lp, cfg, x)
+    local = (idx >= lo) & (idx < hi) & valid[:, None]
+    g = jnp.where(local, idx - lo, G).reshape(-1)           # [N * k]
+    order = jnp.argsort(g, stable=True)
+    g_sorted = g[order]
+    cnt = (g[:, None] == jnp.arange(G)[None]).sum(0).astype(jnp.int32)
+    tiles = (cnt + M - 1) // M
+    tile_end = jnp.cumsum(tiles)
+    # a group's rows start at a tile's edge; a pair's place is its group's
+    # start plus its rank among the group's pairs
+    ge = jnp.minimum(g_sorted, G - 1)
+    rank = jnp.arange(N * k) - (jnp.cumsum(cnt) - cnt)[ge]
+    R = (N * min(k, G) // M + G) * M                        # rows at most
+    dest = jnp.where(g_sorted < G, (tile_end - tiles)[ge] * M + rank, R)
+    dest_tok = jnp.full((R,), N, jnp.int32).at[dest].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    dest_w = jnp.zeros((R,), jnp.float32).at[dest].set(
+        w.reshape(-1)[order], mode="drop")
+    x_pad = jnp.concatenate(
+        [x.astype(jnp.bfloat16), jnp.zeros((1, D), jnp.bfloat16)])
+
+    def tile(t, out):
+        e = jnp.minimum((t >= tile_end).sum(), G - 1)
+        rows = jax.lax.dynamic_slice(dest_tok, (t * M,), (M,))
+        wt = jax.lax.dynamic_slice(dest_w, (t * M,), (M,))
+        y = _swiglu(x_pad[rows], lp["exp_gate"][e], lp["exp_up"][e],
+                    lp["exp_down"][e])
+        return out.at[rows].add(y * wt[:, None])
+
+    with jax.named_scope("expert_tiles"):
+        out = jax.lax.fori_loop(0, tile_end[-1], tile,
+                                jnp.zeros((N + 1, D), jnp.float32))
+    return out[:N], cnt
+
+
+def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
+             begins):
+    """``tok [F, T]``; per flow its ``slot``, the position ``p0`` its chunk
+    is appended at, the chunk's ``count`` events and whether the flow
+    ``begins`` here; ``cache``: one ``[slots, positions, entry]`` a layer;
+    ``start_entries [layers, entry]``: the start token's, set at position 0
+    of a flow that begins.
+    Returns the final normed hidden ``[F, T, hidden]`` float32, the cache
+    with the chunks appended, tokens per held expert ``[expert layers,
+    G]``."""
+    F, T = tok.shape
+    h = params["embed"][tok].astype(jnp.float32)
+    pos = p0[:, None] + jnp.arange(T)[None]
+    valid = jnp.arange(T)[None] < count[:, None]
+    angle = pos[..., None].astype(jnp.float32) * jnp.asarray(
+        yarn_inv_freq(cfg))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    counts, cache = [], list(cache)
+    for l, lp in enumerate(params["layers"]):
+        with jax.named_scope(f"layer{l}.attention"):
+            a, cache[l] = _attention(
+                lp, cfg, cache[l], _rms(h, lp["attn_norm"], cfg.rms_norm_eps),
+                slot, p0, count, begins, start_entries[l], pos, cos, sin)
+            h = h + a
+        with jax.named_scope(f"layer{l}.ffn"):
+            x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
+            if "router" in lp:
+                flat = x.reshape(F * T, -1)
+                routed, cnt = routed_experts(lp, cfg, flat,
+                                             valid.reshape(-1))
+                counts.append(cnt)
+                y = (_swiglu(flat, lp["shared_gate"], lp["shared_up"],
+                             lp["shared_down"]) + routed).reshape(F, T, -1)
+            else:
+                y = _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+            h = h + y
+    G = cfg.experts_held[1] - cfg.experts_held[0]
+    counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
+    return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(cache),
+            counts)
+
+
+def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
+              T: int):
+    """One call. ``rows [B, 3]`` int32 ``(cell, address, id)``; rows at and
+    past ``n`` are padding. Returns ``(scores [B] float32 in row order,
+    state, counts)``."""
+    cache, length, last_h, (start_entries, start_h) = state
+    S, P = cfg.slots, cfg.positions
+    B = rows.shape[0]
+    live = jnp.arange(B) < n
+    cell = jnp.where(live, rows[:, 0], F * T)
+    tok = jnp.zeros((F * T,), jnp.int32).at[cell].set(
+        rows[:, 2], mode="drop").reshape(F, T)
+    addr = jnp.full((F * T,), -1, jnp.int32).at[cell].set(
+        rows[:, 1], mode="drop").reshape(F, T)
+    count = (addr >= 0).sum(1)              # a chunk fills from t = 0
+    flow = count > 0
+    slot = jnp.where(flow, addr[:, 0] // P, S)
+    p0 = jnp.where(flow, addr[:, 0] % P, 1)
+    begins = flow & (p0 == 1)
+    prev_h = jnp.where(begins[:, None], start_h[None],
+                       last_h[jnp.minimum(slot, S - 1)])
+    h, cache, expert_tokens = _forward(params, cfg, cache, start_entries,
+                                       tok, slot, p0, count, begins)
+    with jax.named_scope("head"):
+        pred = jnp.concatenate(
+            [prev_h[:, None].astype(jnp.float32), h[:, :-1]], 1)
+        logits = _mm(pred, params["head"])
+        nll = (jax.nn.logsumexp(logits, -1)
+               - jnp.take_along_axis(logits, tok[..., None], -1)[..., 0])
+        score = 1.0 - jnp.exp(-nll / math.log(cfg.vocab_slice))
+    newest = jnp.take_along_axis(
+        h, jnp.maximum(count - 1, 0)[:, None, None], 1)[:, 0]
+    last_h = last_h.at[slot].set(newest.astype(jnp.bfloat16), mode="drop")
+    length = length.at[slot].set((p0 + count).astype(jnp.int32), mode="drop")
+    # the call that makes the start token's constants (``with_start``): its
+    # one event is id 0, which no flow's event is, at position 0 of slot 0,
+    # where it has attended over itself alone; what it left there is kept
+    # and the slot is counted empty again
+    making = (n == 1) & (rows[0, 2] == 0)
+    start_entries = jnp.where(making, jnp.stack([c[0, 0] for c in cache]),
+                              start_entries)
+    start_h = jnp.where(making, last_h[0], start_h)
+    length = jnp.where(making, 0, length)
+    scores = jnp.where(live, score.reshape(-1)[jnp.minimum(cell, F * T - 1)],
+                       0.0)
+    counts = {"moe.local_pairs": expert_tokens.sum(),
+              "moe.max_expert_tokens": expert_tokens.max(initial=0),
+              "cache.positions": length.sum(),
+              "expert_tokens": expert_tokens}
+    return scores, (cache, length, last_h, (start_entries, start_h)), counts

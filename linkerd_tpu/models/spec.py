@@ -1,0 +1,151 @@
+"""The model seam of the scorer path: what ``InProcessScorer`` needs to
+know of a model, and nothing of its insides.
+
+A ``ModelSpec`` says what a row is (``row_width``, ``row_dtype``), draws
+the parameters (``init``), makes the state a score step reads beside them
+(``init_state``) and builds the jitted score step for a platform
+(``make_step``). Every model's step has one signature::
+
+    step(params, state, rows, n, layout) -> (scores, state, counts)
+
+``rows`` is the staged batch on the device (the step may donate it), rows
+at and past ``n`` are padding, ``counts`` a dict of small arrays that
+rides back with the scores. A step that advances its state takes it
+donated and hands back the new one; a step that only reads it hands back
+the object it was given, and the scorer then stores nothing. ``layout`` is
+the table's, None where the model has no table.
+
+Three attributes say what else the scorer may do with the model, each on
+its own: ``trains`` (``fit`` and the optimizer's half of a snapshot
+exist), ``single_device`` (``parallel/mesh.py`` has no layout for it) and
+``make_table``: a model whose rows carry a key brings the host's table
+that lays a call's rows out for the step (``map`` -> a plan with ``rows``,
+``layout`` and ``counts``; ``checkpoint`` / ``rollback``). Such a model is
+``keyed``: its calls apply in the order they were made, and ``describe``
+reports the table and the newest call's counts for ``device_state()``.
+
+Two instances: ``mlp36`` (the 36-column autoencoder + classifier: its
+state is the normalisation triple ``(mu, var, initialised)``, which a fit
+repoints and a score step only reads; trains online; sharded over a mesh)
+and ``latent_moe`` (latent attention over a per-flow cache, routed
+experts: keyed, frozen, single-device).
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Callable, Optional
+
+import numpy as np
+
+
+@dataclass(frozen=True)
+class ModelSpec:
+    name: str
+    cfg: Any
+    row_width: int
+    row_dtype: Any
+    trains: bool
+    single_device: bool
+    init: Callable[[Any], Any]
+    init_state: Callable[[], Any]
+    make_step: Callable[[str], Callable]
+    score_path: Callable[[str], str]    # the step's name on a platform
+    make_table: Optional[Callable[[], Any]] = None
+    describe: Optional[Callable[[Any, dict], dict]] = None
+
+    @property
+    def keyed(self) -> bool:
+        return self.make_table is not None
+
+
+def reads_norm(score: Callable) -> Callable:
+    """``score(params, rows, mu, var) -> scores`` (the single-device
+    kernel, or ``parallel/mesh.make_score_step``'s) under the seam's
+    signature: the state is the normalisation triple, read and handed back
+    as it came."""
+    def step(params, state, rows, n, layout):
+        return score(params, rows, state[0], state[1]), state, {}
+    return step
+
+
+def mlp36(recon_weight: float = 0.7) -> ModelSpec:
+    """Today's model: a feature row is 36 float32 columns, scored on its
+    own (padding rows are computed and sliced off). ``init_state`` gives
+    the triple as host arrays: the scorer places it, replicated over a
+    mesh."""
+    from linkerd_tpu.models.anomaly import AnomalyModelConfig, init_params
+
+    cfg = AnomalyModelConfig(recon_weight=recon_weight)
+
+    def init_state():
+        return (np.zeros(cfg.in_dim, np.float32),
+                np.ones(cfg.in_dim, np.float32), np.bool_(False))
+
+    # the kernel's module is imported where a step is built, not where a
+    # spec is only looked at
+    def make_step(platform: str):
+        from linkerd_tpu.ops.scoring import best_scorer
+        return reads_norm(best_scorer(cfg, platform, donate=True))
+
+    def score_path(platform: str) -> str:
+        from linkerd_tpu.ops.scoring import scorer_kind
+        return scorer_kind(platform)
+
+    return ModelSpec(
+        name="mlp36", cfg=cfg, row_width=cfg.in_dim, row_dtype=np.float32,
+        trains=True, single_device=False,
+        init=lambda key: init_params(key, cfg), init_state=init_state,
+        make_step=make_step, score_path=score_path)
+
+
+def latent_moe(cfg=None) -> ModelSpec:
+    """The flow model (``models/latent_moe.py``): a row is int32 ``(stream
+    key, restart flag, event id)``, laid out by ``FlowTable``; the cache,
+    the flows' lengths and the start token's constants are the state,
+    donated to each step."""
+    import jax
+
+    from linkerd_tpu.models import latent_moe as lm
+    from linkerd_tpu.telemetry.flowstate import FlowTable
+
+    cfg = cfg if cfg is not None else lm.LatentMoEConfig()
+
+    def make_step(platform: str):
+        # the state and the staged rows are the program's to reuse
+        program = jax.jit(lm.flow_step, static_argnames=("cfg", "F", "T"),
+                          donate_argnums=(1, 2))
+
+        def step(params, state, rows, n, layout):
+            def run(state, rows, n):
+                return program(params, state, rows, np.int32(n), cfg=cfg,
+                               F=layout[0], T=layout[1])
+            if state[-1] is None:
+                # once: the same program, at this call's shapes
+                state = lm.with_start(run, cfg, state, rows.shape)
+            return run(state, rows, n)
+        return step
+
+    def describe(table, counts: dict) -> dict:
+        tokens = counts.get("expert_tokens")
+        return {"flow": {
+            "slots": cfg.slots, "positions": cfg.positions,
+            "experts_held": list(cfg.experts_held),
+            "layer_share": cfg.layer_share,
+            "resident": len(table.slot_of),
+            "layouts": {f"{f}x{t}": c
+                        for (f, t), c in sorted(table.layouts.items())},
+            "expert_tokens": None if tokens is None else tokens.tolist()}}
+
+    return ModelSpec(
+        name="latent_moe", cfg=cfg, row_width=3, row_dtype=np.int32,
+        trains=False, single_device=True,
+        init=lambda key: lm.init(key, cfg),
+        init_state=lambda: lm.init_state(cfg), make_step=make_step,
+        score_path=lambda platform: "latent_moe",
+        make_table=lambda: FlowTable(cfg.slots, cfg.positions,
+                                     cfg.vocab_slice),
+        describe=describe)
+
+
+SPECS = {"mlp36": mlp36, "latent_moe": latent_moe}

@@ -292,33 +292,68 @@ class InProcessScorer(Scorer):
     device compute of batch N-1 and the event loop never blocks on the
     device (see telemetry/linerate.RingDispatcher).
 
-    With more than one device the SAME serving path runs sharded: a
-    dp x tp mesh from parallel/mesh.py, params placed per the Megatron
-    column/row specs, micro-batches fed per-device via
+    The model is a ``models.spec.ModelSpec`` (default ``mlp36``): what a
+    row is, how the parameters are drawn, the score step, whether it
+    trains, and whether it keeps state on the device between calls. Both
+    models ride the same ring, drainer and phase clock.
+
+    ``mlp36`` with more than one device runs the SAME serving path
+    sharded: a dp x tp mesh from parallel/mesh.py, params placed per the
+    Megatron column/row specs, micro-batches fed per-device via
     ``parallel.mesh.shard_batch`` (each device receives exactly its
     shard; no single host-side device_put of the full batch) — XLA
     inserts the ICI collectives. Single-chip keeps the fused-Pallas
-    kernel (ops/scoring.best_scorer)."""
+    kernel (ops/scoring.best_scorer).
+
+    The flow model (``latent_moe``) is single-device and frozen: more than
+    one device raises, ``fit`` and ``snapshot``/``restore`` raise (its
+    weights arrive whole: ``load``, never an online step; until then they
+    are drawn from the seed and ``weights`` says so). It is keyed: its
+    cache and flow lengths live on the device between calls, ``score``
+    maps the call's stream keys to slots on the loop
+    (``telemetry/flowstate.FlowTable``, span ``flow.map``), the step takes
+    the state donated and hands back the new one at launch, and calls
+    apply in the order ``score`` was called, two in flight. A call that
+    fails before its launch leaves table and state as they were."""
 
     def __init__(self, seed: int = 0, learning_rate: float = 1e-3,
                  recon_weight: float = 0.7, fit_steps: int = 4,
-                 devices=None):
+                 devices=None, spec=None):
         import jax
-        import optax
-        from linkerd_tpu.models.anomaly import AnomalyModelConfig, init_params
-        from linkerd_tpu.ops.scoring import best_scorer, scorer_kind
+        from linkerd_tpu.models.spec import mlp36
+        from linkerd_tpu.telemetry.linerate import RingDispatcher
 
-        self.cfg = AnomalyModelConfig(recon_weight=recon_weight)
-        self._opt = optax.adam(learning_rate)
+        self.spec = spec if spec is not None else mlp36(recon_weight)
+        self.cfg = self.spec.cfg
         devices = list(devices if devices is not None else jax.devices())
+        if len(devices) > 1 and self.spec.single_device:
+            raise ValueError(
+                f"model {self.spec.name!r} is single-device: "
+                f"{len(devices)} devices were given")
         self.mesh = None
         self._batch_multiple = 1
+        self._devices = devices
+        self.fit_steps = fit_steps
+        # cumulative train steps; checkpointed so a restored model resumes
+        # its lineage, not a fresh step count
+        self._step = 0
+        # fit() calls per compiled shape ("<padded rows>", "+mask" when
+        # padding rows are masked out): with the dispatcher's per-bucket
+        # score counts, every program this scorer made XLA compile
+        self._fit_batches: Dict[str, int] = {}
+        # "seed" until a ``restore`` or a ``load`` has brought weights in
+        self.weights = "seed"
         if len(devices) > 1:
+            import optax
+            from linkerd_tpu.models.spec import reads_norm
             from linkerd_tpu.parallel.mesh import (
                 init_sharded, make_mesh, make_score_step, make_train_step,
             )
+            # parallel/mesh.py lays out mlp36 alone, by name: any other
+            # spec is single_device and was refused above.
             # width-aware tp heuristic: at this model's scale the mesh
             # comes out pure-data (tp only engages for wide layers)
+            self._opt = optax.adam(learning_rate)
             self.mesh = make_mesh(devices,
                                   model_width=max(self.cfg.enc_dims))
             self.params, self._opt_state = init_sharded(
@@ -327,60 +362,64 @@ class InProcessScorer(Scorer):
             # caller hands it a buffer it never re-reads (the dispatch
             # ring's staging copy, or a fresh per-call device_put on
             # the instrumented path)
-            self._scorer = make_score_step(self.mesh, self.cfg,
-                                           donate=True)
+            self._scorer = reads_norm(
+                make_score_step(self.mesh, self.cfg, donate=True))
             self._train_step = make_train_step(self.mesh, self._opt, self.cfg)
             self._batch_multiple = self.mesh.shape["data"]
             self.score_path = "mesh"
+            state = self.spec.init_state()
         else:
-            params = init_params(jax.random.key(seed), self.cfg)
-            # honor an explicit device choice (e.g. pin to the second
-            # chip); jit follows the committed placement of the params
+            # drawn where they will live (the flow model's 7 GB never
+            # pass through another device), then committed there: an
+            # explicit device choice (e.g. pin to the second chip) is
+            # honored, and jit follows the params' placement
+            with jax.default_device(devices[0]):
+                params = self.spec.init(jax.random.key(seed))
+                state = self.spec.init_state()
             self.params = jax.device_put(params, devices[0])
-            # committed like the params: adam's fresh step count comes
-            # back uncommitted, and the first train step would compile
-            # once for it and again for its own committed outputs
-            self._opt_state = jax.device_put(
-                self._opt.init(self.params), devices[0])
             # selected by where the params live, never probed: a kernel
             # Mosaic refuses on the chip raises out of the first score
-            self.score_path = scorer_kind(devices[0].platform)
-            self._scorer = best_scorer(self.cfg, devices[0].platform,
-                                       donate=True)
-            self._train_step = self._mk_train_step()
-        self.fit_steps = fit_steps
-        self._devices = devices
-        # cumulative train steps; checkpointed so a restored model resumes
-        # its lineage, not a fresh step count
-        self._step = 0
-        # fit() calls per compiled shape ("<padded rows>", "+mask" when
-        # padding rows are masked out): with the dispatcher's per-bucket
-        # score counts, every program this scorer made XLA compile
-        self._fit_batches: Dict[str, int] = {}
-        # Running feature normalization (updated on non-anomalous training
-        # rows): without it the autoencoder's reconstruction error is
-        # dominated by raw feature scale and tanh() saturates for normal
-        # AND anomalous traffic alike. The statistics live ON THE DEVICE
-        # as one triple ``(mu, var, initialised)``: a fit reduces its
-        # batch's mean and variance there (``_norm_step``) from the copy
-        # of the batch its train steps read, and the jitted steps apply
+            self._scorer = self.spec.make_step(devices[0].platform)
+            self.score_path = self.spec.score_path(devices[0].platform)
+            if self.spec.trains:
+                import optax
+                self._opt = optax.adam(learning_rate)
+                # committed like the params: adam's fresh step count comes
+                # back uncommitted, and the first train step would compile
+                # once for it and again for its own committed outputs
+                self._opt_state = jax.device_put(
+                    self._opt.init(self.params), devices[0])
+                self._train_step = self._mk_train_step()
+        # What a score step reads beside the parameters, ON THE DEVICE, as
+        # ONE attribute stored once. A keyed model's step takes it donated
+        # and the new one replaces it at launch. mlp36's is the running
+        # feature normalization ``(mu, var, initialised)`` (updated on
+        # non-anomalous training rows): without it the autoencoder's
+        # reconstruction error is dominated by raw feature scale and
+        # tanh() saturates for normal AND anomalous traffic alike. A fit
+        # reduces its batch's mean and variance on the device
+        # (``_norm_step``) from the copy of the batch its train steps
+        # read, and the jitted steps apply
         # models.anomaly.normalize_features there too — the z-score with
         # its 1e-2 soft variance floor (a near-constant training dim must
         # register novelty as a LARGE z-score, not a 1e3-sigma blowup;
         # hard clipping cost ~0.15 AUC on the k8s-restart benchmark). The
-        # host never reads a batch; ``snapshot()`` fetches the triple.
-        # ONE attribute, stored once: ``score`` captures a fit's pair
-        # whole, never a new ``mu`` beside an old ``var``.
-        self._norm_momentum = 0.2
-        self._norm_step = self._mk_norm_step()
-        self._norm = self._put_norm(np.zeros(self.cfg.in_dim, np.float32),
-                                    np.ones(self.cfg.in_dim, np.float32),
-                                    False)
+        # host never reads a batch; ``snapshot()`` fetches the triple;
+        # ``score`` captures a fit's pair whole, never a new ``mu`` beside
+        # an old ``var``.
+        self._state = self._put_state(state)
+        if self.spec.trains:
+            self._norm_momentum = 0.2
+            self._norm_step = self._mk_norm_step()
+        # a keyed model's rows are laid out by the host's table, on the
+        # loop, in call order (``_map_rows``)
+        self._table = self.spec.make_table() if self.spec.keyed else None
         # persistent double-buffered staging ring (the line-rate
         # dispatch path; see class docstring)
-        from linkerd_tpu.telemetry.linerate import RingDispatcher
-        self._dispatcher = RingDispatcher(self.cfg.in_dim,
-                                          self._bucket_target)
+        self._dispatcher = RingDispatcher(
+            self.spec.row_width, self._bucket_target,
+            dtype=self.spec.row_dtype,
+            prepare=self._map_rows if self.spec.keyed else None)
 
     @property
     def last_timing(self) -> Optional[dict]:
@@ -403,14 +442,27 @@ class InProcessScorer(Scorer):
         score path it built and the batch shapes it has dispatched —
         the ``device`` block of /model.json. ``chip_smoke.py`` and the
         bench read the platform from here: a linker that came up on the
-        CPU says so instead of serving under TPU names unnoticed."""
+        CPU says so instead of serving under TPU names unnoticed.
+
+        Keys: ``platform``, ``device_kind``, ``count`` (devices JAX sees),
+        ``model`` (the spec's name), ``weights`` (``seed`` as drawn at
+        construction, ``loaded`` once a ``restore`` or a ``load`` brought
+        them), ``score_path``, ``mesh`` (axis sizes,
+        or None), ``score_batches`` / ``fit_batches`` (calls per compiled
+        shape), and what the spec's ``describe`` adds. The flow model adds
+        ``flow``: ``slots``, ``positions``, ``experts_held``,
+        ``layer_share``, ``resident`` flows, ``layouts`` (calls per ``FxT``
+        layout the step was compiled for) and ``expert_tokens`` (the newest
+        call's tokens per expert layer and held expert)."""
         import jax
 
         d0 = self._devices[0]
-        return {
+        state = {
             "platform": d0.platform,
             "device_kind": d0.device_kind,
             "count": len(jax.devices()),
+            "model": self.spec.name,
+            "weights": self.weights,
             "score_path": self.score_path,
             "mesh": (dict(self.mesh.shape)
                      if self.mesh is not None else None),
@@ -418,6 +470,10 @@ class InProcessScorer(Scorer):
                               sorted(self._dispatcher.batches.items())},
             "fit_batches": dict(self._fit_batches),
         }
+        if self.spec.describe is not None:
+            state.update(self.spec.describe(
+                self._table, self._dispatcher.last_extras or {}))
+        return state
 
     def _put_rows(self, arr: np.ndarray):
         """Place a host array whose leading axis is the batch's rows: on
@@ -429,25 +485,22 @@ class InProcessScorer(Scorer):
         import jax
         return jax.device_put(arr, self._devices[0])  # l5d: ignore[jax-hotpath] — async placement: the score path's persistent staging buffer (donated to the step, never re-read) or a fit's batch, once a fit
 
-    def _put_norm(self, mu, var, initialized):
-        """The statistics triple as the jitted steps take it: tiny
-        arrays on the pinned device, replicated over the mesh."""
+    def _put_state(self, state):
+        """The state as the jitted steps take it: committed to the pinned
+        device (no copy of what was made there), replicated over the
+        mesh (mlp36's triple is tiny)."""
         import jax
 
         if self.mesh is not None:
             from linkerd_tpu.parallel.mesh import replicated
-            placement = replicated(self.mesh)
-        else:
-            placement = self._devices[0]
-        return jax.device_put((np.asarray(mu, np.float32),
-                               np.asarray(var, np.float32),
-                               np.bool_(initialized)), placement)
+            return jax.device_put(state, replicated(self.mesh))
+        return jax.device_put(state, self._devices[0])
 
     @property
     def _norm_initialized(self) -> bool:
         """Whether a fit has set the statistics yet. Blocking (reads the
         device's flag): for snapshots and tests, not the serving path."""
-        return bool(self._norm[2])
+        return bool(self._state[2])
 
     def _mk_norm_step(self):
         """The statistics program (``models.anomaly.running_norm``): a
@@ -508,6 +561,12 @@ class InProcessScorer(Scorer):
             target += m - target % m
         return target
 
+    def _must_train(self, what: str) -> None:
+        if not self.spec.trains:
+            raise RuntimeError(
+                f"model {self.spec.name!r} is frozen: it has no optimizer "
+                f"and no online fit, so no {what}()")
+
     def _pad_rows(self, arr: np.ndarray) -> np.ndarray:
         n = len(arr)
         target = self._bucket_target(n)
@@ -527,8 +586,9 @@ class InProcessScorer(Scorer):
 
         from linkerd_tpu.lifecycle.store import ModelSnapshot
 
+        self._must_train("snapshot")
         params = jax.device_get(self.params)
-        mu, var, initialized = jax.device_get(self._norm)
+        mu, var, initialized = jax.device_get(self._state)
         opt_leaves = [np.asarray(leaf) for leaf in
                       jax.tree_util.tree_leaves(
                           jax.device_get(self._opt_state))]
@@ -547,6 +607,7 @@ class InProcessScorer(Scorer):
 
         from linkerd_tpu.lifecycle.store import _cfg_to_dict
 
+        self._must_train("restore")
         if _cfg_to_dict(snap.cfg) != _cfg_to_dict(self.cfg):
             raise ValueError(
                 f"snapshot config {snap.cfg_dict()} does not match "
@@ -575,9 +636,34 @@ class InProcessScorer(Scorer):
                                              self._devices[0]))
             self.params = params
             self._opt_state = jax.tree_util.tree_unflatten(treedef, placed)
-        self._norm = self._put_norm(snap.mu, snap.var,
-                                    snap.norm_initialized)
+        self._state = self._put_state(
+            (np.asarray(snap.mu, np.float32),
+             np.asarray(snap.var, np.float32),
+             np.bool_(snap.norm_initialized)))
         self._step = int(snap.step)
+        self.weights = "loaded"
+
+    def load(self, params) -> None:
+        """A frozen model's weights arrive whole (the lifecycle's push
+        lands here): the old parameters and the state, a function of
+        them, are dropped BEFORE the new ones are placed (two copies of
+        the flow model do not fit a chip), the table starts empty, and
+        calls in flight finish on what they took. Blocking; between two
+        calls on the loop."""
+        import jax
+
+        if self.spec.trains:
+            raise RuntimeError(
+                f"model {self.spec.name!r} trains: its weights come by "
+                f"restore(), with their optimizer state")
+        self.params = self._state = None
+        with jax.default_device(self._devices[0]):
+            state = self.spec.init_state()
+        self.params = jax.device_put(params, self._devices[0])
+        self._state = self._put_state(state)
+        if self.spec.keyed:
+            self._table = self.spec.make_table()
+        self.weights = "loaded"
 
     def swap(self, snap):
         """Restore ``snap``; returns the displaced state so a failed
@@ -592,10 +678,17 @@ class InProcessScorer(Scorer):
         Also exercises the snapshot->restore->score hot-swap path (host
         gather, re-placement, optimizer-state rebuild) so the first real
         swap doesn't stall the event loop."""
+        if self.spec.keyed:
+            # its state is its flows': a dummy call would leave a flow
+            # behind; its programs compile on first use
+            return
         rows = max(rows, self._batch_multiple, 1)
-        x = np.zeros((rows, self.cfg.in_dim), np.float32)
+        x = np.zeros((rows, self.spec.row_width), self.spec.row_dtype)
+        if not self.spec.trains:
+            await self.score(x)
+            return
         params, opt_state = self.params, self._opt_state
-        norm = self._norm
+        norm = self._state
         step = self._step
         try:
             await self.score(x)
@@ -608,7 +701,7 @@ class InProcessScorer(Scorer):
             # startup-sequenced: warmup runs before the telemeter's drain
             # loop starts, so no concurrent fit/score exists to clobber
             self.params, self._opt_state = params, opt_state  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
-            self._norm = norm  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
+            self._state = norm  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
             self._step = step  # l5d: ignore[await-atomicity] — warmup is startup-sequenced; no concurrent mutator yet
 
     def _prep(self, x: np.ndarray) -> np.ndarray:
@@ -625,16 +718,17 @@ class InProcessScorer(Scorer):
         """Score [n, D] -> [n] through the donated staging ring. The
         event loop only pays one host memcpy into the staging slot plus
         JAX async dispatch; readback happens on the drainer thread.
-        Hot-swap safety: ``params``/``mu``/``var`` are captured HERE —
-        a concurrent ``restore``/``fit`` repoints the attributes but
-        never mutates the captured (immutable) device arrays, so an
-        in-flight donated batch always completes against a consistent
-        model."""
-        params = self.params
-        mu_d, var_d, _ = self._norm     # one read: one fit's pair
-
-        def step(xd):
-            return self._scorer(params, xd, mu_d, var_d)
+        Hot-swap safety: ``params`` and the state are each read ONCE, at
+        the launch — a concurrent ``restore``/``fit`` repoints the
+        attributes but never mutates the (immutable) device arrays a call
+        took, so an in-flight donated batch always completes against a
+        consistent model. A step that hands back another state than it
+        was given has advanced it (a keyed model's, donated): the new one
+        replaces the old here, before any other call can map — so calls
+        apply in order; a state handed back as it came is not stored, and
+        so never written over one a fit or a restore has repointed
+        since."""
+        n = len(x)
 
         def put(staging: np.ndarray):
             # per-device shard feed on the mesh; the array is donated. A
@@ -642,10 +736,38 @@ class InProcessScorer(Scorer):
             # lint follows calls, and this placement is on its path
             return self._put_rows(staging)
 
+        def step(xd, plan=None):
+            state = self._state     # one read: one fit's pair
+            scores, new, counts = self._scorer(
+                self.params, state, xd, n,
+                None if plan is None else plan.layout)
+            if new is not state:
+                self._state = new
+            return (scores, counts) if counts else scores
+
         return await self._dispatcher.dispatch(x, step, put)
+
+    def _map_rows(self, x: np.ndarray, rec: phases.Call):
+        """The dispatcher's ``prepare`` for a keyed model, run on the loop
+        in call order once the slot is held: the host's table gives the
+        call's flows their slots and positions, and moves forward at once,
+        so the next call maps on top of this one. Returns the rows as the
+        step takes them, the plan (whose layout the step is compiled for)
+        and the ``undo`` that puts the table back if the call fails before
+        its launch: such a call leaves table and state as they were."""
+        saved = self._table.checkpoint()
+        try:
+            plan = self._table.map(x)
+        except BaseException:
+            self._table.rollback(saved)
+            raise
+        for name, v in plan.counts.items():
+            rec.count(name, v)
+        return plan.rows, plan, lambda: self._table.rollback(saved)
 
     async def fit(self, x: np.ndarray, labels: np.ndarray,
                   mask: np.ndarray) -> float:
+        self._must_train("fit")
         rec = phases.Call(phases.FIT)
         try:
             return await self._fit(x, labels, mask, rec)
@@ -677,7 +799,7 @@ class InProcessScorer(Scorer):
         batch = [None if a is None else self._put_rows(a) for a in host]
         rec.count("fit.shipped_bytes",
                   sum(a.nbytes for a in host if a is not None))
-        norm = self._norm = self._norm_step(self._norm, *batch)
+        norm = self._state = self._norm_step(self._state, *batch)
         rec.mark(phases.UPDATE_NORM)
 
         def run() -> float:
@@ -711,6 +833,19 @@ class JaxAnomalyConfig:
     trainEveryBatches: int = 8      # online-fit cadence (0 = never train)
     reconWeight: float = 0.7
     learningRate: float = 0.001
+    # the scoring model (models/spec.py). "mlp36": the 36-column
+    # autoencoder + classifier, every row scored on its own, trained
+    # online. "latent_moe": a second, FROZEN tier beside it for flows:
+    # engine rows that carry a stream key are scored by the flow model
+    # (latent attention over a per-flow cache on the device, routed
+    # experts) as the next event of their stream; rows without a key keep
+    # the mlp36 path. The flow tier has no online fit: its weights arrive
+    # whole, by ``InProcessScorer.load`` (where the lifecycle's push will
+    # land). Until they have, the tier runs in SHADOW on weights drawn
+    # from the seed: keyed rows are scored by both tiers, the flow tier's
+    # scores are counted (flow_shadow_total) and not published.
+    # Single-device: the first chip, whatever the host has.
+    model: str = "mlp36"
     # line-rate micro-batcher (the default): drain is size- and
     # deadline-triggered — a batch dispatches when maxBatch rows are
     # pending OR the oldest pending row has lingered maxLingerMs,
@@ -776,7 +911,11 @@ class JaxAnomalyConfig:
 
 class JaxAnomalyTelemeter(Telemeter):
     def __init__(self, cfg: JaxAnomalyConfig, metrics: MetricsTree,
-                 scorer: Optional[Scorer] = None):
+                 scorer: Optional[Scorer] = None,
+                 flow_scorer: Optional[Scorer] = None):
+        from linkerd_tpu.models.spec import SPECS
+        if cfg.model not in SPECS:
+            raise ValueError(f"model must be one of {sorted(SPECS)}")
         if cfg.maxBatchesPerWake < 1:
             # 0 would silently disable draining (NOT a sentinel like
             # trainEveryBatches' 0 = never)
@@ -811,11 +950,23 @@ class JaxAnomalyTelemeter(Telemeter):
         self._native_featurizer = NativeFeaturizer()
         self.board = ScoreBoard(ttl_s=cfg.scoreTtlSecs)
         self._scorer = scorer
+        # a keyed model is a second tier BESIDE the row scorer, for the
+        # rows that carry a stream key: its spec, resolved here once;
+        # its scorer is built with the row scorer (``_ensure_scorer``)
+        # unless one was handed in
+        model = SPECS[cfg.model]()
+        self._flow_spec = model if model.keyed else None
+        self._flow_scorer = flow_scorer
         self._stop = asyncio.Event()
         self._wake = asyncio.Event()  # batcher wake: rows pending
         self._fit_lock = asyncio.Lock()
         self._node = metrics.scope("anomaly")
         self._scored = self._node.counter("scored_total")
+        # events scored by the flow tier; scored_total includes them
+        self._flow_scored = self._node.counter("flow_scored_total")
+        # events the flow tier scored in shadow (weights from the seed):
+        # not published; the row scorer scored those rows too
+        self._flow_shadow = self._node.counter("flow_shadow_total")
         # rows scored IN the native engines (in-data-plane tier); the
         # scored_total counter includes them — native_scored_fraction
         # is the native-vs-JAX tier split
@@ -1216,6 +1367,12 @@ class JaxAnomalyTelemeter(Telemeter):
         return GrpcScorerClient(addr)
 
     def _ensure_scorer(self) -> Scorer:
+        if self._flow_spec is not None and self._flow_scorer is None:
+            import jax
+            # single-device, whatever the host has: the first chip, which
+            # it shares with the row scorer (or with its mesh)
+            self._flow_scorer = InProcessScorer(
+                spec=self._flow_spec, devices=jax.devices()[:1])
         if self._scorer is None:
             if self.cfg.sidecarAddress:
                 from linkerd_tpu.telemetry.linerate import TieredScorer
@@ -1492,6 +1649,7 @@ class JaxAnomalyTelemeter(Telemeter):
         nat_dsts: List[str] = []
         nat_scored: Optional[dict] = None
         x_nat: Optional[np.ndarray] = None
+        flow: Optional[dict] = None
         if k:
             # encode the WHOLE block in one pass — the featurizer's
             # per-route drift EWMA must advance exactly once per drain,
@@ -1502,6 +1660,14 @@ class JaxAnomalyTelemeter(Telemeter):
             x_enc, inv_all, dsts = \
                 self._native_featurizer.encode_block(nat_block)
             is_scored = nat_block[:, NATIVE_COL_SCORED] > 0.5
+            if self._flow_scorer is not None:
+                flow, in_flow = self._flow_rows(nat_block, x_enc, inv_all,
+                                                dsts)
+                if flow is not None and flow["live"]:
+                    # a row with a stream key is the flow tier's alone
+                    x_enc, inv_all = x_enc[~in_flow], inv_all[~in_flow]
+                    nat_block = nat_block[~in_flow]
+                    is_scored = is_scored[~in_flow]
             if is_scored.any():
                 all_sc = bool(is_scored.all())
                 nat_scored = {
@@ -1531,7 +1697,59 @@ class JaxAnomalyTelemeter(Telemeter):
             x = x_py
         return {"items": items, "fvs": fvs, "x": x, "labels": labels,
                 "mask": mask, "n_py": n_py, "nat_inv": nat_inv,
-                "nat_dsts": nat_dsts, "nat_scored": nat_scored}
+                "nat_dsts": nat_dsts, "nat_scored": nat_scored,
+                "flow": flow}
+
+    def _flow_rows(self, nat_block: np.ndarray, x_enc: np.ndarray,
+                   inv: np.ndarray, dsts: List[str]):
+        """The engine rows that carry a stream key, as the flow scorer
+        takes them: int32 ``(stream key, restart flag, event id)``, the
+        flag set on a stream's first sample (frame count 1 or less), the
+        id by ``models.features.event_ids``. ``live``: whether the flow
+        scorer's weights were loaded; on weights drawn from the seed the
+        tier runs in shadow (``_score_flows``) and the rows stay the row
+        scorer's too. Returns ``(None, mask)`` where no row carries a
+        key."""
+        from linkerd_tpu.models.features import event_ids
+        from linkerd_tpu.telemetry.linerate import (
+            NATIVE_COL_SEQ, NATIVE_COL_STREAM,
+        )
+        key = nat_block[:, NATIVE_COL_STREAM].astype(np.int64)
+        in_flow = key > 0
+        if not in_flow.any():
+            return None, in_flow
+        vocab = self._flow_scorer.cfg.vocab_slice
+        rows = np.stack([key[in_flow],
+                         nat_block[in_flow, NATIVE_COL_SEQ] <= 1,
+                         event_ids(x_enc[in_flow], vocab)], 1
+                        ).astype(np.int32)
+        live = getattr(self._flow_scorer, "weights", "loaded") == "loaded"
+        return {"rows": rows, "inv": inv[in_flow], "dsts": dsts,
+                "live": live}, in_flow
+
+    async def _score_flows(self, flow: dict) -> int:
+        """The flow tier's half of a batch: score the stream rows as their
+        flows' next events and, where the tier is live, publish per-route
+        means as the MLP's scores publish. In shadow (weights from the
+        seed: nothing a route should be ejected on) the scores are counted
+        under ``flow_shadow_total`` and go no further. A failing flow
+        scorer drops this half only."""
+        try:
+            scores = await self._flow_scorer.score(flow["rows"])
+        except asyncio.CancelledError:
+            raise
+        except Exception as e:  # noqa: BLE001 — best-effort, as the MLP tier
+            self._score_failures.incr()
+            log.warning("flow scorer failed (flow rows dropped): %r", e)
+            return 0
+        if not flow["live"]:
+            self._flow_shadow.incr(len(scores))
+            return 0
+        self._publish_route_means(flow["dsts"], flow["inv"],
+                                  np.asarray(scores))  # l5d: ignore[jax-hotpath] — a host array already: the drainer read it back
+        self._flow_scored.incr(len(scores))
+        self._scored.incr(len(scores))
+        return len(scores)
 
     async def _score_and_publish(self, scorer: Scorer, b: dict) -> int:
         """Score one assembled batch and publish every downstream
@@ -1546,6 +1764,10 @@ class JaxAnomalyTelemeter(Telemeter):
         ns = b.get("nat_scored")
         k_ns = 0 if ns is None else len(ns["x"])
         n_jax = len(x)
+        n_flow = (await self._score_flows(b["flow"])
+                  if b.get("flow") is not None else 0)
+        if not n_jax and not k_ns:
+            return n_flow
         t_drain = time.monotonic()
         ts_us = int(time.time() * 1e6)
         scores: Optional[np.ndarray] = None
@@ -1570,7 +1792,7 @@ class JaxAnomalyTelemeter(Telemeter):
                         "plane unaffected): %r", e)
                 self._set_degraded(True)
                 if k_ns == 0:
-                    return 0
+                    return n_flow
             else:
                 scores = np.asarray(scores)  # l5d: ignore[jax-hotpath] — scorers return host arrays (the drainer already did readback); this is a no-op view
                 if self.board.degraded:
@@ -1673,7 +1895,7 @@ class JaxAnomalyTelemeter(Telemeter):
                 self._train_loss.set(loss)
                 self._maybe_refresh_native_weights(scorer)
         self._maybe_distill(scorer)
-        return n_scored
+        return n_scored + n_flow
 
     def _publish_native_batch(self, ns: Optional[dict]) -> None:
         """Publish engine-scored rows to the board: per-route score
@@ -1868,6 +2090,8 @@ class JaxAnomalyTelemeter(Telemeter):
                     log.exception("shutdown checkpoint failed")
         if self._scorer is not None:
             self._scorer.close()
+        if self._flow_scorer is not None:
+            self._flow_scorer.close()
 
 
 # -- score-driven failure accrual -------------------------------------------

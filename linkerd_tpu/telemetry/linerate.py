@@ -97,14 +97,29 @@ class _Slot:
 class RingDispatcher:
     """Persistent double-buffered score dispatch.
 
-    ``dispatch(x, step, put)`` copies ``x`` ([n, D], cast to float32 by
-    the copy) into a preallocated staging buffer for the padded batch
+    ``dispatch(x, step, put)`` copies ``x`` ([n, width], cast to the ring's
+    dtype by the copy: float32 feature rows, or int32 flow rows) into a
+    preallocated staging buffer for the padded batch
     bucket, hands the buffer to ``put`` (which places it on device),
     hands that to ``step`` (which invokes the DONATING jitted score step
     — async dispatch, no barrier), and returns an awaitable resolved by
     the background drainer thread once readback completes. Two slots per
     bucket: batch N fills slot B while slot A's
     transfer+compute+readback chain is in flight.
+
+    A model with state per flow builds the ring with ``prepare``: called
+    with ``x`` once the slot is held, it returns the rows to stage in
+    ``x``'s place (the call laid out for the step), the plan of that
+    layout, which the step is then called with (``step(xd, plan)``), and an
+    ``undo`` that is called if the call fails before its launch. Calls of
+    such a ring map, stage and launch one at a time in the order
+    ``dispatch`` was called (a FIFO lock from entry to launch, which
+    ``dispatch.slot_wait`` takes in), so the device applies them in that
+    order. A step may return ``(scores, extras)``: ``extras`` (a dict of
+    small device arrays) is read back with the scores, its scalars counted
+    on the call's record and the whole kept in ``last_extras``; anything
+    else the step makes (the model's state) stays on the device with the
+    caller.
 
     Every call is one ``phases.Call`` record, stamped at each of those
     boundaries on the loop and on the drainer and closed when the call
@@ -114,14 +129,23 @@ class RingDispatcher:
     buffer to a step compiled with ``donate_argnums`` —
     neither the dispatcher nor any caller may re-read the device array
     after dispatch (JAX deletes donated buffers; re-reads raise).
-    Staging rows beyond ``n`` may hold stale rows from earlier batches;
-    the model scores rows independently and the result is sliced to
-    ``n``, so stale padding never contaminates live scores.
+    Staging rows beyond ``n`` hold stale rows from earlier batches. A
+    model that scores rows independently computes them and the result is
+    sliced to ``n``; a model with state must mask them out of its state
+    by ``n`` (its step is bound to ``n``): the dispatcher never trusts
+    padding to be harmless.
     """
 
     def __init__(self, in_dim: int, bucket_fn: Callable[[int], int],
-                 depth: int = 2):
+                 depth: int = 2, dtype=np.float32,
+                 prepare: Optional[Callable] = None):
         self.in_dim = in_dim
+        self.dtype = np.dtype(dtype)
+        self._prepare = prepare
+        # calls that are prepared take their turn from entry to launch
+        self._turn = asyncio.Lock() if prepare is not None else None
+        # the newest call's extras, as host arrays
+        self.last_extras: Optional[Dict[str, np.ndarray]] = None
         self._bucket_fn = bucket_fn
         self.depth = max(1, depth)
         self._slots: Dict[int, List[_Slot]] = {}
@@ -153,7 +177,7 @@ class RingDispatcher:
             item = self._queue.get()
             if item is None:
                 return
-            result, n, loop, fut, slot, rec = item
+            result, extras, n, loop, fut, slot, rec = item
             rec.mark(phases.QUEUE_WAIT)
             out: Optional[np.ndarray] = None
             err: Optional[BaseException] = None
@@ -169,6 +193,12 @@ class RingDispatcher:
                 scores = np.asarray(result, dtype=np.float32)
                 rec.count("readback.bytes", scores.nbytes)
                 out = scores[:n].copy()
+                if extras is not None:
+                    host = {k: np.asarray(v) for k, v in extras.items()}
+                    for name, v in host.items():
+                        if v.ndim == 0:
+                            rec.count(name, int(v))
+                    self.last_extras = host
             except BaseException as e:  # noqa: BLE001 — surfaced via fut
                 err = e
             self._release(slot)
@@ -195,7 +225,7 @@ class RingDispatcher:
     def _acquire_nowait(self, bucket: int) -> Optional[_Slot]:
         slots = self._slots.get(bucket)
         if slots is None:
-            slots = [_Slot(np.zeros((bucket, self.in_dim), np.float32),
+            slots = [_Slot(np.zeros((bucket, self.in_dim), self.dtype),
                            bucket)
                      for _ in range(self.depth)]
             self._slots[bucket] = slots
@@ -247,19 +277,31 @@ class RingDispatcher:
 
     # -- dispatch ---------------------------------------------------------
     async def dispatch(self, x: np.ndarray,
-                       step: Callable[[object], object],
+                       step: Callable[..., object],
                        put: Callable[[np.ndarray], object]) -> np.ndarray:
         """Score one batch through the donated ring; returns f32 [n]."""
         if self._closed:
             raise RuntimeError("dispatcher closed")
         rec = phases.Call(phases.SCORE)
         try:
-            return await self._dispatch(x, step, put, rec)
+            if self._turn is None:
+                fut = await self._launch(x, step, put, rec)
+            else:
+                async with self._turn:
+                    fut = await self._launch(x, step, put, rec)
+            try:
+                return await fut
+            finally:
+                # cancelled here, the call made no hop: the drainer still
+                # holds it
+                if not fut.cancelled():
+                    rec.mark(phases.HOP)
         finally:
             # a raised call closes too, with the stamps it got to
             self.last = rec.close()
 
-    async def _dispatch(self, x, step, put, rec: phases.Call) -> np.ndarray:
+    async def _launch(self, x, step, put,
+                      rec: phases.Call) -> asyncio.Future:
         n = len(x)
         loop = asyncio.get_running_loop()
         bucket = int(self._bucket_fn(n))
@@ -268,7 +310,11 @@ class RingDispatcher:
         if self._closed:  # re-check: close() may have raced the acquire
             self._release(slot)
             raise RuntimeError("dispatcher closed")
+        plan = undo = None
         try:
+            if self._prepare is not None:
+                x, plan, undo = self._prepare(x, rec)
+                rec.mark(phases.FLOW_MAP)
             np.copyto(slot.staging[:n], x, casting="unsafe")
             rec.mark(phases.STAGE)
             xd = put(slot.staging)
@@ -277,10 +323,15 @@ class RingDispatcher:
                 warnings.filterwarnings(
                     "ignore", message=_DONATION_DECLINED_MSG)
                 # async dispatch; the step donates the device copy
-                result = step(xd)
+                result = step(xd) if plan is None else step(xd, plan)
         except BaseException:
+            if undo is not None:
+                undo()
             self._release(slot)
             raise
+        extras = None
+        if isinstance(result, tuple):
+            result, extras = result
         self.batches[bucket] = self.batches.get(bucket, 0) + 1
         rec.count("score.calls")
         rec.count("put.bytes", slot.staging.nbytes)
@@ -289,14 +340,8 @@ class RingDispatcher:
         # stamped ahead of the hand-over: past it the record is the
         # drainer's to stamp
         rec.mark(phases.LAUNCH)
-        self._queue.put((result, n, loop, fut, slot, rec))
-        try:
-            return await fut
-        finally:
-            # cancelled here, the call made no hop: the drainer still
-            # holds it
-            if not fut.cancelled():
-                rec.mark(phases.HOP)
+        self._queue.put((result, extras, n, loop, fut, slot, rec))
+        return fut
 
     def close(self) -> None:
         self._closed = True
@@ -313,7 +358,7 @@ class RingDispatcher:
                 break
             if item is None:
                 continue
-            _result, _n, loop, fut, slot, _rec = item
+            _result, _extras, _n, loop, fut, slot, _rec = item
             self._release(slot)
             try:
                 loop.call_soon_threadsafe(

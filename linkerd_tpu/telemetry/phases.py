@@ -12,13 +12,22 @@ when they began.
 
 Counts, each on the call that made it: ``score.calls``, ``put.bytes``
 (handed to ``device_put``: the whole padded bucket), ``slot.waits`` (a
-dispatch that found no free slot), ``readback.bytes``; ``fit.calls``,
+dispatch that found no free slot), ``readback.bytes``; for a model with
+state per flow also what the host's table did for the call
+(``flow.events``, ``flow.restarts``: chunks that began a flow,
+``flow.evictions``, ``flow.wraps``: flows restarted for want of
+positions, ``flow.resident`` after the call) and what the device step
+reported (``cache.positions``: the sum of the flows' lengths after the
+call, ``moe.local_pairs``: token-expert pairs computed here,
+``moe.max_expert_tokens``: the fullest held expert of the call, over its
+layers); ``fit.calls``,
 ``fit.shipped_bytes`` (the padded host arrays a fit places on the
 device: rows, labels, mask and, where rows were padded, the row mask;
 once a fit, whatever ``fit_steps``).
 
-Score call (``RingDispatcher.dispatch``), in order: SLOT_WAIT, STAGE,
-PUT, LAUNCH on the event loop; QUEUE_WAIT, DEVICE_WAIT, READBACK on the
+Score call (``RingDispatcher.dispatch``), in order: SLOT_WAIT, FLOW_MAP
+(only where the model keeps state per flow: stream key to slot, the
+call's layout), STAGE, PUT, LAUNCH on the event loop; QUEUE_WAIT, DEVICE_WAIT, READBACK on the
 drainer; HOP back onto the loop, up to the awaiting coroutine having its
 result. Fit call (``InProcessScorer.fit``): PREP (pad and cast), then
 UPDATE_NORM (the batch placed on the device, the statistics program
@@ -41,6 +50,7 @@ LOG_CAPACITY = 4096
 
 SCORE, FIT = "score", "fit"
 SLOT_WAIT, STAGE = "dispatch.slot_wait", "dispatch.stage"
+FLOW_MAP = "flow.map"
 PUT, LAUNCH = "dispatch.put", "dispatch.launch"
 QUEUE_WAIT, DEVICE_WAIT = "drain.queue_wait", "drain.device_wait"
 READBACK, HOP = "drain.readback", "drain.hop"

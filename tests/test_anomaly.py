@@ -517,9 +517,9 @@ class TestFitStatistics:
                 for v in (2.0, 3.0)]
             pairs, real, stop = [], scorer._scorer, threading.Event()
 
-            def recording(params, xd, mu, var):
-                pairs.append((mu, var))
-                return real(params, xd, mu, var)
+            def recording(params, state, xd, n, layout):
+                pairs.append(state[:2])
+                return real(params, state, xd, n, layout)
 
             def swapper():
                 i = 0

@@ -143,6 +143,61 @@ class TestScorerSelection:
                  if line.startswith("TEMP")]
         assert len(temps) == 4 and max(temps) < 32 * 2 ** 20, temps
 
+    def test_flow_step_compiles_for_v5e_at_the_published_widths(self):
+        """The flow model's step (``models.latent_moe.flow_step``) as the
+        benchmark's cell runs it, 64 flows x 64 events at hidden 7,168 with
+        12 of 384 experts held, compiled by the TPU's own compiler with no
+        chip: it fits one v5e (15.75 GiB) with its weights and its cache
+        as arguments, and the cache is updated in place (aliased)."""
+        code = (
+            "import jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models import latent_moe as lm\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "cfg = lm.LatentMoEConfig()\n"
+            "held = cfg.experts_held[1] - cfg.experts_held[0]\n"
+            "params = {'layers': [{} for _ in range(cfg.layers)]}\n"
+            "for name, (shape, _, _, each) in lm.tensor_table(cfg).items():\n"
+            "    a = S(jnp.bfloat16, *((held,) if each else ()), *shape)\n"
+            "    p = name.split('.')\n"
+            "    if p[0] == 'layers': params['layers'][int(p[1])][p[2]] = a\n"
+            "    else: params[name] = a\n"
+            "state = jax.tree_util.tree_map(\n"
+            "    lambda a: S(a.dtype, *a.shape),\n"
+            "    jax.eval_shape(lambda: lm.init_state(cfg)))[:3] + (\n"
+            "    (S(jnp.bfloat16, cfg.layers, cfg.entry_width),\n"
+            "     S(jnp.bfloat16, cfg.hidden_size)),)\n"
+            "step = jax.jit(lm.flow_step, static_argnames=('cfg', 'F', 'T'),\n"
+            "               donate_argnums=(1, 2))\n"
+            "m = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
+            "               S(jnp.int32), cfg=cfg, F=64, T=64\n"
+            "               ).compile().memory_analysis()\n"
+            "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
+            "      m.alias_size_in_bytes)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=900,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        args, temp, alias = (int(v) for v in next(
+            line for line in proc.stdout.splitlines()
+            if line.startswith("BYTES")).split()[1:])
+        # weights 6.99 GB and the cache 3.02 GB; the cache comes back aliased
+        assert 9.9e9 < args < 10.2e9 and alias > 3.0e9
+        assert temp < 2.5 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
+
 
 class TestFailLoud:
     def test_inprocess_primary_that_cannot_build_fails_at_start(

@@ -1,0 +1,569 @@
+"""The flow model (``models/latent_moe.py``) behind ``InProcessScorer``,
+against the benchmark's plain reference (``chipbench/reference/
+latent_moe.py``) on seeded weights, at a tiny preset on the CPU: hidden 64,
+4 heads, 16 experts top 2 of which 4 are held, a vocabulary of 128."""
+
+import asyncio
+import dataclasses
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import latent_moe as ref
+from linkerd_tpu.models import latent_moe as lm
+from linkerd_tpu.models.features import FEATURE_DIM, event_ids
+from linkerd_tpu.models.spec import SPECS, latent_moe, mlp36
+from linkerd_tpu.telemetry import phases
+from linkerd_tpu.telemetry.anomaly import (
+    InProcessScorer, JaxAnomalyConfig, JaxAnomalyTelemeter,
+)
+from linkerd_tpu.telemetry.flowstate import FlowTable
+from linkerd_tpu.telemetry.linerate import (
+    NATIVE_COL_KIND, NATIVE_COL_SEQ, NATIVE_COL_STREAM, NATIVE_ROW_WIDTH,
+)
+from linkerd_tpu.telemetry.metrics import MetricsTree
+
+SEED = 7
+TINY = {
+    "hidden_size": 64, "num_attention_heads": 4, "q_lora_rank": 32,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "n_routed_experts": 4, "n_shared_experts": 1, "num_experts_per_tok": 2,
+    "routed_scaling_factor": 2.827, "first_k_dense_replace": 1,
+    "rms_norm_eps": 1e-5, "rope_theta": 50000,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 64,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 4096,
+                     "type": "yarn"},
+    "scoring_func": "sigmoid", "n_group": 1, "num_hidden_layers": 3,
+    "vocab_size": 128,
+    "model": {"in_dim": 3, "router_experts": 16, "experts_held": [4, 8],
+              "layer_share": 4, "slots": 8, "positions": 64,
+              "expert_tile": 8, "compute_dtype": "bfloat16"}}
+CFG = lm.LatentMoEConfig.from_config(TINY)
+# a score is off by the compute type's rounding, a few 1e-4 at this width;
+# a token whose second and third router scores lie within rounding takes
+# another expert in bfloat16 than in float32 and is off by up to 1e-2
+TYPICAL, WORST = 6e-4, 2e-2
+
+
+def run(coro):
+    return asyncio.run(asyncio.wait_for(coro, 300))
+
+
+def scorer(cfg=CFG, seed=SEED):
+    return InProcessScorer(seed=seed, spec=latent_moe(cfg),
+                           devices=jax.devices()[:1])
+
+
+def rows_of(flows: dict, restart=()) -> np.ndarray:
+    """``{key: ids}`` -> rows, the flows' events interleaved round robin."""
+    out, t = [], 0
+    while any(t < len(v) for v in flows.values()):
+        out += [(k, int(k in restart and t == 0), v[t])
+                for k, v in flows.items() if t < len(v)]
+        t += 1
+    return np.array(out, np.int32).reshape(-1, 3)
+
+
+def reference_scores(seqs: dict, cfg=TINY) -> dict:
+    """``{key: ids}`` -> ``{key: the reference's score of every event}``,
+    each flow forward once from its start token."""
+    L = cfg["model"]["positions"]
+    tokens = np.zeros((len(seqs), L), np.int32)
+    for b, ids in enumerate(seqs.values()):
+        tokens[b, 1:1 + len(ids)] = ids
+    got = ref.forward(SEED, cfg, tokens, block=2)
+    return ({k: got["score"][b, 1:1 + len(v)]
+             for b, (k, v) in enumerate(seqs.items())}, got)
+
+
+def close_to(got, want):
+    gap = np.abs(np.asarray(got) - np.asarray(want))
+    assert np.median(gap) < TYPICAL and gap.max() < WORST, (
+        np.median(gap), gap.max())
+
+
+@pytest.fixture(scope="module")
+def seqs():
+    rng = np.random.default_rng(0)
+    return {11: rng.integers(1, 128, 40), 22: rng.integers(1, 128, 25),
+            33: rng.integers(1, 128, 33)}
+
+
+class TestAgainstTheReference:
+    def test_the_reference_is_plain(self):
+        with open(ref.__file__) as f:
+            src = f.read()
+        assert "linkerd_tpu" not in src.replace(
+            "It imports\nnothing of the program", "")
+        assert 'default_matmul_precision("highest")' in src
+
+    def test_one_full_forward(self, seqs):
+        async def go():
+            s = scorer()
+            try:
+                rows = rows_of(seqs)
+                return rows, await s.score(rows), s._state
+            finally:
+                s.close()
+        rows, got, state = run(go())
+        want, full = reference_scores(seqs)
+        assert got.shape == (len(rows),) and got.dtype == np.float32
+        assert ((got >= 0) & (got <= 1)).all()
+        for key in seqs:
+            close_to(got[rows[:, 0] == key], want[key])
+        # the cache holds what the reference computes of each position
+        cache = np.stack([np.asarray(c, np.float32) for c in state[0]])
+        for b, (key, ids) in enumerate(seqs.items()):
+            n = 1 + len(ids)
+            gap = np.abs(cache[:, b, :n] - full["entries"][:, b, :n])
+            assert np.median(gap) < 4e-3 and gap.max() < 0.2
+        assert np.asarray(state[1])[:3].tolist() == [41, 26, 34]
+
+    def test_chunked_appends_through_the_cache(self, seqs):
+        """Chunks of unequal length, a restart in the middle: every call's
+        scores are the reference's for one full forward of each flow since
+        its restart."""
+        rng = np.random.default_rng(1)
+        again = rng.integers(1, 128, 17)     # flow 22's second life
+
+        async def go():
+            s = scorer()
+            at, got = {k: 0 for k in seqs}, {k: [] for k in seqs}
+            got[220] = []
+            try:
+                step = 0
+                while any(at[k] < len(v) for k, v in seqs.items()):
+                    chunk = {k: v[at[k]:at[k] + int(rng.integers(0, 9))]
+                             for k, v in seqs.items()}
+                    rows = rows_of({k: c for k, c in chunk.items()
+                                    if len(c)})
+                    for k, c in chunk.items():
+                        at[k] += len(c)
+                    if len(rows):
+                        out = await s.score(rows)
+                        for k in chunk:
+                            got[k].extend(out[rows[:, 0] == k])
+                    step += 1
+                # flow 22 restarts under its key and runs on in two calls
+                out = await s.score(rows_of({22: again[:9]}, restart={22}))
+                got[220].extend(out)
+                got[220].extend(await s.score(rows_of({22: again[9:]})))
+                return got, s.device_state()
+            finally:
+                s.close()
+        got, state = run(go())
+        want, _ = reference_scores({**seqs, 220: again})
+        for key in got:
+            close_to(got[key], want[key])
+        assert len(state["flow"]["layouts"]) > 1     # more than one shape
+        assert state["flow"]["resident"] == 3
+
+    def test_the_shares_add_up_to_the_uncut_layer(self):
+        """Guide section 4: the routed parts that all 4 shares give, with
+        the shared expert counted once, add up to what the uncut reference
+        gives for the whole layer; program and reference alike."""
+        layer = 1
+        x = jax.random.normal(jax.random.key(3), (24, CFG.hidden_size))
+        whole = ref.layer_weights(SEED, TINY, layer, held=(0, 16))
+        idx, wts, _ = ref.route(whole, TINY, ref._q(x, "bf16"))
+        uncut = (ref.shared_part(whole, x)
+                 + ref.routed_part(whole, TINY, x, idx, wts, 0))
+        ref_sum = ref.shared_part(whole, x)
+        got_sum = ref.shared_part(whole, x)
+        tokens = 0
+        for lo in range(0, 16, 4):
+            part = ref.layer_weights(SEED, TINY, layer, held=(lo, lo + 4))
+            ref_sum = ref_sum + ref.routed_part(part, TINY, x, idx, wts, lo)
+            cfg = dataclasses.replace(CFG, experts_held=(lo, lo + 4))
+            lp = lm.init(jax.random.key(SEED), cfg)["layers"][layer]
+            out, cnt = lm.routed_experts(lp, cfg, x, jnp.ones(24, bool))
+            got_sum = got_sum + out
+            tokens += int(cnt.sum())
+        assert tokens == 24 * 2      # every pair computed on one share
+        np.testing.assert_allclose(ref_sum, uncut, atol=1e-5)
+        gap = np.abs(np.asarray(got_sum) - np.asarray(uncut))
+        scale = np.abs(np.asarray(uncut)).mean()
+        assert np.median(gap) < 0.01 * scale and gap.max() < 0.2 * scale
+
+    def test_routing_is_dropless_when_every_token_picks_one_expert(self):
+        params = lm.init(jax.random.key(SEED), CFG)
+        lp = dict(params["layers"][1])
+        # the bias puts experts 5 and 6 (both held) first for every token
+        lp["router_bias"] = jnp.zeros(16, jnp.bfloat16).at[
+            jnp.array([5, 6])].set(10.0)
+        x = jax.random.normal(jax.random.key(4), (50, CFG.hidden_size))
+        valid = jnp.arange(50) < 47          # three rows of padding
+        out, cnt = lm.routed_experts(lp, CFG, x, valid)
+        assert cnt.tolist() == [0, 47, 47, 0]
+        idx, w = (np.asarray(a) for a in lm.route(lp, CFG, x))
+        assert (np.sort(idx, 1) == [5, 6]).all()
+        # expert 5 is the second held here (4..7), 6 the third
+        want = sum(
+            np.where(idx == 4 + e, w, 0).sum(1, keepdims=True)
+            * np.asarray(lm._swiglu(x, lp["exp_gate"][e], lp["exp_up"][e],
+                                    lp["exp_down"][e]))
+            for e in (1, 2))
+        np.testing.assert_allclose(np.asarray(out)[:47], want[:47],
+                                   rtol=2e-2, atol=2e-3)
+        assert not np.asarray(out)[47:].any()
+
+
+class TestState:
+    def test_padding_rows_leave_the_state_untouched(self, seqs):
+        """The same rows in a bucket of their own size and in a larger one
+        whose padding holds stale rows: the same scores, the same state."""
+        spec = latent_moe(CFG)
+        params = spec.init(jax.random.key(SEED))
+        step = spec.make_step("cpu")
+        rows = rows_of({k: v[:5] for k, v in seqs.items()})    # 15 rows
+        plan = FlowTable(CFG.slots, CFG.positions, CFG.vocab_slice).map(rows)
+        outs = []
+        for bucket, stale in ((15, None), (32, 77)):
+            staged = np.full((bucket, 3), stale or 0, np.int32)
+            staged[:15] = plan.rows
+            outs.append(step(params, spec.init_state(), jnp.asarray(staged),
+                             15, plan.layout))
+        (a, sa, ca), (b, sb, cb) = outs
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:15])
+        assert not np.asarray(b)[15:].any()
+        for x, y in zip(jax.tree_util.tree_leaves(sa),
+                        jax.tree_util.tree_leaves(sb)):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+        assert int(ca["cache.positions"]) == int(cb["cache.positions"]) == 18
+
+    def test_two_calls_in_flight_apply_in_call_order(self, seqs):
+        calls = [rows_of({k: v[a:a + 6] for k, v in seqs.items()})
+                 for a in range(0, 24, 6)]
+
+        async def go(together):
+            s = scorer()
+            try:
+                if together:
+                    return await asyncio.gather(*(s.score(c) for c in calls))
+                return [await s.score(c) for c in calls]
+            finally:
+                s.close()
+        for a, b in zip(run(go(True)), run(go(False))):
+            np.testing.assert_array_equal(a, b)
+
+    def test_a_failed_call_leaves_the_state_as_it_was(self, seqs):
+        first = rows_of({k: v[:6] for k, v in seqs.items()})
+        second = rows_of({k: v[6:12] for k, v in seqs.items()})
+        bad = second.copy()
+        bad[3, 2] = 128                     # an id outside the slice
+
+        async def go(fail):
+            s = scorer()
+            try:
+                await s.score(first)
+                if fail:
+                    before = s._table.checkpoint()
+                    with pytest.raises(ValueError):
+                        await s.score(bad)
+                    # a new key in a call that fails at its launch
+                    real = s._scorer
+
+                    def boom(*a, **kw):
+                        raise RuntimeError("launch failed")
+                    s._scorer = boom
+                    with pytest.raises(RuntimeError, match="launch failed"):
+                        await s.score(np.array([[99, 0, 5]], np.int32))
+                    s._scorer = real
+                    after = s._table.checkpoint()
+                    assert before[0] == after[0] and before[4] == after[4]
+                    np.testing.assert_array_equal(before[2], after[2])
+                return await s.score(second)
+            finally:
+                s.close()
+        np.testing.assert_array_equal(run(go(True)), run(go(False)))
+
+    def test_the_frozen_spec_has_no_fit_and_one_device(self, seqs):
+        async def go():
+            s = scorer()
+            try:
+                rows = rows_of(seqs)
+                with pytest.raises(RuntimeError, match="frozen"):
+                    await s.fit(rows, np.zeros(len(rows)),
+                                np.zeros(len(rows)))
+                with pytest.raises(RuntimeError, match="frozen"):
+                    s.snapshot()
+                d = s.device_state()
+                assert d["model"] == "latent_moe"
+                assert d["flow"]["experts_held"] == [4, 8]
+                assert d["flow"]["slots"] == 8
+                assert d["flow"]["positions"] == 64
+            finally:
+                s.close()
+        run(go())
+        with pytest.raises(ValueError, match="single-device"):
+            InProcessScorer(spec=latent_moe(CFG), devices=jax.devices()[:2])
+
+    def test_the_default_spec_is_todays_model(self):
+        s = InProcessScorer(devices=jax.devices()[:1])
+        try:
+            assert s.spec.name == "mlp36" and s.spec.trains
+            assert s.spec.row_width == FEATURE_DIM
+            assert s.device_state()["model"] == "mlp36"
+            assert "flow" not in s.device_state()
+        finally:
+            s.close()
+        assert sorted(SPECS) == ["latent_moe", "mlp36"]
+        assert mlp36(0.5).cfg.recon_weight == 0.5
+
+
+class TestFlowTable:
+    def table(self, slots=4, positions=16):
+        return FlowTable(slots, positions, 128)
+
+    def rows(self, *events):
+        return np.array(events, np.int32).reshape(-1, 3)
+
+    def test_layout_and_addresses(self):
+        t = self.table()
+        p = t.map(self.rows((7, 0, 3), (9, 0, 4), (7, 0, 5), (7, 0, 6)))
+        assert p.layout == (2, 4) and t.layouts == {(2, 4): 1}
+        # flow 7 is the call's first flow, slot 0; its events at 1, 2, 3
+        assert p.rows.tolist() == [[0, 1, 3], [4, 17, 4], [1, 2, 5],
+                                   [2, 3, 6]]
+        assert p.counts["flow.restarts"] == 2
+        assert p.counts["flow.resident"] == 2
+        q = t.map(self.rows((9, 0, 8), (7, 0, 2)))
+        assert q.rows.tolist() == [[0, 18, 8], [1, 4, 2]]
+        assert q.counts["flow.restarts"] == 0
+
+    def test_restart_wrap_and_eviction_are_counted(self):
+        t = self.table(slots=2, positions=8)
+        t.map(self.rows(*[(1, 0, 9)] * 5))
+        p = t.map(self.rows((1, 0, 9), (1, 1, 9)))   # a flag on any row
+        assert p.rows[:, 1].tolist() == [1, 2]
+        assert p.counts["flow.restarts"] == 1 and not p.counts["flow.wraps"]
+        t.map(self.rows(*[(1, 0, 9)] * 4))           # holds 7 of 8
+        p = t.map(self.rows((1, 0, 9), (1, 0, 9)))   # would pass 8: wraps
+        assert p.rows[:, 1].tolist() == [1, 2]
+        assert p.counts["flow.wraps"] == 1 and p.counts["flow.restarts"] == 1
+        t.map(self.rows((2, 0, 9)))
+        p = t.map(self.rows((3, 0, 9)))              # evicts 1, the older
+        assert p.counts["flow.evictions"] == 1
+        assert sorted(t.slot_of) == [2, 3]
+        with pytest.raises(ValueError, match="more than 2 flows"):
+            t.map(self.rows((4, 0, 9), (5, 0, 9), (6, 0, 9)))
+
+    def test_rows_are_validated_and_a_rollback_undoes_a_call(self):
+        t = self.table()
+        t.map(self.rows((7, 0, 3)))
+        saved = t.checkpoint()
+        for bad in ((0, 0, 3), (7, 0, 0), (7, 0, 128)):
+            with pytest.raises(ValueError):
+                t.map(self.rows(bad))
+        with pytest.raises(ValueError, match="does not fit"):
+            t.map(self.rows(*[(8, 0, 3)] * 16))
+        t.map(self.rows((8, 0, 3), (7, 0, 4)))
+        t.rollback(saved)
+        assert t.slot_of == {7: 0} and t.length[0] == 2 and t.free[-1] == 1
+
+
+def test_event_ids_fold_a_row_onto_the_slice():
+    x = np.zeros((4, FEATURE_DIM), np.float32)
+    x[:, 0] = np.log1p([0.0, 3.0, 3.0, 1000.0])
+    x[0, 2] = x[1, 2] = x[2, 5] = 1.0           # 2xx, 2xx, 5xx, none
+    x[:, 14] = 1.0
+    x[3, 14], x[3, 20], x[3, 13] = 0.0, -1.0, 1.0
+    ids = event_ids(x)
+    assert ids.dtype == np.int32 and ((ids >= 1) & (ids < 20480)).all()
+    assert len(set(ids.tolist())) == 4
+    assert (event_ids(x) == ids).all()
+    small = event_ids(x, vocab=128)
+    assert ((small >= 1) & (small < 128)).all()
+
+
+async def drain_streams(tele, drain: int) -> None:
+    """One drain of 12 engine rows: rows 0..7 are samples of two h2
+    streams (their frames 1..4, then 5..8), rows 8..11 requests."""
+    block = np.zeros((12, NATIVE_ROW_WIDTH), np.float32)
+    block[:, 0] = np.arange(12) % 3            # route ids
+    block[:, 1] = 5.0 + np.arange(12)          # latency ms
+    block[:, 2] = 200.0
+    block[:8, NATIVE_COL_KIND] = 1.0
+    block[:8, NATIVE_COL_STREAM] = [501, 502] * 4
+    block[:8, NATIVE_COL_SEQ] = np.arange(8) // 2 + 1 + 4 * drain
+    views = tele.native_ring.produce_views(12)
+    views[0][:] = block
+    tele.native_ring.commit(12)
+    assert await tele.drain_once() == 12
+
+
+def test_the_telemeter_sends_keyed_rows_to_the_flow_scorer():
+    """``model: latent_moe`` end to end: engine rows with a stream key are
+    scored by the flow scorer as their streams' next events and their
+    scores published per route; rows without a key keep the MLP path."""
+    async def go():
+        flow = scorer()
+        # the weights arrive: drawn from the seed alone the tier would
+        # run in shadow (the test below)
+        flow.load(flow.params)
+        tele = JaxAnomalyTelemeter(
+            JaxAnomalyConfig(model="latent_moe", nativeTier="off",
+                             trainEveryBatches=0),
+            MetricsTree(), flow_scorer=flow)
+        tele.set_native_route_resolver(lambda rid: f"/svc/r{rid}")
+        t0 = time.monotonic()   # the log is a ring: by time, not by place
+        try:
+            for drain in range(2):
+                await drain_streams(tele, drain)
+            state = flow.device_state()
+            assert state["weights"] == "loaded"
+            assert state["flow"]["resident"] == 2
+            assert state["score_batches"] == {"8": 2}
+            assert tele._flow_scored.value == 16
+            # (put.bytes: a call the ring made, not a record another
+            # file's test wrote by hand)
+            calls = [c for c in phases.records()
+                     if c.t0 >= t0 and "flow.events" in c.counts
+                     and "put.bytes" in c.counts]
+            assert [c.counts["flow.events"] for c in calls] == [8, 8]
+            # the streams' first samples (frame 1) began their flows; the
+            # second drain went on from the cache
+            assert [c.counts["flow.restarts"] for c in calls] == [2, 0]
+            assert flow._table.length[:2].tolist() == [9, 9]
+            # the 4 request rows of each drain went the MLP's way
+            assert sum(tele._scorer.device_state()[
+                "score_batches"].values()) == 2
+            assert tele._scored.value == 24
+            for r in range(3):
+                assert 0.0 < tele.board.score_of(f"/svc/r{r}") < 1.0
+        finally:
+            tele.close()
+    run(go())
+
+
+def test_the_telemeter_builds_the_flow_tier_on_one_device_in_shadow(
+        monkeypatch):
+    """Through ``_ensure_scorer`` on a host of 8 devices: the flow scorer
+    is pinned to the first, and on weights drawn from the seed it runs in
+    shadow: keyed rows are scored by both tiers, the flow tier's scores are
+    counted and not published."""
+    assert len(jax.devices()) > 1
+    monkeypatch.setitem(SPECS, "latent_moe", lambda: latent_moe(CFG))
+
+    async def go():
+        tele = JaxAnomalyTelemeter(
+            JaxAnomalyConfig(model="latent_moe", nativeTier="off",
+                             trainEveryBatches=0), MetricsTree())
+        tele.set_native_route_resolver(lambda rid: f"/svc/r{rid}")
+        published = []
+        real = tele._publish_route_means
+        monkeypatch.setattr(
+            tele, "_publish_route_means",
+            lambda dsts, inv, scores: (published.append(len(scores)),
+                                       real(dsts, inv, scores)))
+        try:
+            await drain_streams(tele, 0)
+            flow = tele._flow_scorer
+            state = flow.device_state()
+            assert state["model"] == "latent_moe"
+            assert state["weights"] == "seed"
+            assert flow._devices == jax.devices()[:1]
+            assert state["score_batches"] == {"8": 1}
+            assert tele._flow_shadow.value == 8
+            assert tele._flow_scored.value == 0
+            # all 12 rows went the row scorer's way, and only they
+            # were published
+            assert tele._scored.value == 12 and published == [12]
+            assert sum(tele._scorer.device_state()[
+                "score_batches"].values()) == 1
+        finally:
+            tele.close()
+    run(go())
+
+
+def test_the_default_model_builds_no_flow_tier():
+    tele = JaxAnomalyTelemeter(JaxAnomalyConfig(), MetricsTree())
+    try:
+        assert tele._flow_spec is None and tele._flow_scorer is None
+    finally:
+        tele.close()
+    with pytest.raises(ValueError, match="model must be one of"):
+        JaxAnomalyTelemeter(JaxAnomalyConfig(model="gru"), MetricsTree())
+
+
+class TestTheSeam:
+    def test_a_frozen_model_without_a_table_scores_and_does_not_fit(self):
+        """``trains`` alone says whether a fit exists; a table, whether
+        calls are mapped and ordered: mlp36 frozen is neither."""
+        spec = dataclasses.replace(mlp36(), name="mlp36-frozen",
+                                   trains=False)
+        assert not spec.keyed and not latent_moe(CFG).trains
+
+        async def go():
+            s = InProcessScorer(spec=spec, devices=jax.devices()[:1])
+            try:
+                assert s._table is None and s._dispatcher._turn is None
+                await s.warmup()
+                x = np.ones((5, FEATURE_DIM), np.float32)
+                out = await s.score(x)
+                assert out.shape == (5,) and np.isfinite(out).all()
+                with pytest.raises(RuntimeError, match="frozen"):
+                    await s.fit(x, np.zeros(5), np.zeros(5))
+                s.load(s.params)
+                assert s.device_state()["weights"] == "loaded"
+                np.testing.assert_array_equal(await s.score(x), out)
+            finally:
+                s.close()
+        run(go())
+
+    def test_both_models_steps_have_one_signature(self, seqs):
+        rows = rows_of({k: v[:5] for k, v in seqs.items()})
+        plan = FlowTable(CFG.slots, CFG.positions, CFG.vocab_slice).map(rows)
+        for spec, staged, layout in (
+                (mlp36(), np.ones((16, FEATURE_DIM), np.float32), None),
+                (latent_moe(CFG), plan.rows, plan.layout)):
+            params = spec.init(jax.random.key(SEED))
+            state = jax.device_put(spec.init_state())
+            scores, new, counts = spec.make_step("cpu")(
+                params, state, jnp.asarray(staged), len(staged), layout)
+            assert scores.shape == (len(staged),)
+            # a step that only reads its state hands back what it got
+            assert (new is state) == (not spec.keyed)
+            assert isinstance(counts, dict)
+
+    def test_a_trained_model_takes_its_weights_by_restore(self):
+        s = InProcessScorer(devices=jax.devices()[:1])
+        try:
+            assert s.device_state()["weights"] == "seed"
+            with pytest.raises(RuntimeError, match="restore"):
+                s.load(s.params)
+            s.restore(s.snapshot())
+            assert s.device_state()["weights"] == "loaded"
+        finally:
+            s.close()
+
+    def test_the_start_tokens_constants_come_from_the_steps_own_program(
+            self, seqs):
+        """The first call makes them (``with_start``) with the program it
+        is about to run, and they ride in the state from then on."""
+        spec = latent_moe(CFG)
+        params = spec.init(jax.random.key(SEED))
+        assert "start_h" not in params
+        rows = rows_of({k: v[:5] for k, v in seqs.items()})
+        table = FlowTable(CFG.slots, CFG.positions, CFG.vocab_slice)
+        plan = table.map(rows)
+        step = spec.make_step("cpu")
+        state = spec.init_state()
+        assert state[-1] is None
+        _, state, _ = step(params, state, jnp.asarray(plan.rows), len(rows),
+                           plan.layout)
+        entries, h = state[-1]
+        assert entries.shape == (CFG.layers, CFG.entry_width)
+        assert h.shape == (CFG.hidden_size,)
+        # what the reference computes of position 0 of any flow
+        _, full = reference_scores(seqs)
+        gap = np.abs(np.asarray(entries, np.float32)
+                     - full["entries"][:, 0, 0])
+        assert np.median(gap) < 4e-3 and gap.max() < 0.2
+        # and the lengths count the call's flows alone, not the making
+        assert int(np.asarray(state[1]).sum()) == 3 * 6

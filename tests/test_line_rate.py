@@ -177,9 +177,9 @@ class TestDonationSafety:
             captured = []
             orig_step = scorer._scorer
 
-            def spying(params, xd, mu, var):
+            def spying(params, state, xd, n, layout):
                 captured.append(xd)
-                return orig_step(params, xd, mu, var)
+                return orig_step(params, state, xd, n, layout)
 
             scorer._scorer = spying
             try:

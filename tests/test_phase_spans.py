@@ -89,6 +89,44 @@ class TestScorePath:
             assert c.counts == {"score.calls": 1, "put.bytes": 8 * 36 * 4,
                                 "readback.bytes": 8 * 4}
 
+    def test_a_flow_call_maps_its_flows_between_slot_and_stage(self):
+        """A model with state per flow: ``flow.map`` after the slot is
+        held, the table's counts and the device step's on the record."""
+        import jax
+        from linkerd_tpu.models.spec import latent_moe
+        from tests.test_latent_moe import CFG, rows_of
+
+        async def go():
+            scorer = InProcessScorer(seed=1, spec=latent_moe(CFG),
+                                     devices=jax.devices()[:1])
+            try:
+                t = time.monotonic()
+                rows = rows_of({5: [3, 4, 5], 6: [7, 8]})
+                await asyncio.gather(scorer.score(rows), scorer.score(rows))
+                return t, scorer.last_timing, scorer.device_state()
+            finally:
+                scorer.close()
+
+        t, timing, state = run(go())
+        calls = since(t, phases.SCORE)
+        assert len(calls) == 2
+        for i, c in enumerate(calls):
+            assert tuple(n for n, _ in c.marks) == (
+                phases.SLOT_WAIT, phases.FLOW_MAP) + phases.SCORE_PHASES[1:]
+            assert_tiles(c)
+            pairs = c.counts.pop("moe.local_pairs")
+            assert 0 <= c.counts.pop("moe.max_expert_tokens") <= pairs <= 20
+            # 5 rows pad to the 8-row bucket of int32 triples
+            assert c.counts == {
+                "score.calls": 1, "put.bytes": 8 * 3 * 4,
+                "readback.bytes": 8 * 4, "flow.events": 5,
+                "flow.restarts": 2 if i == 0 else 0, "flow.evictions": 0,
+                "flow.wraps": 0, "flow.resident": 2,
+                "cache.positions": 7 if i == 0 else 12}
+        assert timing["bytes"] == 8 * 3 * 4 + 8 * 4
+        assert state["flow"]["layouts"] == {"2x4": 2}
+        assert np.shape(state["flow"]["expert_tokens"]) == (2, 4)
+
     def test_third_dispatch_at_depth_two_waits_for_a_slot(self):
         release = threading.Event()
 
@@ -315,6 +353,10 @@ def hand_made_run():
     # a call before the window opened: in the log, in no window statistic
     _call(phases.SCORE, 50.0, [(phases.STAGE, 59.0)],
           {"score.calls": 1})
+    # a flow call's own span and counts, past the traced slice
+    _call(phases.SCORE, 120.0, [(phases.FLOW_MAP, 120.25)],
+          {"moe.local_pairs": 960, "moe.max_expert_tokens": 40,
+           "cache.positions": 5000, "flow.events": 64})
 
     def program(start, ops):
         return {"plane": "/device:TPU:0", "name": "jit_score",
@@ -344,6 +386,12 @@ READINGS = [
     ("program_count_per", {"count": "slot.waits", "per": "score.calls",
                            "scale": 100}, 100.0),
     ("program_count_per", {"count": "slot.waits", "per": "no.such"}, None),
+    ("program_span_stat", {"span": "flow.map", "stat": "mean"}, 250.0),
+    # the fullest of 4 x 12 held experts over their mean
+    ("program_count_per", {"count": "moe.max_expert_tokens",
+                           "per": "moe.local_pairs", "scale": 48}, 2.0),
+    ("program_count_per", {"count": "cache.positions",
+                           "per": "flow.events"}, 78.125),
     # idle inside update_norm: 100.5-102 and 103-104
     ("idle_by_span", {"spans": ["fit.update_norm"]}, 25.0),
     # inside fit.step 104-107: 104-105 and 106-107
@@ -407,6 +455,34 @@ def test_every_new_per_layer_entry_has_its_files_and_its_arrow():
             "outside_all_but", [])))
         for name in [named] if isinstance(named, str) else named:
             assert name in vars(phases).values(), name
+
+
+def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
+    flow_cell = "kimi-k2-6-ep32.flows64x64"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    e2e = {m["name"] for m in manifest["end_to_end"]}
+    mine = [m for m in manifest["per_layer"]
+            if m.get("workloads") == [flow_cell]]
+    assert len(mine) == 15
+    assert not [m for m in manifest["per_layer"] if m not in mine
+                and flow_cell in m.get("workloads", [])]
+    with open(os.path.join(REPO, "linkerd_tpu", "telemetry",
+                           "phases.py")) as f:
+        documented = f.read()
+    for m in mine:
+        assert m["moves"] in e2e
+        with open(os.path.join(REPO, "chipbench", "metrics",
+                               m["name"] + ".json")) as f:
+            how = json.load(f)
+        assert os.path.isfile(os.path.join(
+            REPO, "chipbench", "readers", how["reader"] + ".py"))
+        if "span" in how:
+            assert how["span"] in vars(phases).values()
+        for count in (how.get("count"), how.get("per")):
+            assert count is None or f"``{count}``" in documented, count
+        if "program" in how:
+            assert how["program"] == "^jit_flow_step$"
 
 
 # -- names on the device ------------------------------------------------------
