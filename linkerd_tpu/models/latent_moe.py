@@ -18,7 +18,19 @@ Per layer ``h += Attn(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``:
   Attention runs with ``wukv`` absorbed into the query and the output
   (``q_nope . Wuk`` against the latent itself; the weighted latents through
   ``Wuv``), so a cached position is never up-projected. RoPE is YaRN's,
-  rotate-half pairing.
+  rotate-half pairing. All heads of a flow share the one latent and the
+  one rope key, so a flow's queries are one ``[events x heads, rank +
+  rope]`` matrix against its slot ``[positions, rank + rope]``. *How that
+  product runs is the step's ``attend``*, chosen by platform where the
+  step is built (``models/spec.py``): on a TPU the Pallas kernel of
+  ``ops/flow_attention.py``, which tiles the query rows and the
+  positions, keeps a tile's scores in VMEM from the product to the
+  softmax's weights and stops at the last block of positions the tile's
+  events may see, **so the score tensor never reaches HBM and positions
+  past a flow's length cost nothing**;
+  elsewhere ``attend_xla``, which forms the scores of ``ATTENTION_BLOCK``
+  flows over their whole slots at a time. Both mask causally by ``p0``
+  and agree to bfloat16 rounding.
 - **FFN.** The first ``first_k_dense_replace`` layers: dense SwiGLU. The
   others: ``shared(x) + routed(x)``. The router scores all
   ``n_routed_experts`` in float32 (``sigmoid``), selects the top
@@ -251,20 +263,24 @@ def init_state(cfg: LatentMoEConfig):
             None)
 
 
-def with_start(run, cfg: LatentMoEConfig, state, shape):
+def with_start(run, cfg: LatentMoEConfig, state, rows):
     """The state with the start token's constants, which every flow begins
     from: its cache entry in every layer ``[layers, entry]`` and its final
     hidden state ``[hidden]`` (which predicts a flow's first event). They
     are constants of the parameters, as the cache is a function of them,
-    and are made by the step's own program (``run(state, rows, n)``, at
-    the shape of the rows it is about to take, so nothing else compiles):
-    one call whose single event is the start token itself, which
-    ``flow_step`` takes as the call that makes them."""
+    and are made by the step's own program (``run(state, rows, n)``): one
+    call whose single event is the start token itself, which ``flow_step``
+    takes as the call that makes them. Its arguments have the shapes of
+    the call about to be made (``rows``: that call's, staged) and are
+    placed where that call's are, so the program is lowered and read from
+    the compile cache once: for arrays of the host it was lowered a second
+    time (1.1 s of every set-up; my chip runs, PR 29)."""
     cache, length, last_h, _ = state
-    blank = (np.zeros((cfg.layers, cfg.entry_width), jnp.bfloat16),
-             np.zeros((cfg.hidden_size,), jnp.bfloat16))
-    return run((cache, length, last_h, blank), np.zeros(shape, np.int32),
-               1)[1]
+    blank, first = jax.device_put(
+        ((np.zeros((cfg.layers, cfg.entry_width), jnp.bfloat16),
+          np.zeros((cfg.hidden_size,), jnp.bfloat16)),
+         np.zeros(rows.shape, np.int32)), rows.sharding)
+    return run((cache, length, last_h, blank), first, 1)[1]
 
 
 # -- the block ----------------------------------------------------------------
@@ -316,15 +332,47 @@ def _rope(x, cos, sin):
 ATTENTION_BLOCK = 8     # flows attended at a time: bounds the score tensor
 
 
+def attend_xla(q_abs, q_rope, kv, p0, scale: float):
+    """Attention as XLA does it, the whole score tensor formed in blocks
+    of ``ATTENTION_BLOCK`` flows: the path of every platform but the TPU,
+    and what ``ops/flow_attention.latent_attention_fused`` is tested
+    against. ``q_abs [F, T, H, rank]``, ``q_rope [F, T, H, rope]``, ``kv
+    [F, P, rank + rope]`` bfloat16; event ``t`` of flow ``f`` sees
+    positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H, rank]``
+    bfloat16, the blocks of positions attended over ``[F]``, the blocks of
+    a whole slot)``: here every slot is attended whole, as one block."""
+    F, T, H, rank = q_abs.shape
+    P = kv.shape[1]
+
+    def attend(block):
+        qk, kv, pos = block
+        s = jnp.einsum("fthk,fpk->fhtp", qk, kv,
+                       preferred_element_type=jnp.float32) * scale
+        seen = jnp.arange(P)[None, None] <= pos[:, :, None]
+        p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), -1)
+        return jnp.einsum("fhtp,fpc->fhtc", p.astype(jnp.bfloat16),
+                          kv[..., :rank], preferred_element_type=jnp.float32
+                          ).astype(jnp.bfloat16)
+
+    nb = min(ATTENTION_BLOCK, F)
+    o = jax.lax.map(attend, jax.tree_util.tree_map(
+        lambda a: a.reshape(F // nb, nb, *a.shape[1:]),
+        (jnp.concatenate([q_abs, q_rope], -1), kv,
+         p0[:, None] + jnp.arange(T)[None])))
+    return (o.reshape(F, H, T, rank).transpose(0, 2, 1, 3),
+            jnp.ones((F,), jnp.int32), 1)
+
+
 def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
-               pos, cos, sin):
+               cos, sin, attend):
     """``x [F, T, hidden]`` normed, ``cache [slots, positions, entry]``
     this layer's. Each flow's slot is read whole, the chunk's ``count``
     entries are set into it from position ``p0`` on (and the start
     token's at position 0 where the flow ``begins``), the slot is written
     back whole (a ``slot`` out of range: read clipped, write dropped) and
-    the chunk attends over it causally. Returns the output and the
-    cache."""
+    the chunk attends over it causally by ``attend`` (``attend_xla``'s
+    signature). Returns the output, the cache and the blocks of positions
+    attended over and of the slots whole, summed over the flows."""
     F, T, _ = x.shape
     H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
@@ -347,25 +395,13 @@ def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
     wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
     q_abs = jnp.einsum("fthd,chd->fthc", q[..., :nope].astype(jnp.bfloat16),
                        wukv[..., :nope], preferred_element_type=jnp.float32)
-    qk = jnp.concatenate([q_abs, q_rope], -1).astype(jnp.bfloat16)
-
-    def attend(block):
-        qk, kv, pos = block
-        s = jnp.einsum("fthk,fpk->fhtp", qk, kv,
-                       preferred_element_type=jnp.float32
-                       ) * softmax_scale(cfg)
-        seen = jnp.arange(P)[None, None] <= pos[:, :, None]
-        p = jax.nn.softmax(jnp.where(seen[:, None], s, -jnp.inf), -1)
-        return jnp.einsum("fhtp,fpc->fhtc", p.astype(jnp.bfloat16),
-                          kv[..., :rank], preferred_element_type=jnp.float32
-                          ).astype(jnp.bfloat16)
-
-    nb = min(ATTENTION_BLOCK, F)
-    o = jax.lax.map(attend, jax.tree_util.tree_map(
-        lambda a: a.reshape(F // nb, nb, *a.shape[1:]), (qk, kv, pos)))
-    o = jnp.einsum("fhtc,chd->fthd", o.reshape(F, H, T, rank),
-                   wukv[..., nope:], preferred_element_type=jnp.float32)
-    return _mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache
+    o, blocks, whole = attend(q_abs.astype(jnp.bfloat16),
+                              q_rope.astype(jnp.bfloat16), kv, p0,
+                              softmax_scale(cfg))
+    o = jnp.einsum("fthc,chd->fthd", o, wukv[..., nope:],
+                   preferred_element_type=jnp.float32)
+    return (_mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache,
+            jnp.stack([blocks.sum(), F * whole]))
 
 
 def route(lp, cfg, x):
@@ -424,7 +460,7 @@ def routed_experts(lp, cfg, x, valid):
 
 
 def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
-             begins):
+             begins, attend):
     """``tok [F, T]``; per flow its ``slot``, the position ``p0`` its chunk
     is appended at, the chunk's ``count`` events and whether the flow
     ``begins`` here; ``cache``: one ``[slots, positions, entry]`` a layer;
@@ -432,7 +468,8 @@ def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
     of a flow that begins.
     Returns the final normed hidden ``[F, T, hidden]`` float32, the cache
     with the chunks appended, tokens per held expert ``[expert layers,
-    G]``."""
+    G]``, and the blocks of positions attention ran over and those of the
+    slots whole ``[2]``, summed over flows and layers."""
     F, T = tok.shape
     h = params["embed"][tok].astype(jnp.float32)
     pos = p0[:, None] + jnp.arange(T)[None]
@@ -440,13 +477,14 @@ def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
     angle = pos[..., None].astype(jnp.float32) * jnp.asarray(
         yarn_inv_freq(cfg))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    counts, cache = [], list(cache)
+    counts, cache, blocks = [], list(cache), jnp.zeros((2,), jnp.int32)
     for l, lp in enumerate(params["layers"]):
         with jax.named_scope(f"layer{l}.attention"):
-            a, cache[l] = _attention(
+            a, cache[l], attended = _attention(
                 lp, cfg, cache[l], _rms(h, lp["attn_norm"], cfg.rms_norm_eps),
-                slot, p0, count, begins, start_entries[l], pos, cos, sin)
+                slot, p0, count, begins, start_entries[l], cos, sin, attend)
             h = h + a
+            blocks = blocks + attended
         with jax.named_scope(f"layer{l}.ffn"):
             x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
             if "router" in lp:
@@ -462,14 +500,16 @@ def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
     G = cfg.experts_held[1] - cfg.experts_held[0]
     counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
     return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(cache),
-            counts)
+            counts, blocks)
 
 
 def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
-              T: int):
+              T: int, attend=attend_xla):
     """One call. ``rows [B, 3]`` int32 ``(cell, address, id)``; rows at and
-    past ``n`` are padding. Returns ``(scores [B] float32 in row order,
-    state, counts)``."""
+    past ``n`` are padding; ``attend``: the attention over a slot
+    (``attend_xla``, or the kernel ``ops/flow_attention.best_attention``
+    gives for a TPU). Returns ``(scores [B] float32 in row order, state,
+    counts)``."""
     cache, length, last_h, (start_entries, start_h) = state
     S, P = cfg.slots, cfg.positions
     B = rows.shape[0]
@@ -486,8 +526,9 @@ def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
     begins = flow & (p0 == 1)
     prev_h = jnp.where(begins[:, None], start_h[None],
                        last_h[jnp.minimum(slot, S - 1)])
-    h, cache, expert_tokens = _forward(params, cfg, cache, start_entries,
-                                       tok, slot, p0, count, begins)
+    h, cache, expert_tokens, blocks = _forward(
+        params, cfg, cache, start_entries, tok, slot, p0, count, begins,
+        attend)
     with jax.named_scope("head"):
         pred = jnp.concatenate(
             [prev_h[:, None].astype(jnp.float32), h[:, :-1]], 1)
@@ -513,5 +554,7 @@ def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
     counts = {"moe.local_pairs": expert_tokens.sum(),
               "moe.max_expert_tokens": expert_tokens.max(initial=0),
               "cache.positions": length.sum(),
+              "attn.kv_blocks": blocks[0],
+              "attn.kv_blocks_whole": blocks[1],
               "expert_tokens": expert_tokens}
     return scores, (cache, length, last_h, (start_entries, start_h)), counts

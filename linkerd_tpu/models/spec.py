@@ -103,26 +103,35 @@ def latent_moe(cfg=None) -> ModelSpec:
     """The flow model (``models/latent_moe.py``): a row is int32 ``(stream
     key, restart flag, event id)``, laid out by ``FlowTable``; the cache,
     the flows' lengths and the start token's constants are the state,
-    donated to each step."""
+    donated to each step. The step is built with the attention its
+    platform gets (``ops/flow_attention.best_attention``: the fused
+    kernel on a TPU, XLA's elsewhere), and ``describe`` says which."""
     import jax
 
     from linkerd_tpu.models import latent_moe as lm
     from linkerd_tpu.telemetry.flowstate import FlowTable
 
     cfg = cfg if cfg is not None else lm.LatentMoEConfig()
+    built = {}      # what make_step chose, for describe
 
     def make_step(platform: str):
+        # the kernel's module is imported where a step is built
+        from linkerd_tpu.ops.flow_attention import (
+            attention_kind, best_attention)
+        attend = best_attention(platform)
+        built["attention"] = attention_kind(platform)
         # the state and the staged rows are the program's to reuse
-        program = jax.jit(lm.flow_step, static_argnames=("cfg", "F", "T"),
+        program = jax.jit(lm.flow_step,
+                          static_argnames=("cfg", "F", "T", "attend"),
                           donate_argnums=(1, 2))
 
         def step(params, state, rows, n, layout):
             def run(state, rows, n):
                 return program(params, state, rows, np.int32(n), cfg=cfg,
-                               F=layout[0], T=layout[1])
+                               F=layout[0], T=layout[1], attend=attend)
             if state[-1] is None:
-                # once: the same program, at this call's shapes
-                state = lm.with_start(run, cfg, state, rows.shape)
+                # once: the same program, on arguments like this call's
+                state = lm.with_start(run, cfg, state, rows)
             return run(state, rows, n)
         return step
 
@@ -132,6 +141,7 @@ def latent_moe(cfg=None) -> ModelSpec:
             "slots": cfg.slots, "positions": cfg.positions,
             "experts_held": list(cfg.experts_held),
             "layer_share": cfg.layer_share,
+            "attention": built.get("attention"),
             "resident": len(table.slot_of),
             "layouts": {f"{f}x{t}": c
                         for (f, t), c in sorted(table.layouts.items())},

@@ -1,0 +1,194 @@
+"""Fused latent attention over a flow's slot of the cache: a Pallas kernel.
+
+``models/latent_moe._attention`` absorbs ``wukv`` into the query, so every
+head of a flow attends over the one latent ``[positions, rank]`` and the
+one rope key ``[positions, rope]`` of the flow's slot: a flow's queries are
+one ``[events * heads, rank + rope]`` matrix against ``[positions, rank +
+rope]``, and the weighted values are the latent again. The XLA path
+(``models.latent_moe.attend_xla``) forms the whole float32 score tensor,
+masks it, takes a softmax over it and multiplies it into the latent: that
+tensor crosses HBM four or so times and every event attends over all the
+slot's positions whatever the flow holds.
+
+Here a grid cell is one flow and one tile of its query rows (a few whole
+events, all heads). The slot's keys and values lie in VMEM whole, and so
+do the tile's scores, in a scratch ``[rows, positions]`` that never
+leaves the chip. Two loops run over blocks of ``KV_BLOCK`` positions:
+the first forms a block's scores and keeps their maximum lane by lane;
+then one reduction across lanes gives each row's maximum; the second
+takes ``exp(score - maximum)``, sums it lane by lane and adds the block's
+weights, cast to bfloat16, times the block's latent into a float32
+accumulator; one division a row at the end. **Both loops end at the last
+block that holds a position any of the tile's events may see**
+(``blocks_seen``, from the flow's position ``p0``, handed in by
+``PrefetchScalarGridSpec``), and only the blocks that reach past the
+tile's first position are masked.
+(Online softmax, a running maximum and a rescaled accumulator a block,
+computes the same in one loop and was the first attempt: its two
+reductions across lanes a block are what a v5e does slowest: 2.59 ms a
+layer of the benchmark's cell against 2.12; my chip runs, PR 29.)
+
+The entry is split at ``rank`` (``s = q_abs . c^T + q_rope . k_rope^T``:
+576 lanes are no multiple of Mosaic's 128). The keys come transposed,
+``[entry, positions]``, a copy XLA makes a layer: transposing a block in
+the kernel, for every tile again, took 1.4 ms a layer against the copy's
+0.5 (same runs). The values are the latent in place, lanes ``0..rank`` of
+the gathered slot.
+
+``best_attention`` selects by platform as ``ops/scoring.best_scorer``
+does: this kernel on ``tpu``, the XLA path elsewhere. There is no probe
+and no fallback: a kernel that Mosaic refuses on the chip is an error the
+caller sees. CPU tests run the kernel with ``interpret=True``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+KV_BLOCK = 128      # positions a block: one lane tile of scores
+QUERY_ROWS = 1024   # query rows (events x heads) a tile, at most
+SCORE_BYTES = 4 * 2 ** 20   # a tile's scores [rows, positions] float32
+MASKED = -1e30      # finite: a block a row sees nothing of makes no NaN
+VMEM_LIMIT = 32 * 2 ** 20   # 1,024 rows x 1,024 positions need 20 MiB
+
+
+def kv_block(P: int) -> int:
+    """Positions a block of the loops holds, for a slot of ``P``."""
+    return KV_BLOCK if P % KV_BLOCK == 0 else P
+
+
+def blocks_seen(first, events: int, P: int):
+    """Blocks of a slot that hold a position which ``events`` events
+    appended at position ``first`` may see (positions ``0 .. first +
+    events - 1``): where a tile's loops end, and what ``attn.kv_blocks``
+    counts. At least 1: position 0 is always seen."""
+    bk = kv_block(P)
+    return (jnp.minimum(first + events, P) + bk - 1) // bk
+
+
+def _events_a_tile(T: int, H: int, P: int) -> int:
+    """Whole events a tile of query rows holds: ``T`` halved until the
+    tile's rows and its scores fit."""
+    te = T
+    while te % 2 == 0 and te * H > min(QUERY_ROWS, SCORE_BYTES // (4 * P)):
+        te //= 2
+    return te
+
+
+def _kernel(p0_ref, qa_ref, qr_ref, kt_ref, c_ref, o_ref, s_ref, m_ref,
+            l_ref, acc_ref, *, scale: float, heads: int):
+    f, i = pl.program_id(0), pl.program_id(1)
+    rows, (P, rank) = qa_ref.shape[1], c_ref.shape[1:]
+    events, bk = rows // heads, m_ref.shape[1]
+    first = p0_ref[f] + i * events      # position of the tile's first event
+    last = blocks_seen(first, events, P)
+    qa, qr = qa_ref[0], qr_ref[0]
+
+    def score(j, masked):
+        at = pl.multiple_of(j * bk, bk)
+        s = (jnp.dot(qa, kt_ref[0, :rank, pl.ds(at, bk)],
+                     preferred_element_type=jnp.float32)
+             + jnp.dot(qr, kt_ref[0, rank:, pl.ds(at, bk)],
+                       preferred_element_type=jnp.float32)) * scale
+        if masked:
+            pos = first + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 0) // heads
+            col = at + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(col <= pos, s, MASKED)
+        s_ref[:, pl.ds(at, bk)] = s
+        m_ref[...] = jnp.maximum(m_ref[...], s)     # lane by lane
+
+    m_ref[...] = jnp.full(m_ref.shape, MASKED, jnp.float32)
+    # blocks that end at or before the tile's first position hide nothing
+    # from any of its rows
+    clear = jnp.minimum((first + 1) // bk, last)
+    jax.lax.fori_loop(0, clear, lambda j, _: score(j, False), None)
+    jax.lax.fori_loop(clear, last, lambda j, _: score(j, True), None)
+    # each row's maximum, in every lane (position 0 is seen by every row,
+    # so it is a score and not MASKED)
+    m_ref[...] = jnp.broadcast_to(m_ref[...].max(-1, keepdims=True),
+                                  m_ref.shape)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def weigh(j, _):
+        at = pl.multiple_of(j * bk, bk)
+        p = jnp.exp(s_ref[:, pl.ds(at, bk)] - m_ref[...])
+        l_ref[...] += p         # lane by lane: summed across once, below
+        c = c_ref[0, pl.ds(at, bk), :]
+        acc_ref[...] += jnp.dot(p.astype(c.dtype), c,
+                                preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, last, weigh, None)
+    o_ref[0] = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
+                ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def latent_attention_fused(q_abs, q_rope, kv, p0, scale: float,
+                           interpret: bool = False):
+    """``q_abs [F, T, H, rank]``, ``q_rope [F, T, H, rope]``, ``kv [F, P,
+    rank + rope]`` bfloat16, ``p0 [F]`` int32: event ``t`` of flow ``f``
+    sees positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H, rank]``
+    bfloat16 ``= softmax(mask(q . kv^T * scale)) . kv[..., :rank]``, the
+    blocks of positions attended over, summed over a flow's tiles of
+    query rows ``[F]``, and what the slot whole would have been)``.
+    Jitted, so that a step of several layers traces and lowers the kernel
+    once (0.1 s a layer of every set-up; my chip runs, PR 29)."""
+    F, T, H, rank = q_abs.shape
+    rope, P = q_rope.shape[-1], kv.shape[1]
+    bk, events = kv_block(P), _events_a_tile(T, H, P)
+    rows, tiles = events * H, T // events
+    kernel = functools.partial(_kernel, scale=scale, heads=H)
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(F, tiles),
+            in_specs=[
+                pl.BlockSpec((1, rows, rank), lambda f, i, p0: (f, i, 0)),
+                pl.BlockSpec((1, rows, rope), lambda f, i, p0: (f, i, 0)),
+                # the keys, positions along the lanes
+                pl.BlockSpec((1, rank + rope, P),
+                             lambda f, i, p0: (f, 0, 0)),
+                # the values: lanes 0..rank of the entry, read in place
+                pl.BlockSpec((1, P, rank), lambda f, i, p0: (f, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, rows, rank),
+                                   lambda f, i, p0: (f, i, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, P), jnp.float32),
+                            pltpu.VMEM((rows, bk), jnp.float32),
+                            pltpu.VMEM((rows, bk), jnp.float32),
+                            pltpu.VMEM((rows, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((F, T * H, rank), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name="latent_attention_fused",
+    )(p0.astype(jnp.int32), q_abs.reshape(F, T * H, rank),
+      q_rope.reshape(F, T * H, rope), kv.transpose(0, 2, 1), kv)
+    attended = sum(blocks_seen(p0 + i * events, events, P)
+                   for i in range(tiles))
+    return o.reshape(F, T, H, rank), attended, tiles * (P // bk)
+
+
+def attention_kind(platform: str) -> str:
+    """Which attention ``best_attention`` hands the flow step on
+    ``platform``: ``"fused_pallas"`` on a TPU, ``"xla"`` elsewhere."""
+    return "fused_pallas" if platform == "tpu" else "xla"
+
+
+def best_attention(platform: str):
+    """The flow step's ``attend`` for parameters living on ``platform``:
+    the fused kernel on ``tpu``, the XLA path elsewhere (an interpreted
+    kernel is far too slow to serve)."""
+    if attention_kind(platform) == "fused_pallas":
+        return latent_attention_fused
+    from linkerd_tpu.models.latent_moe import attend_xla
+    return attend_xla

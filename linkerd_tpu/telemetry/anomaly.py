@@ -451,9 +451,10 @@ class InProcessScorer(Scorer):
         or None), ``score_batches`` / ``fit_batches`` (calls per compiled
         shape), and what the spec's ``describe`` adds. The flow model adds
         ``flow``: ``slots``, ``positions``, ``experts_held``,
-        ``layer_share``, ``resident`` flows, ``layouts`` (calls per ``FxT``
-        layout the step was compiled for) and ``expert_tokens`` (the newest
-        call's tokens per expert layer and held expert)."""
+        ``layer_share``, ``attention`` (``fused_pallas`` | ``xla``: what
+        the step was built with), ``resident`` flows, ``layouts`` (calls
+        per ``FxT`` layout the step was compiled for) and ``expert_tokens``
+        (the newest call's tokens per expert layer and held expert)."""
         import jax
 
         d0 = self._devices[0]

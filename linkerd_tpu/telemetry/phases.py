@@ -20,7 +20,11 @@ positions, ``flow.resident`` after the call) and what the device step
 reported (``cache.positions``: the sum of the flows' lengths after the
 call, ``moe.local_pairs``: token-expert pairs computed here,
 ``moe.max_expert_tokens``: the fullest held expert of the call, over its
-layers); ``fit.calls``,
+layers, ``attn.kv_blocks``: the blocks of cache positions attention ran
+over, one for every tile of query rows that ran over it, summed over
+flows and layers, ``attn.kv_blocks_whole``: what the slots whole would
+have been; XLA's attention runs over every slot whole as one block, so
+there the two are equal); ``fit.calls``,
 ``fit.shipped_bytes`` (the padded host arrays a fit places on the
 device: rows, labels, mask and, where rows were padded, the row mask;
 once a fit, whatever ``fit_steps``).

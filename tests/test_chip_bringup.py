@@ -146,9 +146,10 @@ class TestScorerSelection:
     def test_flow_step_compiles_for_v5e_at_the_published_widths(self):
         """The flow model's step (``models.latent_moe.flow_step``) as the
         benchmark's cell runs it, 64 flows x 64 events at hidden 7,168 with
-        12 of 384 experts held, compiled by the TPU's own compiler with no
-        chip: it fits one v5e (15.75 GiB) with its weights and its cache
-        as arguments, and the cache is updated in place (aliased)."""
+        12 of 384 experts held and the attention a TPU gets (the fused
+        kernel, one call a layer), compiled by the TPU's own compiler with
+        no chip: it fits one v5e (15.75 GiB) with its weights and its
+        cache as arguments, and the cache is updated in place (aliased)."""
         code = (
             "import jax, jax.numpy as jnp\n"
             "from jax.experimental import topologies\n"
@@ -159,6 +160,7 @@ class TestScorerSelection:
             "except Exception as e:\n"
             "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
             "from linkerd_tpu.models import latent_moe as lm\n"
+            "from linkerd_tpu.ops.flow_attention import best_attention\n"
             "sh = SingleDeviceSharding(topo.devices[0])\n"
             "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
             "cfg = lm.LatentMoEConfig()\n"
@@ -174,13 +176,16 @@ class TestScorerSelection:
             "    jax.eval_shape(lambda: lm.init_state(cfg)))[:3] + (\n"
             "    (S(jnp.bfloat16, cfg.layers, cfg.entry_width),\n"
             "     S(jnp.bfloat16, cfg.hidden_size)),)\n"
-            "step = jax.jit(lm.flow_step, static_argnames=('cfg', 'F', 'T'),\n"
-            "               donate_argnums=(1, 2))\n"
-            "m = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
-            "               S(jnp.int32), cfg=cfg, F=64, T=64\n"
-            "               ).compile().memory_analysis()\n"
+            "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
+            "               static_argnames=('cfg', 'F', 'T', 'attend'))\n"
+            "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
+            "               S(jnp.int32), cfg=cfg, F=64, T=64,\n"
+            "               attend=best_attention('tpu')).compile()\n"
+            "m = c.memory_analysis()\n"
             "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
             "      m.alias_size_in_bytes)\n"
+            "print('KERNELS', c.as_text().count(\n"
+            "    'custom_call_target=\"tpu_custom_call\"'))\n"
             "print('COMPILED', topo.devices[0].device_kind)\n")
         proc = _run([sys.executable, "-c", code], timeout=900,
                     env=_clean_env(
@@ -196,7 +201,49 @@ class TestScorerSelection:
             if line.startswith("BYTES")).split()[1:])
         # weights 6.99 GB and the cache 3.02 GB; the cache comes back aliased
         assert 9.9e9 < args < 10.2e9 and alias > 3.0e9
-        assert temp < 2.5 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
+        # no block of float32 scores among the temporaries (1.45 GiB here,
+        # 1.78 with XLA's attention; my compile-only readings, PR 29)
+        assert temp < 1.65 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
+        assert "KERNELS 5" in proc.stdout
+
+    def test_flow_attention_compiles_for_v5e_at_every_kind_of_layout(self):
+        """The fused attention alone at the published entry (512 + 64, 64
+        heads, 1,024 positions) in the layouts ``FlowTable`` makes: the
+        cell's 64 x 64 (tiles of 16 events), chunks of 1 event (64 query
+        rows a flow), one long flow, and calls of a few flows."""
+        code = (
+            "import functools, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.ops.flow_attention import (\n"
+            "    latent_attention_fused)\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "bf = functools.partial(S, jnp.bfloat16)\n"
+            "fn = jax.jit(functools.partial(latent_attention_fused,\n"
+            "                               scale=0.135))\n"
+            "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 512)):\n"
+            "    text = fn.lower(bf(F, T, 64, 512), bf(F, T, 64, 64),\n"
+            "                    bf(F, 1024, 576), S(jnp.int32, F)\n"
+            "                    ).compile().as_text()\n"
+            "    assert 'tpu_custom_call' in text, (F, T)\n"
+            "    print('LAYOUT', F, T)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=600,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        assert proc.stdout.count("LAYOUT") == 5
 
 
 class TestFailLoud:

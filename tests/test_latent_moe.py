@@ -5,6 +5,7 @@ latent_moe.py``) on seeded weights, at a tiny preset on the CPU: hidden 64,
 
 import asyncio
 import dataclasses
+import functools
 import time
 
 import jax
@@ -87,6 +88,19 @@ def close_to(got, want):
         np.median(gap), gap.max())
 
 
+@pytest.fixture(params=["xla", "fused"])
+def attention(request, monkeypatch):
+    """The step built on each attention: XLA's, which this platform gets,
+    and the TPU's kernel (``ops/flow_attention.py``), interpreted."""
+    if request.param == "fused":
+        from linkerd_tpu.ops import flow_attention
+        interpreted = functools.partial(
+            flow_attention.latent_attention_fused, interpret=True)
+        monkeypatch.setattr(flow_attention, "best_attention",
+                            lambda platform: interpreted)
+    return request.param
+
+
 @pytest.fixture(scope="module")
 def seqs():
     rng = np.random.default_rng(0)
@@ -102,7 +116,7 @@ class TestAgainstTheReference:
             "It imports\nnothing of the program", "")
         assert 'default_matmul_precision("highest")' in src
 
-    def test_one_full_forward(self, seqs):
+    def test_one_full_forward(self, seqs, attention):
         async def go():
             s = scorer()
             try:
@@ -124,7 +138,7 @@ class TestAgainstTheReference:
             assert np.median(gap) < 4e-3 and gap.max() < 0.2
         assert np.asarray(state[1])[:3].tolist() == [41, 26, 34]
 
-    def test_chunked_appends_through_the_cache(self, seqs):
+    def test_chunked_appends_through_the_cache(self, seqs, attention):
         """Chunks of unequal length, a restart in the middle: every call's
         scores are the reference's for one full forward of each flow since
         its restart."""
@@ -214,7 +228,7 @@ class TestAgainstTheReference:
 
 
 class TestState:
-    def test_padding_rows_leave_the_state_untouched(self, seqs):
+    def test_padding_rows_leave_the_state_untouched(self, seqs, attention):
         """The same rows in a bucket of their own size and in a larger one
         whose padding holds stale rows: the same scores, the same state."""
         spec = latent_moe(CFG)
@@ -297,6 +311,7 @@ class TestState:
                 assert d["flow"]["experts_held"] == [4, 8]
                 assert d["flow"]["slots"] == 8
                 assert d["flow"]["positions"] == 64
+                assert d["flow"]["attention"] == "xla"   # not a TPU
             finally:
                 s.close()
         run(go())

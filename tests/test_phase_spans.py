@@ -122,7 +122,9 @@ class TestScorePath:
                 "readback.bytes": 8 * 4, "flow.events": 5,
                 "flow.restarts": 2 if i == 0 else 0, "flow.evictions": 0,
                 "flow.wraps": 0, "flow.resident": 2,
-                "cache.positions": 7 if i == 0 else 12}
+                "cache.positions": 7 if i == 0 else 12,
+                # XLA's attention: 3 layers x 2 flows, each slot whole
+                "attn.kv_blocks": 6, "attn.kv_blocks_whole": 6}
         assert timing["bytes"] == 8 * 3 * 4 + 8 * 4
         assert state["flow"]["layouts"] == {"2x4": 2}
         assert np.shape(state["flow"]["expert_tokens"]) == (2, 4)
@@ -464,7 +466,7 @@ def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
     e2e = {m["name"] for m in manifest["end_to_end"]}
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == [flow_cell]]
-    assert len(mine) == 15
+    assert len(mine) == 16
     assert not [m for m in manifest["per_layer"] if m not in mine
                 and flow_cell in m.get("workloads", [])]
     with open(os.path.join(REPO, "linkerd_tpu", "telemetry",
