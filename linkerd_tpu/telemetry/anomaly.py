@@ -827,9 +827,7 @@ class InProcessScorer(Scorer):
 @dataclass
 class JaxAnomalyConfig:
     maxBatch: int = 1024
-    intervalMs: int = 50
     ringCapacity: int = 65536
-    maxBatchesPerWake: int = 8  # catch-up burst ceiling under backlog
     scoreThreshold: float = 0.5
     trainEveryBatches: int = 8      # online-fit cadence (0 = never train)
     reconWeight: float = 0.7
@@ -847,13 +845,10 @@ class JaxAnomalyConfig:
     # scores are counted (flow_shadow_total) and not published.
     # Single-device: the first chip, whatever the host has.
     model: str = "mlp36"
-    # line-rate micro-batcher (the default): drain is size- and
-    # deadline-triggered — a batch dispatches when maxBatch rows are
-    # pending OR the oldest pending row has lingered maxLingerMs,
-    # whichever first — so 100% of requests are scored with bounded
-    # added queue latency. lineRate: false falls back to the legacy
-    # intervalMs polling loop (sampled-batch behavior).
-    lineRate: bool = True
+    # line-rate micro-batcher: drain is size- and deadline-triggered —
+    # a batch dispatches when maxBatch rows are pending OR the oldest
+    # pending row has lingered maxLingerMs, whichever first — so 100%
+    # of requests are scored with bounded added queue latency.
     maxLingerMs: float = 2.0
     scoreConcurrency: int = 2  # batches in flight (double-buffer depth)
     # gRPC sidecar address: "host:port" (one pinned replica),
@@ -917,10 +912,6 @@ class JaxAnomalyTelemeter(Telemeter):
         from linkerd_tpu.models.spec import SPECS
         if cfg.model not in SPECS:
             raise ValueError(f"model must be one of {sorted(SPECS)}")
-        if cfg.maxBatchesPerWake < 1:
-            # 0 would silently disable draining (NOT a sentinel like
-            # trainEveryBatches' 0 = never)
-            raise ValueError("maxBatchesPerWake must be >= 1")
         if cfg.sidecarTier not in ("primary", "fallback"):
             raise ValueError("sidecarTier must be 'primary' or 'fallback'")
         if cfg.nativeTier not in ("primary", "off"):
@@ -1437,10 +1428,7 @@ class JaxAnomalyTelemeter(Telemeter):
                 self.control.run(), name="control-loop")
             monitor(control_task, what="control-loop")
         try:
-            if self.cfg.lineRate:
-                await self._line_rate_loop(scorer)
-            else:
-                await self._interval_loop(scorer)
+            await self._line_rate_loop(scorer)
         except asyncio.CancelledError:
             pass
         finally:
@@ -1463,27 +1451,9 @@ class JaxAnomalyTelemeter(Telemeter):
             await self.lifecycle_cycle()
         return last_cycle
 
-    async def _interval_loop(self, scorer: Scorer) -> None:
-        """Legacy polling drain (lineRate: false): one burst per
-        intervalMs tick; rows arriving between ticks wait a full
-        interval."""
-        interval = self.cfg.intervalMs / 1e3
-        last_cycle = time.monotonic()
-        while not self._stop.is_set():
-            await asyncio.sleep(interval)
-            try:
-                await self._drain_burst(scorer)
-            except asyncio.CancelledError:
-                raise
-            except Exception:  # noqa: BLE001 — the drain loop must
-                # outlive any scoring failure; drain_once already
-                # downgraded scorer faults, so this is a last resort
-                log.exception("anomaly drain failed; continuing")
-            last_cycle = await self._maybe_lifecycle(last_cycle)
-
     async def _line_rate_loop(self, scorer: Scorer) -> None:
-        """Adaptive micro-batcher (the default): dispatch when maxBatch
-        rows are pending OR the oldest pending row has lingered
+        """Adaptive micro-batcher: dispatch when maxBatch rows are
+        pending OR the oldest pending row has lingered
         ``maxLingerMs``. Up to ``scoreConcurrency`` batches stay in
         flight so the staging ring double-buffers — host→device of
         batch N overlaps device compute of batch N-1 — while the
@@ -1595,22 +1565,6 @@ class JaxAnomalyTelemeter(Telemeter):
         from linkerd_tpu.core.tasks import monitor
         monitor(asyncio.create_task(go(), name="fleet-model-push"),
                 what="fleet-model-push")
-
-    async def _drain_burst(self, scorer: Scorer,
-                           max_batches: Optional[int] = None) -> int:
-        """Catch-up drain: under backlog, score several micro-batches
-        per wake instead of one per interval — one full batch per 50ms
-        caps at ~20k rows/s, below the proxy's saturation, and the ring
-        would otherwise shed its OLDEST rows under sustained load."""
-        if max_batches is None:
-            max_batches = self.cfg.maxBatchesPerWake
-        total = 0
-        for _ in range(max_batches):
-            n = await self.drain_once(scorer)
-            total += n
-            if n < self.cfg.maxBatch:
-                break  # ring drained below one full batch
-        return total
 
     async def drain_once(self, scorer: Optional[Scorer] = None) -> int:
         """Drain one micro-batch through the scorer; returns rows scored."""
@@ -2041,7 +1995,6 @@ class JaxAnomalyTelemeter(Telemeter):
             "requests_total": self._requests.value,
             "scored_total": self._scored.value,
             "scored_fraction": round(self._scored_fraction(), 6),
-            "line_rate": bool(self.cfg.lineRate),
             # in-data-plane tier: blob version/CRC, publish (swap)
             # count, native-vs-JAX scored split
             "native_tier": self.native_tier_state(),

@@ -126,16 +126,26 @@ def auc(labels, scores) -> float:
     return (rank_sum - n_pos * (n_pos + 1) / 2.0) / (n_pos * n_neg)
 
 
-class BlackholeServer:
-    """Transport-level black hole: accepts TCP connections, reads and
-    discards forever, never writes a byte. The shape of a hung sidecar
-    or a partitioned downstream — connects succeed, requests vanish,
-    and only the caller's own deadline gets it unstuck. Chaos tests
-    point gRPC/HTTP clients here to prove those deadlines exist."""
+class LoopbackServer:
+    """A loopback TCP server whose ``close()`` always returns.
 
-    def __init__(self, host: str = "127.0.0.1", port: int = 0):
-        self.host = host
-        self.port = port
+    Since Python 3.12 ``asyncio.Server.wait_closed()`` waits until every
+    accepted connection's transport is closed, so a handler that returns
+    on the peer's EOF without ``writer.close()``, or a keep-alive client
+    that never hangs up, holds a test's teardown for ever. Here each
+    connection's writer is closed when its handler ends, and ``close()``
+    stops accepting, closes the transports it still holds and only then
+    awaits ``wait_closed()``.
+
+    ``handle(reader, writer)`` is given or overridden; a peer that goes
+    away in the middle of it ends the connection quietly. Use it with
+    ``await ....start()`` / ``await ....close()`` or ``async with``."""
+
+    def __init__(self, handle=None, host: str = "127.0.0.1",
+                 port: int = 0):
+        if handle is not None:
+            self.handle = handle
+        self._addr = (host, port)
         self._server = None
         self._writers: set = set()
         self.connections = 0
@@ -145,30 +155,63 @@ class BlackholeServer:
         assert self._server is not None, "server not started"
         return self._server.sockets[0].getsockname()[1]
 
-    async def start(self) -> "BlackholeServer":
+    async def handle(self, reader, writer) -> None:
+        raise NotImplementedError
+
+    async def start(self):
         self._server = await asyncio.start_server(
-            self._on_conn, self.host, self.port)
+            self._on_conn, *self._addr)
         return self
 
     async def _on_conn(self, reader, writer) -> None:
         self.connections += 1
         self._writers.add(writer)
         try:
-            while await reader.read(65536):
-                pass  # swallow and never answer
-        except (ConnectionError, asyncio.CancelledError):
+            await self.handle(reader, writer)
+        except (ConnectionError, asyncio.IncompleteReadError,
+                asyncio.CancelledError):
             pass
         finally:
             self._writers.discard(writer)
             writer.close()
 
     async def close(self) -> None:
-        if self._server is not None:
-            self._server.close()
+        if self._server is None:
+            return
+        self._server.close()
         for w in list(self._writers):
             w.close()
-        if self._server is not None:
-            await self._server.wait_closed()
+        await self._server.wait_closed()
+
+    async def __aenter__(self):
+        return await self.start()
+
+    async def __aexit__(self, *exc) -> None:
+        await self.close()
+
+
+class EchoBackend(LoopbackServer):
+    """The downstream of the engine e2es: answers every HTTP/1.1
+    request head on a connection with ``200 OK`` / ``ok``, keep-alive,
+    until the peer hangs up."""
+
+    async def handle(self, reader, writer) -> None:
+        while True:
+            await reader.readuntil(b"\r\n\r\n")
+            writer.write(b"HTTP/1.1 200 OK\r\nContent-Length: 2\r\n\r\nok")
+            await writer.drain()
+
+
+class BlackholeServer(LoopbackServer):
+    """Transport-level black hole: accepts TCP connections, reads and
+    discards forever, never writes a byte. The shape of a hung sidecar
+    or a partitioned downstream — connects succeed, requests vanish,
+    and only the caller's own deadline gets it unstuck. Chaos tests
+    point gRPC/HTTP clients here to prove those deadlines exist."""
+
+    async def handle(self, reader, writer) -> None:
+        while await reader.read(65536):
+            pass  # swallow and never answer
 
 
 class FaultScorer:

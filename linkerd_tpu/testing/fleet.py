@@ -37,6 +37,8 @@ import urllib.error
 import urllib.request
 from typing import Dict, List, Optional, Sequence, Set
 
+from linkerd_tpu.testing.faults import LoopbackServer
+
 log = logging.getLogger(__name__)
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -62,58 +64,41 @@ def _http(method: str, url: str, body: bytes = b"",
         return e.code, e.read()
 
 
-class FaultableCluster:
+class FaultableCluster(LoopbackServer):
     """An HTTP downstream whose responses fault (500 + added latency)
     for requests tagged with an instance id in ``fault_insts``."""
 
     def __init__(self, name: str, fault_delay_s: float = 0.12):
+        super().__init__()
         self.name = name
         self.fault_insts: Set[str] = set()
         self.fault_delay_s = fault_delay_s
         self.requests = 0
-        self._server: Optional[asyncio.AbstractServer] = None
 
     @property
     def port(self) -> int:
-        return self._server.sockets[0].getsockname()[1]
+        return self.bound_port
 
-    async def start(self) -> "FaultableCluster":
-        self._server = await asyncio.start_server(
-            self._on_conn, "127.0.0.1", 0)
-        return self
-
-    async def _on_conn(self, reader, writer) -> None:
-        try:
-            while True:
-                head = await reader.readuntil(b"\r\n\r\n")
-                if not head:
-                    return
-                self.requests += 1
-                inst = ""
-                for line in head.split(b"\r\n")[1:]:
-                    k, _, v = line.partition(b":")
-                    if k.strip().lower() == FAULT_HEADER.encode():
-                        inst = v.strip().decode("latin-1")
-                if inst and inst in self.fault_insts:
-                    await asyncio.sleep(self.fault_delay_s)
-                    body = b"fault"
-                    status = b"500 Internal Server Error"
-                else:
-                    body = self.name.encode()
-                    status = b"200 OK"
-                writer.write(
-                    b"HTTP/1.1 " + status + b"\r\nContent-Length: "
-                    + str(len(body)).encode() + b"\r\n\r\n" + body)
-                await writer.drain()
-        except (asyncio.IncompleteReadError, ConnectionResetError):
-            pass
-        finally:
-            writer.close()
-
-    async def close(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
+    async def handle(self, reader, writer) -> None:
+        while True:
+            head = await reader.readuntil(b"\r\n\r\n")
+            self.requests += 1
+            inst = ""
+            for line in head.split(b"\r\n")[1:]:
+                k, _, v = line.partition(b":")
+                if k.strip().lower() == FAULT_HEADER.encode():
+                    inst = v.strip().decode("latin-1")
+            if inst and inst in self.fault_insts:
+                await asyncio.sleep(self.fault_delay_s)
+                body = b"fault"
+                status = b"500 Internal Server Error"
+            else:
+                body = self.name.encode()
+                status = b"200 OK"
+            writer.write(
+                b"HTTP/1.1 " + status + b"\r\nContent-Length: "
+                + str(len(body)).encode() + b"\r\n\r\n" + body)
+            await writer.drain()
 
 
 class FleetHarness:
@@ -445,6 +430,10 @@ class WanProxy:
         except OSError:
             writer.close()
             return
+        if self.partitioned:  # cut while the uplink was connecting
+            writer.close()
+            up_writer.close()
+            return
 
         async def pipe(rd, wr) -> None:
             try:
@@ -472,13 +461,16 @@ class WanProxy:
         self.partitioned = True
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+        # drop the flows BEFORE waiting: since Python 3.12 wait_closed()
+        # waits for every accepted connection, and the pipes hold them
         for t in list(self._pipes):
             t.cancel()
         if self._pipes:
             await asyncio.gather(*self._pipes, return_exceptions=True)
         self._pipes.clear()
+        if self._server is not None:
+            await self._server.wait_closed()
+            self._server = None
 
     async def heal(self) -> None:
         self.partitioned = False

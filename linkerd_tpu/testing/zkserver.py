@@ -14,6 +14,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Set, Tuple
 
+from linkerd_tpu.testing.faults import LoopbackServer
 from linkerd_tpu.zk import jute
 from linkerd_tpu.zk.client import (
     EPHEMERAL, EVENT_NODE_CHILDREN_CHANGED, EVENT_NODE_CREATED,
@@ -44,36 +45,18 @@ class _Session:
     watches: Set[Tuple[str, str]] = field(default_factory=set)
 
 
-class FakeZkServer:
+class FakeZkServer(LoopbackServer):
     def __init__(self, host: str = "127.0.0.1", port: int = 0):
+        super().__init__(host=host, port=port)
         self.host = host
-        self.port = port
         self.nodes: Dict[str, _Node] = {"/": _Node()}
         self.zxid = 0
         self._next_sid = 0x1000
         self._sessions: Dict[int, _Session] = {}
-        self._server: Optional[asyncio.AbstractServer] = None
-
-    # ── lifecycle ────────────────────────────────────────────────────────
-    async def start(self) -> "FakeZkServer":
-        self._server = await asyncio.start_server(
-            self._serve_conn, self.host, self.port)
-        self.port = self._server.sockets[0].getsockname()[1]
-        return self
-
-    async def close(self) -> None:
-        if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-        for sess in list(self._sessions.values()):
-            try:
-                sess.writer.close()
-            except Exception:  # noqa: BLE001
-                pass
 
     @property
     def hosts(self) -> str:
-        return f"{self.host}:{self.port}"
+        return f"{self.host}:{self.bound_port}"
 
     # ── tree helpers (also used by tests to script state) ────────────────
     def _parent(self, path: str) -> str:
@@ -142,8 +125,8 @@ class FakeZkServer:
                 pass
 
     # ── connection handling ──────────────────────────────────────────────
-    async def _serve_conn(self, reader: asyncio.StreamReader,
-                          writer: asyncio.StreamWriter) -> None:
+    async def handle(self, reader: asyncio.StreamReader,
+                     writer: asyncio.StreamWriter) -> None:
         sess: Optional[_Session] = None
         try:
             # connect handshake
@@ -181,9 +164,6 @@ class FakeZkServer:
                     w.buf += body.buf
                 writer.write(w.packet())
                 await writer.drain()
-        except (asyncio.IncompleteReadError, ConnectionResetError,
-                BrokenPipeError, asyncio.CancelledError):
-            pass
         finally:
             if sess is not None:
                 self._sessions.pop(sess.sid, None)
@@ -192,10 +172,6 @@ class FakeZkServer:
                         del self.nodes[path]
                         self._notify(EVENT_NODE_DELETED, path)
                         self._touch_children(self._parent(path))
-            try:
-                writer.close()
-            except Exception:  # noqa: BLE001
-                pass
 
     @staticmethod
     async def _read_packet(reader: asyncio.StreamReader) -> bytes:

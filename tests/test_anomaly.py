@@ -274,36 +274,6 @@ class TestSidecarCodec:
             encode_fit(x, np.zeros(3, np.float32), np.ones(4, np.float32))
 
 
-class TestDrainBurst:
-    def test_backlog_drains_multiple_batches_per_wake(self, tmp_path):
-        """Under backlog the telemeter scores several micro-batches per
-        wake (capped), not one per interval."""
-        from linkerd_tpu.telemetry.anomaly import (
-            FeatureVector, JaxAnomalyConfig, JaxAnomalyTelemeter,
-        )
-        from linkerd_tpu.telemetry.metrics import MetricsTree
-
-        async def go():
-            cfg = JaxAnomalyConfig(maxBatch=32, trainEveryBatches=0)
-            tele = JaxAnomalyTelemeter(cfg, MetricsTree())
-            for i in range(3 * 32 + 5):
-                tele.ring.append((FeatureVector(latency_ms=float(i)), None))
-            scorer = tele._ensure_scorer()
-            drained = await tele._drain_burst(scorer)
-            # 3 full batches + the 5-row remainder in ONE burst
-            assert drained == 3 * 32 + 5
-            assert len(tele.ring) == 0
-
-            # bounded: a deeper backlog stops at max_batches full batches
-            for i in range(12 * 32):
-                tele.ring.append((FeatureVector(), None))
-            drained = await tele._drain_burst(scorer, max_batches=4)
-            assert drained == 4 * 32
-            assert len(tele.ring) == 8 * 32
-
-        run(go())
-
-
 # -- a fit's statistics: reduced on the device from the one placed batch ------
 
 def scorer_on(placement: str, **kw) -> InProcessScorer:

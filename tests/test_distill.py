@@ -43,6 +43,7 @@ from linkerd_tpu.telemetry.anomaly import (
 )
 from linkerd_tpu.telemetry.linerate import NATIVE_COL_SCORED, NATIVE_ROW_WIDTH
 from linkerd_tpu.telemetry.metrics import MetricsTree
+from linkerd_tpu.testing.faults import EchoBackend
 
 native = pytest.importorskip("linkerd_tpu.native")
 
@@ -356,21 +357,6 @@ class TestTornWeightsDeltaStress:
             slab.close()
 
 
-async def _echo_server():
-    async def handle(r, w):
-        try:
-            while True:
-                await r.readuntil(b"\r\n\r\n")
-                w.write(b"HTTP/1.1 200 OK\r\n"
-                        b"Content-Length: 2\r\n\r\nok")
-                await w.drain()
-        except Exception:  # noqa: BLE001 — client went away
-            pass
-
-    srv = await asyncio.start_server(handle, "127.0.0.1", 0)
-    return srv, srv.sockets[0].getsockname()[1]
-
-
 async def _paced(port: int, n: int, host: bytes = b"svc"):
     r, w = await asyncio.open_connection("127.0.0.1", port)
     rsp = b"HTTP/1.1 200 OK\r\nContent-Length: 2\r\n\r\nok"
@@ -400,7 +386,8 @@ class TestEngineBankServing:
         async def go():
             eng = native.FastPathEngine(workers=2)
             port = eng.listen("127.0.0.1", 0)
-            srv, bport = await _echo_server()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             try:
                 eng.start()
                 eng.set_route("svc", [("127.0.0.1", bport)])
@@ -432,8 +419,7 @@ class TestEngineBankServing:
                 assert st["delta_swaps"] == 1
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -647,7 +633,8 @@ class TestContinuousLearningE2E:
             tele = JaxAnomalyTelemeter(cfg, mt)
             eng = native.FastPathEngine(workers=2)
             port = eng.listen("127.0.0.1", 0)
-            srv, bport = await _echo_server()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             try:
                 eng.start()
                 eng.set_route("svc", [("127.0.0.1", bport)])
@@ -752,8 +739,7 @@ class TestContinuousLearningE2E:
             finally:
                 tele.close()
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 

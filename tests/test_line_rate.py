@@ -392,7 +392,58 @@ class TestLineRateBatcher:
                 assert flat["anomaly/scored_fraction"] == 1.0
                 state = tele.model_state()
                 assert state["scored_fraction"] == 1.0
-                assert state["line_rate"] is True
+            finally:
+                drain.cancel()
+                await asyncio.gather(drain, return_exceptions=True)
+                tele.close()
+
+        run(go())
+
+    def test_backlog_drains_in_full_batches_with_bounded_inflight(self):
+        """A backlog already on the ring when ``run()`` starts is
+        drained to the last row by the one loop: full batches of at
+        most ``maxBatch`` with no linger between them, the remainder
+        last, never more than ``scoreConcurrency`` calls in flight."""
+        max_batch, concurrency = 32, 2
+        backlog = 3 * max_batch + 5
+
+        class Stub:
+            def __init__(self):
+                self.sizes = []
+                self.inflight = self.peak = 0
+
+            async def score(self, x):
+                self.sizes.append(len(x))
+                self.inflight += 1
+                self.peak = max(self.peak, self.inflight)
+                await asyncio.sleep(0.01)  # let the loop get ahead
+                self.inflight -= 1
+                return np.zeros(len(x), np.float32)
+
+            async def fit(self, x, labels, mask):
+                return 0.0
+
+            def close(self):
+                pass
+
+        async def go():
+            mt = MetricsTree()
+            stub = Stub()
+            cfg = JaxAnomalyConfig(maxBatch=max_batch, trainEveryBatches=0,
+                                   scoreConcurrency=concurrency)
+            tele = JaxAnomalyTelemeter(cfg, mt, scorer=stub)
+            for i in range(backlog):
+                tele.ring.append((FeatureVector(latency_ms=float(i)), None))
+            drain = asyncio.ensure_future(tele.run())
+            try:
+                t0 = time.monotonic()
+                while mt.flatten().get("anomaly/scored_total", 0) < backlog:
+                    assert time.monotonic() - t0 < 5.0, \
+                        f"backlog not drained: {stub.sizes}"
+                    await asyncio.sleep(0.005)
+                assert len(tele.ring) == 0
+                assert stub.sizes == [max_batch] * 3 + [5]
+                assert stub.peak == concurrency
             finally:
                 drain.cancel()
                 await asyncio.gather(drain, return_exceptions=True)

@@ -26,6 +26,8 @@ import subprocess
 import numpy as np
 import pytest
 
+from linkerd_tpu.testing.faults import EchoBackend
+
 native = pytest.importorskip("linkerd_tpu.native")
 
 pytestmark = pytest.mark.skipif(
@@ -52,20 +54,6 @@ def certs(tmp_path_factory):
     return cert, key
 
 
-async def _echo_backend():
-    async def handle(r, w):
-        try:
-            while True:
-                await r.readuntil(b"\r\n\r\n")
-                w.write(b"HTTP/1.1 200 OK\r\nContent-Length: 2\r\n\r\nok")
-                await w.drain()
-        except Exception:  # noqa: BLE001 — client went away
-            pass
-
-    srv = await asyncio.start_server(handle, "127.0.0.1", 0)
-    return srv, srv.sockets[0].getsockname()[1]
-
-
 async def _one_shot(port: int, host: str = "svc",
                     headers: str = "") -> bytes:
     """One request on a FRESH connection (a fresh 4-tuple, so the
@@ -86,7 +74,8 @@ async def _one_shot(port: int, host: str = "svc",
 class TestShardedEngine:
     def test_both_workers_serve_and_merged_equals_sum(self):
         async def go():
-            srv, bport = await _echo_backend()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine(workers=2)
             try:
                 port = eng.listen("127.0.0.1", 0)
@@ -113,8 +102,7 @@ class TestShardedEngine:
                     int(s.get("accepted", 0)) for s in st["workers"])
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -134,7 +122,8 @@ class TestShardedEngine:
 
     def test_single_publish_fans_out_to_all_workers(self):
         async def go():
-            srv, bport = await _echo_backend()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine(workers=2)
             try:
                 port = eng.listen("127.0.0.1", 0)
@@ -170,15 +159,15 @@ class TestShardedEngine:
                            for s in st["workers"])
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
     def test_quota_splits_and_zero_per_worker_sheds_all(self):
         async def go():
             from linkerd_tpu.router.tenancy import tenant_hash
-            srv, bport = await _echo_backend()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine(workers=2)
             eng.set_tenant("header", "l5d-tenant")
             try:
@@ -214,8 +203,7 @@ class TestShardedEngine:
                 assert shed == 6
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -232,7 +220,8 @@ class TestShardedEngine:
 
     def test_drain_features_into_fans_in_across_workers(self):
         async def go():
-            srv, bport = await _echo_backend()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine(workers=2)
             try:
                 port = eng.listen("127.0.0.1", 0)
@@ -249,8 +238,7 @@ class TestShardedEngine:
                 assert np.all(out[:n, 2] == 200.0)
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -306,7 +294,8 @@ routers:
         counter equals their sum."""
         async def go():
             from linkerd_tpu.linker import load_linker
-            srv, bport = await _echo_backend()
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             disco = tmp_path / "disco"
             disco.mkdir()
             (disco / "web").write_text(f"127.0.0.1 {bport}\n")
@@ -352,8 +341,7 @@ namers:
                 assert merged == w0 + w1, (merged, w0, w1)
             finally:
                 await linker.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 

@@ -34,6 +34,7 @@ from linkerd_tpu.telemetry.linerate import (
     NATIVE_COL_SCORE, NATIVE_COL_SCORED, NATIVE_ROW_WIDTH, NativeFeaturizer,
 )
 from linkerd_tpu.telemetry.metrics import MetricsTree
+from linkerd_tpu.testing.faults import EchoBackend
 
 native = pytest.importorskip("linkerd_tpu.native")
 
@@ -369,19 +370,8 @@ class TestEngineEndToEnd:
         async def go():
             eng = native.FastPathEngine()
             port = eng.listen("127.0.0.1", 0)
-
-            async def handle(r, w):
-                try:
-                    while True:
-                        await r.readuntil(b"\r\n\r\n")
-                        w.write(b"HTTP/1.1 200 OK\r\n"
-                                b"Content-Length: 2\r\n\r\nok")
-                        await w.drain()
-                except Exception:
-                    pass
-
-            srv = await asyncio.start_server(handle, "127.0.0.1", 0)
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             try:
                 eng.start()
                 eng.set_route("svc", [("127.0.0.1", bport)])
@@ -413,8 +403,7 @@ class TestEngineEndToEnd:
                 assert sum(hist[:20]) == 25, f"score >1ms: {hist}"
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -424,19 +413,8 @@ class TestEngineEndToEnd:
         async def go():
             eng = native.FastPathEngine()
             port = eng.listen("127.0.0.1", 0)
-
-            async def handle(r, w):
-                try:
-                    while True:
-                        await r.readuntil(b"\r\n\r\n")
-                        w.write(b"HTTP/1.1 200 OK\r\n"
-                                b"Content-Length: 2\r\n\r\nok")
-                        await w.drain()
-                except Exception:
-                    pass
-
-            srv = await asyncio.start_server(handle, "127.0.0.1", 0)
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             try:
                 eng.start()
                 eng.set_route("svc", [("127.0.0.1", bport)])
@@ -457,8 +435,7 @@ class TestEngineEndToEnd:
                 assert st["unscored"] == 5 and st["scored"] == 0
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 

@@ -784,7 +784,6 @@ namers:
   rootDir: {disco}
 telemetry:
 - kind: io.l5d.jaxAnomaly
-  intervalMs: 10
   maxBatch: 128
   trainEveryBatches: 0
   scoreTtlSecs: 0.5

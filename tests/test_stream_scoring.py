@@ -29,6 +29,7 @@ from linkerd_tpu.streams import (
     FRAME_WINDOW_UPDATE, H2FrameObserver, StreamSentinel, StreamTracker,
     fold_key, stream_feature_vector,
 )
+from linkerd_tpu.testing.faults import EchoBackend, LoopbackServer
 
 
 def run(coro):
@@ -519,7 +520,6 @@ async def _echo_upstream():
         while b"\r\n\r\n" not in data:
             chunk = await reader.read(1024)
             if not chunk:
-                writer.close()
                 return
             data += chunk
         head = data.split(b"\r\n", 1)[0]
@@ -539,9 +539,8 @@ async def _echo_upstream():
                 break
             writer.write(b"echo:" + chunk)
             await writer.drain()
-        writer.close()
 
-    return await asyncio.start_server(on_conn, "127.0.0.1", 0)
+    return await LoopbackServer(on_conn).start()
 
 
 class TestH1Tunnels:
@@ -550,8 +549,8 @@ class TestH1Tunnels:
         from linkerd_tpu.protocol.http.server import HttpServer
 
         upstream = await _echo_upstream()
-        up_port = upstream.sockets[0].getsockname()[1]
-        client = HttpClient("127.0.0.1", up_port, max_connections=2)
+        client = HttpClient("127.0.0.1", upstream.bound_port,
+                            max_connections=2)
         front = await HttpServer(client).start()
         return upstream, client, front
 
@@ -592,7 +591,7 @@ class TestH1Tunnels:
             finally:
                 await front.close()
                 await client.close()
-                upstream.close()
+                await upstream.close()
 
         run(go())
 
@@ -615,7 +614,7 @@ class TestH1Tunnels:
             finally:
                 await front.close()
                 await client.close()
-                upstream.close()
+                await upstream.close()
 
         run(go())
 
@@ -638,31 +637,17 @@ class TestH1Tunnels:
             finally:
                 await front.close()
                 await client.close()
-                upstream.close()
+                await upstream.close()
 
         run(go())
 
     def test_plain_requests_still_pool(self):
         # the tunnel branch must not disturb ordinary keep-alive reuse
         async def go():
-            async def on_conn(reader, writer):
-                while True:
-                    data = b""
-                    while b"\r\n\r\n" not in data:
-                        chunk = await reader.read(1024)
-                        if not chunk:
-                            writer.close()
-                            return
-                        data += chunk
-                    writer.write(b"HTTP/1.1 200 OK\r\n"
-                                 b"Content-Length: 2\r\n\r\nok")
-                    await writer.drain()
-
             from linkerd_tpu.protocol.http.client import HttpClient
             from linkerd_tpu.protocol.http.message import Request
-            upstream = await asyncio.start_server(on_conn, "127.0.0.1", 0)
-            port = upstream.sockets[0].getsockname()[1]
-            client = HttpClient("127.0.0.1", port)
+            upstream = await EchoBackend().start()
+            client = HttpClient("127.0.0.1", upstream.bound_port)
             try:
                 for _ in range(3):
                     rsp = await client(Request(method="GET", uri="/"))
@@ -670,7 +655,7 @@ class TestH1Tunnels:
                 assert client._n_open == 1  # one conn, reused
             finally:
                 await client.close()
-                upstream.close()
+                await upstream.close()
 
         run(go())
 

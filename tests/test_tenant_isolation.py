@@ -36,8 +36,8 @@ from linkerd_tpu.router.tenancy import (
 )
 from linkerd_tpu.router.service import FnService
 from linkerd_tpu.testing.faults import (
-    ConnectionChurnAttack, PacedTenantClient, SlowlorisAttack,
-    TenantRetryStorm,
+    ConnectionChurnAttack, EchoBackend, LoopbackServer, PacedTenantClient,
+    SlowlorisAttack, TenantRetryStorm,
 )
 
 native_only = pytest.mark.skipif(
@@ -436,21 +436,6 @@ namers:
 
 @native_only
 class TestNativeTenantExtraction:
-    async def _serve_ok(self):
-        async def handle(reader, writer):
-            while True:
-                try:
-                    await reader.readuntil(b"\r\n\r\n")
-                except (asyncio.IncompleteReadError,
-                        ConnectionResetError):
-                    break
-                writer.write(b"HTTP/1.1 200 OK\r\n"
-                             b"Content-Length: 2\r\n\r\nok")
-                await writer.drain()
-            writer.close()
-
-        return await asyncio.start_server(handle, "127.0.0.1", 0)
-
     async def _h1_get(self, port, host, uri="/", headers=()):
         r, w = await asyncio.open_connection("127.0.0.1", port)
         try:
@@ -477,8 +462,8 @@ class TestNativeTenantExtraction:
 
     def test_header_extraction_parity_and_feature_row(self):
         async def go():
-            srv = await self._serve_ok()
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine()
             eng.set_tenant("header", "l5d-tenant")
             port = eng.listen("127.0.0.1", 0)
@@ -501,15 +486,14 @@ class TestNativeTenantExtraction:
                     tenant_hash(t) for t in ("alice", "bob", "T-42")}
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
     def test_path_segment_extraction_parity(self):
         async def go():
-            srv = await self._serve_ok()
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine()
             eng.set_tenant("pathSegment", segment=0)
             port = eng.listen("127.0.0.1", 0)
@@ -530,15 +514,14 @@ class TestNativeTenantExtraction:
                     tenant_hash(pyside))
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
     def test_native_lru_bound_under_id_churn(self):
         async def go():
-            srv = await self._serve_ok()
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine()
             eng.set_tenant("header", "l5d-tenant")
             eng.set_guard(tenant_cap=16)
@@ -564,15 +547,14 @@ class TestNativeTenantExtraction:
                 assert tn["evicted"] >= 200 - 16 - 16  # amortized sweeps
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
     def test_native_quota_shed_is_retryable_503(self):
         async def go():
-            srv = await self._serve_ok()
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine()
             eng.set_tenant("header", "l5d-tenant")
             port = eng.listen("127.0.0.1", 0)
@@ -595,8 +577,7 @@ class TestNativeTenantExtraction:
                 assert eng.stats()["guard"]["tenant_shed"] == 1
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -607,8 +588,8 @@ class TestNativeTenantExtraction:
         requests miss routes accrues phantom inflight and is shed
         forever (and its pinned table entry defeats LRU eviction)."""
         async def go():
-            srv = await self._serve_ok()
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine()
             eng.set_tenant("header", "l5d-tenant")
             port = eng.listen("127.0.0.1", 0)
@@ -644,8 +625,7 @@ class TestNativeTenantExtraction:
                 assert by[str(tenant_hash("t"))]["inflight"] == 0
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -725,10 +705,9 @@ class TestNativeConnectionGuard:
                 with contextlib.suppress(Exception):
                     await reader.readuntil(b"\r\n\r\n")
                 await asyncio.sleep(30)
-                writer.close()
 
-            srv = await asyncio.start_server(handle, "127.0.0.1", 0)
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await LoopbackServer(handle).start()
+            bport = srv.bound_port
             eng = native.FastPathEngine()
             eng.set_guard(header_budget_ms=30_000, body_stall_ms=600)
             port = eng.listen("127.0.0.1", 0)
@@ -746,8 +725,7 @@ class TestNativeConnectionGuard:
                 w.close()
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())
 
@@ -1032,20 +1010,8 @@ class TestChaosMatrixNative:
         shared weight slab must not break the isolation loop)."""
 
         async def go():
-            async def handle(reader, writer):
-                while True:
-                    try:
-                        await reader.readuntil(b"\r\n\r\n")
-                    except (asyncio.IncompleteReadError,
-                            ConnectionResetError):
-                        break
-                    writer.write(b"HTTP/1.1 200 OK\r\n"
-                                 b"Content-Length: 2\r\n\r\nok")
-                    await writer.drain()
-                writer.close()
-
-            srv = await asyncio.start_server(handle, "127.0.0.1", 0)
-            bport = srv.sockets[0].getsockname()[1]
+            srv = await EchoBackend().start()
+            bport = srv.bound_port
             eng = native.FastPathEngine(workers=workers)
             eng.set_tenant("header", "l5d-tenant")
             port = eng.listen("127.0.0.1", 0)
@@ -1090,7 +1056,6 @@ class TestChaosMatrixNative:
                 assert st["native_scorer"]["scored"] > 0
             finally:
                 eng.close()
-                srv.close()
-                await srv.wait_closed()
+                await srv.close()
 
         run(go())

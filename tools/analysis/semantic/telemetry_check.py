@@ -69,10 +69,6 @@ def _bad(source: ConfigSource, rule: str, where: str, message: str,
 
 def _check_anomaly_cfg(source: ConfigSource, cfg, where: str
                        ) -> Iterator[Finding]:
-    if cfg.intervalMs <= 0:
-        yield _bad(source, "scorer-config", where,
-                   f"intervalMs must be > 0 (got {cfg.intervalMs})",
-                   "intervalMs")
     if cfg.maxBatch < 1:
         yield _bad(source, "scorer-config", where,
                    f"maxBatch must be >= 1 (got {cfg.maxBatch})",
@@ -83,12 +79,6 @@ def _check_anomaly_cfg(source: ConfigSource, cfg, where: str
                    f"({cfg.maxBatch}) — the feature ring can never hold "
                    f"a full scoring batch",
                    "ringCapacity")
-    if cfg.maxBatchesPerWake < 1:
-        yield _bad(source, "scorer-config", where,
-                   f"maxBatchesPerWake must be >= 1 (got "
-                   f"{cfg.maxBatchesPerWake}) — 0 silently disables "
-                   f"draining (the telemeter refuses it at startup)",
-                   "maxBatchesPerWake")
     if not (0.0 <= cfg.scoreThreshold <= 1.0):
         yield _bad(source, "scorer-config", where,
                    f"scoreThreshold must be in [0, 1] (got "

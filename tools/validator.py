@@ -395,7 +395,6 @@ telemetry:
 - kind: io.l5d.jaxAnomaly
   sidecarAddress: 127.0.0.1:{ports['sidecar']}
   sidecarTier: primary  # the chaos scenario exercises the sidecar path
-  intervalMs: 20
   trainEveryBatches: 0
   scoreTimeoutMs: 200
   breakerFailures: 1
