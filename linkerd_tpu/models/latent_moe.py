@@ -20,17 +20,25 @@ Per layer ``h += Attn(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``:
   ``Wuv``), so a cached position is never up-projected. RoPE is YaRN's,
   rotate-half pairing. All heads of a flow share the one latent and the
   one rope key, so a flow's queries are one ``[events x heads, rank +
-  rope]`` matrix against its slot ``[positions, rank + rope]``. *How that
-  product runs is the step's ``attend``*, chosen by platform where the
-  step is built (``models/spec.py``): on a TPU the Pallas kernel of
-  ``ops/flow_attention.py``, which tiles the query rows and the
-  positions, keeps a tile's scores in VMEM from the product to the
-  softmax's weights and stops at the last block of positions the tile's
-  events may see, **so the score tensor never reaches HBM and positions
-  past a flow's length cost nothing**;
-  elsewhere ``attend_xla``, which forms the scores of ``ATTENTION_BLOCK``
-  flows over their whole slots at a time. Both mask causally by ``p0``
-  and agree to bfloat16 rounding.
+  rope]`` matrix against its slot ``[positions, rank + rope]``. **The
+  cache is read and written where it lies** (PR 31). A layer first
+  appends the call's entries in place (``append_chunk``: a window of
+  ``T + 1`` positions a flow is read, the chunk's rows and, where the flow
+  begins, the start token's are set into it, and the window is written
+  back; no slot is gathered, merged and written back whole), then the
+  chunk attends over its flow's slot of the appended cache. *How that
+  product runs is the step's ``attend``*, which takes the layer's cache
+  whole and each flow's slot number, chosen by platform where the step is
+  built (``models/spec.py``): on a TPU the Pallas kernel of
+  ``ops/flow_attention.py``, whose block specs take a flow's slot from
+  the cache by its number, which tiles the query rows and the positions,
+  keeps a tile's scores in VMEM from the product to the softmax's weights
+  and stops at the last block of positions the tile's events may see,
+  **so neither a copy of the slots nor the score tensor reaches HBM and
+  positions past a flow's length cost nothing**; elsewhere ``attend_xla``,
+  which gathers the flows' slots itself and forms the scores of
+  ``ATTENTION_BLOCK`` flows over their whole slots at a time. Both mask
+  causally by ``p0`` and agree to bfloat16 rounding.
 - **FFN.** The first ``first_k_dense_replace`` layers: dense SwiGLU. The
   others: ``shared(x) + routed(x)``. The router scores all
   ``n_routed_experts`` in float32 (``sigmoid``), selects the top
@@ -332,16 +340,21 @@ def _rope(x, cos, sin):
 ATTENTION_BLOCK = 8     # flows attended at a time: bounds the score tensor
 
 
-def attend_xla(q_abs, q_rope, kv, p0, scale: float):
+def attend_xla(q_abs, q_rope, cache, slot, p0, scale: float):
     """Attention as XLA does it, the whole score tensor formed in blocks
     of ``ATTENTION_BLOCK`` flows: the path of every platform but the TPU,
     and what ``ops/flow_attention.latent_attention_fused`` is tested
-    against. ``q_abs [F, T, H, rank]``, ``q_rope [F, T, H, rope]``, ``kv
-    [F, P, rank + rope]`` bfloat16; event ``t`` of flow ``f`` sees
-    positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H, rank]``
-    bfloat16, the blocks of positions attended over ``[F]``, the blocks of
-    a whole slot)``: here every slot is attended whole, as one block."""
+    against. ``q_abs [F, T, H, rank]``, ``q_rope [F, T, H, rope]``
+    bfloat16; ``cache [slots, positions, rank + rope]`` the layer's,
+    whole, of which flow ``f`` attends over slot ``slot[f]`` (clipped into
+    range): **this path gathers the flows' slots itself**, a copy of ``F``
+    slots (the kernel reads them where they lie); event ``t`` of flow
+    ``f`` sees positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H,
+    rank]`` bfloat16, the blocks of positions attended over ``[F]``, the
+    blocks of a whole slot)``: here every slot is attended whole, as one
+    block."""
     F, T, H, rank = q_abs.shape
+    kv = cache[jnp.minimum(slot, cache.shape[0] - 1)]       # [F, P, entry]
     P = kv.shape[1]
 
     def attend(block):
@@ -363,20 +376,64 @@ def attend_xla(q_abs, q_rope, kv, p0, scale: float):
             jnp.ones((F,), jnp.int32), 1)
 
 
+def append_chunk(cache, entry, start_entry, slot, p0, count, begins):
+    """The call's entries into the layer's ``cache [slots, positions,
+    entry]`` where they belong, and no other row touched: flow ``f``'s
+    ``entry[f, t]`` for ``t < count[f]`` at ``(slot[f], p0[f] + t)``, and
+    ``start_entry`` at ``(slot[f], 0)`` where the flow ``begins``. A
+    window of ``W = T + 1`` positions that holds them all (from ``p0 - 1``
+    on, pushed back where it would pass the slot's end) is read from the
+    slot, the entries are set into it and the window is written back over
+    itself, so what the window holds besides is bit for bit what it was;
+    with a donated cache that is ``F`` small updates in place (XLA makes
+    a loop of ``F`` reads and one of ``F`` writes of it: 0.63 ms a layer
+    at the benchmark's cell, where gathering and scattering whole slots
+    took 4.07; a scatter of single rows makes XLA relayout the whole
+    layer; a Pallas append of whole lane tiles took 0.15 but writes 256
+    positions a flow and is a second implementation; my chip runs, PR
+    31). A ``slot`` out of range (a flow of the layout that brings
+    nothing) reads clipped and writes nothing. Returns the cache and the
+    rows written (``W`` a flow whose slot is in range)."""
+    S, P, E = cache.shape
+    F, T, _ = entry.shape
+    W = min(T + 1, P)
+    w0 = jnp.clip(p0 - 1, 0, P - W)                         # [F]
+    pos = w0[:, None] + jnp.arange(W)[None]                 # [F, W]
+    t = pos - p0[:, None]
+    window = jax.vmap(lambda s, w: jax.lax.dynamic_slice(
+        cache, (s, w, 0), (1, W, E))[0])(jnp.minimum(slot, S - 1), w0)
+    mine = (t >= 0) & (t < count[:, None])
+    window = jnp.where(mine[..., None], jnp.take_along_axis(
+        entry, jnp.clip(t, 0, T - 1)[..., None], 1), window)
+    window = jnp.where((begins[:, None] & (pos == 0))[..., None],
+                       start_entry[None, None], window)
+    cache = jax.lax.scatter(
+        cache, jnp.stack([slot, w0], -1), window,
+        jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1, 2), inserted_window_dims=(0,),
+            scatter_dims_to_operand_dims=(0, 1)),
+        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+    return cache, (slot < S).sum() * W
+
+
 def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
                cos, sin, attend):
     """``x [F, T, hidden]`` normed, ``cache [slots, positions, entry]``
-    this layer's. Each flow's slot is read whole, the chunk's ``count``
-    entries are set into it from position ``p0`` on (and the start
-    token's at position 0 where the flow ``begins``), the slot is written
-    back whole (a ``slot`` out of range: read clipped, write dropped) and
-    the chunk attends over it causally by ``attend`` (``attend_xla``'s
-    signature). Returns the output, the cache and the blocks of positions
-    attended over and of the slots whole, summed over the flows."""
+    this layer's, donated. The chunk's ``count`` entries are appended to
+    the cache in place *before* the layer attends (``append_chunk``: at
+    ``(slot, p0 + t)``, and the start token's at position 0 where the
+    flow ``begins``; a ``slot`` out of range writes nothing), then the
+    chunk attends causally over its flow's slot by ``attend``
+    (``attend_xla``'s signature), which takes the appended cache whole
+    and the slots' numbers: **no slot is gathered, merged and written
+    back here** (PRs 28-30 did: 604 MB of a layer sliced and copied to
+    read 75 MB and write 4.7). Returns the output, the cache, and
+    ``[blocks of positions attended over, those of the slots whole, rows
+    of the cache written]``, summed over the flows."""
     F, T, _ = x.shape
     H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
-    rank, eps, P = cfg.kv_lora_rank, cfg.rms_norm_eps, cfg.positions
+    rank, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
     q = _mm(_rms(_mm(x, lp["wdq"]), lp["q_norm"], eps), lp["wuq"]).reshape(
         F, T, H, nope + rope)
     q_rope = _rope(q[..., nope:], cos[:, :, None], sin[:, :, None])
@@ -384,24 +441,18 @@ def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
     entry = jnp.concatenate(
         [_rms(ckr[..., :rank], lp["kv_norm"], eps),
          _rope(ckr[..., rank:], cos, sin)], -1).astype(jnp.bfloat16)
-    kv = cache[jnp.minimum(slot, cache.shape[0] - 1)]       # [F, P, entry]
-    t = jnp.arange(P)[None] - p0[:, None]                   # [F, P]
-    mine = (t >= 0) & (t < count[:, None])
-    kv = jnp.where(mine[..., None], jnp.take_along_axis(
-        entry, jnp.clip(t, 0, T - 1)[..., None], 1), kv)
-    kv = kv.at[:, 0].set(jnp.where(begins[:, None], start_entry[None],
-                                   kv[:, 0]))
-    cache = cache.at[slot].set(kv, mode="drop")
+    cache, written = append_chunk(cache, entry, start_entry, slot, p0, count,
+                                  begins)
     wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
     q_abs = jnp.einsum("fthd,chd->fthc", q[..., :nope].astype(jnp.bfloat16),
                        wukv[..., :nope], preferred_element_type=jnp.float32)
     o, blocks, whole = attend(q_abs.astype(jnp.bfloat16),
-                              q_rope.astype(jnp.bfloat16), kv, p0,
-                              softmax_scale(cfg))
+                              q_rope.astype(jnp.bfloat16), cache, slot,
+                              p0, softmax_scale(cfg))
     o = jnp.einsum("fthc,chd->fthd", o, wukv[..., nope:],
                    preferred_element_type=jnp.float32)
     return (_mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache,
-            jnp.stack([blocks.sum(), F * whole]))
+            jnp.stack([blocks.sum(), F * whole, written]))
 
 
 def route(lp, cfg, x):
@@ -468,8 +519,9 @@ def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
     of a flow that begins.
     Returns the final normed hidden ``[F, T, hidden]`` float32, the cache
     with the chunks appended, tokens per held expert ``[expert layers,
-    G]``, and the blocks of positions attention ran over and those of the
-    slots whole ``[2]``, summed over flows and layers."""
+    G]``, and the blocks of positions attention ran over, those of the
+    slots whole and the rows of the cache written ``[3]``, summed over
+    flows and layers."""
     F, T = tok.shape
     h = params["embed"][tok].astype(jnp.float32)
     pos = p0[:, None] + jnp.arange(T)[None]
@@ -477,14 +529,14 @@ def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
     angle = pos[..., None].astype(jnp.float32) * jnp.asarray(
         yarn_inv_freq(cfg))
     cos, sin = jnp.cos(angle), jnp.sin(angle)
-    counts, cache, blocks = [], list(cache), jnp.zeros((2,), jnp.int32)
+    counts, cache, tally = [], list(cache), jnp.zeros((3,), jnp.int32)
     for l, lp in enumerate(params["layers"]):
         with jax.named_scope(f"layer{l}.attention"):
-            a, cache[l], attended = _attention(
+            a, cache[l], layer_tally = _attention(
                 lp, cfg, cache[l], _rms(h, lp["attn_norm"], cfg.rms_norm_eps),
                 slot, p0, count, begins, start_entries[l], cos, sin, attend)
             h = h + a
-            blocks = blocks + attended
+            tally = tally + layer_tally
         with jax.named_scope(f"layer{l}.ffn"):
             x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
             if "router" in lp:
@@ -500,7 +552,7 @@ def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
     G = cfg.experts_held[1] - cfg.experts_held[0]
     counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
     return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(cache),
-            counts, blocks)
+            counts, tally)
 
 
 def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
@@ -526,7 +578,7 @@ def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
     begins = flow & (p0 == 1)
     prev_h = jnp.where(begins[:, None], start_h[None],
                        last_h[jnp.minimum(slot, S - 1)])
-    h, cache, expert_tokens, blocks = _forward(
+    h, cache, expert_tokens, tally = _forward(
         params, cfg, cache, start_entries, tok, slot, p0, count, begins,
         attend)
     with jax.named_scope("head"):
@@ -554,7 +606,9 @@ def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
     counts = {"moe.local_pairs": expert_tokens.sum(),
               "moe.max_expert_tokens": expert_tokens.max(initial=0),
               "cache.positions": length.sum(),
-              "attn.kv_blocks": blocks[0],
-              "attn.kv_blocks_whole": blocks[1],
+              "attn.kv_blocks": tally[0],
+              "attn.kv_blocks_whole": tally[1],
+              "cache.rows_written": tally[2],
+              "cache.rows_whole": flow.sum() * P * cfg.layers,
               "expert_tokens": expert_tokens}
     return scores, (cache, length, last_h, (start_entries, start_h)), counts

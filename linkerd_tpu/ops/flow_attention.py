@@ -5,35 +5,51 @@ head of a flow attends over the one latent ``[positions, rank]`` and the
 one rope key ``[positions, rope]`` of the flow's slot: a flow's queries are
 one ``[events * heads, rank + rope]`` matrix against ``[positions, rank +
 rope]``, and the weighted values are the latent again. The XLA path
-(``models.latent_moe.attend_xla``) forms the whole float32 score tensor,
-masks it, takes a softmax over it and multiplies it into the latent: that
-tensor crosses HBM four or so times and every event attends over all the
-slot's positions whatever the flow holds.
+(``models.latent_moe.attend_xla``) gathers the flows' slots, forms the
+whole float32 score tensor, masks it, takes a softmax over it and
+multiplies it into the latent: that tensor crosses HBM four or so times
+and every event attends over all the slot's positions whatever the flow
+holds.
 
 Here a grid cell is one flow and one tile of its query rows (a few whole
-events, all heads). The slot's keys and values lie in VMEM whole, and so
-do the tile's scores, in a scratch ``[rows, positions]`` that never
-leaves the chip. Two loops run over blocks of ``KV_BLOCK`` positions:
-the first forms a block's scores and keeps their maximum lane by lane;
-then one reduction across lanes gives each row's maximum; the second
-takes ``exp(score - maximum)``, sums it lane by lane and adds the block's
-weights, cast to bfloat16, times the block's latent into a float32
-accumulator; one division a row at the end. **Both loops end at the last
-block that holds a position any of the tile's events may see**
-(``blocks_seen``, from the flow's position ``p0``, handed in by
-``PrefetchScalarGridSpec``), and only the blocks that reach past the
-tile's first position are masked.
+events, all heads). **The flow's slot is read where it lies**: the kernel
+takes the layer's cache whole, and the block spec of the keys and values
+indexes it by ``slot[f]``, handed in with ``p0`` by
+``PrefetchScalarGridSpec``, so a slot comes from HBM once a flow and no
+gathered copy of the slots exists (PRs 29-30 handed the kernel
+``cache[slot]``, for which XLA sliced and copied the whole layer: 604 MB
+read and written to reach 75; PR 31). The slot's keys and values lie in
+VMEM whole, and so do the tile's scores, in a scratch ``[rows,
+positions]`` that never leaves the chip. Two loops run over blocks of
+``KV_BLOCK`` positions: the first forms a block's scores and keeps their
+maximum lane by lane; then one reduction across lanes gives each row's
+maximum; the second takes ``exp(score - maximum)``, sums it lane by lane
+and adds the block's weights, cast to bfloat16, times the block's latent
+into a float32 accumulator; one division a row at the end. **Both loops
+end at the last block that holds a position any of the tile's events may
+see** (``blocks_seen``, from the flow's position ``p0``), and only the
+blocks that reach past the tile's first position are masked.
 (Online softmax, a running maximum and a rescaled accumulator a block,
 computes the same in one loop and was the first attempt: its two
 reductions across lanes a block are what a v5e does slowest: 2.59 ms a
 layer of the benchmark's cell against 2.12; my chip runs, PR 29.)
 
-The entry is split at ``rank`` (``s = q_abs . c^T + q_rope . k_rope^T``:
-576 lanes are no multiple of Mosaic's 128). The keys come transposed,
-``[entry, positions]``, a copy XLA makes a layer: transposing a block in
-the kernel, for every tile again, took 1.4 ms a layer against the copy's
-0.5 (same runs). The values are the latent in place, lanes ``0..rank`` of
-the gathered slot.
+**The slot lies transposed, and is used so.** The TPU's compiler stores a
+layer ``[slots, positions, 576]`` positions-minor (576 is no multiple of
+128 lanes, 1,024 is), so the kernel is handed ``cache.transpose(0, 2, 1)``,
+which in that layout is a bitcast, and a slot arrives as ``[entry,
+positions]``: the keys as the first product wants them, positions along
+the lanes (``s = q_abs . c^T + q_rope . k_rope^T``, the entry split at
+``rank``: sublanes ``0..rank`` and ``rank..``), with no transposed copy
+(XLA's took 0.5 ms a layer). The values are the same rows, and the
+second product contracts the weights with them over the positions, the
+last axis of both (``POSITION_AXES``), which the MXU does at no cost
+that shows: 1.93 ms a layer, against 1.94 with a flow's latent
+transposed once, at its first tile, into a VMEM scratch, and 2.04 for
+PR 29's kernel with its copy (my chip runs, PR 31; the variant with the
+scratch was deleted). Where a compiler stores the cache entry-minor the
+transpose is a copy of the layer: ``tests/test_chip_bringup.py`` compiles
+the cell's step for a described v5e and fails on any such copy.
 
 ``best_attention`` selects by platform as ``ops/scoring.best_scorer``
 does: this kernel on ``tpu``, the XLA path elsewhere. There is no probe
@@ -80,10 +96,14 @@ def _events_a_tile(T: int, H: int, P: int) -> int:
     return te
 
 
-def _kernel(p0_ref, qa_ref, qr_ref, kt_ref, c_ref, o_ref, s_ref, m_ref,
+POSITION_AXES = (((1,), (1,)), ((), ()))    # p [rows, pos] . c [rank, pos]
+
+
+def _kernel(slot_ref, p0_ref, qa_ref, qr_ref, kt_ref, o_ref, s_ref, m_ref,
             l_ref, acc_ref, *, scale: float, heads: int):
+    del slot_ref    # the block specs' alone: which slot ``kt_ref`` holds
     f, i = pl.program_id(0), pl.program_id(1)
-    rows, (P, rank) = qa_ref.shape[1], c_ref.shape[1:]
+    (rows, rank), P = qa_ref.shape[1:], kt_ref.shape[2]
     events, bk = rows // heads, m_ref.shape[1]
     first = p0_ref[f] + i * events      # position of the tile's first event
     last = blocks_seen(first, events, P)
@@ -120,9 +140,10 @@ def _kernel(p0_ref, qa_ref, qr_ref, kt_ref, c_ref, o_ref, s_ref, m_ref,
         at = pl.multiple_of(j * bk, bk)
         p = jnp.exp(s_ref[:, pl.ds(at, bk)] - m_ref[...])
         l_ref[...] += p         # lane by lane: summed across once, below
-        c = c_ref[0, pl.ds(at, bk), :]
-        acc_ref[...] += jnp.dot(p.astype(c.dtype), c,
-                                preferred_element_type=jnp.float32)
+        ct = kt_ref[0, :rank, pl.ds(at, bk)]
+        acc_ref[...] += jax.lax.dot_general(
+            p.astype(ct.dtype), ct, POSITION_AXES,
+            preferred_element_type=jnp.float32)
 
     jax.lax.fori_loop(0, last, weigh, None)
     o_ref[0] = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
@@ -130,37 +151,42 @@ def _kernel(p0_ref, qa_ref, qr_ref, kt_ref, c_ref, o_ref, s_ref, m_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def latent_attention_fused(q_abs, q_rope, kv, p0, scale: float,
+def latent_attention_fused(q_abs, q_rope, cache, slot, p0, scale: float,
                            interpret: bool = False):
-    """``q_abs [F, T, H, rank]``, ``q_rope [F, T, H, rope]``, ``kv [F, P,
-    rank + rope]`` bfloat16, ``p0 [F]`` int32: event ``t`` of flow ``f``
-    sees positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H, rank]``
+    """``q_abs [F, T, H, rank]``, ``q_rope [F, T, H, rope]`` bfloat16;
+    ``cache [slots, P, rank + rope]`` bfloat16, the layer's, whole, with
+    the call's entries already appended; ``slot [F]``, ``p0 [F]`` int32:
+    flow ``f`` attends over ``kv = cache[slot[f]]`` (a slot out of range:
+    clipped), read in place by the block spec, and its event ``t`` sees
+    positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H, rank]``
     bfloat16 ``= softmax(mask(q . kv^T * scale)) . kv[..., :rank]``, the
     blocks of positions attended over, summed over a flow's tiles of
     query rows ``[F]``, and what the slot whole would have been)``.
     Jitted, so that a step of several layers traces and lowers the kernel
     once (0.1 s a layer of every set-up; my chip runs, PR 29)."""
     F, T, H, rank = q_abs.shape
-    rope, P = q_rope.shape[-1], kv.shape[1]
+    rope, (S, P, _) = q_rope.shape[-1], cache.shape
     bk, events = kv_block(P), _events_a_tile(T, H, P)
     rows, tiles = events * H, T // events
     kernel = functools.partial(_kernel, scale=scale, heads=H)
     o = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(F, tiles),
             in_specs=[
-                pl.BlockSpec((1, rows, rank), lambda f, i, p0: (f, i, 0)),
-                pl.BlockSpec((1, rows, rope), lambda f, i, p0: (f, i, 0)),
-                # the keys, positions along the lanes
+                pl.BlockSpec((1, rows, rank),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                pl.BlockSpec((1, rows, rope),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                # the flow's slot where it lies, positions along the
+                # lanes: the same block for all of a flow's tiles, so it
+                # is fetched once a flow
                 pl.BlockSpec((1, rank + rope, P),
-                             lambda f, i, p0: (f, 0, 0)),
-                # the values: lanes 0..rank of the entry, read in place
-                pl.BlockSpec((1, P, rank), lambda f, i, p0: (f, 0, 0)),
+                             lambda f, i, slot, p0: (slot[f], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, rows, rank),
-                                   lambda f, i, p0: (f, i, 0)),
+                                   lambda f, i, slot, p0: (f, i, 0)),
             scratch_shapes=[pltpu.VMEM((rows, P), jnp.float32),
                             pltpu.VMEM((rows, bk), jnp.float32),
                             pltpu.VMEM((rows, bk), jnp.float32),
@@ -171,8 +197,9 @@ def latent_attention_fused(q_abs, q_rope, kv, p0, scale: float,
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="latent_attention_fused",
-    )(p0.astype(jnp.int32), q_abs.reshape(F, T * H, rank),
-      q_rope.reshape(F, T * H, rope), kv.transpose(0, 2, 1), kv)
+    )(jnp.minimum(slot, S - 1).astype(jnp.int32), p0.astype(jnp.int32),
+      q_abs.reshape(F, T * H, rank), q_rope.reshape(F, T * H, rope),
+      cache.transpose(0, 2, 1))
     attended = sum(blocks_seen(p0 + i * events, events, P)
                    for i in range(tiles))
     return o.reshape(F, T, H, rank), attended, tiles * (P // bk)

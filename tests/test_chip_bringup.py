@@ -143,15 +143,20 @@ class TestScorerSelection:
                  if line.startswith("TEMP")]
         assert len(temps) == 4 and max(temps) < 32 * 2 ** 20, temps
 
-    def test_flow_step_compiles_for_v5e_at_the_published_widths(self):
-        """The flow model's step (``models.latent_moe.flow_step``) as the
-        benchmark's cell runs it, 64 flows x 64 events at hidden 7,168 with
-        12 of 384 experts held and the attention a TPU gets (the fused
-        kernel, one call a layer), compiled by the TPU's own compiler with
-        no chip: it fits one v5e (15.75 GiB) with its weights and its
-        cache as arguments, and the cache is updated in place (aliased)."""
+    @pytest.fixture(scope="class")
+    def flow_step_compiled(self):
+        """What a child says of the flow model's step
+        (``models.latent_moe.flow_step``) as the benchmark's cell runs it:
+        the configuration ``kimi-k2-6-ep32`` (hidden 7,168, 12 of 384
+        experts held, a cache of 512 slots x 1,024 positions x 576 a
+        layer), 64 flows x 64 events, the attention a TPU gets, compiled
+        by the TPU's own compiler with no chip. One compile (half a
+        minute) for the tests below; the child prints the program's bytes,
+        its kernels, and every instruction of the optimised program whose
+        result is a layer's cache or a range of its positions (``WHOLE
+        <opcode> <name> <type>``)."""
         code = (
-            "import jax, jax.numpy as jnp\n"
+            "import json, re, jax, jax.numpy as jnp\n"
             "from jax.experimental import topologies\n"
             "from jax.sharding import SingleDeviceSharding\n"
             "try:\n"
@@ -163,7 +168,9 @@ class TestScorerSelection:
             "from linkerd_tpu.ops.flow_attention import best_attention\n"
             "sh = SingleDeviceSharding(topo.devices[0])\n"
             "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
-            "cfg = lm.LatentMoEConfig()\n"
+            "with open('chipbench/configs/kimi-k2-6-ep32.json') as f:\n"
+            "    cfg = lm.LatentMoEConfig.from_config(json.load(f))\n"
+            "assert cfg == lm.LatentMoEConfig()\n"
             "held = cfg.experts_held[1] - cfg.experts_held[0]\n"
             "params = {'layers': [{} for _ in range(cfg.layers)]}\n"
             "for name, (shape, _, _, each) in lm.tensor_table(cfg).items():\n"
@@ -184,8 +191,27 @@ class TestScorerSelection:
             "m = c.memory_analysis()\n"
             "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
             "      m.alias_size_in_bytes)\n"
-            "print('KERNELS', c.as_text().count(\n"
+            "text = c.as_text()\n"
+            "print('KERNELS', text.count(\n"
             "    'custom_call_target=\"tpu_custom_call\"'))\n"
+            "# a layer's cache, or 128 and more of its positions, in either\n"
+            "# order of the two minor dimensions\n"
+            "S_, P, E = cfg.slots, cfg.positions, cfg.entry_width\n"
+            "whole = re.compile(rf'bf16\\[{S_},(\\d+),{E}\\]|'\n"
+            "                   rf'bf16\\[{S_},{E},(\\d+)\\]')\n"
+            "for line in text.splitlines():\n"
+            "    name, eq, rest = line.strip().partition(' = ')\n"
+            "    if not eq or name.startswith('//'): continue\n"
+            "    if rest.startswith('('):\n"
+            "        depth = 0\n"
+            "        for i, ch in enumerate(rest):\n"
+            "            depth += (ch == '(') - (ch == ')')\n"
+            "            if depth == 0: break\n"
+            "        typ, rest = rest[:i + 1], rest[i + 2:]\n"
+            "    else:\n"
+            "        typ, _, rest = rest.partition(' ')\n"
+            "    if any(int(a or b) >= 128 for a, b in whole.findall(typ)):\n"
+            "        print('WHOLE', rest.split('(', 1)[0], name, typ[:80])\n"
             "print('COMPILED', topo.devices[0].device_kind)\n")
         proc = _run([sys.executable, "-c", code], timeout=900,
                     env=_clean_env(
@@ -196,19 +222,54 @@ class TestScorerSelection:
             pytest.skip("no compile-only TPU client in this installation")
         assert proc.returncode == 0, proc.stderr[-2000:]
         assert "COMPILED TPU v5" in proc.stdout
+        return proc.stdout
+
+    def test_flow_step_compiles_for_v5e_at_the_published_widths(
+            self, flow_step_compiled):
+        """It fits one v5e (15.75 GiB) with its weights and its cache as
+        arguments, the cache is updated in place (aliased), and the
+        attention is the fused kernel, one call a layer."""
         args, temp, alias = (int(v) for v in next(
-            line for line in proc.stdout.splitlines()
+            line for line in flow_step_compiled.splitlines()
             if line.startswith("BYTES")).split()[1:])
         # weights 6.99 GB and the cache 3.02 GB; the cache comes back aliased
         assert 9.9e9 < args < 10.2e9 and alias > 3.0e9
-        # no block of float32 scores among the temporaries (1.45 GiB here,
-        # 1.78 with XLA's attention; my compile-only readings, PR 29)
-        assert temp < 1.65 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
-        assert "KERNELS 5" in proc.stdout
+        # no block of float32 scores among the temporaries, and since PR 31
+        # no slice of a layer's cache either (0.78 GiB here; 1.60 with the
+        # slots gathered; 1.78 with XLA's attention too; my compile-only
+        # readings, PRs 29 and 31)
+        assert temp < 0.95 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
+        assert "KERNELS 5" in flow_step_compiled
+
+    def test_flow_step_makes_no_copy_of_a_layers_cache(
+            self, flow_step_compiled):
+        """Reading 64 slots and appending 4,096 rows makes no array of a
+        layer's size. The compiler stores a layer positions-minor
+        (``bf16[512,1024,576]{1,2,0}``: 576 is no multiple of 128 lanes)
+        and, while the step gathered the flows' slots, first sliced the
+        **whole** layer into three ranges of positions (ten operations,
+        fifteen arrays of ``[512, 256..384, 576]``: 604 MB read and
+        written a layer whatever the call touched; PR 31). Now the
+        optimised program names a layer's cache only to pass it on: as a
+        parameter, through the append's loop (a ``dynamic-update-slice``
+        of one window in place a trip), as the kernel's operand (the
+        transpose to ``[slots, entry, positions]`` is a ``bitcast`` of
+        that layout) and in the result."""
+        seen = [line.split()[1:] for line in flow_step_compiled.splitlines()
+                if line.startswith("WHOLE")]
+        assert len(seen) >= 5                   # a parameter a layer
+        passes_on = {"parameter", "get-tuple-element", "bitcast", "tuple",
+                     "while", "dynamic-update-slice", "custom-call"}
+        made = [s for s in seen if s[0] not in passes_on]
+        assert not made, made[:10]
+        # the kernel's view of each layer is free
+        assert sum(s[0] == "bitcast" and "[512,576,1024]" in s[2]
+                   for s in seen) >= 5
 
     def test_flow_attention_compiles_for_v5e_at_every_kind_of_layout(self):
         """The fused attention alone at the published entry (512 + 64, 64
-        heads, 1,024 positions) in the layouts ``FlowTable`` makes: the
+        heads, 1,024 positions), each flow's slot taken from a layer's
+        cache of 512 by its number, in the layouts ``FlowTable`` makes: the
         cell's 64 x 64 (tiles of 16 events), chunks of 1 event (64 query
         rows a flow), one long flow, and calls of a few flows."""
         code = (
@@ -229,8 +290,8 @@ class TestScorerSelection:
             "                               scale=0.135))\n"
             "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 512)):\n"
             "    text = fn.lower(bf(F, T, 64, 512), bf(F, T, 64, 64),\n"
-            "                    bf(F, 1024, 576), S(jnp.int32, F)\n"
-            "                    ).compile().as_text()\n"
+            "                    bf(512, 1024, 576), S(jnp.int32, F),\n"
+            "                    S(jnp.int32, F)).compile().as_text()\n"
             "    assert 'tpu_custom_call' in text, (F, T)\n"
             "    print('LAYOUT', F, T)\n"
             "print('COMPILED', topo.devices[0].device_kind)\n")

@@ -1,7 +1,11 @@
 """The fused latent attention (``ops/flow_attention.py``), interpreted on
 the CPU, against XLA's ``attend_xla`` on the same inputs: alone, over
-layouts, widths and flows of every length; and inside ``flow_step``, where
-padding rows and empty flows meet it."""
+layouts, widths and flows of every length, each flow's slot taken from
+the layer's cache by its number; inside ``flow_step``, where padding rows
+and empty flows meet it; and the append that goes before it
+(``models.latent_moe.append_chunk``), against the formulation it replaced
+(gather the slots, ``where`` the chunk in, scatter them back whole), which
+is kept here as the oracle."""
 
 import functools
 
@@ -34,6 +38,18 @@ def flows_at(F: int, T: int, P: int) -> np.ndarray:
     return p0
 
 
+def slots_for(F: int) -> tuple:
+    """``(slots of the cache, each flow's slot)``: a cache of twice the
+    flows and one, the flows in no order and never two adjacent ones
+    running (odd slots, permuted), the last flow of a call of several a
+    padding flow (its slot out of range: read clipped)."""
+    S = 2 * F + 1
+    slot = (1 + 2 * np.random.default_rng(F).permutation(F)).astype(np.int32)
+    if F > 1:
+        slot[-1] = S
+    return S, slot
+
+
 def by_hand(p0, T: int, H: int, P: int) -> tuple:
     """``(blocks attended over, blocks of the slots whole)``, counted tile
     by tile: a tile of ``events`` events at ``first`` sees positions
@@ -54,14 +70,15 @@ def test_the_kernel_is_xlas_attention(layout, width):
     k = jax.random.split(jax.random.key(F * T + P), 3)
     qa = jax.random.normal(k[0], (F, T, H, rank), jnp.bfloat16)
     qr = jax.random.normal(k[1], (F, T, H, rope), jnp.bfloat16)
-    kv = jax.random.normal(k[2], (F, P, rank + rope), jnp.bfloat16)
+    S, slot = slots_for(F)
+    cache = jax.random.normal(k[2], (S, P, rank + rope), jnp.bfloat16)
     p0 = flows_at(F, T, P)
     want, one, whole = jax.jit(functools.partial(
-        lm.attend_xla, scale=SCALE))(qa, qr, kv, p0)
+        lm.attend_xla, scale=SCALE))(qa, qr, cache, slot, p0)
     assert np.asarray(one).tolist() == [1] * F and whole == 1
     got, seen, whole = jax.jit(functools.partial(
         fa.latent_attention_fused, scale=SCALE, interpret=True))(
-            qa, qr, kv, p0)
+            qa, qr, cache, slot, p0)
     assert got.shape == (F, T, H, rank) and got.dtype == jnp.bfloat16
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     assert np.isfinite(got).all()
@@ -180,3 +197,150 @@ def test_in_the_step_padding_and_empty_flows_come_out_finite(layout):
     assert int(cb["attn.kv_blocks_whole"]) == TINY.layers * whole
     assert (int(ca["attn.kv_blocks"]) == int(ca["attn.kv_blocks_whole"])
             == TINY.layers * F)
+
+
+# -- the append that goes before the attention ---------------------------------
+
+def append_by_gather(cache, entry, start_entry, slot, p0, count, begins):
+    """The formulation ``append_chunk`` replaced, as PRs 28-30 had it in
+    ``_attention``: every flow's slot gathered whole, the chunk set into
+    it by a ``where`` over all its positions, the slots scattered back
+    whole. Returns the cache and the rows it wrote (whole slots)."""
+    S, P, _ = cache.shape
+    T = entry.shape[1]
+    kv = cache[jnp.minimum(slot, S - 1)]
+    t = jnp.arange(P)[None] - p0[:, None]
+    mine = (t >= 0) & (t < count[:, None])
+    kv = jnp.where(mine[..., None], jnp.take_along_axis(
+        entry, jnp.clip(t, 0, T - 1)[..., None], 1), kv)
+    kv = kv.at[:, 0].set(jnp.where(begins[:, None], start_entry[None],
+                                   kv[:, 0]))
+    return cache.at[slot].set(kv, mode="drop"), (slot < S).sum() * P
+
+
+# (positions, T, [(slot, p0, count) a flow]); 8 slots, so slot 8 is a flow
+# of the layout that brings nothing
+APPENDS = {
+    "counts-0-1-T": (64, 8, [(8, 1, 0), (2, 9, 1), (5, 30, 8)]),
+    "a-flow-begins": (64, 8, [(4, 1, 5), (1, 17, 8)]),
+    "a-padding-flow-with-rows": (64, 8, [(8, 12, 3), (3, 12, 3)]),
+    "ends-at-the-last-position": (64, 8, [(6, 56, 8), (0, 61, 3),
+                                          (2, 63, 1)]),
+    "non-adjacent-slots": (64, 8, [(1, 20, 8), (6, 33, 7)]),
+    "the-start-tokens-own-call": (64, 1, [(0, 0, 1)]),
+    "a-chunk-as-long-as-the-slot": (16, 16, [(3, 1, 15), (7, 4, 12)]),
+    "four-blocks-of-positions": (512, 32, [(0, 1, 32), (7, 120, 17),
+                                           (3, 480, 32), (8, 1, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(APPENDS))
+def test_the_append_touches_the_chunks_rows_and_no_other(case):
+    P, T, flows = APPENDS[case]
+    S, E, F = 8, 24, len(flows)
+    slot, p0, count = (np.array(c, np.int32) for c in zip(*flows))
+    begins = (count > 0) & (p0 == 1)
+    k = jax.random.split(jax.random.key(P + T + F), 3)
+    before = jax.random.normal(k[0], (S, P, E), jnp.bfloat16)
+    entry = jax.random.normal(k[1], (F, T, E), jnp.bfloat16)
+    start = jax.random.normal(k[2], (E,), jnp.bfloat16)
+    args = (before, entry, start, slot, p0, count, begins)
+    got, written = jax.jit(lm.append_chunk)(*args)
+    want, whole = jax.jit(append_by_gather)(*args)
+    got, want, was = (np.asarray(a, np.float32) for a in (got, want, before))
+    np.testing.assert_array_equal(got, want)
+    # by hand: the rows of the chunk and the start token's, nothing else
+    mine = np.zeros((S, P), bool)
+    for f, (sl, at, n) in enumerate(flows):
+        if sl < S:
+            mine[sl, at:at + n] = True
+            mine[sl, 0] |= bool(begins[f])
+            np.testing.assert_array_equal(
+                got[sl, at:at + n], np.asarray(entry, np.float32)[f, :n])
+            if begins[f]:
+                np.testing.assert_array_equal(
+                    got[sl, 0], np.asarray(start, np.float32))
+    np.testing.assert_array_equal(got[~mine], was[~mine])
+    changed = int((got != was).any(-1).sum())
+    live = int((slot < S).sum())
+    assert changed <= mine.sum() <= int(written) == live * min(T + 1, P)
+    assert int(whole) == live * P
+
+
+def test_three_calls_append_to_one_slot_as_the_gathered_slots_did(
+        monkeypatch):
+    """Three calls in a row bring the same flows their next chunks (one of
+    them begins in the first call; one call has a flow of fewer events and
+    a flow that brings nothing). On XLA's attention the step with the
+    append in place gives the scores and the cache of the step with the
+    gathered slots **bit for bit**; the kernel's step agrees to rounding;
+    after each call every row outside the chunks is what it was; and the
+    step counts the rows it wrote."""
+    F, T, P, S = 4, 8, TINY.positions, TINY.slots
+    params = lm.init(jax.random.key(5), TINY)
+    rng = np.random.default_rng(11)
+
+    def build(attend):
+        return jax.jit(functools.partial(lm.flow_step, cfg=TINY, F=F, T=T,
+                                         attend=attend))
+
+    def run(step, state, chunks):
+        rows = device_rows(chunks, T)
+        staged = np.zeros((F * T, 3), np.int32)
+        staged[:len(rows)] = rows
+        return step(params, state, jnp.asarray(staged), np.int32(len(rows)))
+
+    place = build(lm.attend_xla)
+    fused = build(functools.partial(fa.latent_attention_fused,
+                                    interpret=True))
+    gathered = build(lm.attend_xla)
+    with monkeypatch.context() as oracle:
+        # traced here, once, with the oracle's append: the start token's
+        # own call has the shapes of every call below
+        oracle.setattr(lm, "append_chunk", append_by_gather)
+        state = lm.with_start(
+            lambda s, r, n: gathered(params, s, jnp.asarray(r), np.int32(n)),
+            TINY, lm.init_state(TINY), jnp.zeros((F * T, 3), jnp.int32))
+    states = {"place": state, "gathered": state, "fused": state}
+    at = {0: 1, 1: 120, 2: P - 3 * T}       # flow f's next position
+    slot_of = {0: 9, 1: 2, 2: 14}
+    for call in range(3):
+        n = {0: T, 1: T if call != 1 else 3, 2: T}
+        chunks = {f: (slot_of[f], at[f], rng.integers(1, 128, n[f]))
+                  for f in at}
+        (sg, stg, cg), (sp, stp, cp), (sf, stf, cf) = (
+            run(step, states[name], chunks) for name, step in (
+                ("gathered", gathered), ("place", place), ("fused", fused)))
+        np.testing.assert_array_equal(np.asarray(sp), np.asarray(sg))
+        for a, b in zip(jax.tree_util.tree_leaves(stp),
+                        jax.tree_util.tree_leaves(stg)):
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+        live = sum(n.values())
+        gap = np.abs(np.asarray(sf) - np.asarray(sp))[:live]
+        assert np.median(gap) < 6e-4 and gap.max() < 5e-2, gap.max()
+        # rows outside the chunks: bit for bit what they were
+        mine = np.zeros((S, P), bool)
+        for f in at:
+            mine[slot_of[f], at[f]:at[f] + n[f]] = True
+            mine[slot_of[f], 0] |= at[f] == 1
+        for name, st in (("place", stp), ("fused", stf)):
+            for new, old in zip(st[0], states[name][0]):
+                new, old = (np.asarray(a, np.float32) for a in (new, old))
+                np.testing.assert_array_equal(new[~mine], old[~mine])
+                assert (new[mine] != old[mine]).any()
+        # the counts: 3 live flows (the layout's fourth brings nothing)
+        changed = sum(int((np.asarray(new, np.float32) != np.asarray(
+            old, np.float32)).any(-1).sum())
+            for new, old in zip(stp[0], states["place"][0]))
+        wrote = TINY.layers * 3 * (T + 1)
+        assert changed <= TINY.layers * int(mine.sum()) <= wrote
+        for c in (cp, cf):
+            assert int(c["cache.rows_written"]) == wrote
+            assert int(c["cache.rows_whole"]) == TINY.layers * 3 * P
+        assert (int(cg["cache.rows_written"]) == int(cg["cache.rows_whole"])
+                == TINY.layers * 3 * P)
+        states = {"place": stp, "gathered": stg, "fused": stf}
+        at = {f: at[f] + n[f] for f in at}
+    # the third flow's last chunk ended at the slot's last position
+    assert at[2] == P == int(stp[1][14])

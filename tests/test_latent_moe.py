@@ -250,6 +250,50 @@ class TestState:
             np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
         assert int(ca["cache.positions"]) == int(cb["cache.positions"]) == 18
 
+    def test_a_call_writes_its_chunks_rows_and_counts_them(self, seqs,
+                                                            attention):
+        """Three calls bring two flows their next chunks (the second call
+        one flow alone). Each call leaves every cache row outside its
+        chunks (and position 0 of a flow that begins) bit for bit as it
+        was, the slots are taken in place whichever attention runs, and
+        the call's record counts the rows written: a window of ``T + 1``
+        positions a live flow a layer, of the slot's 64."""
+        flows = {11: seqs[11][:24], 33: seqs[33][:16]}
+        calls = [{11: (0, 8), 33: (0, 8)}, {11: (8, 16)},
+                 {11: (16, 24), 33: (8, 16)}]
+
+        async def go():
+            s = scorer()
+            try:
+                t0, caches = time.monotonic(), []
+                for call in calls:
+                    await s.score(rows_of(
+                        {k: flows[k][a:b] for k, (a, b) in call.items()}))
+                    caches.append(np.stack(
+                        [np.asarray(c, np.float32) for c in s._state[0]]))
+                return t0, caches, dict(s._table.slot_of)
+            finally:
+                s.close()
+        t0, caches, slot_of = run(go())
+        records = [c for c in phases.records()
+                   if c.kind == phases.SCORE and c.t0 >= t0]
+        assert len(records) == len(calls)
+        before = np.zeros_like(caches[0])
+        for call, after, rec in zip(calls, caches, records):
+            mine = np.zeros(after.shape[1:3], bool)
+            for k, (a, b) in call.items():
+                mine[slot_of[k], 1 + a:1 + b] = True
+                mine[slot_of[k], 0] |= a == 0
+            np.testing.assert_array_equal(after[:, ~mine], before[:, ~mine])
+            changed = int((after != before).any(-1).sum())
+            assert 0 < changed <= CFG.layers * mine.sum()
+            # a layout of 8 events: windows of 9 positions
+            wrote = CFG.layers * len(call) * 9
+            assert changed <= wrote == rec.counts["cache.rows_written"]
+            assert rec.counts["cache.rows_whole"] == (
+                CFG.layers * len(call) * CFG.positions)
+            before = after
+
     def test_two_calls_in_flight_apply_in_call_order(self, seqs):
         calls = [rows_of({k: v[a:a + 6] for k, v in seqs.items()})
                  for a in range(0, 24, 6)]

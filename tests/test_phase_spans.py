@@ -124,7 +124,11 @@ class TestScorePath:
                 "flow.wraps": 0, "flow.resident": 2,
                 "cache.positions": 7 if i == 0 else 12,
                 # XLA's attention: 3 layers x 2 flows, each slot whole
-                "attn.kv_blocks": 6, "attn.kv_blocks_whole": 6}
+                "attn.kv_blocks": 6, "attn.kv_blocks_whole": 6,
+                # the append: a window of T + 1 = 5 of a slot's 64
+                # positions a flow a layer
+                "cache.rows_written": 3 * 2 * 5,
+                "cache.rows_whole": 3 * 2 * 64}
         assert timing["bytes"] == 8 * 3 * 4 + 8 * 4
         assert state["flow"]["layouts"] == {"2x4": 2}
         assert np.shape(state["flow"]["expert_tokens"]) == (2, 4)
@@ -466,7 +470,7 @@ def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
     e2e = {m["name"] for m in manifest["end_to_end"]}
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == [flow_cell]]
-    assert len(mine) == 16
+    assert len(mine) == 17
     assert not [m for m in manifest["per_layer"] if m not in mine
                 and flow_cell in m.get("workloads", [])]
     with open(os.path.join(REPO, "linkerd_tpu", "telemetry",
