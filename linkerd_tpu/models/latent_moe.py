@@ -1,5 +1,12 @@
 """A flow model: latent attention over a per-flow cache, and a sigmoid-routed
-mixture of experts beside a shared one (the DeepSeek-V3 family's block).
+mixture of experts beside a shared one (the DeepSeek-V3 family's block);
+and **the step every flow model runs** (``flow_step``: the ``[F, T]``
+layout, the layers' loop, the routed experts, the head and the score
+mapping), which takes a model's layers from its configuration: each
+layer's *operator* (``cfg.operator(l)``, an ``Operator``: how it is
+applied, and what state of a flow it keeps) and its tensors
+(``cfg.tensors()``). This model's one operator is the latent attention
+below; ``models/lfm2_moe.py`` brings a second model's two.
 
 An *event* is one token: an id in ``[1, vocab_slice)``; id 0 is the start
 token the program writes at position 0 of every flow. A *flow* is the
@@ -74,7 +81,7 @@ from __future__ import annotations
 import math
 import zlib
 from dataclasses import dataclass
-from typing import Any, Dict, Tuple
+from typing import Any, Callable, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -113,17 +120,21 @@ class LatentMoEConfig:
     slots: int = 512
     positions: int = 1024
     expert_tile: int = 128
+    route_eps: float = 0.0      # added to the selected scores' sum
 
     def __post_init__(self):
-        lo, hi = self.experts_held
-        if not 0 <= lo < hi <= self.n_routed_experts:
-            raise ValueError(f"experts_held {self.experts_held} outside "
-                             f"0..{self.n_routed_experts}")
+        check_held(self)
 
     @property
     def entry_width(self) -> int:
         """Values the cache holds a position a layer."""
         return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def operator(self, l: int) -> "Operator":
+        return LATENT_ATTENTION
+
+    def tensors(self) -> Dict[str, tuple]:
+        return tensor_table(self)
 
     @classmethod
     def from_config(cls, cfg: dict) -> "LatentMoEConfig":
@@ -161,6 +172,48 @@ class LatentMoEConfig:
             layer_share=m["layer_share"], vocab_slice=cfg["vocab_size"],
             slots=m["slots"], positions=m["positions"],
             expert_tile=m.get("expert_tile", 128))
+
+
+def check_held(cfg) -> None:
+    lo, hi = cfg.experts_held
+    if not 0 <= lo < hi <= cfg.n_routed_experts:
+        raise ValueError(f"experts_held {cfg.experts_held} outside "
+                         f"0..{cfg.n_routed_experts}")
+
+
+class Call(NamedTuple):
+    """What a call brings every layer's operator: per flow of the layout
+    its ``slot`` (``slots`` where the flow brings nothing), the position
+    ``p0`` its chunk is appended at, the chunk's ``count`` events and
+    whether the flow ``begins`` here; ``pos [F, T]`` each event's
+    position; ``attend``: the attention over a slot the step was built
+    with."""
+    slot: Any
+    p0: Any
+    count: Any
+    begins: Any
+    pos: Any
+    attend: Callable
+
+
+class Operator(NamedTuple):
+    """One kind of a layer's operator (what stands before the
+    feed-forward), and the per-flow state it keeps. ``apply(lp, cfg, kept,
+    start, h, call) -> (y, kept, tally)``: ``h [F, T, hidden]`` the
+    residual stream (the operator norms it itself), ``kept`` this layer's
+    state, donated, ``start`` what the start token leaves of it (set
+    where a flow begins), ``tally [4]`` the blocks of positions attended
+    over, those of the slots whole, the cache rows written and the rows
+    of fixed-size state written. ``init(cfg)``: the state, empty;
+    ``start_of(kept)``: what slot 0 holds of the start token once the
+    call that makes the constants (``with_start``) has run; ``scope``: the
+    operator's name in a device scope; ``caches``: whether the state
+    grows by a row a position."""
+    apply: Callable
+    init: Callable
+    start_of: Callable
+    scope: str
+    caches: bool
 
 
 # -- weights from the seed ----------------------------------------------------
@@ -242,12 +295,12 @@ def name_tag(name: str) -> np.uint32:
     return np.uint32(zlib.crc32(name.encode()))
 
 
-def init(key, cfg: LatentMoEConfig) -> Params:
+def init(key, cfg) -> Params:
     """The parameters on the default device, tensor by tensor (each draw's
     float32 scratch is freed before the next)."""
     held = jnp.arange(*cfg.experts_held, dtype=jnp.uint32)
     params: Params = {"layers": [{} for _ in range(cfg.layers)]}
-    for name, (shape, std, mean, per_expert) in tensor_table(cfg).items():
+    for name, (shape, std, mean, per_expert) in cfg.tensors().items():
         w = _draw(key, name_tag(name), np.float32(std), np.float32(mean),
                   held if per_expert else None, shape=shape)
         parts = name.split(".")
@@ -258,23 +311,36 @@ def init(key, cfg: LatentMoEConfig) -> Params:
     return params
 
 
-def init_state(cfg: LatentMoEConfig):
-    """``(cache, length [slots], last_h [slots, hidden], start)``: the
-    latent cache, one array ``[slots, positions, entry]`` a layer, the
-    positions each slot holds, each slot's newest final hidden state (which
-    predicts the flow's next event), and what the start token leaves
-    behind: None until the first call has computed it (``with_start``)."""
-    return (tuple(jnp.zeros((cfg.slots, cfg.positions, cfg.entry_width),
-                            jnp.bfloat16) for _ in range(cfg.layers)),
+def init_state(cfg):
+    """``(kept, length [slots], last_h [slots, hidden], start)``: what
+    each layer's operator keeps of a flow (``Operator.init``: the latent
+    cache, one array ``[slots, positions, entry]`` a layer, in this model;
+    keys and values, or a short convolution's tail, in
+    ``models/lfm2_moe.py``), the positions each slot holds, each slot's
+    newest final hidden state (which predicts the flow's next event), and
+    what the start token leaves behind: None until the first call has
+    computed it (``with_start``)."""
+    return (tuple(cfg.operator(l).init(cfg) for l in range(cfg.layers)),
             jnp.zeros((cfg.slots,), jnp.int32),
             jnp.zeros((cfg.slots, cfg.hidden_size), jnp.bfloat16),
             None)
 
 
-def with_start(run, cfg: LatentMoEConfig, state, rows):
+def start_shapes(cfg) -> tuple:
+    """``ShapeDtypeStruct``s of the start token's constants: what each
+    layer keeps of it (``Operator.start_of``), and its final hidden
+    state."""
+    return (jax.eval_shape(lambda: tuple(
+        cfg.operator(l).start_of(cfg.operator(l).init(cfg))
+        for l in range(cfg.layers))),
+        jax.ShapeDtypeStruct((cfg.hidden_size,), jnp.bfloat16))
+
+
+def with_start(run, cfg, state, rows):
     """The state with the start token's constants, which every flow begins
-    from: its cache entry in every layer ``[layers, entry]`` and its final
-    hidden state ``[hidden]`` (which predicts a flow's first event). They
+    from: what it leaves in every layer's state (a cache entry
+    ``[entry]``; a convolution's tail) and its final hidden state
+    ``[hidden]`` (which predicts a flow's first event). They
     are constants of the parameters, as the cache is a function of them,
     and are made by the step's own program (``run(state, rows, n)``): one
     call whose single event is the start token itself, which ``flow_step``
@@ -283,12 +349,12 @@ def with_start(run, cfg: LatentMoEConfig, state, rows):
     placed where that call's are, so the program is lowered and read from
     the compile cache once: for arrays of the host it was lowered a second
     time (1.1 s of every set-up; my chip runs, PR 29)."""
-    cache, length, last_h, _ = state
+    kept, length, last_h, _ = state
     blank, first = jax.device_put(
-        ((np.zeros((cfg.layers, cfg.entry_width), jnp.bfloat16),
-          np.zeros((cfg.hidden_size,), jnp.bfloat16)),
+        (jax.tree_util.tree_map(lambda a: np.zeros(a.shape, a.dtype),
+                                start_shapes(cfg)),
          np.zeros(rows.shape, np.int32)), rows.sharding)
-    return run((cache, length, last_h, blank), first, 1)[1]
+    return run((kept, length, last_h, blank), first, 1)[1]
 
 
 # -- the block ----------------------------------------------------------------
@@ -376,7 +442,8 @@ def attend_xla(q_abs, q_rope, cache, slot, p0, scale: float):
             jnp.ones((F,), jnp.int32), 1)
 
 
-def append_chunk(cache, entry, start_entry, slot, p0, count, begins):
+def append_chunk(cache, entry, start_entry, slot, p0, count, begins,
+                 positions_last: bool = False):
     """The call's entries into the layer's ``cache [slots, positions,
     entry]`` where they belong, and no other row touched: flow ``f``'s
     ``entry[f, t]`` for ``t < count[f]`` at ``(slot[f], p0[f] + t)``, and
@@ -392,48 +459,67 @@ def append_chunk(cache, entry, start_entry, slot, p0, count, begins):
     layer; a Pallas append of whole lane tiles took 0.15 but writes 256
     positions a flow and is a second implementation; my chip runs, PR
     31). A ``slot`` out of range (a flow of the layout that brings
-    nothing) reads clipped and writes nothing. Returns the cache and the
-    rows written (``W`` a flow whose slot is in range)."""
+    nothing) reads clipped and writes nothing. ``positions_last``: the
+    cache lies ``[slots, entry, positions]`` (as a kernel reads it where
+    the compiler would not store it so of itself), and a window is
+    ``[entry, W]`` of it. Returns the cache and the rows written (``W`` a
+    flow whose slot is in range)."""
     S, P, E = cache.shape
+    if positions_last:
+        P, E = E, P
     F, T, _ = entry.shape
     W = min(T + 1, P)
     w0 = jnp.clip(p0 - 1, 0, P - W)                         # [F]
     pos = w0[:, None] + jnp.arange(W)[None]                 # [F, W]
     t = pos - p0[:, None]
-    window = jax.vmap(lambda s, w: jax.lax.dynamic_slice(
-        cache, (s, w, 0), (1, W, E))[0])(jnp.minimum(slot, S - 1), w0)
+
+    def read(s, w):
+        if positions_last:
+            return jax.lax.dynamic_slice(cache, (s, 0, w), (1, E, W))[0].T
+        return jax.lax.dynamic_slice(cache, (s, w, 0), (1, W, E))[0]
+
+    window = jax.vmap(read)(jnp.minimum(slot, S - 1), w0)   # [F, W, E]
     mine = (t >= 0) & (t < count[:, None])
     window = jnp.where(mine[..., None], jnp.take_along_axis(
         entry, jnp.clip(t, 0, T - 1)[..., None], 1), window)
     window = jnp.where((begins[:, None] & (pos == 0))[..., None],
                        start_entry[None, None], window)
     cache = jax.lax.scatter(
-        cache, jnp.stack([slot, w0], -1), window,
+        cache, jnp.stack([slot, w0], -1),
+        window.transpose(0, 2, 1) if positions_last else window,
         jax.lax.ScatterDimensionNumbers(
             update_window_dims=(1, 2), inserted_window_dims=(0,),
-            scatter_dims_to_operand_dims=(0, 1)),
+            scatter_dims_to_operand_dims=(0, 2 if positions_last else 1)),
         mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
     return cache, (slot < S).sum() * W
 
 
-def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
-               cos, sin, attend):
-    """``x [F, T, hidden]`` normed, ``cache [slots, positions, entry]``
+def angles(pos, inv_freq):
+    """``(cos, sin) [F, T, dim / 2]`` of the events' positions."""
+    angle = pos[..., None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    return jnp.cos(angle), jnp.sin(angle)
+
+
+def _attention(lp, cfg, cache, start_entry, h, call):
+    """The latent attention as an ``Operator.apply``: ``h [F, T, hidden]``
+    the residual stream, ``cache [slots, positions, entry]``
     this layer's, donated. The chunk's ``count`` entries are appended to
     the cache in place *before* the layer attends (``append_chunk``: at
     ``(slot, p0 + t)``, and the start token's at position 0 where the
     flow ``begins``; a ``slot`` out of range writes nothing), then the
-    chunk attends causally over its flow's slot by ``attend``
+    chunk attends causally over its flow's slot by ``call.attend``
     (``attend_xla``'s signature), which takes the appended cache whole
     and the slots' numbers: **no slot is gathered, merged and written
     back here** (PRs 28-30 did: 604 MB of a layer sliced and copied to
     read 75 MB and write 4.7). Returns the output, the cache, and
     ``[blocks of positions attended over, those of the slots whole, rows
-    of the cache written]``, summed over the flows."""
-    F, T, _ = x.shape
+    of the cache written, 0]``, summed over the flows."""
+    F, T, _ = h.shape
     H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
     rank, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
+    x = _rms(h, lp["attn_norm"], eps)
+    cos, sin = angles(call.pos, yarn_inv_freq(cfg))
     q = _mm(_rms(_mm(x, lp["wdq"]), lp["q_norm"], eps), lp["wuq"]).reshape(
         F, T, H, nope + rope)
     q_rope = _rope(q[..., nope:], cos[:, :, None], sin[:, :, None])
@@ -441,18 +527,25 @@ def _attention(lp, cfg, cache, x, slot, p0, count, begins, start_entry,
     entry = jnp.concatenate(
         [_rms(ckr[..., :rank], lp["kv_norm"], eps),
          _rope(ckr[..., rank:], cos, sin)], -1).astype(jnp.bfloat16)
-    cache, written = append_chunk(cache, entry, start_entry, slot, p0, count,
-                                  begins)
+    cache, written = append_chunk(cache, entry, start_entry, call.slot,
+                                  call.p0, call.count, call.begins)
     wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
     q_abs = jnp.einsum("fthd,chd->fthc", q[..., :nope].astype(jnp.bfloat16),
                        wukv[..., :nope], preferred_element_type=jnp.float32)
-    o, blocks, whole = attend(q_abs.astype(jnp.bfloat16),
-                              q_rope.astype(jnp.bfloat16), cache, slot,
-                              p0, softmax_scale(cfg))
+    o, blocks, whole = call.attend(
+        q_abs.astype(jnp.bfloat16), q_rope.astype(jnp.bfloat16), cache,
+        call.slot, call.p0, softmax_scale(cfg))
     o = jnp.einsum("fthc,chd->fthd", o, wukv[..., nope:],
                    preferred_element_type=jnp.float32)
     return (_mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache,
-            jnp.stack([blocks.sum(), F * whole, written]))
+            jnp.stack([blocks.sum(), F * whole, written, 0]))
+
+
+LATENT_ATTENTION = Operator(
+    apply=_attention,
+    init=lambda cfg: jnp.zeros((cfg.slots, cfg.positions, cfg.entry_width),
+                               jnp.bfloat16),
+    start_of=lambda cache: cache[0, 0], scope="attention", caches=True)
 
 
 def route(lp, cfg, x):
@@ -465,7 +558,8 @@ def route(lp, cfg, x):
     _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
                            cfg.num_experts_per_tok)
     sel = jnp.take_along_axis(s, idx, -1)
-    return idx, sel / sel.sum(-1, keepdims=True) * cfg.routed_scaling_factor
+    return idx, (sel / (sel.sum(-1, keepdims=True) + cfg.route_eps)
+                 * cfg.routed_scaling_factor)
 
 
 def routed_experts(lp, cfg, x, valid):
@@ -510,59 +604,88 @@ def routed_experts(lp, cfg, x, valid):
     return out[:N], cnt
 
 
-def _forward(params, cfg, cache, start_entries, tok, slot, p0, count,
-             begins, attend):
-    """``tok [F, T]``; per flow its ``slot``, the position ``p0`` its chunk
-    is appended at, the chunk's ``count`` events and whether the flow
-    ``begins`` here; ``cache``: one ``[slots, positions, entry]`` a layer;
-    ``start_entries [layers, entry]``: the start token's, set at position 0
-    of a flow that begins.
-    Returns the final normed hidden ``[F, T, hidden]`` float32, the cache
-    with the chunks appended, tokens per held expert ``[expert layers,
-    G]``, and the blocks of positions attention ran over, those of the
-    slots whole and the rows of the cache written ``[3]``, summed over
-    flows and layers."""
+def _forward(params, cfg, operators, kept, starts, tok, call):
+    """``tok [F, T]``; ``call``: the flows' slots, positions and counts;
+    ``kept``: each layer's state (``init_state``); ``starts``: the start
+    token's, a layer, set where a flow begins. A layer is its operator
+    (``operators[l]``: a kind of attention over a cache, or one with
+    state of a fixed size) and its feed-forward (dense where the layer
+    has no router; else the routed experts, beside a shared one where
+    the layer has one), both residual.
+    Returns the final normed hidden ``[F, T, hidden]`` float32, the
+    layers' state with the chunks applied, tokens per held expert
+    ``[expert layers, G]``, and the operators' tallies ``[4]``
+    (``Operator``), summed over flows and layers."""
     F, T = tok.shape
     h = params["embed"][tok].astype(jnp.float32)
-    pos = p0[:, None] + jnp.arange(T)[None]
-    valid = jnp.arange(T)[None] < count[:, None]
-    angle = pos[..., None].astype(jnp.float32) * jnp.asarray(
-        yarn_inv_freq(cfg))
-    cos, sin = jnp.cos(angle), jnp.sin(angle)
-    counts, cache, tally = [], list(cache), jnp.zeros((3,), jnp.int32)
-    for l, lp in enumerate(params["layers"]):
-        with jax.named_scope(f"layer{l}.attention"):
-            a, cache[l], layer_tally = _attention(
-                lp, cfg, cache[l], _rms(h, lp["attn_norm"], cfg.rms_norm_eps),
-                slot, p0, count, begins, start_entries[l], cos, sin, attend)
+    valid = jnp.arange(T)[None] < call.count[:, None]
+    counts, kept, tally = [], list(kept), jnp.zeros((4,), jnp.int32)
+    for l, (lp, op) in enumerate(zip(params["layers"], operators)):
+        with jax.named_scope(f"layer{l}.{op.scope}"):
+            a, kept[l], layer_tally = op.apply(lp, cfg, kept[l], starts[l],
+                                               h, call)
             h = h + a
             tally = tally + layer_tally
         with jax.named_scope(f"layer{l}.ffn"):
             x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
             if "router" in lp:
                 flat = x.reshape(F * T, -1)
-                routed, cnt = routed_experts(lp, cfg, flat,
-                                             valid.reshape(-1))
+                y, cnt = routed_experts(lp, cfg, flat, valid.reshape(-1))
                 counts.append(cnt)
-                y = (_swiglu(flat, lp["shared_gate"], lp["shared_up"],
-                             lp["shared_down"]) + routed).reshape(F, T, -1)
+                if "shared_gate" in lp:
+                    y = _swiglu(flat, lp["shared_gate"], lp["shared_up"],
+                                lp["shared_down"]) + y
+                y = y.reshape(F, T, -1)
             else:
                 y = _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
             h = h + y
     G = cfg.experts_held[1] - cfg.experts_held[0]
     counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
-    return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(cache),
+    return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(kept),
             counts, tally)
 
 
-def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
-              T: int, attend=attend_xla):
-    """One call. ``rows [B, 3]`` int32 ``(cell, address, id)``; rows at and
-    past ``n`` are padding; ``attend``: the attention over a slot
-    (``attend_xla``, or the kernel ``ops/flow_attention.best_attention``
-    gives for a TPU). Returns ``(scores [B] float32 in row order, state,
-    counts)``."""
-    cache, length, last_h, (start_entries, start_h) = state
+HEAD_LOGITS_BYTES = 384 * 2 ** 20   # a block of float32 logits, at most
+
+
+def event_scores(params, cfg, pred, tok):
+    """``pred [F, T, hidden]`` float32, the hidden state that predicts
+    each event, ``tok [F, T]`` the events -> their scores ``[F, T]``: ``1 -
+    exp(-nll / ln vocab)`` under the logits of ``pred``, through the head,
+    or the embedding where the two are tied (no ``head`` among the
+    parameters). Where the call's logits pass ``HEAD_LOGITS_BYTES`` (4,096
+    events over a vocabulary of 65,536 are 1 GiB of float32) they are
+    formed in blocks of rows, one after the other."""
+    N, vocab = tok.size, cfg.vocab_slice
+    rows = N
+    while rows * vocab * 4 > HEAD_LOGITS_BYTES and rows % 2 == 0:
+        rows //= 2
+
+    def block(args):
+        x, ids = args
+        logits = _mm(x, params["head"] if "head" in params
+                     else params["embed"].T)
+        nll = (jax.nn.logsumexp(logits, -1)
+               - jnp.take_along_axis(logits, ids[..., None], -1)[..., 0])
+        return 1.0 - jnp.exp(-nll / math.log(vocab))
+
+    if rows == N:
+        return block((pred, tok))
+    return jax.lax.map(block, (pred.reshape(N // rows, rows, -1),
+                               tok.reshape(N // rows, rows))
+                       ).reshape(tok.shape)
+
+
+def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
+              attend=attend_xla):
+    """One call, of this model or of any whose configuration gives its
+    layers' operators (``cfg.operator``, ``cfg.tensors``: here and
+    ``models/lfm2_moe.py``). ``rows [B, 3]`` int32 ``(cell, address,
+    id)``; rows at and past ``n`` are padding; ``attend``: the attention
+    over a slot that the model's attention layers call (``attend_xla``,
+    or the kernel ``ops/flow_attention.best_attention`` gives for a TPU).
+    Returns ``(scores [B] float32 in row order, state, counts)``."""
+    kept, length, last_h, (starts, start_h) = state
     S, P = cfg.slots, cfg.positions
     B = rows.shape[0]
     live = jnp.arange(B) < n
@@ -578,16 +701,15 @@ def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
     begins = flow & (p0 == 1)
     prev_h = jnp.where(begins[:, None], start_h[None],
                        last_h[jnp.minimum(slot, S - 1)])
-    h, cache, expert_tokens, tally = _forward(
-        params, cfg, cache, start_entries, tok, slot, p0, count, begins,
-        attend)
+    call = Call(slot, p0, count, begins, p0[:, None] + jnp.arange(T)[None],
+                attend)
+    operators = [cfg.operator(l) for l in range(cfg.layers)]
+    h, kept, expert_tokens, tally = _forward(params, cfg, operators, kept,
+                                             starts, tok, call)
     with jax.named_scope("head"):
         pred = jnp.concatenate(
             [prev_h[:, None].astype(jnp.float32), h[:, :-1]], 1)
-        logits = _mm(pred, params["head"])
-        nll = (jax.nn.logsumexp(logits, -1)
-               - jnp.take_along_axis(logits, tok[..., None], -1)[..., 0])
-        score = 1.0 - jnp.exp(-nll / math.log(cfg.vocab_slice))
+        score = event_scores(params, cfg, pred, tok)
     newest = jnp.take_along_axis(
         h, jnp.maximum(count - 1, 0)[:, None, None], 1)[:, 0]
     last_h = last_h.at[slot].set(newest.astype(jnp.bfloat16), mode="drop")
@@ -597,18 +719,22 @@ def flow_step(params, state, rows, n, *, cfg: LatentMoEConfig, F: int,
     # where it has attended over itself alone; what it left there is kept
     # and the slot is counted empty again
     making = (n == 1) & (rows[0, 2] == 0)
-    start_entries = jnp.where(making, jnp.stack([c[0, 0] for c in cache]),
-                              start_entries)
+    starts = tuple(jnp.where(making, op.start_of(k), s)
+                   for op, k, s in zip(operators, kept, starts))
     start_h = jnp.where(making, last_h[0], start_h)
     length = jnp.where(making, 0, length)
     scores = jnp.where(live, score.reshape(-1)[jnp.minimum(cell, F * T - 1)],
                        0.0)
+    M = cfg.expert_tile
     counts = {"moe.local_pairs": expert_tokens.sum(),
               "moe.max_expert_tokens": expert_tokens.max(initial=0),
+              "moe.tiles": ((expert_tokens + M - 1) // M).sum(),
               "cache.positions": length.sum(),
               "attn.kv_blocks": tally[0],
               "attn.kv_blocks_whole": tally[1],
               "cache.rows_written": tally[2],
-              "cache.rows_whole": flow.sum() * P * cfg.layers,
+              "cache.rows_whole": (flow.sum() * P
+                                   * sum(op.caches for op in operators)),
+              "conv.state_rows": tally[3],
               "expert_tokens": expert_tokens}
-    return scores, (cache, length, last_h, (start_entries, start_h)), counts
+    return scores, (kept, length, last_h, (starts, start_h)), counts

@@ -24,11 +24,14 @@ that lays a call's rows out for the step (``map`` -> a plan with ``rows``,
 ``keyed``: its calls apply in the order they were made, and ``describe``
 reports the table and the newest call's counts for ``device_state()``.
 
-Two instances: ``mlp36`` (the 36-column autoencoder + classifier: its
+Three instances: ``mlp36`` (the 36-column autoencoder + classifier: its
 state is the normalisation triple ``(mu, var, initialised)``, which a fit
 repoints and a score step only reads; trains online; sharded over a mesh)
-and ``latent_moe`` (latent attention over a per-flow cache, routed
-experts: keyed, frozen, single-device).
+and the flow models ``latent_moe`` (latent attention over a per-flow
+cache, routed experts beside a shared one) and ``lfm2_moe`` (short
+convolutions among grouped-query attention layers, so two kinds of
+per-flow state, routed experts alone): keyed, frozen, single-device, one
+step (``models/latent_moe.flow_step``) over either's layers.
 """
 
 from __future__ import annotations
@@ -99,26 +102,28 @@ def mlp36(recon_weight: float = 0.7) -> ModelSpec:
         make_step=make_step, score_path=score_path)
 
 
-def latent_moe(cfg=None) -> ModelSpec:
-    """The flow model (``models/latent_moe.py``): a row is int32 ``(stream
-    key, restart flag, event id)``, laid out by ``FlowTable``; the cache,
-    the flows' lengths and the start token's constants are the state,
-    donated to each step. The step is built with the attention its
-    platform gets (``ops/flow_attention.best_attention``: the fused
-    kernel on a TPU, XLA's elsewhere), and ``describe`` says which."""
+def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
+    """A flow model's spec: a row is int32 ``(stream key, restart flag,
+    event id)``, laid out by ``FlowTable``; what the layers keep of a
+    flow, the flows' lengths and the start token's constants are the
+    state, donated to each step. The step is ``models/latent_moe.
+    flow_step`` over the configuration's layers, built with the attention
+    its platform gets (``ops/flow_attention.best_attention``: the fused
+    kernel on a TPU, XLA's elsewhere; ``grouped``: over keys and values
+    in groups of heads, else over the latent), and ``describe`` says
+    which."""
     import jax
 
     from linkerd_tpu.models import latent_moe as lm
     from linkerd_tpu.telemetry.flowstate import FlowTable
 
-    cfg = cfg if cfg is not None else lm.LatentMoEConfig()
     built = {}      # what make_step chose, for describe
 
     def make_step(platform: str):
         # the kernel's module is imported where a step is built
         from linkerd_tpu.ops.flow_attention import (
             attention_kind, best_attention)
-        attend = best_attention(platform)
+        attend = best_attention(platform, grouped)
         built["attention"] = attention_kind(platform)
         # the state and the staged rows are the program's to reuse
         program = jax.jit(lm.flow_step,
@@ -148,14 +153,31 @@ def latent_moe(cfg=None) -> ModelSpec:
             "expert_tokens": None if tokens is None else tokens.tolist()}}
 
     return ModelSpec(
-        name="latent_moe", cfg=cfg, row_width=3, row_dtype=np.int32,
+        name=name, cfg=cfg, row_width=3, row_dtype=np.int32,
         trains=False, single_device=True,
         init=lambda key: lm.init(key, cfg),
         init_state=lambda: lm.init_state(cfg), make_step=make_step,
-        score_path=lambda platform: "latent_moe",
+        score_path=lambda platform: name,
         make_table=lambda: FlowTable(cfg.slots, cfg.positions,
                                      cfg.vocab_slice),
         describe=describe)
 
 
-SPECS = {"mlp36": mlp36, "latent_moe": latent_moe}
+def latent_moe(cfg=None) -> ModelSpec:
+    """The flow model of ``models/latent_moe.py``: latent attention over
+    one cache a layer, routed experts beside a shared one."""
+    from linkerd_tpu.models.latent_moe import LatentMoEConfig
+    return _flow_model("latent_moe",
+                       cfg if cfg is not None else LatentMoEConfig(), False)
+
+
+def lfm2_moe(cfg=None) -> ModelSpec:
+    """The flow model of ``models/lfm2_moe.py``: short convolutions among
+    grouped-query attention layers (two kinds of per-flow state), routed
+    experts with no shared one, the whole vocabulary."""
+    from linkerd_tpu.models.lfm2_moe import Lfm2MoEConfig
+    return _flow_model("lfm2_moe",
+                       cfg if cfg is not None else Lfm2MoEConfig(), True)
+
+
+SPECS = {"mlp36": mlp36, "latent_moe": latent_moe, "lfm2_moe": lfm2_moe}

@@ -1,4 +1,7 @@
-"""Fused latent attention over a flow's slot of the cache: a Pallas kernel.
+"""Fused attention over a flow's slot of the cache: one Pallas kernel, for
+the latent attention of ``models/latent_moe.py`` (told first, as it was
+built) and for the grouped-query attention of ``models/lfm2_moe.py`` (at the
+end).
 
 ``models/latent_moe._attention`` absorbs ``wukv`` into the query, so every
 head of a flow attends over the one latent ``[positions, rank]`` and the
@@ -51,6 +54,24 @@ scratch was deleted). Where a compiler stores the cache entry-minor the
 transpose is a copy of the layer: ``tests/test_chip_bringup.py`` compiles
 the cell's step for a described v5e and fails on any such copy.
 
+**Keys and values in groups of heads** (PR 32) are the same kernel body
+(``_kernel``) under another grid and other block specs (``_attend``): the
+grid gains an axis of ``G`` groups, a group's queries are scored against
+key rows of their own, in as many parts as the queries come in, and the
+values are either among the key rows or rows of their own. The latent
+attention is one group of all the heads, queries in two parts (``rank``,
+``rope``) against the ``rank + rope`` rows of the slot, values the first
+``rank`` of them. Grouped-query attention (``grouped_attention_fused``)
+is ``G`` key/value heads, each a grid cell with the ``H / G`` query heads
+that attend over it: the cache ``[slots, 2 x G x head, positions]`` is
+kept positions-last by the model (1,024 entry values a position are a
+multiple of 128 lanes, so the compiler would not store it so of itself),
+and the block specs take the head's ``head`` key rows (block ``g``) and
+``head`` value rows (block ``G + g``) of the flow's slot: 128 KB each at
+the published sizes, fetched once a cell. At a head of 64 the lanes of the
+queries and the output are half used and the first product contracts
+over 64: the kernel's pace there is its grid cells' fetches, not the MXU.
+
 ``best_attention`` selects by platform as ``ops/scoring.best_scorer``
 does: this kernel on ``tpu``, the XLA path elsewhere. There is no probe
 and no fallback: a kernel that Mosaic refuses on the chip is an error the
@@ -99,22 +120,33 @@ def _events_a_tile(T: int, H: int, P: int) -> int:
 POSITION_AXES = (((1,), (1,)), ((), ()))    # p [rows, pos] . c [rank, pos]
 
 
-def _kernel(slot_ref, p0_ref, qa_ref, qr_ref, kt_ref, o_ref, s_ref, m_ref,
-            l_ref, acc_ref, *, scale: float, heads: int):
-    del slot_ref    # the block specs' alone: which slot ``kt_ref`` holds
-    f, i = pl.program_id(0), pl.program_id(1)
-    (rows, rank), P = qa_ref.shape[1:], kt_ref.shape[2]
-    events, bk = rows // heads, m_ref.shape[1]
+def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
+            values_apart: bool):
+    """One flow, one group of heads, one tile of its query rows. ``refs``:
+    the queries in ``len(widths)`` parts, part ``j`` ``widths[j]`` wide and
+    scored against the key rows that follow those of the parts before it;
+    the keys ``[key rows, positions]``; the values ``[value rows,
+    positions]`` where they are ``values_apart`` (else they are the first
+    of the key rows); the output; the scratch. ``heads``: query rows an
+    event."""
+    del slot_ref    # the block specs' alone: which slot the keys are of
+    q_refs, refs = refs[:len(widths)], refs[len(widths):]
+    k_ref, v_ref = refs[0], refs[1 if values_apart else 0]
+    o_ref, s_ref, m_ref, l_ref, acc_ref = refs[-5:]
+    f, i = pl.program_id(0), pl.program_id(2)
+    rows, P = s_ref.shape
+    events, bk, vd = rows // heads, m_ref.shape[1], acc_ref.shape[1]
     first = p0_ref[f] + i * events      # position of the tile's first event
     last = blocks_seen(first, events, P)
-    qa, qr = qa_ref[0], qr_ref[0]
+    qs = [q_ref[0, 0] for q_ref in q_refs]
+    at_row = [sum(widths[:j]) for j in range(len(widths))]
 
     def score(j, masked):
         at = pl.multiple_of(j * bk, bk)
-        s = (jnp.dot(qa, kt_ref[0, :rank, pl.ds(at, bk)],
-                     preferred_element_type=jnp.float32)
-             + jnp.dot(qr, kt_ref[0, rank:, pl.ds(at, bk)],
-                       preferred_element_type=jnp.float32)) * scale
+        s = functools.reduce(jnp.add, (
+            jnp.dot(q, k_ref[0, a:a + w, pl.ds(at, bk)],
+                    preferred_element_type=jnp.float32)
+            for q, a, w in zip(qs, at_row, widths))) * scale
         if masked:
             pos = first + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0) // heads
@@ -140,14 +172,69 @@ def _kernel(slot_ref, p0_ref, qa_ref, qr_ref, kt_ref, o_ref, s_ref, m_ref,
         at = pl.multiple_of(j * bk, bk)
         p = jnp.exp(s_ref[:, pl.ds(at, bk)] - m_ref[...])
         l_ref[...] += p         # lane by lane: summed across once, below
-        ct = kt_ref[0, :rank, pl.ds(at, bk)]
+        ct = v_ref[0, :vd, pl.ds(at, bk)]
         acc_ref[...] += jax.lax.dot_general(
             p.astype(ct.dtype), ct, POSITION_AXES,
             preferred_element_type=jnp.float32)
 
     jax.lax.fori_loop(0, last, weigh, None)
-    o_ref[0] = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
-                ).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
+                   ).astype(o_ref.dtype)
+
+
+def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
+            interpret: bool, name: str):
+    """The kernel over ``parts``: the queries ``[F, G, T * heads, width]``
+    a part, ``G`` groups of heads each with keys and values of its own;
+    ``kt [slots, rows, P]``, the layer's cache, positions last: group
+    ``g``'s keys are its rows ``[g * kd, (g + 1) * kd)``, ``kd`` the
+    parts' widths together, and its values the ``vd`` rows of block
+    ``values_at + g`` (in blocks of ``vd`` rows), or, where ``values_at``
+    is None, the first ``vd`` of its key rows. Returns ``(o [F, G, T *
+    heads, vd]``, the blocks of positions attended over ``[F]``, those of
+    a slot whole)``."""
+    F, G, rows_all, _ = parts[0].shape
+    S, _, P = kt.shape
+    heads = rows_all // T
+    widths = tuple(q.shape[-1] for q in parts)
+    bk, events = kv_block(P), _events_a_tile(T, heads, P)
+    rows, tiles = events * heads, T // events
+    kernel = functools.partial(_kernel, scale=scale, heads=heads,
+                               widths=widths,
+                               values_apart=values_at is not None)
+    # the flow's slot where it lies, positions along the lanes: the same
+    # block for all of a flow's (and a group's) tiles, so it is fetched
+    # once
+    kv_specs = [pl.BlockSpec((1, sum(widths), P),
+                             lambda f, g, i, slot, p0: (slot[f], g, 0))]
+    if values_at is not None:
+        kv_specs.append(pl.BlockSpec(
+            (1, vd, P), lambda f, g, i, slot, p0: (slot[f], values_at + g, 0)))
+    o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(F, G, tiles),
+            in_specs=[pl.BlockSpec((1, 1, rows, w),
+                                   lambda f, g, i, slot, p0: (f, g, i, 0))
+                      for w in widths] + kv_specs,
+            out_specs=pl.BlockSpec((1, 1, rows, vd),
+                                   lambda f, g, i, slot, p0: (f, g, i, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, P), jnp.float32),
+                            pltpu.VMEM((rows, bk), jnp.float32),
+                            pltpu.VMEM((rows, bk), jnp.float32),
+                            pltpu.VMEM((rows, vd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((F, G, rows_all, vd), parts[0].dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(jnp.minimum(slot, S - 1).astype(jnp.int32), p0.astype(jnp.int32),
+      *parts, *[kt] * len(kv_specs))
+    attended = sum(blocks_seen(p0 + i * events, events, P)
+                   for i in range(tiles))
+    return o, attended, tiles * (P // bk)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -161,48 +248,42 @@ def latent_attention_fused(q_abs, q_rope, cache, slot, p0, scale: float,
     positions ``0 .. p0[f] + t``. Returns ``(o [F, T, H, rank]``
     bfloat16 ``= softmax(mask(q . kv^T * scale)) . kv[..., :rank]``, the
     blocks of positions attended over, summed over a flow's tiles of
-    query rows ``[F]``, and what the slot whole would have been)``.
-    Jitted, so that a step of several layers traces and lowers the kernel
-    once (0.1 s a layer of every set-up; my chip runs, PR 29)."""
+    query rows ``[F]``, and what the slot whole would have been)``: one
+    group of all the heads, the queries in two parts, the values among
+    the keys. Jitted, so that a step of several layers traces and lowers
+    the kernel once (0.1 s a layer of every set-up; my chip runs, PR
+    29)."""
     F, T, H, rank = q_abs.shape
-    rope, (S, P, _) = q_rope.shape[-1], cache.shape
-    bk, events = kv_block(P), _events_a_tile(T, H, P)
-    rows, tiles = events * H, T // events
-    kernel = functools.partial(_kernel, scale=scale, heads=H)
-    o = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(F, tiles),
-            in_specs=[
-                pl.BlockSpec((1, rows, rank),
-                             lambda f, i, slot, p0: (f, i, 0)),
-                pl.BlockSpec((1, rows, rope),
-                             lambda f, i, slot, p0: (f, i, 0)),
-                # the flow's slot where it lies, positions along the
-                # lanes: the same block for all of a flow's tiles, so it
-                # is fetched once a flow
-                pl.BlockSpec((1, rank + rope, P),
-                             lambda f, i, slot, p0: (slot[f], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, rows, rank),
-                                   lambda f, i, slot, p0: (f, i, 0)),
-            scratch_shapes=[pltpu.VMEM((rows, P), jnp.float32),
-                            pltpu.VMEM((rows, bk), jnp.float32),
-                            pltpu.VMEM((rows, bk), jnp.float32),
-                            pltpu.VMEM((rows, rank), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((F, T * H, rank), q_abs.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
-            vmem_limit_bytes=VMEM_LIMIT),
-        interpret=interpret,
-        name="latent_attention_fused",
-    )(jnp.minimum(slot, S - 1).astype(jnp.int32), p0.astype(jnp.int32),
-      q_abs.reshape(F, T * H, rank), q_rope.reshape(F, T * H, rope),
-      cache.transpose(0, 2, 1))
-    attended = sum(blocks_seen(p0 + i * events, events, P)
-                   for i in range(tiles))
-    return o.reshape(F, T, H, rank), attended, tiles * (P // bk)
+    o, attended, whole = _attend(
+        [q_abs.reshape(F, 1, T * H, rank), q_rope.reshape(F, 1, T * H, -1)],
+        cache.transpose(0, 2, 1), slot, p0, T=T, values_at=None, vd=rank,
+        scale=scale, interpret=interpret, name="latent_attention_fused")
+    return o.reshape(F, T, H, rank), attended, whole
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def grouped_attention_fused(q, cache, slot, p0, scale: float,
+                            interpret: bool = False):
+    """Grouped-query attention by the same kernel: ``q [F, T, H, head]``
+    bfloat16; ``cache [slots, 2 x G x head, P]`` bfloat16, the layer's,
+    whole and **positions last** (``models/lfm2_moe.py`` keeps it so),
+    a position's keys of ``G`` key/value heads and then its values. A
+    grid cell is one flow, one key/value head and a tile of the ``H / G``
+    query heads' rows that attend over it; the block specs take that
+    head's ``head`` key rows and ``head`` value rows from the flow's slot.
+    Returns what ``models.lfm2_moe.attend_grouped_xla`` returns: ``(o [F,
+    T, H, head]``, the blocks attended over ``[F]``, those of a slot
+    whole)``."""
+    F, T, H, hd = q.shape
+    G = cache.shape[1] // (2 * hd)
+    R = H // G
+    o, attended, whole = _attend(
+        [q.reshape(F, T, G, R, hd).transpose(0, 2, 1, 3, 4).reshape(
+            F, G, T * R, hd)],
+        cache, slot, p0, T=T, values_at=G, vd=hd, scale=scale,
+        interpret=interpret, name="grouped_attention_fused")
+    return (o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(
+        F, T, H, hd), attended, whole)
 
 
 def attention_kind(platform: str) -> str:
@@ -211,11 +292,16 @@ def attention_kind(platform: str) -> str:
     return "fused_pallas" if platform == "tpu" else "xla"
 
 
-def best_attention(platform: str):
+def best_attention(platform: str, grouped: bool = False):
     """The flow step's ``attend`` for parameters living on ``platform``:
     the fused kernel on ``tpu``, the XLA path elsewhere (an interpreted
-    kernel is far too slow to serve)."""
+    kernel is far too slow to serve); ``grouped``: for grouped-query
+    attention over keys and values (``models/lfm2_moe.py``), else for the
+    latent attention (``models/latent_moe.py``)."""
     if attention_kind(platform) == "fused_pallas":
-        return latent_attention_fused
+        return grouped_attention_fused if grouped else latent_attention_fused
+    if grouped:
+        from linkerd_tpu.models.lfm2_moe import attend_grouped_xla
+        return attend_grouped_xla
     from linkerd_tpu.models.latent_moe import attend_xla
     return attend_xla

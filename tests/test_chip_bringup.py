@@ -178,11 +178,11 @@ class TestScorerSelection:
             "    p = name.split('.')\n"
             "    if p[0] == 'layers': params['layers'][int(p[1])][p[2]] = a\n"
             "    else: params[name] = a\n"
-            "state = jax.tree_util.tree_map(\n"
-            "    lambda a: S(a.dtype, *a.shape),\n"
-            "    jax.eval_shape(lambda: lm.init_state(cfg)))[:3] + (\n"
-            "    (S(jnp.bfloat16, cfg.layers, cfg.entry_width),\n"
-            "     S(jnp.bfloat16, cfg.hidden_size)),)\n"
+            "place = lambda t: jax.tree_util.tree_map(\n"
+            "    lambda a: S(a.dtype, *a.shape), t)\n"
+            "state = place(jax.eval_shape(\n"
+            "    lambda: lm.init_state(cfg)))[:3] + (\n"
+            "    place(lm.start_shapes(cfg)),)\n"
             "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
             "               static_argnames=('cfg', 'F', 'T', 'attend'))\n"
             "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
@@ -292,6 +292,164 @@ class TestScorerSelection:
             "    text = fn.lower(bf(F, T, 64, 512), bf(F, T, 64, 64),\n"
             "                    bf(512, 1024, 576), S(jnp.int32, F),\n"
             "                    S(jnp.int32, F)).compile().as_text()\n"
+            "    assert 'tpu_custom_call' in text, (F, T)\n"
+            "    print('LAYOUT', F, T)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=600,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        assert proc.stdout.count("LAYOUT") == 5
+
+
+    @pytest.fixture(scope="class")
+    def lfm2_step_compiled(self):
+        """The same step over the second flow model's layers
+        (``models/lfm2_moe.py``) as the benchmark's cell
+        ``lfm2-24b-a2b.flows64x64-fullvocab`` runs it: the published widths
+        (hidden 2,048, 64 of 64 experts held, the whole vocabulary), 9
+        layers with two kinds of state (2 caches of keys and values ``[512,
+        1024, 1024]``, 7 tails ``[512, 2, 2048]``), 64 flows x 64 events,
+        the attention a TPU gets. The child prints what
+        ``flow_step_compiled`` prints, and every instruction that makes
+        an array of the tied embedding's size."""
+        code = (
+            "import json, re, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models import latent_moe as lm, lfm2_moe as lf\n"
+            "from linkerd_tpu.ops.flow_attention import best_attention\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "with open('chipbench/configs/lfm2-24b-a2b.json') as f:\n"
+            "    cfg = lf.Lfm2MoEConfig.from_config(json.load(f))\n"
+            "assert cfg == lf.Lfm2MoEConfig()\n"
+            "held = cfg.experts_held[1] - cfg.experts_held[0]\n"
+            "params = {'layers': [{} for _ in range(cfg.layers)]}\n"
+            "for name, (shape, _, _, each) in cfg.tensors().items():\n"
+            "    a = S(jnp.bfloat16, *((held,) if each else ()), *shape)\n"
+            "    p = name.split('.')\n"
+            "    if p[0] == 'layers': params['layers'][int(p[1])][p[2]] = a\n"
+            "    else: params[name] = a\n"
+            "place = lambda t: jax.tree_util.tree_map(\n"
+            "    lambda a: S(a.dtype, *a.shape), t)\n"
+            "state = place(jax.eval_shape(\n"
+            "    lambda: lm.init_state(cfg)))[:3] + (\n"
+            "    place(lm.start_shapes(cfg)),)\n"
+            "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
+            "               static_argnames=('cfg', 'F', 'T', 'attend'))\n"
+            "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
+            "               S(jnp.int32), cfg=cfg, F=64, T=64,\n"
+            "               attend=best_attention('tpu', True)).compile()\n"
+            "m = c.memory_analysis()\n"
+            "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
+            "      m.alias_size_in_bytes)\n"
+            "text = c.as_text()\n"
+            "print('KERNELS', text.count(\n"
+            "    'custom_call_target=\"tpu_custom_call\"'))\n"
+            "S_, P, E = cfg.slots, cfg.positions, cfg.entry_width\n"
+            "V = cfg.vocab_slice\n"
+            "whole = re.compile(rf'bf16\\[{S_},(\\d+),{P}\\]|'\n"
+            "                   rf'bf16\\[{S_},{E},(\\d+)\\]|'\n"
+            "                   rf'(?:bf16|f32)\\[{V},(\\d+)\\]|'\n"
+            "                   rf'(?:bf16|f32)\\[4096,({V})\\]')\n"
+            "for line in text.splitlines():\n"
+            "    name, eq, rest = line.strip().partition(' = ')\n"
+            "    if not eq or name.startswith('//'): continue\n"
+            "    if rest.startswith('('):\n"
+            "        depth = 0\n"
+            "        for i, ch in enumerate(rest):\n"
+            "            depth += (ch == '(') - (ch == ')')\n"
+            "            if depth == 0: break\n"
+            "        typ, rest = rest[:i + 1], rest[i + 2:]\n"
+            "    else:\n"
+            "        typ, _, rest = rest.partition(' ')\n"
+            "    if any(int(next(x for x in g if x)) >= 128\n"
+            "           for g in whole.findall(typ)):\n"
+            "        op = rest.split('(', 1)[0]\n"
+            "        if op == 'fusion' and 'calls=%bitcast_fusion' in rest:\n"
+            "            op = 'bitcast'     # a fusion of a bitcast alone\n"
+            "        print('WHOLE', op, name, typ[:80])\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=900,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        return proc.stdout
+
+    def test_lfm2_step_compiles_for_v5e_at_the_published_widths(
+            self, lfm2_step_compiled):
+        """It fits one v5e (15.75 GiB) with all 9 layers' weights and both
+        kinds of state as arguments, the state is updated in place
+        (aliased), the attention is the fused kernel, one call an
+        attention layer, and the head's logits come in blocks."""
+        args, temp, alias = (int(v) for v in next(
+            line for line in lfm2_step_compiled.splitlines()
+            if line.startswith("BYTES")).split()[1:])
+        # weights 10.36 GB; keys and values 2 x 1.07 GB, tails 7 x 2 MB
+        assert 12.4e9 < args < 12.7e9 and alias > 2.17e9
+        # the float32 logits of 4,096 events over 65,536 ids would be 1
+        # GiB at once: 0.36 GiB of temporaries in all (my compile-only
+        # reading, PR 32)
+        assert temp < 0.6 * 2 ** 30 and args + temp < 14.5 * 2 ** 30
+        assert "KERNELS 2" in lfm2_step_compiled
+
+    def test_lfm2_step_makes_no_copy_of_a_layers_keys_and_values(
+            self, lfm2_step_compiled):
+        """The cache of an attention layer lies ``[slots, entry,
+        positions]`` as the model keeps it (no transpose on the way to the
+        kernel), and the optimised program names it only to pass it on; no
+        array of the embedding's size is made either (the tied head
+        contracts with the embedding as it lies), nor the logits of all
+        4,096 events at once."""
+        seen = [line.split()[1:] for line in lfm2_step_compiled.splitlines()
+                if line.startswith("WHOLE")]
+        assert len(seen) >= 3                   # two caches, the embedding
+        passes_on = {"parameter", "get-tuple-element", "bitcast", "tuple",
+                     "while", "dynamic-update-slice", "custom-call"}
+        made = [s for s in seen if s[0] not in passes_on]
+        assert not made, made[:10]
+
+    def test_grouped_attention_compiles_for_v5e_at_every_kind_of_layout(self):
+        """The same kernel over keys and values in groups of heads, alone
+        at the published sizes (32 query heads over 8 key/value heads of
+        64; a cache ``[512, 1024, 1024]``, positions last) in the layouts
+        ``FlowTable`` makes."""
+        code = (
+            "import functools, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.ops.flow_attention import (\n"
+            "    grouped_attention_fused)\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "bf = functools.partial(S, jnp.bfloat16)\n"
+            "fn = jax.jit(functools.partial(grouped_attention_fused,\n"
+            "                               scale=0.125))\n"
+            "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 512)):\n"
+            "    text = fn.lower(bf(F, T, 32, 64), bf(512, 1024, 1024),\n"
+            "                    S(jnp.int32, F), S(jnp.int32, F)\n"
+            "                    ).compile().as_text()\n"
             "    assert 'tpu_custom_call' in text, (F, T)\n"
             "    print('LAYOUT', F, T)\n"
             "print('COMPILED', topo.devices[0].device_kind)\n")

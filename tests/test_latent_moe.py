@@ -1,12 +1,17 @@
-"""The flow model (``models/latent_moe.py``) behind ``InProcessScorer``,
-against the benchmark's plain reference (``chipbench/reference/
-latent_moe.py``) on seeded weights, at a tiny preset on the CPU: hidden 64,
-4 heads, 16 experts top 2 of which 4 are held, a vocabulary of 128."""
+"""The flow models (``models/latent_moe.py``, ``models/lfm2_moe.py``) behind
+``InProcessScorer``, each against the benchmark's plain reference
+(``chipbench/reference/``) on seeded weights, at a tiny preset on the CPU:
+hidden 64, 4 heads, a vocabulary of 128; 16 experts, top 2 of which 4 are
+held (``latent_moe``) or top 4 with all held, 2 key/value heads and 3
+convolution layers beside 1 of attention (``lfm2_moe``). The cases that
+are the same for both run over both (the ``model`` fixture); what only
+the second model has is in ``tests/test_lfm2_moe.py``."""
 
 import asyncio
 import dataclasses
 import functools
 import time
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -14,9 +19,11 @@ import numpy as np
 import pytest
 
 from chipbench.reference import latent_moe as ref
+from chipbench.reference import lfm2_moe as ref_lfm2
 from linkerd_tpu.models import latent_moe as lm
+from linkerd_tpu.models import lfm2_moe as lf
 from linkerd_tpu.models.features import FEATURE_DIM, event_ids
-from linkerd_tpu.models.spec import SPECS, latent_moe, mlp36
+from linkerd_tpu.models.spec import SPECS, latent_moe, lfm2_moe, mlp36
 from linkerd_tpu.telemetry import phases
 from linkerd_tpu.telemetry.anomaly import (
     InProcessScorer, JaxAnomalyConfig, JaxAnomalyTelemeter,
@@ -45,19 +52,75 @@ TINY = {
               "layer_share": 4, "slots": 8, "positions": 64,
               "expert_tile": 8, "compute_dtype": "bfloat16"}}
 CFG = lm.LatentMoEConfig.from_config(TINY)
+TINY_LFM2 = {
+    "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "conv_L_cache": 3,
+    "conv_bias": False, "num_experts": 16, "num_experts_per_tok": 4,
+    "routed_scaling_factor": 1, "norm_eps": 1e-5, "norm_topk_prob": True,
+    "use_expert_bias": True,
+    "rope_parameters": {"rope_theta": 1000000, "rope_type": "default"},
+    "layer_types": ["conv", "full_attention", "conv", "conv"],
+    "num_hidden_layers": 4, "num_dense_layers": 1, "vocab_size": 128,
+    "model": {"in_dim": 3, "experts_held": [0, 16], "layer_share": 1,
+              "slots": 8, "positions": 64, "expert_tile": 8,
+              "compute_dtype": "bfloat16", "router_bias_std": 0.05}}
+CFG_LFM2 = lf.Lfm2MoEConfig.from_config(TINY_LFM2)
+
+
 # a score is off by the compute type's rounding, a few 1e-4 at this width;
 # a token whose second and third router scores lie within rounding takes
 # another expert in bfloat16 than in float32 and is off by up to 1e-2
 TYPICAL, WORST = 6e-4, 2e-2
+# the second model selects 4 of 16, whose 4th and 5th scores lie closer:
+# more tokens take another expert, and one that does is further off
+TYPICAL_LFM2, WORST_LFM2 = 1e-3, 6e-2
+
+
+class Model(NamedTuple):
+    """One flow model at its tiny preset: the configuration file's dict,
+    the program's configuration of it, its spec and its reference."""
+    name: str
+    tiny: dict
+    cfg: Any
+    spec: Callable
+    ref: Any
+    typical: float = TYPICAL    # a score's median gap, and its largest
+    worst: float = WORST
+
+    def kept_gap(self, state, full, b: int, n: int) -> list:
+        """Per layer, what the state keeps of the flow in slot ``b`` (``n``
+        positions long) less what the reference computes of them: the
+        cache's entries; of a convolution layer ``u`` of the last two
+        positions."""
+        gaps = []
+        for l in range(self.cfg.layers):
+            got = np.asarray(state[0][l][b], np.float32)
+            if self.name == "latent_moe":
+                gaps.append(got[:n] - full["entries"][l, b, :n])
+            elif self.tiny["layer_types"][l] == "conv":
+                gaps.append(got - full["kept"][l][b, n - 2:n])
+            else:       # the cache lies [entry, positions]
+                gaps.append(got.T[:n] - full["kept"][l][b, :n])
+        return gaps
+
+
+MODELS = {"latent_moe": Model("latent_moe", TINY, CFG, latent_moe, ref),
+          "lfm2_moe": Model("lfm2_moe", TINY_LFM2, CFG_LFM2, lfm2_moe,
+                            ref_lfm2, TYPICAL_LFM2, WORST_LFM2)}
 
 
 def run(coro):
     return asyncio.run(asyncio.wait_for(coro, 300))
 
 
-def scorer(cfg=CFG, seed=SEED):
-    return InProcessScorer(seed=seed, spec=latent_moe(cfg),
+def scorer(model=MODELS["latent_moe"], seed=SEED):
+    return InProcessScorer(seed=seed, spec=model.spec(model.cfg),
                            devices=jax.devices()[:1])
+
+
+@pytest.fixture(params=sorted(MODELS))
+def model(request):
+    return MODELS[request.param]
 
 
 def rows_of(flows: dict, restart=()) -> np.ndarray:
@@ -70,21 +133,22 @@ def rows_of(flows: dict, restart=()) -> np.ndarray:
     return np.array(out, np.int32).reshape(-1, 3)
 
 
-def reference_scores(seqs: dict, cfg=TINY) -> dict:
+def reference_scores(seqs: dict, model=MODELS["latent_moe"]) -> dict:
     """``{key: ids}`` -> ``{key: the reference's score of every event}``,
     each flow forward once from its start token."""
-    L = cfg["model"]["positions"]
+    L = model.tiny["model"]["positions"]
     tokens = np.zeros((len(seqs), L), np.int32)
     for b, ids in enumerate(seqs.values()):
         tokens[b, 1:1 + len(ids)] = ids
-    got = ref.forward(SEED, cfg, tokens, block=2)
+    got = model.ref.forward(SEED, model.tiny, tokens, block=2)
     return ({k: got["score"][b, 1:1 + len(v)]
              for b, (k, v) in enumerate(seqs.items())}, got)
 
 
-def close_to(got, want):
+def close_to(got, want, model=None):
+    model = model or MODELS["latent_moe"]
     gap = np.abs(np.asarray(got) - np.asarray(want))
-    assert np.median(gap) < TYPICAL and gap.max() < WORST, (
+    assert np.median(gap) < model.typical and gap.max() < model.worst, (
         np.median(gap), gap.max())
 
 
@@ -93,11 +157,12 @@ def attention(request, monkeypatch):
     """The step built on each attention: XLA's, which this platform gets,
     and the TPU's kernel (``ops/flow_attention.py``), interpreted."""
     if request.param == "fused":
-        from linkerd_tpu.ops import flow_attention
-        interpreted = functools.partial(
-            flow_attention.latent_attention_fused, interpret=True)
-        monkeypatch.setattr(flow_attention, "best_attention",
-                            lambda platform: interpreted)
+        from linkerd_tpu.ops import flow_attention as fa
+        monkeypatch.setattr(
+            fa, "best_attention",
+            lambda platform, grouped=False: functools.partial(
+                fa.grouped_attention_fused if grouped
+                else fa.latent_attention_fused, interpret=True))
     return request.param
 
 
@@ -109,36 +174,37 @@ def seqs():
 
 
 class TestAgainstTheReference:
-    def test_the_reference_is_plain(self):
-        with open(ref.__file__) as f:
+    def test_the_reference_is_plain(self, model):
+        with open(model.ref.__file__) as f:
             src = f.read()
         assert "linkerd_tpu" not in src.replace(
             "It imports\nnothing of the program", "")
         assert 'default_matmul_precision("highest")' in src
 
-    def test_one_full_forward(self, seqs, attention):
+    def test_one_full_forward(self, seqs, attention, model):
         async def go():
-            s = scorer()
+            s = scorer(model)
             try:
                 rows = rows_of(seqs)
                 return rows, await s.score(rows), s._state
             finally:
                 s.close()
         rows, got, state = run(go())
-        want, full = reference_scores(seqs)
+        want, full = reference_scores(seqs, model)
         assert got.shape == (len(rows),) and got.dtype == np.float32
         assert ((got >= 0) & (got <= 1)).all()
         for key in seqs:
-            close_to(got[rows[:, 0] == key], want[key])
-        # the cache holds what the reference computes of each position
-        cache = np.stack([np.asarray(c, np.float32) for c in state[0]])
+            close_to(got[rows[:, 0] == key], want[key], model)
+        # every layer's state holds what the reference computes of each
+        # position (a product of two gates, ``u``, is the wider)
         for b, (key, ids) in enumerate(seqs.items()):
-            n = 1 + len(ids)
-            gap = np.abs(cache[:, b, :n] - full["entries"][:, b, :n])
-            assert np.median(gap) < 4e-3 and gap.max() < 0.2
+            for gap in model.kept_gap(state, full, b, 1 + len(ids)):
+                gap = np.abs(gap)
+                assert np.median(gap) < 8e-3 and gap.max() < 0.2
         assert np.asarray(state[1])[:3].tolist() == [41, 26, 34]
 
-    def test_chunked_appends_through_the_cache(self, seqs, attention):
+    def test_chunked_appends_through_the_cache(self, seqs, attention,
+                                               model):
         """Chunks of unequal length, a restart in the middle: every call's
         scores are the reference's for one full forward of each flow since
         its restart."""
@@ -146,7 +212,7 @@ class TestAgainstTheReference:
         again = rng.integers(1, 128, 17)     # flow 22's second life
 
         async def go():
-            s = scorer()
+            s = scorer(model)
             at, got = {k: 0 for k in seqs}, {k: [] for k in seqs}
             got[220] = []
             try:
@@ -171,71 +237,86 @@ class TestAgainstTheReference:
             finally:
                 s.close()
         got, state = run(go())
-        want, _ = reference_scores({**seqs, 220: again})
+        want, _ = reference_scores({**seqs, 220: again}, model)
         for key in got:
-            close_to(got[key], want[key])
+            close_to(got[key], want[key], model)
         assert len(state["flow"]["layouts"]) > 1     # more than one shape
         assert state["flow"]["resident"] == 3
 
-    def test_the_shares_add_up_to_the_uncut_layer(self):
+    def test_the_shares_add_up_to_the_uncut_layer(self, model):
         """Guide section 4: the routed parts that all 4 shares give, with
-        the shared expert counted once, add up to what the uncut reference
-        gives for the whole layer; program and reference alike."""
-        layer = 1
-        x = jax.random.normal(jax.random.key(3), (24, CFG.hidden_size))
-        whole = ref.layer_weights(SEED, TINY, layer, held=(0, 16))
-        idx, wts, _ = ref.route(whole, TINY, ref._q(x, "bf16"))
-        uncut = (ref.shared_part(whole, x)
-                 + ref.routed_part(whole, TINY, x, idx, wts, 0))
-        ref_sum = ref.shared_part(whole, x)
-        got_sum = ref.shared_part(whole, x)
+        what every share computes alike (the shared expert, where the model
+        has one: ``lfm2_moe`` has none) counted once, add up to what the
+        uncut reference gives for the whole layer; program and reference
+        alike. ``lfm2_moe``: 64 experts in shares of 16."""
+        layer, r = 1, model.ref
+        E = 16 if model.name == "latent_moe" else 64
+        tiny = {**model.tiny, "num_experts": E}
+        x = jax.random.normal(jax.random.key(3), (24, model.cfg.hidden_size))
+        whole = r.layer_weights(SEED, tiny, layer, held=(0, E))
+        idx, wts, _ = r.route(whole, tiny, r._q(x, "bf16"))
+        alike = (r.swiglu(x, whole["shared_gate"], whole["shared_up"],
+                          whole["shared_down"], None)
+                 if "shared_gate" in whole else jnp.zeros_like(x))
+        uncut = alike + r.routed_part(whole, tiny, x, idx, wts, 0)
+        ref_sum = got_sum = alike
         tokens = 0
-        for lo in range(0, 16, 4):
-            part = ref.layer_weights(SEED, TINY, layer, held=(lo, lo + 4))
-            ref_sum = ref_sum + ref.routed_part(part, TINY, x, idx, wts, lo)
-            cfg = dataclasses.replace(CFG, experts_held=(lo, lo + 4))
+        for lo in range(0, E, E // 4):
+            held = (lo, lo + E // 4)
+            part = r.layer_weights(SEED, tiny, layer, held=held)
+            ref_sum = ref_sum + r.routed_part(part, tiny, x, idx, wts, lo)
+            cfg = dataclasses.replace(model.cfg, n_routed_experts=E,
+                                      experts_held=held)
             lp = lm.init(jax.random.key(SEED), cfg)["layers"][layer]
             out, cnt = lm.routed_experts(lp, cfg, x, jnp.ones(24, bool))
             got_sum = got_sum + out
             tokens += int(cnt.sum())
-        assert tokens == 24 * 2      # every pair computed on one share
+        # every pair computed on one share
+        assert tokens == 24 * model.cfg.num_experts_per_tok
         np.testing.assert_allclose(ref_sum, uncut, atol=1e-5)
         gap = np.abs(np.asarray(got_sum) - np.asarray(uncut))
         scale = np.abs(np.asarray(uncut)).mean()
         assert np.median(gap) < 0.01 * scale and gap.max() < 0.2 * scale
 
-    def test_routing_is_dropless_when_every_token_picks_one_expert(self):
-        params = lm.init(jax.random.key(SEED), CFG)
+    def test_routing_is_dropless_when_every_token_picks_one_expert(
+            self, model):
+        cfg = model.cfg
+        (lo, hi), k = cfg.experts_held, cfg.num_experts_per_tok
+        params = lm.init(jax.random.key(SEED), cfg)
         lp = dict(params["layers"][1])
-        # the bias puts experts 5 and 6 (both held) first for every token
+        # the bias puts experts 5, 6, .. (all held) first for every token
+        first = list(range(5, 5 + k))
         lp["router_bias"] = jnp.zeros(16, jnp.bfloat16).at[
-            jnp.array([5, 6])].set(10.0)
-        x = jax.random.normal(jax.random.key(4), (50, CFG.hidden_size))
+            jnp.array(first)].set(10.0)
+        x = jax.random.normal(jax.random.key(4), (50, cfg.hidden_size))
         valid = jnp.arange(50) < 47          # three rows of padding
-        out, cnt = lm.routed_experts(lp, CFG, x, valid)
-        assert cnt.tolist() == [0, 47, 47, 0]
-        idx, w = (np.asarray(a) for a in lm.route(lp, CFG, x))
-        assert (np.sort(idx, 1) == [5, 6]).all()
-        # expert 5 is the second held here (4..7), 6 the third
+        out, cnt = lm.routed_experts(lp, cfg, x, valid)
+        assert cnt.tolist() == [47 * (lo + g in first)
+                                for g in range(hi - lo)]
+        idx, w = (np.asarray(a) for a in lm.route(lp, cfg, x))
+        assert (np.sort(idx, 1) == first).all()
         want = sum(
-            np.where(idx == 4 + e, w, 0).sum(1, keepdims=True)
-            * np.asarray(lm._swiglu(x, lp["exp_gate"][e], lp["exp_up"][e],
-                                    lp["exp_down"][e]))
-            for e in (1, 2))
+            np.where(idx == e, w, 0).sum(1, keepdims=True)
+            * np.asarray(lm._swiglu(x, lp["exp_gate"][e - lo],
+                                    lp["exp_up"][e - lo],
+                                    lp["exp_down"][e - lo]))
+            for e in first)
         np.testing.assert_allclose(np.asarray(out)[:47], want[:47],
                                    rtol=2e-2, atol=2e-3)
         assert not np.asarray(out)[47:].any()
 
 
 class TestState:
-    def test_padding_rows_leave_the_state_untouched(self, seqs, attention):
+    def test_padding_rows_leave_the_state_untouched(self, seqs, attention,
+                                                    model):
         """The same rows in a bucket of their own size and in a larger one
-        whose padding holds stale rows: the same scores, the same state."""
-        spec = latent_moe(CFG)
+        whose padding holds stale rows: the same scores, the same state,
+        of every kind."""
+        spec = model.spec(model.cfg)
         params = spec.init(jax.random.key(SEED))
         step = spec.make_step("cpu")
         rows = rows_of({k: v[:5] for k, v in seqs.items()})    # 15 rows
-        plan = FlowTable(CFG.slots, CFG.positions, CFG.vocab_slice).map(rows)
+        plan = spec.make_table().map(rows)
         outs = []
         for bucket, stale in ((15, None), (32, 77)):
             staged = np.full((bucket, 3), stale or 0, np.int32)
@@ -294,12 +375,12 @@ class TestState:
                 CFG.layers * len(call) * CFG.positions)
             before = after
 
-    def test_two_calls_in_flight_apply_in_call_order(self, seqs):
+    def test_two_calls_in_flight_apply_in_call_order(self, seqs, model):
         calls = [rows_of({k: v[a:a + 6] for k, v in seqs.items()})
                  for a in range(0, 24, 6)]
 
         async def go(together):
-            s = scorer()
+            s = scorer(model)
             try:
                 if together:
                     return await asyncio.gather(*(s.score(c) for c in calls))
@@ -309,18 +390,19 @@ class TestState:
         for a, b in zip(run(go(True)), run(go(False))):
             np.testing.assert_array_equal(a, b)
 
-    def test_a_failed_call_leaves_the_state_as_it_was(self, seqs):
+    def test_a_failed_call_leaves_the_state_as_it_was(self, seqs, model):
         first = rows_of({k: v[:6] for k, v in seqs.items()})
         second = rows_of({k: v[6:12] for k, v in seqs.items()})
         bad = second.copy()
         bad[3, 2] = 128                     # an id outside the slice
 
         async def go(fail):
-            s = scorer()
+            s = scorer(model)
             try:
                 await s.score(first)
                 if fail:
                     before = s._table.checkpoint()
+                    state = jax.tree_util.tree_map(np.asarray, s._state)
                     with pytest.raises(ValueError):
                         await s.score(bad)
                     # a new key in a call that fails at its launch
@@ -335,14 +417,18 @@ class TestState:
                     after = s._table.checkpoint()
                     assert before[0] == after[0] and before[4] == after[4]
                     np.testing.assert_array_equal(before[2], after[2])
+                    # and the device's state, of every kind, is untouched
+                    for a, b in zip(jax.tree_util.tree_leaves(state),
+                                    jax.tree_util.tree_leaves(s._state)):
+                        np.testing.assert_array_equal(a, np.asarray(b))
                 return await s.score(second)
             finally:
                 s.close()
         np.testing.assert_array_equal(run(go(True)), run(go(False)))
 
-    def test_the_frozen_spec_has_no_fit_and_one_device(self, seqs):
+    def test_the_frozen_spec_has_no_fit_and_one_device(self, seqs, model):
         async def go():
-            s = scorer()
+            s = scorer(model)
             try:
                 rows = rows_of(seqs)
                 with pytest.raises(RuntimeError, match="frozen"):
@@ -351,8 +437,9 @@ class TestState:
                 with pytest.raises(RuntimeError, match="frozen"):
                     s.snapshot()
                 d = s.device_state()
-                assert d["model"] == "latent_moe"
-                assert d["flow"]["experts_held"] == [4, 8]
+                assert d["model"] == model.name
+                assert d["flow"]["experts_held"] == list(
+                    model.cfg.experts_held)
                 assert d["flow"]["slots"] == 8
                 assert d["flow"]["positions"] == 64
                 assert d["flow"]["attention"] == "xla"   # not a TPU
@@ -360,7 +447,8 @@ class TestState:
                 s.close()
         run(go())
         with pytest.raises(ValueError, match="single-device"):
-            InProcessScorer(spec=latent_moe(CFG), devices=jax.devices()[:2])
+            InProcessScorer(spec=model.spec(model.cfg),
+                            devices=jax.devices()[:2])
 
     def test_the_default_spec_is_todays_model(self):
         s = InProcessScorer(devices=jax.devices()[:1])
@@ -371,7 +459,7 @@ class TestState:
             assert "flow" not in s.device_state()
         finally:
             s.close()
-        assert sorted(SPECS) == ["latent_moe", "mlp36"]
+        assert sorted(SPECS) == ["latent_moe", "lfm2_moe", "mlp36"]
         assert mlp36(0.5).cfg.recon_weight == 0.5
 
 
@@ -501,17 +589,17 @@ def test_the_telemeter_sends_keyed_rows_to_the_flow_scorer():
 
 
 def test_the_telemeter_builds_the_flow_tier_on_one_device_in_shadow(
-        monkeypatch):
+        monkeypatch, model):
     """Through ``_ensure_scorer`` on a host of 8 devices: the flow scorer
     is pinned to the first, and on weights drawn from the seed it runs in
     shadow: keyed rows are scored by both tiers, the flow tier's scores are
     counted and not published."""
     assert len(jax.devices()) > 1
-    monkeypatch.setitem(SPECS, "latent_moe", lambda: latent_moe(CFG))
+    monkeypatch.setitem(SPECS, model.name, lambda: model.spec(model.cfg))
 
     async def go():
         tele = JaxAnomalyTelemeter(
-            JaxAnomalyConfig(model="latent_moe", nativeTier="off",
+            JaxAnomalyConfig(model=model.name, nativeTier="off",
                              trainEveryBatches=0), MetricsTree())
         tele.set_native_route_resolver(lambda rid: f"/svc/r{rid}")
         published = []
@@ -524,7 +612,7 @@ def test_the_telemeter_builds_the_flow_tier_on_one_device_in_shadow(
             await drain_streams(tele, 0)
             flow = tele._flow_scorer
             state = flow.device_state()
-            assert state["model"] == "latent_moe"
+            assert state["model"] == model.name
             assert state["weights"] == "seed"
             assert flow._devices == jax.devices()[:1]
             assert state["score_batches"] == {"8": 1}
@@ -580,7 +668,8 @@ class TestTheSeam:
         plan = FlowTable(CFG.slots, CFG.positions, CFG.vocab_slice).map(rows)
         for spec, staged, layout in (
                 (mlp36(), np.ones((16, FEATURE_DIM), np.float32), None),
-                (latent_moe(CFG), plan.rows, plan.layout)):
+                (latent_moe(CFG), plan.rows, plan.layout),
+                (lfm2_moe(CFG_LFM2), plan.rows, plan.layout)):
             params = spec.init(jax.random.key(SEED))
             state = jax.device_put(spec.init_state())
             scores, new, counts = spec.make_step("cpu")(
@@ -602,27 +691,37 @@ class TestTheSeam:
             s.close()
 
     def test_the_start_tokens_constants_come_from_the_steps_own_program(
-            self, seqs):
+            self, seqs, model):
         """The first call makes them (``with_start``) with the program it
-        is about to run, and they ride in the state from then on."""
-        spec = latent_moe(CFG)
+        is about to run, and they ride in the state from then on: of every
+        layer what the start token leaves of its kind of state."""
+        cfg = model.cfg
+        spec = model.spec(cfg)
         params = spec.init(jax.random.key(SEED))
         assert "start_h" not in params
         rows = rows_of({k: v[:5] for k, v in seqs.items()})
-        table = FlowTable(CFG.slots, CFG.positions, CFG.vocab_slice)
-        plan = table.map(rows)
+        plan = spec.make_table().map(rows)
         step = spec.make_step("cpu")
         state = spec.init_state()
         assert state[-1] is None
         _, state, _ = step(params, state, jnp.asarray(plan.rows), len(rows),
                            plan.layout)
-        entries, h = state[-1]
-        assert entries.shape == (CFG.layers, CFG.entry_width)
-        assert h.shape == (CFG.hidden_size,)
-        # what the reference computes of position 0 of any flow
-        _, full = reference_scores(seqs)
-        gap = np.abs(np.asarray(entries, np.float32)
-                     - full["entries"][:, 0, 0])
-        assert np.median(gap) < 4e-3 and gap.max() < 0.2
+        starts, h = state[-1]
+        assert [s.shape for s in starts] == [
+            s.shape for s in lm.start_shapes(cfg)[0]]
+        assert h.shape == (cfg.hidden_size,)
+        # what the reference computes of position 0 of any flow: a cache
+        # entry; of a convolution layer the tail [0, u(start token)]
+        _, full = reference_scores(seqs, model)
+        for l, got in enumerate(np.asarray(s, np.float32) for s in starts):
+            if model.name == "latent_moe":
+                want = full["entries"][l, 0, 0]
+            elif model.tiny["layer_types"][l] == "conv":
+                assert got.shape == (2, cfg.hidden_size) and not got[0].any()
+                got, want = got[1], full["kept"][l][0, 0]
+            else:
+                want = full["kept"][l][0, 0]
+            gap = np.abs(got - want)
+            assert np.median(gap) < 8e-3 and gap.max() < 0.2
         # and the lengths count the call's flows alone, not the making
         assert int(np.asarray(state[1]).sum()) == 3 * 6

@@ -116,6 +116,8 @@ class TestScorePath:
             assert_tiles(c)
             pairs = c.counts.pop("moe.local_pairs")
             assert 0 <= c.counts.pop("moe.max_expert_tokens") <= pairs <= 20
+            # tiles of 8 rows the expert loop ran: they hold the pairs
+            assert pairs <= 8 * c.counts.pop("moe.tiles") < pairs + 2 * 4 * 8
             # 5 rows pad to the 8-row bucket of int32 triples
             assert c.counts == {
                 "score.calls": 1, "put.bytes": 8 * 3 * 4,
@@ -128,7 +130,9 @@ class TestScorePath:
                 # the append: a window of T + 1 = 5 of a slot's 64
                 # positions a flow a layer
                 "cache.rows_written": 3 * 2 * 5,
-                "cache.rows_whole": 3 * 2 * 64}
+                "cache.rows_whole": 3 * 2 * 64,
+                # no layer of this model keeps a state of fixed size
+                "conv.state_rows": 0}
         assert timing["bytes"] == 8 * 3 * 4 + 8 * 4
         assert state["flow"]["layouts"] == {"2x4": 2}
         assert np.shape(state["flow"]["expert_tokens"]) == (2, 4)
