@@ -55,9 +55,19 @@ Per layer ``h += Attn(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``:
   layer: ``routed(x)`` sums over the token's selected experts that are
   held here, the others add nothing, and nothing stands in for them. The
   held pairs are sorted by expert into tiles of ``expert_tile`` rows, each
-  tile of one expert, and a loop over the tiles *that hold a pair* does
-  the grouped product: no capacity, no token dropped, work in proportion
-  to the pairs.
+  tile of one expert, and the tiles *that hold a pair* go through **one
+  grouped product** (``routed_experts``): their rows are gathered, the
+  step's ``experts.product`` computes every tile's SwiGLU under its
+  expert's weights, and ``experts.combine`` adds the weighted rows to
+  their tokens'. *How those two run is chosen by platform where the step
+  is built*, as ``attend`` is: on a TPU the Pallas kernels of
+  ``ops/expert_product.py``, whose grids are the tiles that hold a pair:
+  the product fetches an expert's weights once for all its tiles, the
+  next expert's under this one's matmuls, and the combine keeps a block
+  of the output's columns in VMEM while the rows are added to it;
+  elsewhere ``swiglu_tiles_xla``, a loop that slices the tile's expert
+  out of the weights every trip, and XLA's scatter-add. No capacity, no
+  token dropped, work in proportion to the pairs.
 
 The device step (``flow_step``) takes the call's rows as the host's
 ``FlowTable`` laid them out (``telemetry/flowstate.py``): row ``i`` is
@@ -186,14 +196,16 @@ class Call(NamedTuple):
     its ``slot`` (``slots`` where the flow brings nothing), the position
     ``p0`` its chunk is appended at, the chunk's ``count`` events and
     whether the flow ``begins`` here; ``pos [F, T]`` each event's
-    position; ``attend``: the attention over a slot the step was built
-    with."""
+    position; ``attend``: the attention over a slot, and ``experts``: the
+    routed experts' grouped product and combine (``ExpertOps``), that
+    the step was built with."""
     slot: Any
     p0: Any
     count: Any
     begins: Any
     pos: Any
     attend: Callable
+    experts: Any
 
 
 class Operator(NamedTuple):
@@ -562,10 +574,74 @@ def route(lp, cfg, x):
                  * cfg.routed_scaling_factor)
 
 
-def routed_experts(lp, cfg, x, valid):
-    """The held experts' part of the routed sum for ``x [N, hidden]``:
-    ``(out [N, hidden] float32, tokens per held expert [G])``. Tokens not
-    ``valid`` (padding) are routed nowhere."""
+CHUNK_BYTES = 192 * 2 ** 20  # the float32 rows a run of tiles gives back
+
+
+def swiglu_tiles_xla(xs, wt, tile_expert, live, gate, up, down):
+    """The grouped product as XLA does it, a loop over the tiles that
+    slices the tile's expert out of ``gate``, ``up [G, D, I]`` and ``down
+    [G, I, D]`` every trip: the path of every platform but the TPU, and
+    what ``ops/expert_product.swiglu_tiles_fused`` is tested against.
+    ``xs [tiles x M, D]`` bfloat16 the tiles' rows, ``wt [tiles x M]``
+    their routing weights, ``tile_expert [tiles]`` each tile's held
+    expert, ``live`` how many tiles, the first, hold a pair. Returns ``(y
+    [tiles x M, D]`` float32 ``= wt * swiglu(xs)`` for the rows of the
+    first ``live`` tiles (the others zero here, unspecified on the
+    kernel), the whole-expert equivalents of weights read``: one a tile
+    here)``."""
+    M = xs.shape[0] // tile_expert.shape[0]
+
+    def tile(t, y):
+        e = tile_expert[t]
+        w = jax.lax.dynamic_slice_in_dim(wt, t * M, M)
+        return jax.lax.dynamic_update_slice_in_dim(
+            y, _swiglu(jax.lax.dynamic_slice_in_dim(xs, t * M, M),
+                       gate[e], up[e], down[e]) * w[:, None], t * M, 0)
+
+    return (jax.lax.fori_loop(0, live, tile,
+                              jnp.zeros(xs.shape, jnp.float32)),
+            jnp.asarray(live, jnp.int32))
+
+
+def add_rows_xla(y, tok, live, out):
+    """``out [N, D]`` with the rows of ``y``'s first ``live`` tiles added
+    to their tokens' rows, as XLA does it: one scatter-add (``tok [tiles,
+    M]``; ``N``: no one's, dropped). What
+    ``ops/expert_product.add_rows_fused`` is tested against."""
+    mine = (jnp.arange(tok.shape[0]) < live)[:, None]
+    return out.at[jnp.where(mine, tok, out.shape[0]).reshape(-1)].add(
+        y, mode="drop")
+
+
+class ExpertOps(NamedTuple):
+    """How the routed experts' sorted rows are multiplied and brought
+    back to their tokens: ``product`` (``swiglu_tiles_xla``'s signature)
+    and ``combine`` (``add_rows_xla``'s). The XLA forms unless
+    ``ops/expert_product.best_expert_product`` chose for a platform."""
+    product: Callable = swiglu_tiles_xla
+    combine: Callable = add_rows_xla
+
+
+def routed_experts(lp, cfg, x, valid, experts=ExpertOps(), base=None):
+    """The held experts' part of the routed sum for ``x [N, hidden]``,
+    added to ``base [N, hidden]`` float32 (nought where None: the layer
+    hands in what the sum is added to, the residual stream and the shared
+    expert's output, so that no array of zeros is made and no pass adds
+    two arrays afterwards): ``(out [N, hidden] float32, tokens per held
+    expert [G], whole-expert equivalents of weights the product brought
+    to the chip)``. Tokens not ``valid`` (padding) are routed nowhere.
+    The held pairs are sorted by
+    expert and placed in tiles of ``expert_tile`` rows of one expert (a
+    pair's row: ``dest``); ``R`` rows hold them at worst, and the tiles
+    that hold a pair are the first ``tile_end[-1]``. Those go through
+    ``experts`` (``ExpertOps``) in runs of ``C``
+    consecutive tiles, as many runs as hold a pair, one as a rule: a
+    run's rows are gathered from ``x``, multiplied (``experts.product``)
+    and added to their tokens' rows of ``out`` (``experts.combine``),
+    **so what moves follows the tiles that hold a pair and not ``R`` or
+    ``N x k``** (where 12 of 384 experts are held ``R`` is 34,304 rows
+    for some 1,000 pairs). ``C`` is as many tiles as give back
+    ``CHUNK_BYTES`` of float32 rows."""
     N, D = x.shape
     lo, hi = cfg.experts_held
     G, k, M = hi - lo, cfg.num_experts_per_tok, cfg.expert_tile
@@ -581,27 +657,37 @@ def routed_experts(lp, cfg, x, valid):
     # start plus its rank among the group's pairs
     ge = jnp.minimum(g_sorted, G - 1)
     rank = jnp.arange(N * k) - (jnp.cumsum(cnt) - cnt)[ge]
-    R = (N * min(k, G) // M + G) * M                        # rows at most
+    most = N * min(k, G) // M + G                           # tiles at most
+    C = min(most, max(1, CHUNK_BYTES // (M * D * 4)))
+    R = -(-most // C) * C * M                               # rows: whole runs
     dest = jnp.where(g_sorted < G, (tile_end - tiles)[ge] * M + rank, R)
     dest_tok = jnp.full((R,), N, jnp.int32).at[dest].set(
         (order // k).astype(jnp.int32), mode="drop")
     dest_w = jnp.zeros((R,), jnp.float32).at[dest].set(
         w.reshape(-1)[order], mode="drop")
+    tile_expert = jnp.minimum(
+        (jnp.arange(R // M)[:, None] >= tile_end[None]).sum(1), G - 1
+    ).astype(jnp.int32)
     x_pad = jnp.concatenate(
         [x.astype(jnp.bfloat16), jnp.zeros((1, D), jnp.bfloat16)])
 
-    def tile(t, out):
-        e = jnp.minimum((t >= tile_end).sum(), G - 1)
-        rows = jax.lax.dynamic_slice(dest_tok, (t * M,), (M,))
-        wt = jax.lax.dynamic_slice(dest_w, (t * M,), (M,))
-        y = _swiglu(x_pad[rows], lp["exp_gate"][e], lp["exp_up"][e],
-                    lp["exp_down"][e])
-        return out.at[rows].add(y * wt[:, None])
+    def run(c, carry):
+        out, loads = carry
+        rows = jax.lax.dynamic_slice_in_dim(dest_tok, c * C * M, C * M)
+        live = jnp.minimum(tile_end[-1] - c * C, C)
+        y, n = experts.product(
+            x_pad[rows],
+            jax.lax.dynamic_slice_in_dim(dest_w, c * C * M, C * M),
+            jax.lax.dynamic_slice_in_dim(tile_expert, c * C, C), live,
+            lp["exp_gate"], lp["exp_up"], lp["exp_down"])
+        return experts.combine(y, rows.reshape(C, M), live, out), loads + n
 
     with jax.named_scope("expert_tiles"):
-        out = jax.lax.fori_loop(0, tile_end[-1], tile,
-                                jnp.zeros((N + 1, D), jnp.float32))
-    return out[:N], cnt
+        out, loads = jax.lax.fori_loop(
+            0, (tile_end[-1] + C - 1) // C, run,
+            (jnp.zeros((N, D), jnp.float32) if base is None else base,
+             jnp.int32(0)))
+    return out, cnt, loads
 
 
 def _forward(params, cfg, operators, kept, starts, tok, call):
@@ -614,12 +700,14 @@ def _forward(params, cfg, operators, kept, starts, tok, call):
     the layer has one), both residual.
     Returns the final normed hidden ``[F, T, hidden]`` float32, the
     layers' state with the chunks applied, tokens per held expert
-    ``[expert layers, G]``, and the operators' tallies ``[4]``
-    (``Operator``), summed over flows and layers."""
+    ``[expert layers, G]``, the operators' tallies ``[4]``
+    (``Operator``), summed over flows and layers, and the expert layers'
+    ``weight_loads`` (``routed_experts``), summed."""
     F, T = tok.shape
     h = params["embed"][tok].astype(jnp.float32)
     valid = jnp.arange(T)[None] < call.count[:, None]
     counts, kept, tally = [], list(kept), jnp.zeros((4,), jnp.int32)
+    loads = jnp.int32(0)
     for l, (lp, op) in enumerate(zip(params["layers"], operators)):
         with jax.named_scope(f"layer{l}.{op.scope}"):
             a, kept[l], layer_tally = op.apply(lp, cfg, kept[l], starts[l],
@@ -630,19 +718,22 @@ def _forward(params, cfg, operators, kept, starts, tok, call):
             x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
             if "router" in lp:
                 flat = x.reshape(F * T, -1)
-                y, cnt = routed_experts(lp, cfg, flat, valid.reshape(-1))
-                counts.append(cnt)
+                base = h.reshape(F * T, -1)
                 if "shared_gate" in lp:
-                    y = _swiglu(flat, lp["shared_gate"], lp["shared_up"],
-                                lp["shared_down"]) + y
-                y = y.reshape(F, T, -1)
+                    base = base + _swiglu(flat, lp["shared_gate"],
+                                          lp["shared_up"], lp["shared_down"])
+                # the routed rows are added to the stream where it lies
+                y, cnt, loaded = routed_experts(
+                    lp, cfg, flat, valid.reshape(-1), call.experts, base)
+                counts.append(cnt)
+                loads = loads + loaded
+                h = y.reshape(F, T, -1)
             else:
-                y = _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-            h = h + y
+                h = h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
     G = cfg.experts_held[1] - cfg.experts_held[0]
     counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
     return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(kept),
-            counts, tally)
+            counts, tally, loads)
 
 
 HEAD_LOGITS_BYTES = 384 * 2 ** 20   # a block of float32 logits, at most
@@ -677,13 +768,15 @@ def event_scores(params, cfg, pred, tok):
 
 
 def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
-              attend=attend_xla):
+              attend=attend_xla, experts=ExpertOps()):
     """One call, of this model or of any whose configuration gives its
     layers' operators (``cfg.operator``, ``cfg.tensors``: here and
     ``models/lfm2_moe.py``). ``rows [B, 3]`` int32 ``(cell, address,
     id)``; rows at and past ``n`` are padding; ``attend``: the attention
     over a slot that the model's attention layers call (``attend_xla``,
-    or the kernel ``ops/flow_attention.best_attention`` gives for a TPU).
+    or the kernel ``ops/flow_attention.best_attention`` gives for a TPU);
+    ``experts``: the routed experts' grouped product and combine (XLA's,
+    or the kernels ``ops/expert_product.best_expert_product`` gives).
     Returns ``(scores [B] float32 in row order, state, counts)``."""
     kept, length, last_h, (starts, start_h) = state
     S, P = cfg.slots, cfg.positions
@@ -702,10 +795,10 @@ def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
     prev_h = jnp.where(begins[:, None], start_h[None],
                        last_h[jnp.minimum(slot, S - 1)])
     call = Call(slot, p0, count, begins, p0[:, None] + jnp.arange(T)[None],
-                attend)
+                attend, experts)
     operators = [cfg.operator(l) for l in range(cfg.layers)]
-    h, kept, expert_tokens, tally = _forward(params, cfg, operators, kept,
-                                             starts, tok, call)
+    h, kept, expert_tokens, tally, loads = _forward(
+        params, cfg, operators, kept, starts, tok, call)
     with jax.named_scope("head"):
         pred = jnp.concatenate(
             [prev_h[:, None].astype(jnp.float32), h[:, :-1]], 1)
@@ -729,6 +822,7 @@ def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
     counts = {"moe.local_pairs": expert_tokens.sum(),
               "moe.max_expert_tokens": expert_tokens.max(initial=0),
               "moe.tiles": ((expert_tokens + M - 1) // M).sum(),
+              "moe.weight_loads": loads,
               "cache.positions": length.sum(),
               "attn.kv_blocks": tally[0],
               "attn.kv_blocks_whole": tally[1],

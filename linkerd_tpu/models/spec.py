@@ -110,8 +110,10 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
     flow_step`` over the configuration's layers, built with the attention
     its platform gets (``ops/flow_attention.best_attention``: the fused
     kernel on a TPU, XLA's elsewhere; ``grouped``: over keys and values
-    in groups of heads, else over the latent), and ``describe`` says
-    which."""
+    in groups of heads, else over the latent) and with the routed
+    experts' grouped product its platform gets
+    (``ops/expert_product.best_expert_product``, likewise), and
+    ``describe`` says which."""
     import jax
 
     from linkerd_tpu.models import latent_moe as lm
@@ -121,19 +123,25 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
 
     def make_step(platform: str):
         # the kernel's module is imported where a step is built
+        from linkerd_tpu.ops.expert_product import (
+            best_expert_product, expert_product_kind)
         from linkerd_tpu.ops.flow_attention import (
             attention_kind, best_attention)
         attend = best_attention(platform, grouped)
+        experts = best_expert_product(platform)
         built["attention"] = attention_kind(platform)
+        built["expert_product"] = expert_product_kind(platform)
         # the state and the staged rows are the program's to reuse
-        program = jax.jit(lm.flow_step,
-                          static_argnames=("cfg", "F", "T", "attend"),
-                          donate_argnums=(1, 2))
+        program = jax.jit(
+            lm.flow_step,
+            static_argnames=("cfg", "F", "T", "attend", "experts"),
+            donate_argnums=(1, 2))
 
         def step(params, state, rows, n, layout):
             def run(state, rows, n):
                 return program(params, state, rows, np.int32(n), cfg=cfg,
-                               F=layout[0], T=layout[1], attend=attend)
+                               F=layout[0], T=layout[1], attend=attend,
+                               experts=experts)
             if state[-1] is None:
                 # once: the same program, on arguments like this call's
                 state = lm.with_start(run, cfg, state, rows)
@@ -147,6 +155,7 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
             "experts_held": list(cfg.experts_held),
             "layer_share": cfg.layer_share,
             "attention": built.get("attention"),
+            "expert_product": built.get("expert_product"),
             "resident": len(table.slot_of),
             "layouts": {f"{f}x{t}": c
                         for (f, t), c in sorted(table.layouts.items())},

@@ -451,8 +451,10 @@ class InProcessScorer(Scorer):
         or None), ``score_batches`` / ``fit_batches`` (calls per compiled
         shape), and what the spec's ``describe`` adds. The flow model adds
         ``flow``: ``slots``, ``positions``, ``experts_held``,
-        ``layer_share``, ``attention`` (``fused_pallas`` | ``xla``: what
-        the step was built with), ``resident`` flows, ``layouts`` (calls
+        ``layer_share``, ``attention`` and ``expert_product``
+        (``fused_pallas`` | ``xla``: what the step was built with: the
+        attention over a slot, the routed experts' grouped product),
+        ``resident`` flows, ``layouts`` (calls
         per ``FxT`` layout the step was compiled for) and ``expert_tokens``
         (the newest call's tokens per expert layer and held expert)."""
         import jax

@@ -304,6 +304,8 @@ class RingDispatcher:
                       rec: phases.Call) -> asyncio.Future:
         n = len(x)
         loop = asyncio.get_running_loop()
+        if self._closed:    # closed while this call waited for its turn
+            raise RuntimeError("dispatcher closed")
         bucket = int(self._bucket_fn(n))
         slot = await self._acquire(bucket, rec)
         rec.mark(phases.SLOT_WAIT)
@@ -365,6 +367,13 @@ class RingDispatcher:
                     self._reject, fut, RuntimeError("dispatcher closed"))
             except RuntimeError:
                 pass
+        # the owner's bound methods: with them a closed dispatcher and its
+        # owner hold each other, and the owner's device arrays (12.6 GB of
+        # a flow model) wait for the cycle collector, which a caller that
+        # needs the device's memory next cannot count on (the benchmark's
+        # check: RESOURCE_EXHAUSTED in PR 33's runs); no call passes
+        # ``_closed`` to reach them
+        self._prepare = self._bucket_fn = None
 
 
 # -- native feature ring ------------------------------------------------------

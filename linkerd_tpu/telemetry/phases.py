@@ -20,7 +20,12 @@ positions, ``flow.resident`` after the call) and what the device step
 reported (``cache.positions``: the sum of the flows' lengths after the
 call, ``moe.local_pairs``: token-expert pairs computed here,
 ``moe.max_expert_tokens``: the fullest held expert of the call, over its
-layers, ``attn.kv_blocks``: the blocks of cache positions attention ran
+layers, ``moe.tiles``: the tiles of ``expert_tile`` rows of one expert that
+held a pair, ``moe.weight_loads``: whole-expert equivalents of weights the
+expert layers' grouped product brought to the chip, by the schedule it
+ran: one for every held expert with a pair on the fused kernel where an
+expert is one block (``device_state()["flow"]["expert_product"]`` says
+which form runs), one a tile on XLA's loop, ``attn.kv_blocks``: the blocks of cache positions attention ran
 over, one for every tile of query rows that ran over it, summed over
 flows and layers, ``attn.kv_blocks_whole``: what the slots whole would
 have been; XLA's attention runs over every slot whole as one block, so

@@ -35,6 +35,25 @@ def _clean_env(**extra):
     return env
 
 
+# What the children that compile a flow cell's whole step print of the
+# routed experts (``models/latent_moe.routed_experts``), for every
+# instruction of the optimised program: ``EXPERT_MATRIX`` where one under
+# the scope ``expert_tiles`` makes an array of one expert's matrix (the loop
+# of PRs 28-32 sliced the three out of the layer's tensors every tile), and
+# ``ROWS`` where any makes float32 rows of the hidden width by the ten
+# thousand (the sort's worst case ``R`` is 34,304 rows where 12 of 384
+# experts are held: nothing may be sized by it).
+EXPERTS_PATTERNS = (
+    "D_, I_ = cfg.hidden_size, cfg.moe_intermediate_size\n"
+    "matrix = re.compile(rf'bf16\\[(?:1,)?(?:{D_},{I_}|{I_},{D_})\\]')\n"
+    "many = re.compile(rf'f32\\[(\\d+),{D_}\\]')\n")
+EXPERTS_SEEN = (
+    "    if 'expert_tiles' in line and matrix.search(typ):\n"
+    "        print('EXPERT_MATRIX', rest.split('(', 1)[0], name, typ[:60])\n"
+    "    if any(int(n) >= 16384 for n in many.findall(typ)):\n"
+    "        print('ROWS', rest.split('(', 1)[0], name, typ[:60])\n")
+
+
 class TestScorerSelection:
     def test_cpu_gets_xla_without_touching_pallas(self, monkeypatch):
         def no_kernel(*a, **k):
@@ -165,6 +184,8 @@ class TestScorerSelection:
             "except Exception as e:\n"
             "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
             "from linkerd_tpu.models import latent_moe as lm\n"
+            "from linkerd_tpu.ops.expert_product import (\n"
+            "    best_expert_product)\n"
             "from linkerd_tpu.ops.flow_attention import best_attention\n"
             "sh = SingleDeviceSharding(topo.devices[0])\n"
             "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
@@ -184,10 +205,12 @@ class TestScorerSelection:
             "    lambda: lm.init_state(cfg)))[:3] + (\n"
             "    place(lm.start_shapes(cfg)),)\n"
             "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
-            "               static_argnames=('cfg', 'F', 'T', 'attend'))\n"
+            "               static_argnames=('cfg', 'F', 'T', 'attend',\n"
+            "                                'experts'))\n"
             "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
             "               S(jnp.int32), cfg=cfg, F=64, T=64,\n"
-            "               attend=best_attention('tpu')).compile()\n"
+            "               attend=best_attention('tpu'),\n"
+            "               experts=best_expert_product('tpu')).compile()\n"
             "m = c.memory_analysis()\n"
             "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
             "      m.alias_size_in_bytes)\n"
@@ -196,6 +219,7 @@ class TestScorerSelection:
             "    'custom_call_target=\"tpu_custom_call\"'))\n"
             "# a layer's cache, or 128 and more of its positions, in either\n"
             "# order of the two minor dimensions\n"
+            + EXPERTS_PATTERNS +
             "S_, P, E = cfg.slots, cfg.positions, cfg.entry_width\n"
             "whole = re.compile(rf'bf16\\[{S_},(\\d+),{E}\\]|'\n"
             "                   rf'bf16\\[{S_},{E},(\\d+)\\]')\n"
@@ -212,6 +236,7 @@ class TestScorerSelection:
             "        typ, _, rest = rest.partition(' ')\n"
             "    if any(int(a or b) >= 128 for a, b in whole.findall(typ)):\n"
             "        print('WHOLE', rest.split('(', 1)[0], name, typ[:80])\n"
+            + EXPERTS_SEEN +
             "print('COMPILED', topo.devices[0].device_kind)\n")
         proc = _run([sys.executable, "-c", code], timeout=900,
                     env=_clean_env(
@@ -239,7 +264,20 @@ class TestScorerSelection:
         # slots gathered; 1.78 with XLA's attention too; my compile-only
         # readings, PRs 29 and 31)
         assert temp < 0.95 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
-        assert "KERNELS 5" in flow_step_compiled
+        # an attention kernel a layer; a grouped product and a combine an
+        # expert layer
+        assert "KERNELS 13" in flow_step_compiled
+
+    def test_flow_step_runs_the_experts_as_one_grouped_product(
+            self, flow_step_compiled):
+        """No instruction under ``expert_tiles`` makes one expert's matrix
+        (88 MB here: the kernel's block specs take an expert's blocks from
+        the layer's tensors where they lie), and nothing is sized by the
+        sort's worst case: 34,304 rows of 7,168 float32 would be 983 MB
+        for the ~1,000 pairs a layer this chip's 12 experts get."""
+        lines = flow_step_compiled.splitlines()
+        assert not [l for l in lines if l.startswith("EXPERT_MATRIX")]
+        assert not [l for l in lines if l.startswith("ROWS")]
 
     def test_flow_step_makes_no_copy_of_a_layers_cache(
             self, flow_step_compiled):
@@ -328,6 +366,8 @@ class TestScorerSelection:
             "except Exception as e:\n"
             "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
             "from linkerd_tpu.models import latent_moe as lm, lfm2_moe as lf\n"
+            "from linkerd_tpu.ops.expert_product import (\n"
+            "    best_expert_product)\n"
             "from linkerd_tpu.ops.flow_attention import best_attention\n"
             "sh = SingleDeviceSharding(topo.devices[0])\n"
             "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
@@ -347,16 +387,19 @@ class TestScorerSelection:
             "    lambda: lm.init_state(cfg)))[:3] + (\n"
             "    place(lm.start_shapes(cfg)),)\n"
             "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
-            "               static_argnames=('cfg', 'F', 'T', 'attend'))\n"
+            "               static_argnames=('cfg', 'F', 'T', 'attend',\n"
+            "                                'experts'))\n"
             "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
             "               S(jnp.int32), cfg=cfg, F=64, T=64,\n"
-            "               attend=best_attention('tpu', True)).compile()\n"
+            "               attend=best_attention('tpu', True),\n"
+            "               experts=best_expert_product('tpu')).compile()\n"
             "m = c.memory_analysis()\n"
             "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
             "      m.alias_size_in_bytes)\n"
             "text = c.as_text()\n"
             "print('KERNELS', text.count(\n"
             "    'custom_call_target=\"tpu_custom_call\"'))\n"
+            + EXPERTS_PATTERNS +
             "S_, P, E = cfg.slots, cfg.positions, cfg.entry_width\n"
             "V = cfg.vocab_slice\n"
             "whole = re.compile(rf'bf16\\[{S_},(\\d+),{P}\\]|'\n"
@@ -380,6 +423,7 @@ class TestScorerSelection:
             "        if op == 'fusion' and 'calls=%bitcast_fusion' in rest:\n"
             "            op = 'bitcast'     # a fusion of a bitcast alone\n"
             "        print('WHOLE', op, name, typ[:80])\n"
+            + EXPERTS_SEEN +
             "print('COMPILED', topo.devices[0].device_kind)\n")
         proc = _run([sys.executable, "-c", code], timeout=900,
                     env=_clean_env(
@@ -405,9 +449,71 @@ class TestScorerSelection:
         assert 12.4e9 < args < 12.7e9 and alias > 2.17e9
         # the float32 logits of 4,096 events over 65,536 ids would be 1
         # GiB at once: 0.36 GiB of temporaries in all (my compile-only
-        # reading, PR 32)
-        assert temp < 0.6 * 2 ** 30 and args + temp < 14.5 * 2 ** 30
-        assert "KERNELS 2" in lfm2_step_compiled
+        # reading, PR 32); 0.65 with a run of 192 tiles' rows in (101 MB)
+        # and out (201 MB) of the grouped product (PR 33)
+        assert temp < 0.75 * 2 ** 30 and args + temp < 14.5 * 2 ** 30
+        # two attention layers; eight expert layers of two kernels each
+        assert "KERNELS 18" in lfm2_step_compiled
+
+    def test_lfm2_step_runs_the_experts_as_one_grouped_product(
+            self, lfm2_step_compiled):
+        """No instruction under ``expert_tiles`` makes one expert's matrix
+        (6.3 MB each of three: 18.9 MB a tile, ~160 tiles a layer, in the
+        loop this replaced). (Here the worst case is 1.5 times the pairs
+        every call brings, 24,576 rows of 2,048: one run of tiles.)"""
+        lines = lfm2_step_compiled.splitlines()
+        assert not [l for l in lines if l.startswith("EXPERT_MATRIX")]
+
+    def test_expert_product_compiles_for_v5e_at_both_cells_widths(self):
+        """The grouped product and the combine alone, compiled by the real
+        Mosaic for a described v5e at the published widths: LFM2's 64
+        experts of 2,048 x 1,536 (one block an expert: two of them, 37.7
+        MB, in VMEM) in a run of 192 tiles, Kimi's 12 of 7,168 x 2,048
+        (four blocks of 22 MB) in runs of 54, and a run of one tile; at
+        tiles of 128 rows and of 8; the combine into 4,096 tokens' rows, a
+        block of 1,024 columns (16.8 MB, in and out) in VMEM."""
+        code = (
+            "import jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.ops.expert_product import (\n"
+            "    add_rows_fused, column_block, row_block,\n"
+            "    swiglu_tiles_fused)\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "bf = lambda *s: S(jnp.bfloat16, *s)\n"
+            "for G, D, I, bi in ((64, 2048, 1536, 1536),\n"
+            "                    (12, 7168, 2048, 512)):\n"
+            "    assert column_block(D, I) == bi\n"
+            "    assert row_block(4096, D) == 1024\n"
+            "    for tiles, M in ((192, 128), (54, 128), (1, 128), (4, 8)):\n"
+            "        text = swiglu_tiles_fused.lower(\n"
+            "            bf(tiles * M, D), S(jnp.float32, tiles * M),\n"
+            "            S(jnp.int32, tiles), S(jnp.int32), bf(G, D, I),\n"
+            "            bf(G, D, I), bf(G, I, D)).compile().as_text()\n"
+            "        assert 'tpu_custom_call' in text, (D, tiles, M)\n"
+            "        text = add_rows_fused.lower(\n"
+            "            S(jnp.float32, tiles * M, D),\n"
+            "            S(jnp.int32, tiles, M), S(jnp.int32),\n"
+            "            S(jnp.float32, 4096, D)).compile().as_text()\n"
+            "        assert 'tpu_custom_call' in text, (D, tiles, M)\n"
+            "        print('RUN', D, tiles, M)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=600,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        assert proc.stdout.count("RUN") == 8
 
     def test_lfm2_step_makes_no_copy_of_a_layers_keys_and_values(
             self, lfm2_step_compiled):

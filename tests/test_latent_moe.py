@@ -154,16 +154,32 @@ def close_to(got, want, model=None):
 
 @pytest.fixture(params=["xla", "fused"])
 def attention(request, monkeypatch):
-    """The step built on each attention: XLA's, which this platform gets,
-    and the TPU's kernel (``ops/flow_attention.py``), interpreted."""
+    """The step built on each attention and each grouped product of the
+    routed experts: XLA's, which this platform gets, and the TPU's
+    kernels (``ops/flow_attention.py``, ``ops/expert_product.py``),
+    interpreted."""
     if request.param == "fused":
+        from linkerd_tpu.ops import expert_product as ep
         from linkerd_tpu.ops import flow_attention as fa
         monkeypatch.setattr(
             fa, "best_attention",
             lambda platform, grouped=False: functools.partial(
                 fa.grouped_attention_fused if grouped
                 else fa.latent_attention_fused, interpret=True))
+        monkeypatch.setattr(ep, "best_expert_product",
+                            lambda platform: product_of("fused"))
     return request.param
+
+
+def product_of(kind: str) -> lm.ExpertOps:
+    """``routed_experts``' ``experts`` of a kind: XLA's loop and
+    scatter-add, or the two kernels interpreted."""
+    if kind == "xla":
+        return lm.ExpertOps()
+    from linkerd_tpu.ops import expert_product as ep
+    return lm.ExpertOps(
+        functools.partial(ep.swiglu_tiles_fused, interpret=True),
+        functools.partial(ep.add_rows_fused, interpret=True))
 
 
 @pytest.fixture(scope="module")
@@ -243,7 +259,8 @@ class TestAgainstTheReference:
         assert len(state["flow"]["layouts"]) > 1     # more than one shape
         assert state["flow"]["resident"] == 3
 
-    def test_the_shares_add_up_to_the_uncut_layer(self, model):
+    @pytest.mark.parametrize("product", ["xla", "fused"])
+    def test_the_shares_add_up_to_the_uncut_layer(self, model, product):
         """Guide section 4: the routed parts that all 4 shares give, with
         what every share computes alike (the shared expert, where the model
         has one: ``lfm2_moe`` has none) counted once, add up to what the
@@ -268,7 +285,8 @@ class TestAgainstTheReference:
             cfg = dataclasses.replace(model.cfg, n_routed_experts=E,
                                       experts_held=held)
             lp = lm.init(jax.random.key(SEED), cfg)["layers"][layer]
-            out, cnt = lm.routed_experts(lp, cfg, x, jnp.ones(24, bool))
+            out, cnt, _ = lm.routed_experts(lp, cfg, x, jnp.ones(24, bool),
+                                            product_of(product))
             got_sum = got_sum + out
             tokens += int(cnt.sum())
         # every pair computed on one share
@@ -278,8 +296,9 @@ class TestAgainstTheReference:
         scale = np.abs(np.asarray(uncut)).mean()
         assert np.median(gap) < 0.01 * scale and gap.max() < 0.2 * scale
 
+    @pytest.mark.parametrize("product", ["xla", "fused"])
     def test_routing_is_dropless_when_every_token_picks_one_expert(
-            self, model):
+            self, model, product):
         cfg = model.cfg
         (lo, hi), k = cfg.experts_held, cfg.num_experts_per_tok
         params = lm.init(jax.random.key(SEED), cfg)
@@ -290,7 +309,8 @@ class TestAgainstTheReference:
             jnp.array(first)].set(10.0)
         x = jax.random.normal(jax.random.key(4), (50, cfg.hidden_size))
         valid = jnp.arange(50) < 47          # three rows of padding
-        out, cnt = lm.routed_experts(lp, cfg, x, valid)
+        out, cnt, _ = lm.routed_experts(lp, cfg, x, valid,
+                                        product_of(product))
         assert cnt.tolist() == [47 * (lo + g in first)
                                 for g in range(hi - lo)]
         idx, w = (np.asarray(a) for a in lm.route(lp, cfg, x))
@@ -304,6 +324,33 @@ class TestAgainstTheReference:
         np.testing.assert_allclose(np.asarray(out)[:47], want[:47],
                                    rtol=2e-2, atol=2e-3)
         assert not np.asarray(out)[47:].any()
+
+
+def test_a_closed_scorer_frees_its_device_arrays_without_the_collector(
+        model):
+    """A caller that closes a scorer and drops it has the device's memory
+    back at once, by reference counts alone: the benchmark's check draws
+    a float32 reference beside where 12.6 GB of weights and state just
+    lay, and cannot wait for the cycle collector (the dispatcher held
+    the scorer's bound methods, and the scorer the dispatcher)."""
+    import gc
+    import weakref
+
+    async def go(s):
+        return await s.score(rows_of({11: [3, 4, 5], 22: [7, 8]}))
+
+    gc.collect()
+    gc.disable()
+    try:
+        s = scorer(model)
+        run(go(s))
+        kept = weakref.ref(s._state[0][0])      # a layer's state
+        s.close()
+        gone = weakref.ref(s)
+        del s
+        assert gone() is None and kept() is None
+    finally:
+        gc.enable()
 
 
 class TestState:

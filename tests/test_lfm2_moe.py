@@ -105,19 +105,25 @@ def test_a_restart_clears_both_kinds_of_state(seqs):
 def test_a_call_counts_the_state_it_wrote_of_each_kind(seqs):
     """Two flows' next 8 events: the call's record counts the tail rows
     written (2 a flow a convolution layer), the cache rows (a window of 9
-    positions a flow an attention layer, of the slot's 64), and the tiles
-    the expert loop ran, which hold every (token, expert) pair."""
+    positions a flow an attention layer, of the slot's 64), the tiles the
+    grouped product ran, which hold every (token, expert) pair, and the
+    experts' weights it read for them. The record is picked from the
+    process's log by what this test sent and when: 16 events between two
+    readings of the clock (``tests/test_phase_spans.py`` logs hand-made
+    records on a clock far ahead of this one, and under ``--dist
+    loadfile`` a worker may have run it first)."""
     async def go():
         s = scorer(MODEL)
         try:
             t0 = time.monotonic()
             await s.score(rows_of({11: seqs[11][:8], 33: seqs[33][:8]}))
-            return t0
+            return t0, time.monotonic()
         finally:
             s.close()
-    t0 = run(go())
+    t0, t1 = run(go())
     rec, = [c for c in phases.records()
-            if c.kind == phases.SCORE and c.t0 >= t0]
+            if c.kind == phases.SCORE and t0 <= c.t0 <= t1
+            and c.counts.get("flow.events") == 16]
     assert rec.counts["conv.state_rows"] == 2 * 2 * len(CONVS)
     assert rec.counts["cache.rows_written"] == 2 * 9 * len(ATTNS)
     assert rec.counts["cache.rows_whole"] == 2 * CFG.positions * len(ATTNS)
@@ -126,6 +132,8 @@ def test_a_call_counts_the_state_it_wrote_of_each_kind(seqs):
     tiles = rec.counts["moe.tiles"]
     assert pairs <= tiles * CFG.expert_tile < pairs + (
         CFG.layers - CFG.num_dense_layers) * 16 * CFG.expert_tile
+    # this platform's product is XLA's loop: an expert's weights a tile
+    assert rec.counts["moe.weight_loads"] == tiles
 
 
 def test_the_bias_is_in_the_selection_and_not_in_the_weights():

@@ -117,7 +117,10 @@ class TestScorePath:
             pairs = c.counts.pop("moe.local_pairs")
             assert 0 <= c.counts.pop("moe.max_expert_tokens") <= pairs <= 20
             # tiles of 8 rows the expert loop ran: they hold the pairs
-            assert pairs <= 8 * c.counts.pop("moe.tiles") < pairs + 2 * 4 * 8
+            tiles = c.counts.pop("moe.tiles")
+            assert pairs <= 8 * tiles < pairs + 2 * 4 * 8
+            # XLA's loop reads an expert's weights a tile
+            assert c.counts.pop("moe.weight_loads") == tiles
             # 5 rows pad to the 8-row bucket of int32 triples
             assert c.counts == {
                 "score.calls": 1, "put.bytes": 8 * 3 * 4,
@@ -366,6 +369,7 @@ def hand_made_run():
     # a flow call's own span and counts, past the traced slice
     _call(phases.SCORE, 120.0, [(phases.FLOW_MAP, 120.25)],
           {"moe.local_pairs": 960, "moe.max_expert_tokens": 40,
+           "moe.tiles": 10, "moe.weight_loads": 4,
            "cache.positions": 5000, "flow.events": 64})
 
     def program(start, ops):
@@ -374,15 +378,22 @@ def hand_made_run():
                 "ops": [{"start": a * 1e9, "dur": (b - a) * 1e9,
                          "name": "%op"} for a, b in ops]}
 
-    return {"window": {"t0": T + 96.0, "t_end": T + 140.0},
-            "trace_marks": {"clock0": T, "lo": T + 100.0, "hi": T + 110.0},
-            "trace": {"programs": [
-                program(102, [(102, 102.5), (102.5, 103)]),
-                program(105, [(105, 106)]), program(108, [(108, 109)]),
-                # another chip's programs are not the first device's
-                {"plane": "/device:TPU:1", "name": "jit_score",
-                 "start": 100e9, "dur": 10e9, "ops": [
-                     {"start": 100e9, "dur": 10e9, "name": "%op"}]}]}}
+    yield {"window": {"t0": T + 96.0, "t_end": T + 140.0},
+           "trace_marks": {"clock0": T, "lo": T + 100.0, "hi": T + 110.0},
+           "trace": {"programs": [
+               program(102, [(102, 102.5), (102.5, 103)]),
+               program(105, [(105, 106)]), program(108, [(108, 109)]),
+               # another chip's programs are not the first device's
+               {"plane": "/device:TPU:1", "name": "jit_score",
+                "start": 100e9, "dur": 10e9, "ops": [
+                    {"start": 100e9, "dur": 10e9, "name": "%op"}]}]}}
+    # the log is the process's: a later test of this worker that picks its
+    # calls by ``t0 >=`` a reading of the real clock would find these, on
+    # their clock far ahead of it, among its own
+    with phases._lock:
+        real = [c for c in phases._log if c.t0 < T]
+        phases._log.clear()
+        phases._log.extend(real)
 
 
 READINGS = [
@@ -402,6 +413,10 @@ READINGS = [
                            "per": "moe.local_pairs", "scale": 48}, 2.0),
     ("program_count_per", {"count": "cache.positions",
                            "per": "flow.events"}, 78.125),
+    # whole experts' weights read, of one a tile (the two
+    # ``*weight_loads_share`` metrics' file)
+    ("program_count_per", {"count": "moe.weight_loads", "per": "moe.tiles",
+                           "scale": 100}, 40.0),
     # idle inside update_norm: 100.5-102 and 103-104
     ("idle_by_span", {"spans": ["fit.update_norm"]}, 25.0),
     # inside fit.step 104-107: 104-105 and 106-107
@@ -474,7 +489,7 @@ def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
     e2e = {m["name"] for m in manifest["end_to_end"]}
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == [flow_cell]]
-    assert len(mine) == 17
+    assert len(mine) == 18     # 17 of PRs 28-31, ``moe.weight_loads_share``
     assert not [m for m in manifest["per_layer"] if m not in mine
                 and flow_cell in m.get("workloads", [])]
     with open(os.path.join(REPO, "linkerd_tpu", "telemetry",
