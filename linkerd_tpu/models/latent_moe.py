@@ -211,21 +211,34 @@ class Call(NamedTuple):
 class Operator(NamedTuple):
     """One kind of a layer's operator (what stands before the
     feed-forward), and the per-flow state it keeps. ``apply(lp, cfg, kept,
-    start, h, call) -> (y, kept, tally)``: ``h [F, T, hidden]`` the
+    start, h, call) -> (y, kept, counts)``: ``h [F, T, hidden]`` the
     residual stream (the operator norms it itself), ``kept`` this layer's
     state, donated, ``start`` what the start token leaves of it (set
-    where a flow begins), ``tally [4]`` the blocks of positions attended
-    over, those of the slots whole, the cache rows written and the rows
-    of fixed-size state written. ``init(cfg)``: the state, empty;
+    where a flow begins), ``counts`` a dict of scalars, what the layer
+    attended over and wrote, under the names ``flow_step`` reports
+    (``OPERATOR_COUNTS``, and any of the operator's own), summed over the
+    flows. ``init(cfg)``: the state, empty;
     ``start_of(kept)``: what slot 0 holds of the start token once the
     call that makes the constants (``with_start``) has run; ``scope``: the
     operator's name in a device scope; ``caches``: whether the state
-    grows by a row a position."""
+    holds positions of the flow (a row a position: ``cfg.positions`` of
+    them, or where ``ring`` is set the newest ``ring``, at ``position mod
+    ring``)."""
     apply: Callable
     init: Callable
     start_of: Callable
     scope: str
     caches: bool
+    ring: int = 0
+
+
+# what every flow model's step reports of its operators, nought where no
+# layer counts it: the blocks of positions attended over, those of the
+# slots whole, the cache rows written, those of the touched slots whole,
+# and the rows of fixed-size state written
+OPERATOR_COUNTS = ("attn.kv_blocks", "attn.kv_blocks_whole",
+                   "cache.rows_written", "cache.rows_whole",
+                   "conv.state_rows")
 
 
 # -- weights from the seed ----------------------------------------------------
@@ -386,22 +399,28 @@ def _swiglu(x, gate, up, down):
     return _mm(jax.nn.silu(_mm(x, gate)) * _mm(x, up), down)
 
 
-def yarn_inv_freq(cfg: LatentMoEConfig) -> np.ndarray:
-    """YaRN's blend of the plain and the interpolated frequencies by the
-    linear ramp between the two correction dimensions."""
-    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+def yarn_frequencies(dim: int, base: float, factor: float, original: int,
+                     beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's blend of the plain and the interpolated frequencies of
+    ``dim`` rotated values by the linear ramp between the two correction
+    dimensions."""
     extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
-    inter = extra / cfg.rope_factor
+    inter = extra / factor
 
     def correction(rotations):
-        return (dim * math.log(cfg.rope_original_positions
-                               / (rotations * 2 * math.pi))
+        return (dim * math.log(original / (rotations * 2 * math.pi))
                 / (2 * math.log(base)))
 
-    low = max(math.floor(correction(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction(cfg.rope_beta_slow)), dim - 1)
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), dim - 1)
     ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
     return (inter * ramp + extra * (1 - ramp)).astype(np.float32)
+
+
+def yarn_inv_freq(cfg: LatentMoEConfig) -> np.ndarray:
+    return yarn_frequencies(cfg.qk_rope_head_dim, cfg.rope_theta,
+                            cfg.rope_factor, cfg.rope_original_positions,
+                            cfg.rope_beta_fast, cfg.rope_beta_slow)
 
 
 def softmax_scale(cfg: LatentMoEConfig) -> float:
@@ -455,7 +474,7 @@ def attend_xla(q_abs, q_rope, cache, slot, p0, scale: float):
 
 
 def append_chunk(cache, entry, start_entry, slot, p0, count, begins,
-                 positions_last: bool = False):
+                 positions_last: bool = False, ring: bool = False):
     """The call's entries into the layer's ``cache [slots, positions,
     entry]`` where they belong, and no other row touched: flow ``f``'s
     ``entry[f, t]`` for ``t < count[f]`` at ``(slot[f], p0[f] + t)``, and
@@ -474,36 +493,48 @@ def append_chunk(cache, entry, start_entry, slot, p0, count, begins,
     nothing) reads clipped and writes nothing. ``positions_last``: the
     cache lies ``[slots, entry, positions]`` (as a kernel reads it where
     the compiler would not store it so of itself), and a window is
-    ``[entry, W]`` of it. Returns the cache and the rows written (``W`` a
-    flow whose slot is in range)."""
+    ``[entry, W]`` of it. ``ring``: a slot is a ring of its positions,
+    position ``p`` at ``p mod positions``: a chunk that passes the
+    ring's end goes on at its start, by a second window there (read,
+    set and written back likewise, for the flows whose chunk wraps).
+    Returns the cache and the rows written (``W`` a window of a slot in
+    range)."""
     S, P, E = cache.shape
     if positions_last:
         P, E = E, P
     F, T, _ = entry.shape
     W = min(T + 1, P)
-    w0 = jnp.clip(p0 - 1, 0, P - W)                         # [F]
-    pos = w0[:, None] + jnp.arange(W)[None]                 # [F, W]
-    t = pos - p0[:, None]
 
     def read(s, w):
         if positions_last:
             return jax.lax.dynamic_slice(cache, (s, 0, w), (1, E, W))[0].T
         return jax.lax.dynamic_slice(cache, (s, w, 0), (1, W, E))[0]
 
-    window = jax.vmap(read)(jnp.minimum(slot, S - 1), w0)   # [F, W, E]
-    mine = (t >= 0) & (t < count[:, None])
-    window = jnp.where(mine[..., None], jnp.take_along_axis(
-        entry, jnp.clip(t, 0, T - 1)[..., None], 1), window)
-    window = jnp.where((begins[:, None] & (pos == 0))[..., None],
-                       start_entry[None, None], window)
-    cache = jax.lax.scatter(
-        cache, jnp.stack([slot, w0], -1),
-        window.transpose(0, 2, 1) if positions_last else window,
-        jax.lax.ScatterDimensionNumbers(
-            update_window_dims=(1, 2), inserted_window_dims=(0,),
-            scatter_dims_to_operand_dims=(0, 2 if positions_last else 1)),
-        mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
-    return cache, (slot < S).sum() * W
+    def put(cache, slot, w0):
+        pos = w0[:, None] + jnp.arange(W)[None]             # [F, W]
+        t = (pos - p0[:, None]) % P if ring else pos - p0[:, None]
+        window = jax.vmap(read)(jnp.minimum(slot, S - 1), w0)   # [F, W, E]
+        mine = (t >= 0) & (t < count[:, None])
+        window = jnp.where(mine[..., None], jnp.take_along_axis(
+            entry, jnp.clip(t, 0, T - 1)[..., None], 1), window)
+        window = jnp.where((begins[:, None] & (pos == 0))[..., None],
+                           start_entry[None, None], window)
+        return jax.lax.scatter(
+            cache, jnp.stack([slot, w0], -1),
+            window.transpose(0, 2, 1) if positions_last else window,
+            jax.lax.ScatterDimensionNumbers(
+                update_window_dims=(1, 2), inserted_window_dims=(0,),
+                scatter_dims_to_operand_dims=(0, 2 if positions_last else 1)),
+            mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+
+    if not ring:
+        return (put(cache, slot, jnp.clip(p0 - 1, 0, P - W)),
+                (slot < S).sum() * W)
+    at = (p0 - 1) % P                       # the start token's, or the
+    cache = put(cache, slot, jnp.minimum(at, P - W))    # position before
+    wraps = jnp.where(at + W > P, slot, S)
+    return (put(cache, wraps, jnp.zeros_like(at)),
+            ((slot < S).sum() + (wraps < S).sum()) * W)
 
 
 def angles(pos, inv_freq):
@@ -523,9 +554,10 @@ def _attention(lp, cfg, cache, start_entry, h, call):
     (``attend_xla``'s signature), which takes the appended cache whole
     and the slots' numbers: **no slot is gathered, merged and written
     back here** (PRs 28-30 did: 604 MB of a layer sliced and copied to
-    read 75 MB and write 4.7). Returns the output, the cache, and
-    ``[blocks of positions attended over, those of the slots whole, rows
-    of the cache written, 0]``, summed over the flows."""
+    read 75 MB and write 4.7). Returns the output, the cache, and the
+    blocks of positions attended over, those of the slots whole, the
+    rows of the cache written and those of the touched slots whole
+    (``Operator``'s counts), summed over the flows."""
     F, T, _ = h.shape
     H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
@@ -550,7 +582,10 @@ def _attention(lp, cfg, cache, start_entry, h, call):
     o = jnp.einsum("fthc,chd->fthd", o, wukv[..., nope:],
                    preferred_element_type=jnp.float32)
     return (_mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache,
-            jnp.stack([blocks.sum(), F * whole, written, 0]))
+            {"attn.kv_blocks": blocks.sum(), "attn.kv_blocks_whole": F * whole,
+             "cache.rows_written": written,
+             "cache.rows_whole": (call.slot < cfg.slots).sum()
+             * cfg.positions})
 
 
 LATENT_ATTENTION = Operator(
@@ -567,8 +602,10 @@ def route(lp, cfg, x):
     xr = x.astype(jnp.bfloat16).astype(jnp.float32)
     s = jax.nn.sigmoid(jnp.dot(xr, lp["router"].astype(jnp.float32),
                                precision=jax.lax.Precision.HIGHEST))
-    _, idx = jax.lax.top_k(s + lp["router_bias"].astype(jnp.float32),
-                           cfg.num_experts_per_tok)
+    # the selection's bias where the layer has one; the weights never
+    biased = (s + lp["router_bias"].astype(jnp.float32)
+              if "router_bias" in lp else s)
+    _, idx = jax.lax.top_k(biased, cfg.num_experts_per_tok)
     sel = jnp.take_along_axis(s, idx, -1)
     return idx, (sel / (sel.sum(-1, keepdims=True) + cfg.route_eps)
                  * cfg.routed_scaling_factor)
@@ -700,20 +737,21 @@ def _forward(params, cfg, operators, kept, starts, tok, call):
     the layer has one), both residual.
     Returns the final normed hidden ``[F, T, hidden]`` float32, the
     layers' state with the chunks applied, tokens per held expert
-    ``[expert layers, G]``, the operators' tallies ``[4]``
-    (``Operator``), summed over flows and layers, and the expert layers'
-    ``weight_loads`` (``routed_experts``), summed."""
+    ``[expert layers, G]``, the operators' counts (``Operator``), summed
+    over flows and layers, and the expert layers' ``weight_loads``
+    (``routed_experts``), summed."""
     F, T = tok.shape
     h = params["embed"][tok].astype(jnp.float32)
     valid = jnp.arange(T)[None] < call.count[:, None]
-    counts, kept, tally = [], list(kept), jnp.zeros((4,), jnp.int32)
+    counts, kept, tally = [], list(kept), {}
     loads = jnp.int32(0)
     for l, (lp, op) in enumerate(zip(params["layers"], operators)):
         with jax.named_scope(f"layer{l}.{op.scope}"):
             a, kept[l], layer_tally = op.apply(lp, cfg, kept[l], starts[l],
                                                h, call)
             h = h + a
-            tally = tally + layer_tally
+            for name, v in layer_tally.items():
+                tally[name] = tally.get(name, 0) + v
         with jax.named_scope(f"layer{l}.ffn"):
             x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
             if "router" in lp:
@@ -824,11 +862,8 @@ def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
               "moe.tiles": ((expert_tokens + M - 1) // M).sum(),
               "moe.weight_loads": loads,
               "cache.positions": length.sum(),
-              "attn.kv_blocks": tally[0],
-              "attn.kv_blocks_whole": tally[1],
-              "cache.rows_written": tally[2],
-              "cache.rows_whole": (flow.sum() * P
-                                   * sum(op.caches for op in operators)),
-              "conv.state_rows": tally[3],
+              **{name: tally.get(name, jnp.int32(0))
+                 for name in OPERATOR_COUNTS},
+              **tally,
               "expert_tokens": expert_tokens}
     return scores, (kept, length, last_h, (starts, start_h)), counts

@@ -34,7 +34,9 @@ side**. Per layer ``h += Op(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``;
   layer, every call). ``append_chunk`` writes the call's entries into it
   in place, then the chunk attends over its flow's slot by the step's
   ``attend``: ``grouped_attention_fused`` on a TPU, ``attend_grouped_xla``
-  elsewhere.
+  elsewhere. The operator is ``models/grouped_attention.py``'s, which
+  another model's layers take with other head counts, a rotary part and
+  a window: this model's instance of it is ``Lfm2MoEConfig.operator``'s.
 
 The first ``num_dense_layers`` feed-forwards are a dense SwiGLU; the
 others route: ``sigmoid`` scores over ``n_routed_experts``, the top
@@ -49,13 +51,15 @@ import math
 from dataclasses import dataclass
 from typing import Dict, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
+# noqa: F401 below: the XLA attention is this model's too, by this name
+from linkerd_tpu.models.grouped_attention import (  # noqa: F401
+    AttentionLayer, attend_grouped_xla, grouped_attention,
+)
 from linkerd_tpu.models.latent_moe import (
-    ATTENTION_BLOCK, GAIN_SPREAD, OUT_GAIN, Operator, _mm, _rms,
-    _rope, angles, append_chunk, check_held,
+    GAIN_SPREAD, OUT_GAIN, Operator, _mm, _rms, check_held,
 )
 
 CONV, ATTENTION = "conv", "full_attention"
@@ -109,7 +113,14 @@ class Lfm2MoEConfig:
         return 2 * self.num_key_value_heads * self.head_dim
 
     def operator(self, l: int) -> Operator:
-        return SHORT_CONV if self.layer_types[l] == CONV else GROUPED_ATTENTION
+        """A convolution, or this model's instance of the grouped-query
+        attention (``models/grouped_attention.py``): one head count, RoPE
+        of the default kind over the whole head, no window."""
+        if self.layer_types[l] == CONV:
+            return SHORT_CONV
+        return grouped_attention(AttentionLayer(
+            self.num_attention_heads, self.num_key_value_heads,
+            self.head_dim, rope_inv_freq(self)))
 
     def tensors(self) -> Dict[str, tuple]:
         return tensor_table(self)
@@ -206,7 +217,8 @@ def _short_conv(lp, cfg, tail, start_tail, h, call):
     the slot is left the last ``K - 1`` rows of tail and chunk together
     (``count`` events of it: padding rows are not the flow's). A
     ``slot`` out of range reads clipped and writes nothing. Returns the
-    output, the tails, and ``[0, 0, 0, rows of tail written]``."""
+    output, the tails, and the rows of tail written
+    (``conv.state_rows``)."""
     F, T, D = h.shape
     S, K = cfg.slots, cfg.conv_L_cache
     x = _rms(h, lp["operator_norm"], cfg.rms_norm_eps)
@@ -223,7 +235,7 @@ def _short_conv(lp, cfg, tail, start_tail, h, call):
         seq, (call.count[:, None] + jnp.arange(K - 1)[None])[..., None], 1)
     tail = tail.at[call.slot].set(left.astype(jnp.bfloat16), mode="drop")
     return (_mm(gate_out * v, lp["out_proj"]), tail,
-            jnp.stack([0, 0, 0, (call.slot < S).sum() * (K - 1)]))
+            {"conv.state_rows": (call.slot < S).sum() * (K - 1)})
 
 
 SHORT_CONV = Operator(
@@ -237,85 +249,3 @@ def rope_inv_freq(cfg: Lfm2MoEConfig) -> np.ndarray:
     dim = cfg.head_dim
     return (1.0 / cfg.rope_theta ** (
         np.arange(0, dim, 2, dtype=np.float64) / dim)).astype(np.float32)
-
-
-def _grouped_attention(lp, cfg, cache, start_entry, h, call):
-    """``h [F, T, hidden]`` the residual stream; ``cache [slots, entry,
-    positions]`` this layer's, donated: a position's normed, rotated keys
-    (``kv heads x head``) and then its values. As the latent operator:
-    the chunk's entries are appended in place, then the chunk attends
-    over its flow's slot by ``call.attend`` (``attend_grouped_xla``'s
-    signature). Returns the output, the cache, and ``[blocks of
-    positions attended over, those of the slots whole, rows of the cache
-    written, 0]``."""
-    F, T, D = h.shape
-    H, G, hd = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    eps = cfg.rms_norm_eps
-    x = _rms(h, lp["operator_norm"], eps)
-    cos, sin = angles(call.pos, rope_inv_freq(cfg))
-    cos, sin = cos[:, :, None], sin[:, :, None]
-    q = _rope(_rms(_mm(x, lp["wq"]).reshape(F, T, H, hd), lp["q_norm"], eps),
-              cos, sin)
-    k = _rope(_rms(_mm(x, lp["wk"]).reshape(F, T, G, hd), lp["k_norm"], eps),
-              cos, sin)
-    entry = jnp.concatenate([k.reshape(F, T, G * hd), _mm(x, lp["wv"])],
-                            -1).astype(jnp.bfloat16)
-    cache, written = append_chunk(cache, entry, start_entry, call.slot,
-                                  call.p0, call.count, call.begins,
-                                  positions_last=True)
-    o, blocks, whole = call.attend(q.astype(jnp.bfloat16), cache, call.slot,
-                                   call.p0, hd ** -0.5)
-    return (_mm(o.reshape(F, T, D), lp["wo"]), cache,
-            jnp.stack([blocks.sum(), F * whole, written, 0]))
-
-
-GROUPED_ATTENTION = Operator(
-    apply=_grouped_attention,
-    init=lambda cfg: jnp.zeros((cfg.slots, cfg.entry_width, cfg.positions),
-                               jnp.bfloat16),
-    start_of=lambda cache: cache[0, :, 0], scope="attention", caches=True)
-
-
-def attend_grouped_xla(q, cache, slot, p0, scale: float):
-    """Grouped-query attention as XLA does it, ``ATTENTION_BLOCK`` flows'
-    whole score tensor at a time: the path of every platform but the TPU,
-    and what ``ops/flow_attention.grouped_attention_fused`` is tested
-    against. ``q [F, T, H, head]`` bfloat16; ``cache [slots, 2 x G x head,
-    positions]`` the layer's, whole: flow ``f`` attends over slot
-    ``slot[f]`` (clipped into range, gathered here), query head ``i``
-    against the keys ``[i // (H / G)]`` and the values ``[G + i // (H /
-    G)]`` of its ``head`` rows; event ``t`` sees positions ``0 .. p0[f] +
-    t``. Returns ``(o [F, T, H, head]`` bfloat16, the blocks of positions
-    attended over ``[F]``, the blocks of a whole slot)``: every slot is
-    attended whole, as one block."""
-    F, T, H, hd = q.shape
-    S, E, P = cache.shape
-    G = E // (2 * hd)
-    R = H // G
-    # one batch axis (flow, key/value head), positions before the head's
-    # width: the products XLA:CPU runs in bfloat16
-    kv = cache[jnp.minimum(slot, S - 1)].reshape(F, 2, G, hd, P).transpose(
-        0, 1, 2, 4, 3)
-    q = q.reshape(F, T, G, R, hd).transpose(0, 2, 1, 3, 4)  # [F, G, T, R, hd]
-
-    def attend(block):
-        q, kv, pos = block
-        nb = q.shape[0]
-        s = jnp.einsum("bqd,bpd->bqp", q.reshape(nb * G, T * R, hd),
-                       kv[:, 0].reshape(nb * G, P, hd),
-                       preferred_element_type=jnp.float32) * scale
-        seen = jnp.arange(P)[None, None] <= pos[:, :, None]     # [nb, T, P]
-        s = jnp.where(seen[:, None, :, None], s.reshape(nb, G, T, R, P),
-                      -jnp.inf)
-        p = jax.nn.softmax(s, -1).reshape(nb * G, T * R, P)
-        return jnp.einsum("bqp,bpd->bqd", p.astype(jnp.bfloat16),
-                          kv[:, 1].reshape(nb * G, P, hd),
-                          preferred_element_type=jnp.float32
-                          ).astype(jnp.bfloat16).reshape(nb, G, T, R, hd)
-
-    nb = min(ATTENTION_BLOCK, F)
-    o = jax.lax.map(attend, jax.tree_util.tree_map(
-        lambda a: a.reshape(F // nb, nb, *a.shape[1:]),
-        (q, kv, p0[:, None] + jnp.arange(T)[None])))
-    return (o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(
-        F, T, H, hd), jnp.ones((F,), jnp.int32), 1)

@@ -24,14 +24,17 @@ that lays a call's rows out for the step (``map`` -> a plan with ``rows``,
 ``keyed``: its calls apply in the order they were made, and ``describe``
 reports the table and the newest call's counts for ``device_state()``.
 
-Three instances: ``mlp36`` (the 36-column autoencoder + classifier: its
+Four instances: ``mlp36`` (the 36-column autoencoder + classifier: its
 state is the normalisation triple ``(mu, var, initialised)``, which a fit
 repoints and a score step only reads; trains online; sharded over a mesh)
 and the flow models ``latent_moe`` (latent attention over a per-flow
-cache, routed experts beside a shared one) and ``lfm2_moe`` (short
+cache, routed experts beside a shared one), ``lfm2_moe`` (short
 convolutions among grouped-query attention layers, so two kinds of
-per-flow state, routed experts alone): keyed, frozen, single-device, one
-step (``models/latent_moe.flow_step``) over either's layers.
+per-flow state, routed experts alone) and ``laguna_moe`` (window and full
+attention layers mixed: a ring of the newest positions beside a cache of
+them all, routed experts beside a shared one): keyed, frozen,
+single-device, one step (``models/latent_moe.flow_step``) over any's
+layers.
 """
 
 from __future__ import annotations
@@ -126,10 +129,15 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
         from linkerd_tpu.ops.expert_product import (
             best_expert_product, expert_product_kind)
         from linkerd_tpu.ops.flow_attention import (
-            attention_kind, best_attention)
+            attention_call, attention_kind, best_attention)
         attend = best_attention(platform, grouped)
         experts = best_expert_product(platform)
         built["attention"] = attention_kind(platform)
+        built["state"] = {
+            op.scope: {"positions": op.ring or cfg.positions,
+                       "call": attention_call(platform, grouped,
+                                              bool(op.ring))}
+            for op in map(cfg.operator, range(cfg.layers)) if op.caches}
         built["expert_product"] = expert_product_kind(platform)
         # the state and the staged rows are the program's to reuse
         program = jax.jit(
@@ -155,6 +163,8 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
             "experts_held": list(cfg.experts_held),
             "layer_share": cfg.layer_share,
             "attention": built.get("attention"),
+            # a kind of layer: the positions a slot keeps, the call made
+            "state": built.get("state"),
             "expert_product": built.get("expert_product"),
             "resident": len(table.slot_of),
             "layouts": {f"{f}x{t}": c
@@ -189,4 +199,14 @@ def lfm2_moe(cfg=None) -> ModelSpec:
                        cfg if cfg is not None else Lfm2MoEConfig(), True)
 
 
-SPECS = {"mlp36": mlp36, "latent_moe": latent_moe, "lfm2_moe": lfm2_moe}
+def laguna_moe(cfg=None) -> ModelSpec:
+    """The flow model of ``models/laguna_moe.py``: window and full
+    attention layers mixed (a ring beside a cache), routed experts beside
+    a shared one, the whole vocabulary through a head of its own."""
+    from linkerd_tpu.models.laguna_moe import LagunaMoEConfig
+    return _flow_model("laguna_moe",
+                       cfg if cfg is not None else LagunaMoEConfig(), True)
+
+
+SPECS = {"mlp36": mlp36, "latent_moe": latent_moe, "lfm2_moe": lfm2_moe,
+         "laguna_moe": laguna_moe}

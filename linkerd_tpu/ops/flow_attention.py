@@ -1,7 +1,7 @@
 """Fused attention over a flow's slot of the cache: one Pallas kernel, for
 the latent attention of ``models/latent_moe.py`` (told first, as it was
-built) and for the grouped-query attention of ``models/lfm2_moe.py`` (at the
-end).
+built) and for the grouped-query attention of
+``models/grouped_attention.py`` (at the end).
 
 ``models/latent_moe._attention`` absorbs ``wukv`` into the query, so every
 head of a flow attends over the one latent ``[positions, rank]`` and the
@@ -72,6 +72,26 @@ the published sizes, fetched once a cell. At a head of 64 the lanes of the
 queries and the output are half used and the first product contracts
 over 64: the kernel's pace there is its grid cells' fetches, not the MXU.
 
+**Longer slots, wider heads, a window** (PR 34). At a head of 128 the
+lanes are full and the first product contracts over 128. A slot of 4,096
+positions is a group's keys and values of 1 MiB each, still one block of
+the cache, and the tile's scores ``[rows, positions]`` are what bounds
+the tile: the events a tile holds halve until they fit ``SCORE_BYTES``
+(a group of 6 heads: 32 events, 192 rows, 3 MiB). **A window** is the same
+body with a second bound: a tile's loops *begin* at the block of the
+first position its first event sees (``block_range``), and the blocks
+that reach behind the last event's window are masked there too. The
+slot is then a ring (``models/grouped_attention.py``): it is as many
+blocks as the ring has, block ``b`` of the flow's positions lies at ``b
+mod`` that, and **the mask is the plain one in the flow's positions**:
+what a ring block holds in place of a position that has been written
+over, or not yet written, is outside every row's window by the ring's
+size. A tile's span of ``window - 1 + events`` positions can touch one
+block more than the ring has (the first and the last then the same ring
+block, under two labels), so the scores' scratch has a block to spare.
+The call is named ``window_attention_fused``, so a trace tells the two
+apart.
+
 ``best_attention`` selects by platform as ``ops/scoring.best_scorer``
 does: this kernel on ``tpu``, the XLA path elsewhere. There is no probe
 and no fallback: a kernel that Mosaic refuses on the chip is an error the
@@ -108,6 +128,17 @@ def blocks_seen(first, events: int, P: int):
     return (jnp.minimum(first + events, P) + bk - 1) // bk
 
 
+def block_range(first, events: int, P: int, window: int):
+    """``(lo, hi)``: the blocks ``[lo, hi)`` **of the flow's positions**
+    that hold one which ``events`` events appended at position ``first``
+    may see through a window of ``window`` (positions ``first - window +
+    1 .. first + events - 1``, from 0); block ``b`` lies in the ring of
+    ``P`` at ``b mod (P / block)``. At most one more than the ring has."""
+    bk = kv_block(P)
+    return (jnp.maximum(first - window + 1, 0) // bk,
+            (first + events + bk - 1) // bk)
+
+
 def _events_a_tile(T: int, H: int, P: int) -> int:
     """Whole events a tile of query rows holds: ``T`` halved until the
     tile's rows and its scores fit."""
@@ -121,37 +152,54 @@ POSITION_AXES = (((1,), (1,)), ((), ()))    # p [rows, pos] . c [rank, pos]
 
 
 def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
-            values_apart: bool):
+            values_apart: bool, window):
     """One flow, one group of heads, one tile of its query rows. ``refs``:
     the queries in ``len(widths)`` parts, part ``j`` ``widths[j]`` wide and
     scored against the key rows that follow those of the parts before it;
     the keys ``[key rows, positions]``; the values ``[value rows,
     positions]`` where they are ``values_apart`` (else they are the first
     of the key rows); the output; the scratch. ``heads``: query rows an
-    event."""
+    event. ``window``: None, or the positions a row sees, itself
+    included, of a slot that is a ring."""
     del slot_ref    # the block specs' alone: which slot the keys are of
     q_refs, refs = refs[:len(widths)], refs[len(widths):]
     k_ref, v_ref = refs[0], refs[1 if values_apart else 0]
     o_ref, s_ref, m_ref, l_ref, acc_ref = refs[-5:]
     f, i = pl.program_id(0), pl.program_id(2)
-    rows, P = s_ref.shape
+    rows, P = s_ref.shape[0], k_ref.shape[-1]
     events, bk, vd = rows // heads, m_ref.shape[1], acc_ref.shape[1]
     first = p0_ref[f] + i * events      # position of the tile's first event
-    last = blocks_seen(first, events, P)
+    if window is None:
+        lo, last = 0, blocks_seen(first, events, P)
+    else:
+        lo, last = block_range(first, events, P, window)
     qs = [q_ref[0, 0] for q_ref in q_refs]
     at_row = [sum(widths[:j]) for j in range(len(widths))]
 
+    def places(j):
+        """Where block ``j``'s scores lie in the scratch and its keys and
+        values in the slot: the same place, but in a ring."""
+        if window is None:
+            at = pl.multiple_of(j * bk, bk)
+            return at, at
+        return (pl.multiple_of((j - lo) * bk, bk),
+                pl.multiple_of(jax.lax.rem(j, P // bk) * bk, bk))
+
     def score(j, masked):
-        at = pl.multiple_of(j * bk, bk)
+        at, held = places(j)
         s = functools.reduce(jnp.add, (
-            jnp.dot(q, k_ref[0, a:a + w, pl.ds(at, bk)],
+            jnp.dot(q, k_ref[0, a:a + w, pl.ds(held, bk)],
                     preferred_element_type=jnp.float32)
             for q, a, w in zip(qs, at_row, widths))) * scale
         if masked:
             pos = first + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 0) // heads
-            col = at + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(col <= pos, s, MASKED)
+            col = (at if window is None else j * bk
+                   ) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            seen = col <= pos
+            if window is not None:
+                seen &= col > pos - window
+            s = jnp.where(seen, s, MASKED)
         s_ref[:, pl.ds(at, bk)] = s
         m_ref[...] = jnp.maximum(m_ref[...], s)     # lane by lane
 
@@ -159,49 +207,63 @@ def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
     # blocks that end at or before the tile's first position hide nothing
     # from any of its rows
     clear = jnp.minimum((first + 1) // bk, last)
-    jax.lax.fori_loop(0, clear, lambda j, _: score(j, False), None)
+    if window is None:
+        begin = 0
+    else:
+        # nor do those that begin inside the last row's window
+        begin = jnp.clip(
+            (jnp.maximum(first + events - window, 0) + bk - 1) // bk, lo,
+            clear)
+        jax.lax.fori_loop(lo, begin, lambda j, _: score(j, True), None)
+    jax.lax.fori_loop(begin, clear, lambda j, _: score(j, False), None)
     jax.lax.fori_loop(clear, last, lambda j, _: score(j, True), None)
-    # each row's maximum, in every lane (position 0 is seen by every row,
-    # so it is a score and not MASKED)
+    # each row's maximum, in every lane (every row sees a position: 0, or
+    # with a window its own; so it is a score and not MASKED)
     m_ref[...] = jnp.broadcast_to(m_ref[...].max(-1, keepdims=True),
                                   m_ref.shape)
     l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def weigh(j, _):
-        at = pl.multiple_of(j * bk, bk)
+        at, held = places(j)
         p = jnp.exp(s_ref[:, pl.ds(at, bk)] - m_ref[...])
         l_ref[...] += p         # lane by lane: summed across once, below
-        ct = v_ref[0, :vd, pl.ds(at, bk)]
+        ct = v_ref[0, :vd, pl.ds(held, bk)]
         acc_ref[...] += jax.lax.dot_general(
             p.astype(ct.dtype), ct, POSITION_AXES,
             preferred_element_type=jnp.float32)
 
-    jax.lax.fori_loop(0, last, weigh, None)
+    jax.lax.fori_loop(lo, last, weigh, None)
     o_ref[0, 0] = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
                    ).astype(o_ref.dtype)
 
 
 def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
-            interpret: bool, name: str):
+            interpret: bool, name: str, window=None):
     """The kernel over ``parts``: the queries ``[F, G, T * heads, width]``
     a part, ``G`` groups of heads each with keys and values of its own;
     ``kt [slots, rows, P]``, the layer's cache, positions last: group
     ``g``'s keys are its rows ``[g * kd, (g + 1) * kd)``, ``kd`` the
     parts' widths together, and its values the ``vd`` rows of block
     ``values_at + g`` (in blocks of ``vd`` rows), or, where ``values_at``
-    is None, the first ``vd`` of its key rows. Returns ``(o [F, G, T *
-    heads, vd]``, the blocks of positions attended over ``[F]``, those of
-    a slot whole)``."""
+    is None, the first ``vd`` of its key rows. ``window``: None, or the
+    positions an event sees of a slot that is a ring. Returns ``(o [F, G,
+    T * heads, vd]``, the blocks of positions attended over ``[F]``,
+    those of a slot whole)``, and with a window those with no window
+    ``[F]``, a fourth."""
     F, G, rows_all, _ = parts[0].shape
     S, _, P = kt.shape
     heads = rows_all // T
     widths = tuple(q.shape[-1] for q in parts)
     bk, events = kv_block(P), _events_a_tile(T, heads, P)
     rows, tiles = events * heads, T // events
+    if window is not None and window - 1 + events > P:
+        raise ValueError(f"{events} events behind a window of {window} do "
+                         f"not fit a ring of {P}")
     kernel = functools.partial(_kernel, scale=scale, heads=heads,
                                widths=widths,
-                               values_apart=values_at is not None)
+                               values_apart=values_at is not None,
+                               window=window)
     # the flow's slot where it lies, positions along the lanes: the same
     # block for all of a flow's (and a group's) tiles, so it is fetched
     # once
@@ -220,7 +282,10 @@ def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
                       for w in widths] + kv_specs,
             out_specs=pl.BlockSpec((1, 1, rows, vd),
                                    lambda f, g, i, slot, p0: (f, g, i, 0)),
-            scratch_shapes=[pltpu.VMEM((rows, P), jnp.float32),
+            # a window's span may touch one block more than the ring has
+            scratch_shapes=[pltpu.VMEM(
+                                (rows, P if window is None else P + bk),
+                                jnp.float32),
                             pltpu.VMEM((rows, bk), jnp.float32),
                             pltpu.VMEM((rows, bk), jnp.float32),
                             pltpu.VMEM((rows, vd), jnp.float32)]),
@@ -232,9 +297,13 @@ def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
         name=name,
     )(jnp.minimum(slot, S - 1).astype(jnp.int32), p0.astype(jnp.int32),
       *parts, *[kt] * len(kv_specs))
-    attended = sum(blocks_seen(p0 + i * events, events, P)
-                   for i in range(tiles))
-    return o, attended, tiles * (P // bk)
+    firsts = [p0 + i * events for i in range(tiles)]
+    if window is None:
+        return (o, sum(blocks_seen(first, events, P) for first in firsts),
+                tiles * (P // bk))
+    ranges = [block_range(first, events, P, window) for first in firsts]
+    return (o, sum(hi - lo for lo, hi in ranges), tiles * (P // bk),
+            sum(hi for _, hi in ranges))
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -261,29 +330,32 @@ def latent_attention_fused(q_abs, q_rope, cache, slot, p0, scale: float,
     return o.reshape(F, T, H, rank), attended, whole
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def grouped_attention_fused(q, cache, slot, p0, scale: float,
-                            interpret: bool = False):
+                            interpret: bool = False, window=None):
     """Grouped-query attention by the same kernel: ``q [F, T, H, head]``
     bfloat16; ``cache [slots, 2 x G x head, P]`` bfloat16, the layer's,
-    whole and **positions last** (``models/lfm2_moe.py`` keeps it so),
-    a position's keys of ``G`` key/value heads and then its values. A
-    grid cell is one flow, one key/value head and a tile of the ``H / G``
-    query heads' rows that attend over it; the block specs take that
-    head's ``head`` key rows and ``head`` value rows from the flow's slot.
-    Returns what ``models.lfm2_moe.attend_grouped_xla`` returns: ``(o [F,
-    T, H, head]``, the blocks attended over ``[F]``, those of a slot
-    whole)``."""
+    whole and **positions last** (``models/grouped_attention.py`` keeps
+    it so), a position's keys of ``G`` key/value heads and then its
+    values. A grid cell is one flow, one key/value head and a tile of the
+    ``H / G`` query heads' rows that attend over it; the block specs take
+    that head's ``head`` key rows and ``head`` value rows from the flow's
+    slot. With a ``window`` the slot is a ring and the call is named
+    ``window_attention_fused``. Returns what
+    ``models.grouped_attention.attend_grouped_xla`` returns: ``(o [F, T,
+    H, head]``, the blocks attended over ``[F]``, those of a slot
+    whole)``, and with a window those with no window ``[F]``."""
     F, T, H, hd = q.shape
     G = cache.shape[1] // (2 * hd)
     R = H // G
-    o, attended, whole = _attend(
+    o, *blocks = _attend(
         [q.reshape(F, T, G, R, hd).transpose(0, 2, 1, 3, 4).reshape(
             F, G, T * R, hd)],
         cache, slot, p0, T=T, values_at=G, vd=hd, scale=scale,
-        interpret=interpret, name="grouped_attention_fused")
+        interpret=interpret, window=window,
+        name=("grouped" if window is None else "window") + "_attention_fused")
     return (o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(
-        F, T, H, hd), attended, whole)
+        F, T, H, hd), *blocks)
 
 
 def attention_kind(platform: str) -> str:
@@ -292,16 +364,26 @@ def attention_kind(platform: str) -> str:
     return "fused_pallas" if platform == "tpu" else "xla"
 
 
+def attention_call(platform: str, grouped: bool, windowed: bool) -> str:
+    """The name of the call a layer's attention makes on ``platform``."""
+    if attention_kind(platform) != "fused_pallas":
+        return "attend_grouped_xla" if grouped else "attend_xla"
+    if not grouped:
+        return "latent_attention_fused"
+    return ("window" if windowed else "grouped") + "_attention_fused"
+
+
 def best_attention(platform: str, grouped: bool = False):
     """The flow step's ``attend`` for parameters living on ``platform``:
     the fused kernel on ``tpu``, the XLA path elsewhere (an interpreted
     kernel is far too slow to serve); ``grouped``: for grouped-query
-    attention over keys and values (``models/lfm2_moe.py``), else for the
-    latent attention (``models/latent_moe.py``)."""
+    attention over keys and values (``models/grouped_attention.py``: a
+    layer with a window hands it ``window=``), else for the latent
+    attention (``models/latent_moe.py``)."""
     if attention_kind(platform) == "fused_pallas":
         return grouped_attention_fused if grouped else latent_attention_fused
     if grouped:
-        from linkerd_tpu.models.lfm2_moe import attend_grouped_xla
+        from linkerd_tpu.models.grouped_attention import attend_grouped_xla
         return attend_grouped_xla
     from linkerd_tpu.models.latent_moe import attend_xla
     return attend_xla

@@ -846,6 +846,11 @@ class JaxAnomalyConfig:
     # from the seed: keyed rows are scored by both tiers, the flow tier's
     # scores are counted (flow_shadow_total) and not published.
     # Single-device: the first chip, whatever the host has.
+    # "lfm2_moe", "laguna_moe": the same tier over another model's layers
+    # (models/lfm2_moe.py: short convolutions among attention layers;
+    # models/laguna_moe.py: window and full attention layers mixed, a
+    # ring of the newest positions beside a cache of them all), by the
+    # same step, table and dispatcher.
     model: str = "mlp36"
     # line-rate micro-batcher: drain is size- and deadline-triggered —
     # a batch dispatches when maxBatch rows are pending OR the oldest

@@ -571,6 +571,222 @@ class TestScorerSelection:
         assert proc.stdout.count("LAYOUT") == 5
 
 
+    @pytest.fixture(scope="class")
+    def laguna_step_compiled(self):
+        """The same step over the third flow model's layers
+        (``models/laguna_moe.py``) as the benchmark's cell
+        ``laguna-xs.2.flows64x64-long`` runs it: the published widths
+        (hidden 2,048, heads of 128, 48 or 64 of them over 8, 256 of 256
+        experts held beside a shared one, the whole vocabulary through a
+        head of its own), 5 layers with two kinds of state (2 caches
+        ``[128, 2048, 4224]``, 3 rings ``[128, 2048, 640]``), 64 flows x
+        64 events, the attention a TPU gets. The child prints what
+        ``lfm2_step_compiled`` prints: every instruction that makes an
+        array of a cache's, a ring's, the embedding's, the head's or the
+        whole logits' size."""
+        code = (
+            "import json, re, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models import latent_moe as lm\n"
+            "from linkerd_tpu.models import laguna_moe as lg\n"
+            "from linkerd_tpu.ops.expert_product import (\n"
+            "    best_expert_product)\n"
+            "from linkerd_tpu.ops.flow_attention import best_attention\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "with open('chipbench/configs/laguna-xs.2.json') as f:\n"
+            "    cfg = lg.LagunaMoEConfig.from_config(json.load(f))\n"
+            "assert cfg == lg.LagunaMoEConfig()\n"
+            "held = cfg.experts_held[1] - cfg.experts_held[0]\n"
+            "params = {'layers': [{} for _ in range(cfg.layers)]}\n"
+            "for name, (shape, _, _, each) in cfg.tensors().items():\n"
+            "    a = S(jnp.bfloat16, *((held,) if each else ()), *shape)\n"
+            "    p = name.split('.')\n"
+            "    if p[0] == 'layers': params['layers'][int(p[1])][p[2]] = a\n"
+            "    else: params[name] = a\n"
+            "place = lambda t: jax.tree_util.tree_map(\n"
+            "    lambda a: S(a.dtype, *a.shape), t)\n"
+            "state = place(jax.eval_shape(\n"
+            "    lambda: lm.init_state(cfg)))[:3] + (\n"
+            "    place(lm.start_shapes(cfg)),)\n"
+            "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
+            "               static_argnames=('cfg', 'F', 'T', 'attend',\n"
+            "                                'experts'))\n"
+            "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
+            "               S(jnp.int32), cfg=cfg, F=64, T=64,\n"
+            "               attend=best_attention('tpu', True),\n"
+            "               experts=best_expert_product('tpu')).compile()\n"
+            "m = c.memory_analysis()\n"
+            "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
+            "      m.alias_size_in_bytes)\n"
+            "text = c.as_text()\n"
+            "print('KERNELS', text.count(\n"
+            "    'custom_call_target=\"tpu_custom_call\"'))\n"
+            "for name in ('grouped', 'window'):\n"
+            "    print('NAMED', name, len(re.findall(\n"
+            "        rf'^\\s*%{name}_attention_fused[\\w.]* = ', text,\n"
+            "        re.M)))\n"
+            + EXPERTS_PATTERNS +
+            "S_, P, R, E = cfg.slots, cfg.positions, cfg.ring, cfg.entry_width\n"
+            "V = cfg.vocab_slice\n"
+            "whole = re.compile(rf'bf16\\[{S_},(\\d+),(?:{P}|{R})\\]|'\n"
+            "                   rf'bf16\\[{S_},{E},(\\d+)\\]|'\n"
+            "                   rf'(?:bf16|f32)\\[{V},(\\d+)\\]|'\n"
+            "                   rf'(?:bf16|f32)\\[(\\d+),{V}\\]')\n"
+            "for line in text.splitlines():\n"
+            "    name, eq, rest = line.strip().partition(' = ')\n"
+            "    if not eq or name.startswith('//'): continue\n"
+            "    if rest.startswith('('):\n"
+            "        depth = 0\n"
+            "        for i, ch in enumerate(rest):\n"
+            "            depth += (ch == '(') - (ch == ')')\n"
+            "            if depth == 0: break\n"
+            "        typ, rest = rest[:i + 1], rest[i + 2:]\n"
+            "    else:\n"
+            "        typ, _, rest = rest.partition(' ')\n"
+            "    if any(int(next(x for x in g if x)) >= 640\n"
+            "           for g in whole.findall(typ)):\n"
+            "        op = rest.split('(', 1)[0]\n"
+            "        if op == 'fusion' and 'calls=%bitcast_fusion' in rest:\n"
+            "            op = 'bitcast'     # a fusion of a bitcast alone\n"
+            "        print('WHOLE', op, name, typ[:80])\n"
+            + EXPERTS_SEEN +
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=900,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        return proc.stdout
+
+    def test_laguna_step_compiles_for_v5e_at_the_published_widths(
+            self, laguna_step_compiled):
+        """It fits one v5e (15.75 GiB) with all 5 layers' weights and both
+        kinds of state as arguments, the state is updated in place
+        (aliased), and each kind of layer makes the call named for it:
+        two ``grouped_attention_fused``, three ``window_attention_fused``."""
+        args, temp, alias = (int(v) for v in next(
+            line for line in laguna_step_compiled.splitlines()
+            if line.startswith("BYTES")).split()[1:])
+        # weights 7.74 GB; caches 2 x 2.21 GB, rings 3 x 0.34 GB: with all
+        # five layers as caches the state alone would be 11.07 GB
+        assert 13.0e9 < args < 13.4e9 and alias > 5.4e9
+        # the float32 logits of 4,096 events over 100,352 ids would be 1.64
+        # GB at once: 0.45 GiB of temporaries in all (my compile-only
+        # reading, PR 34)
+        assert temp < 0.6 * 2 ** 30 and args + temp < 15.75 * 2 ** 30
+        # five attention layers; four expert layers of two kernels each
+        assert "KERNELS 13" in laguna_step_compiled
+        assert "NAMED grouped 2" in laguna_step_compiled
+        assert "NAMED window 3" in laguna_step_compiled
+
+    def test_laguna_step_runs_the_experts_as_one_grouped_product(
+            self, laguna_step_compiled):
+        """No instruction under ``expert_tiles`` makes one expert's matrix
+        (2 MB each of three), and the rows that are made are a run's, 192
+        tiles (24,576 rows of 2,048 float32, 201 MB), not the sort's worst
+        case of 512 tiles."""
+        lines = laguna_step_compiled.splitlines()
+        assert not [l for l in lines if l.startswith("EXPERT_MATRIX")]
+        rows = [l for l in lines if l.startswith("ROWS")]
+        assert rows and all("[24576,2048]" in l for l in rows), rows
+
+    def test_laguna_step_makes_no_copy_of_a_layers_cache_or_ring(
+            self, laguna_step_compiled):
+        """A cache and a ring lie ``[slots, entry, positions]`` as the
+        model keeps them and the optimised program names them only to
+        pass them on (a ring's append is two ``dynamic-update-slice``
+        loops, one for the chunks that wrap); no array of the
+        embedding's or the head's size is made, nor the logits of more
+        than a block of events."""
+        seen = [line.split()[1:] for line in laguna_step_compiled.splitlines()
+                if line.startswith("WHOLE")]
+        assert len(seen) >= 7       # five layers' state, embedding, head
+        passes_on = {"parameter", "get-tuple-element", "bitcast", "tuple",
+                     "while", "dynamic-update-slice", "custom-call"}
+        made = [s for s in seen if s[0] not in passes_on]
+        assert not made, made[:10]
+        assert sum(s[0] == "dynamic-update-slice" for s in seen) == 2 + 3 * 2
+
+    def test_laguna_kernels_compile_for_v5e_at_every_kind_of_layout(self):
+        """Both attention calls alone at the published sizes (48 query
+        heads over 8 of 128 against a cache ``[128, 2048, 4224]``; 64
+        against a ring ``[128, 2048, 640]`` through a window of 512) in
+        the layouts ``FlowTable`` makes and the ring's slack admits, and
+        the grouped product and the combine at this model's widths: 256
+        experts of 2,048 x 512, one block an expert, in the longest run of
+        192 tiles and a run of one."""
+        code = (
+            "import functools, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.ops.expert_product import (\n"
+            "    add_rows_fused, column_block, row_block,\n"
+            "    swiglu_tiles_fused)\n"
+            "from linkerd_tpu.ops.flow_attention import (\n"
+            "    grouped_attention_fused)\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "bf = functools.partial(S, jnp.bfloat16)\n"
+            "full = jax.jit(functools.partial(grouped_attention_fused,\n"
+            "                                 scale=128 ** -0.5))\n"
+            "ring = jax.jit(functools.partial(grouped_attention_fused,\n"
+            "                                 scale=128 ** -0.5, window=512))\n"
+            "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 128)):\n"
+            "    text = full.lower(bf(F, T, 48, 128), bf(128, 2048, 4224),\n"
+            "                      S(jnp.int32, F), S(jnp.int32, F)\n"
+            "                      ).compile().as_text()\n"
+            "    assert 'tpu_custom_call' in text, (F, T)\n"
+            "    assert '%grouped_attention_fused' in text, (F, T)\n"
+            "    text = ring.lower(bf(F, T, 64, 128), bf(128, 2048, 640),\n"
+            "                      S(jnp.int32, F), S(jnp.int32, F)\n"
+            "                      ).compile().as_text()\n"
+            "    assert 'tpu_custom_call' in text, (F, T)\n"
+            "    assert '%window_attention_fused' in text, (F, T)\n"
+            "    print('LAYOUT', F, T)\n"
+            "G, D, I = 256, 2048, 512\n"
+            "assert column_block(D, I) == 512 and row_block(4096, D) == 1024\n"
+            "for tiles, M in ((192, 128), (1, 128)):\n"
+            "    text = swiglu_tiles_fused.lower(\n"
+            "        bf(tiles * M, D), S(jnp.float32, tiles * M),\n"
+            "        S(jnp.int32, tiles), S(jnp.int32), bf(G, D, I),\n"
+            "        bf(G, D, I), bf(G, I, D)).compile().as_text()\n"
+            "    assert 'tpu_custom_call' in text, (tiles, M)\n"
+            "    text = add_rows_fused.lower(\n"
+            "        S(jnp.float32, tiles * M, D), S(jnp.int32, tiles, M),\n"
+            "        S(jnp.int32), S(jnp.float32, 4096, D)\n"
+            "        ).compile().as_text()\n"
+            "    assert 'tpu_custom_call' in text, (tiles, M)\n"
+            "    print('RUN', tiles, M)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=600,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        assert proc.stdout.count("LAYOUT") == 5
+        assert proc.stdout.count("RUN") == 2
+
+
 class TestFailLoud:
     def test_inprocess_primary_that_cannot_build_fails_at_start(
             self, monkeypatch):
