@@ -20,7 +20,12 @@ position's rotated keys and then its values, positions along the lanes, as
 the kernel of ``ops/flow_attention.py`` reads a slot (the compiler would
 store a multiple of 128 lanes entry-minor and transpose it, a copy of the
 layer, every call). ``append_chunk`` writes the call's entries in place,
-then the chunk attends over its flow's slot by the step's ``attend``.
+then the chunk attends over its flow's slot by the step's ``attend``,
+which is handed ``q`` **as the projection left it** (``Queries``: float32,
+unturned, with the angles and the gate) and hands back what ``wo``
+multiplies: on a TPU the kernel turns, rounds and gates on its tile, and
+``q`` and the output cross HBM once each (PR 37); ``k``, an eighth of
+``q`` and what the state holds, is turned and rounded here as it was.
 
 - **No window: a cache.** A slot holds ``cfg.positions``, position ``p``
   at ``p``; event ``t`` sees ``0 .. p0 + t``.
@@ -41,7 +46,9 @@ then the chunk attends over its flow's slot by the step's ``attend``.
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+import dataclasses
+import functools
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,6 +88,33 @@ class AttentionLayer(NamedTuple):
     kind: str = ""
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["q", "cos", "sin", "gate"],
+                   meta_fields=["heads"])
+@dataclasses.dataclass(frozen=True)
+class Queries:
+    """What the operator hands the step's ``attend`` in the queries'
+    place: ``q [F, T, heads x head_dim]`` **float32 as the projection (and
+    ``q_norm``) left it**, ``heads`` of them an event (static), and what
+    is still to be done around the attention: the events' ``cos`` and
+    ``sin [F, T, 1, rotary / 2]`` float32 (times the layer's
+    ``rope_scale``) to turn it by, and the output's ``gate [F, T, heads]``
+    float32 (None: no gate). A wrapper of ``attend`` passes it through
+    unopened; ``attend`` turns ``q`` in float32, rounds it to bfloat16
+    **once, after the turn**, attends, rounds the output to bfloat16,
+    multiplies it by the gate in float32 and hands back ``[F, T, heads x
+    head_dim]`` for ``wo``. On a TPU all of that happens on the kernel's
+    tile (``ops/flow_attention.grouped_attention_fused``): ``q`` and the
+    output cross HBM once each, where ``wq`` wrote and ``wo`` reads (kept
+    ``[F, T, heads x head_dim]`` throughout: a view by heads is another
+    tiling on the chip, and a copy)."""
+    q: Any
+    cos: Any
+    sin: Any
+    gate: Any
+    heads: int
+
+
 def rotate(x, cos, sin, rotary: int):
     """RoPE over the first ``rotary`` values of every head of ``x [F, T,
     heads, head_dim]``; the others pass."""
@@ -94,8 +128,10 @@ def _apply(layer: AttentionLayer, lp, cfg, cache, start_entry, h, call):
     """``h [F, T, hidden]`` the residual stream; ``cache [slots, entry,
     positions]`` this layer's, donated. The chunk's entries are appended
     in place, then the chunk attends over its flow's slot by
-    ``call.attend`` (``attend_grouped_xla``'s signature). Returns the
-    output, the cache and the layer's counts (``Operator``)."""
+    ``call.attend`` (``attend_grouped_xla``'s signature), which is handed
+    the queries as projected (``Queries``) and hands back what ``wo``
+    multiplies. Returns the output, the cache and the layer's counts
+    (``Operator``)."""
     F, T, _ = h.shape
     H, G, hd = layer.heads, layer.kv_heads, layer.head_dim
     S, P = cfg.slots, cache.shape[-1]
@@ -108,43 +144,43 @@ def _apply(layer: AttentionLayer, lp, cfg, cache, start_entry, h, call):
     if layer.rope_scale != 1.0:
         cos, sin = cos * layer.rope_scale, sin * layer.rope_scale
     cos, sin = cos[:, :, None], sin[:, :, None]
-    rotary = 2 * len(layer.inv_freq)
-    q = _mm(x, lp["wq"]).reshape(F, T, H, hd)
+    q = _mm(x, lp["wq"])
     k = _mm(x, lp["wk"]).reshape(F, T, G, hd)
     if "q_norm" in lp:
-        q, k = _rms(q, lp["q_norm"], eps), _rms(k, lp["k_norm"], eps)
-    q, k = rotate(q, cos, sin, rotary), rotate(k, cos, sin, rotary)
+        q = _rms(q.reshape(F, T, H, hd), lp["q_norm"], eps).reshape(F, T, -1)
+        k = _rms(k, lp["k_norm"], eps)
+    k = rotate(k, cos, sin, 2 * len(layer.inv_freq))
     entry = jnp.concatenate([k.reshape(F, T, G * hd), _mm(x, lp["wv"])],
                             -1).astype(jnp.bfloat16)
     cache, written = append_chunk(cache, entry, start_entry, call.slot,
                                   call.p0, call.count, call.begins,
                                   positions_last=True,
                                   ring=layer.window is not None)
+    q = Queries(q, cos, sin,    # an output gate a head, where the layer has
+                jax.nn.sigmoid(_mm(x, lp["wg"])) if "wg" in lp else None, H)
     if layer.window is None:
-        o, blocks, whole = call.attend(q.astype(jnp.bfloat16), cache,
-                                       call.slot, call.p0, hd ** -0.5)
+        o, blocks, whole, in_tile = call.attend(q, cache, call.slot, call.p0,
+                                                hd ** -0.5)
         own = {"attn.{}_blocks_whole": F * whole}
     else:
         # a ring's rows are held whatever the flows' lengths: what is
         # counted beside them is the same layer as a cache
-        o, blocks, whole, unwindowed = call.attend(
-            q.astype(jnp.bfloat16), cache, call.slot, call.p0, hd ** -0.5,
-            window=layer.window)
+        o, blocks, whole, unwindowed, in_tile = call.attend(
+            q, cache, call.slot, call.p0, hd ** -0.5, window=layer.window)
         own = {"attn.{}_blocks_unwindowed": unwindowed.sum(),
                "state.{}_rows": jnp.int32(S * P),
                "state.{}_rows_as_cache": jnp.int32(S * cfg.positions)}
     counts = {"cache.rows_written": written,
               "cache.rows_whole": (call.slot < S).sum() * P,
               "attn.kv_blocks": blocks.sum(),
-              "attn.kv_blocks_whole": F * whole}
+              "attn.kv_blocks_whole": F * whole,
+              "attn.q_rows": jnp.int32(F * T * H),
+              "attn.q_rows_in_tile": jnp.int32(in_tile)}
     if layer.kind:      # and under the kind's own names
         own["attn.{}_blocks"] = blocks.sum()
         counts.update({name.format(layer.kind): v
                        for name, v in own.items()})
-    if "wg" in lp:      # an output gate a head
-        o = o.astype(jnp.float32) * jax.nn.sigmoid(
-            _mm(x, lp["wg"]))[..., None]
-    return _mm(o.reshape(F, T, H * hd), lp["wo"]), cache, counts
+    return _mm(o, lp["wo"]), cache, counts
 
 
 def grouped_attention(layer: AttentionLayer) -> Operator:
@@ -168,19 +204,27 @@ def attend_grouped_xla(q, cache, slot, p0, scale: float,
     """Grouped-query attention as XLA does it, ``ATTENTION_BLOCK`` flows'
     whole score tensor at a time: the path of every platform but the TPU,
     and what ``ops/flow_attention.grouped_attention_fused`` is tested
-    against. ``q [F, T, H, head]`` bfloat16; ``cache [slots, 2 x G x head,
+    against. ``q``: the layer's ``Queries``: turned in float32, rounded to
+    bfloat16, and the output times the gate in float32, each as an array
+    of its own; ``cache [slots, 2 x G x head,
     positions]`` the layer's, whole: flow ``f`` attends over slot
     ``slot[f]`` (clipped into range, gathered here), query head ``i``
     against the keys ``[i // (H / G)]`` and the values ``[G + i // (H /
     G)]`` of its ``head`` rows; event ``t`` sees positions ``0 .. p0[f] +
     t``, or with a ``window`` the last ``window`` of them, the cache then
     a ring: index ``j`` holds the newest position congruent to ``j`` that
-    is no later than the chunk's last. Returns ``(o [F, T, H, head]``
-    bfloat16, the blocks of positions attended over ``[F]``, the blocks of
+    is no later than the chunk's last. Returns ``(o [F, T, H x head]``
+    (bfloat16; float32 where gated: ``wo``'s product rounds it), the
+    blocks of positions attended over ``[F]``, the blocks of
     a whole slot)``: every slot is attended whole, as one block; with a
     window, a fourth: the blocks the flows would attend over with no
-    window, one each as well."""
-    F, T, H, hd = q.shape
+    window, one each as well; and last the query rows taken as projected
+    on a kernel's tile: none."""
+    gate, H = q.gate, q.heads
+    F, T, width = q.q.shape
+    hd = width // H
+    q = rotate(q.q.reshape(F, T, H, hd), q.cos, q.sin,
+               2 * q.cos.shape[-1]).astype(jnp.bfloat16)
     S, E, P = cache.shape
     G = E // (2 * hd)
     R = H // G
@@ -217,5 +261,7 @@ def attend_grouped_xla(q, cache, slot, p0, scale: float,
         (q, kv, p0[:, None] + jnp.arange(T)[None])))
     o = o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(
         F, T, H, hd)
-    one = jnp.ones((F,), jnp.int32)
-    return (o, one, 1) if window is None else (o, one, 1, one)
+    if gate is not None:
+        o = o.astype(jnp.float32) * gate[..., None]
+    o, one = o.reshape(F, T, H * hd), jnp.ones((F,), jnp.int32)
+    return (o, one, 1, 0) if window is None else (o, one, 1, one, 0)

@@ -92,6 +92,30 @@ block, under two labels), so the scores' scratch has a block to spare.
 The call is named ``window_attention_fused``, so a trace tells the two
 apart.
 
+**The queries as the projection left them** (PR 37). The grouped
+operator used to turn ``q`` (RoPE's split and concatenation), cast it,
+lay it ``[F, G, T x R, head]`` for the kernel, lay the output back, widen
+it, gate it and cast it for ``wo``: ten or so passes of XLA's over
+``[events, heads x head]``, 134 MB each in float32 at 8,192 values an
+event, four times the cost of the projections (the compiler's estimate:
+32 ms of an 81 ms step). Now a grid cell's block of ``q`` is the tile's
+events and its group's ``R x head`` columns of ``wq``'s own float32
+output, ``[F, T, H x head]``; the tile is turned in float32 (``_turn``: a
+value's partner is a roll of the lanes away), **rounded to bfloat16 once,
+after the turn, as the step rounded it**, and stored head-major (an
+event's row of a head below the row of the event before: the mask reads
+``row mod events``); the output is rounded to bfloat16 as it always was,
+widened, multiplied by its (event, head)'s gate in float32, rounded as
+``wo``'s product rounded it, and written to the same block of ``[F, T, H
+x head]``, which ``wo`` reads as it is. A head of 128 is its own lane
+tile; two heads of 64 share one and each is rolled against its own half
+(``turn_lanes``). **A tile of fewer than 16 events** (chunks of 1 to 8)
+would be stored row by row, three times the kernel's cost at chunks of 1
+(my chip run, PR 37): ``on_the_tile`` reads the layout, and such a call's
+few hundred rows are turned, rounded and gated by XLA around the kernel,
+in the same order. The latent attention takes its queries ready, as
+before (``turn=None``): its program is unchanged.
+
 ``best_attention`` selects by platform as ``ops/scoring.best_scorer``
 does: this kernel on ``tpu``, the XLA path elsewhere. There is no probe
 and no fallback: a kernel that Mosaic refuses on the chip is an error the
@@ -151,8 +175,38 @@ def _events_a_tile(T: int, H: int, P: int) -> int:
 POSITION_AXES = (((1,), (1,)), ((), ()))    # p [rows, pos] . c [rank, pos]
 
 
+def _turn(q_ref, cos_ref, sin_ref, qt_ref, events: int, head: int, half: int):
+    """The tile's queries as the projection left them, ``q_ref [1, events,
+    heads x head]`` float32, turned (rotate-half over the first ``2 x
+    half`` values of every head) in float32, **rounded to bfloat16 once**
+    and laid head-major into ``qt_ref [heads x events, head]``. ``cos_ref``
+    and ``sin_ref [1, events, lanes]``: an event's cosines, and its sines
+    signed as the rotation adds them (``-sin`` over a head's first
+    ``half`` values, ``+sin`` over the next, 0 and a cosine of 1 over
+    those that pass), over ``lanes`` values, whole heads: a value's
+    partner is ``half`` lanes up or down, a roll of the lanes away, and
+    ``x cos + partner sin`` is, value for value, the ``a cos - b sin``
+    and ``b cos + a sin`` of ``models.latent_moe._rope``."""
+    lanes = cos_ref.shape[-1]
+    cos, sin = cos_ref[0], sin_ref[0]
+    both_ways = 2 * half != lanes   # else half a turn up is half a turn down
+    if both_ways:
+        up = jax.lax.rem(jax.lax.broadcasted_iota(jnp.int32, cos.shape, 1),
+                         head) < half       # where the partner lies above
+    for c in range(q_ref.shape[-1] // lanes):
+        x = q_ref[0, :, c * lanes:(c + 1) * lanes]
+        partner = pltpu.roll(x, half, 1)            # x[lane - half]
+        if both_ways:
+            partner = jnp.where(up, pltpu.roll(x, lanes - half, 1), partner)
+        x = (x * cos + partner * sin).astype(qt_ref.dtype)
+        for j in range(lanes // head):
+            r = c * (lanes // head) + j
+            qt_ref[r * events:(r + 1) * events, :] = x[
+                :, j * head:(j + 1) * head]
+
+
 def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
-            values_apart: bool, window):
+            values_apart: bool, window, turn=None):
     """One flow, one group of heads, one tile of its query rows. ``refs``:
     the queries in ``len(widths)`` parts, part ``j`` ``widths[j]`` wide and
     scored against the key rows that follow those of the parts before it;
@@ -160,11 +214,27 @@ def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
     positions]`` where they are ``values_apart`` (else they are the first
     of the key rows); the output; the scratch. ``heads``: query rows an
     event. ``window``: None, or the positions a row sees, itself
-    included, of a slot that is a ring."""
+    included, of a slot that is a ring. ``turn``: None, and the queries
+    come ready, bfloat16, a row an (event, head), event-major; or ``(half,
+    gated)``: **the one part is the projection's own output** ``[1,
+    events, heads x head]`` float32, followed by the events' cosines and
+    signed sines and, where ``gated``, their gates ``[1, 1, events,
+    heads]``: the tile is turned and rounded here (``_turn``), its rows
+    lie head-major, and the output, rounded to bfloat16 as it always was,
+    is widened, multiplied by its (event, head)'s gate in float32, rounded
+    to bfloat16 again and written ``[1, events, heads x head]``: where the
+    output projection reads it."""
     del slot_ref    # the block specs' alone: which slot the keys are of
-    q_refs, refs = refs[:len(widths)], refs[len(widths):]
-    k_ref, v_ref = refs[0], refs[1 if values_apart else 0]
-    o_ref, s_ref, m_ref, l_ref, acc_ref = refs[-5:]
+    refs = list(refs)
+    q_refs = [refs.pop(0) for _ in widths]
+    if turn is not None:
+        half, gated = turn
+        cos_ref, sin_ref = refs.pop(0), refs.pop(0)
+        gate_ref = refs.pop(0) if gated else None
+        qt_ref = refs.pop()
+    k_ref = refs.pop(0)
+    v_ref = refs.pop(0) if values_apart else k_ref
+    o_ref, s_ref, m_ref, l_ref, acc_ref = refs
     f, i = pl.program_id(0), pl.program_id(2)
     rows, P = s_ref.shape[0], k_ref.shape[-1]
     events, bk, vd = rows // heads, m_ref.shape[1], acc_ref.shape[1]
@@ -173,7 +243,11 @@ def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
         lo, last = 0, blocks_seen(first, events, P)
     else:
         lo, last = block_range(first, events, P, window)
-    qs = [q_ref[0, 0] for q_ref in q_refs]
+    if turn is None:
+        qs = [q_ref[0, 0] for q_ref in q_refs]
+    else:
+        _turn(q_refs[0], cos_ref, sin_ref, qt_ref, events, widths[0], half)
+        qs = [qt_ref[...]]
     at_row = [sum(widths[:j]) for j in range(len(widths))]
 
     def places(j):
@@ -192,8 +266,9 @@ def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
                     preferred_element_type=jnp.float32)
             for q, a, w in zip(qs, at_row, widths))) * scale
         if masked:
-            pos = first + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0) // heads
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            pos = first + (row // heads if turn is None
+                           else jax.lax.rem(row, events))
             col = (at if window is None else j * bk
                    ) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
             seen = col <= pos
@@ -234,12 +309,21 @@ def _kernel(slot_ref, p0_ref, *refs, scale: float, heads: int, widths: tuple,
             preferred_element_type=jnp.float32)
 
     jax.lax.fori_loop(lo, last, weigh, None)
-    o_ref[0, 0] = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
-                   ).astype(o_ref.dtype)
+    o = (acc_ref[...] * (1.0 / l_ref[...].sum(-1, keepdims=True))
+         ).astype(o_ref.dtype)
+    if turn is None:
+        o_ref[0, 0] = o
+        return
+    for r in range(heads):
+        head = o[r * events:(r + 1) * events]
+        if gated:
+            head = (head.astype(jnp.float32) * gate_ref[0, 0, :, r:r + 1]
+                    ).astype(o_ref.dtype)
+        o_ref[0, :, r * vd:(r + 1) * vd] = head
 
 
 def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
-            interpret: bool, name: str, window=None):
+            interpret: bool, name: str, window=None, turn=None):
     """The kernel over ``parts``: the queries ``[F, G, T * heads, width]``
     a part, ``G`` groups of heads each with keys and values of its own;
     ``kt [slots, rows, P]``, the layer's cache, positions last: group
@@ -250,20 +334,63 @@ def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
     positions an event sees of a slot that is a ring. Returns ``(o [F, G,
     T * heads, vd]``, the blocks of positions attended over ``[F]``,
     those of a slot whole)``, and with a window those with no window
-    ``[F]``, a fourth."""
-    F, G, rows_all, _ = parts[0].shape
+    ``[F]``, a fourth.
+
+    ``turn``: None, or ``(half, cos, sin, gate)`` and **the one part is
+    the projection's output** ``[F, T, G x heads x vd]`` float32
+    (``values_at`` groups of heads ``vd`` wide): the block of grid cell
+    ``(f, g, i)`` is the tile's events and group ``g``'s columns of it,
+    ``half``, ``cos`` and ``sin [F, T, lanes]`` what ``_turn`` takes,
+    ``gate [F, G, T, heads]`` float32 or None, and ``o`` comes back ``[F,
+    T, G x heads x vd]`` bfloat16, an event's heads side by side
+    (``_kernel``)."""
     S, _, P = kt.shape
-    heads = rows_all // T
-    widths = tuple(q.shape[-1] for q in parts)
+    if turn is None:
+        F, G, rows_all, _ = parts[0].shape
+        heads = rows_all // T
+        widths = tuple(q.shape[-1] for q in parts)
+    else:
+        F, G, widths = parts[0].shape[0], values_at, (vd,)
+        heads = parts[0].shape[-1] // (G * vd)
     bk, events = kv_block(P), _events_a_tile(T, heads, P)
     rows, tiles = events * heads, T // events
     if window is not None and window - 1 + events > P:
         raise ValueError(f"{events} events behind a window of {window} do "
                          f"not fit a ring of {P}")
+    # a window's span may touch one block more than the ring has
+    scratch = [pltpu.VMEM((rows, P if window is None else P + bk),
+                          jnp.float32),
+               pltpu.VMEM((rows, bk), jnp.float32),
+               pltpu.VMEM((rows, bk), jnp.float32),
+               pltpu.VMEM((rows, vd), jnp.float32)]
+    on_tile, more = None, []    # what the kernel is told, and handed, of it
+    if turn is None:
+        q_specs = [pl.BlockSpec((1, 1, rows, w),
+                                lambda f, g, i, slot, p0: (f, g, i, 0))
+                   for w in widths]
+        out_spec = pl.BlockSpec((1, 1, rows, vd),
+                                lambda f, g, i, slot, p0: (f, g, i, 0))
+        out_shape = jax.ShapeDtypeStruct((F, G, T * heads, vd),
+                                         parts[0].dtype)
+    else:
+        half, cos, sin, gate = turn
+        # an event's heads of the group where the projection wrote them
+        by_event = pl.BlockSpec((1, events, heads * vd),
+                                lambda f, g, i, slot, p0: (f, i, g))
+        angle = pl.BlockSpec((1, events, cos.shape[-1]),
+                             lambda f, g, i, slot, p0: (f, i, 0))
+        q_specs, out_spec, more = [by_event, angle, angle], by_event, [cos, sin]
+        if gate is not None:
+            q_specs.append(pl.BlockSpec(
+                (1, 1, events, heads), lambda f, g, i, slot, p0: (f, g, i, 0)))
+            more.append(gate)
+        out_shape = jax.ShapeDtypeStruct((F, T, G * heads * vd), kt.dtype)
+        scratch.append(pltpu.VMEM((rows, vd), kt.dtype))
+        on_tile = (half, gate is not None)
     kernel = functools.partial(_kernel, scale=scale, heads=heads,
                                widths=widths,
                                values_apart=values_at is not None,
-                               window=window)
+                               window=window, turn=on_tile)
     # the flow's slot where it lies, positions along the lanes: the same
     # block for all of a flow's (and a group's) tiles, so it is fetched
     # once
@@ -277,26 +404,17 @@ def _attend(parts, kt, slot, p0, *, T: int, values_at, vd: int, scale: float,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(F, G, tiles),
-            in_specs=[pl.BlockSpec((1, 1, rows, w),
-                                   lambda f, g, i, slot, p0: (f, g, i, 0))
-                      for w in widths] + kv_specs,
-            out_specs=pl.BlockSpec((1, 1, rows, vd),
-                                   lambda f, g, i, slot, p0: (f, g, i, 0)),
-            # a window's span may touch one block more than the ring has
-            scratch_shapes=[pltpu.VMEM(
-                                (rows, P if window is None else P + bk),
-                                jnp.float32),
-                            pltpu.VMEM((rows, bk), jnp.float32),
-                            pltpu.VMEM((rows, bk), jnp.float32),
-                            pltpu.VMEM((rows, vd), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((F, G, rows_all, vd), parts[0].dtype),
+            in_specs=q_specs + kv_specs,
+            out_specs=out_spec,
+            scratch_shapes=scratch),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
             vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name=name,
     )(jnp.minimum(slot, S - 1).astype(jnp.int32), p0.astype(jnp.int32),
-      *parts, *[kt] * len(kv_specs))
+      *parts, *more, *[kt] * len(kv_specs))
     firsts = [p0 + i * events for i in range(tiles)]
     if window is None:
         return (o, sum(blocks_seen(first, events, P) for first in firsts),
@@ -330,32 +448,85 @@ def latent_attention_fused(q_abs, q_rope, cache, slot, p0, scale: float,
     return o.reshape(F, T, H, rank), attended, whole
 
 
+TILE_EVENTS = 16    # events a tile, from which it is turned in the kernel
+
+
+def on_the_tile(T: int, heads: int, P: int) -> bool:
+    """Whether a call of ``T`` events a flow is turned, rounded and gated
+    on the kernel's tile: where a tile's events fill whole sublane tiles
+    (16 rows of bfloat16). A tile of fewer (chunks of 1 to 8 events, whose
+    ``q`` is a few hundred rows in all) is stored row by row, which on the
+    chip costs three times the whole kernel at chunks of 1 (7.0 ms against
+    2.4 for a full layer of 64 flows; my chip run, PR 37): there XLA turns
+    and gates, as it did."""
+    return _events_a_tile(T, heads, P) % TILE_EVENTS == 0
+
+
+def turn_lanes(head: int, heads: int) -> int:
+    """Lanes the kernel turns at a time: whole heads, so a value's partner
+    lane is among them: a head of 128 and more is its own; narrower heads
+    go as many to the 128 lanes as divide the group's ``heads``."""
+    per = max(1, min(128 // head, heads))
+    while heads % per:
+        per -= 1
+    return head * per
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def grouped_attention_fused(q, cache, slot, p0, scale: float,
                             interpret: bool = False, window=None):
-    """Grouped-query attention by the same kernel: ``q [F, T, H, head]``
-    bfloat16; ``cache [slots, 2 x G x head, P]`` bfloat16, the layer's,
-    whole and **positions last** (``models/grouped_attention.py`` keeps
-    it so), a position's keys of ``G`` key/value heads and then its
-    values. A grid cell is one flow, one key/value head and a tile of the
-    ``H / G`` query heads' rows that attend over it; the block specs take
-    that head's ``head`` key rows and ``head`` value rows from the flow's
-    slot. With a ``window`` the slot is a ring and the call is named
-    ``window_attention_fused``. Returns what
+    """Grouped-query attention by the same kernel. ``q``: what the layer
+    projected and what is still to be done to it
+    (``models.grouped_attention.Queries``: ``q [F, T, H x head]`` float32
+    as the projection (and a norm) left it, ``heads`` = ``H``, ``cos`` and
+    ``sin [F, T, 1, rotary / 2]`` float32, the output's ``gate [F, T, H]``
+    float32 or None): **the kernel reads ``q`` where the projection wrote
+    it**, turns, rounds and scores it on the tile, gates the output there
+    and writes it where the output projection reads it, so neither
+    crosses HBM but once, and no transposed copy of either exists. ``cache [slots, 2 x G x head, P]``
+    bfloat16, the layer's, whole and **positions last**
+    (``models/grouped_attention.py`` keeps it so), a position's keys of
+    ``G`` key/value heads and then its values. A grid cell is one flow,
+    one key/value head and a tile of events: the ``H / G`` query heads
+    that attend over it are ``H / G x head`` columns of ``q``, side by
+    side; the block specs take that head's ``head`` key rows and ``head``
+    value rows from the flow's slot. With a ``window`` the slot is a ring
+    and the call is named ``window_attention_fused``. Returns what
     ``models.grouped_attention.attend_grouped_xla`` returns: ``(o [F, T,
-    H, head]``, the blocks attended over ``[F]``, those of a slot
-    whole)``, and with a window those with no window ``[F]``."""
-    F, T, H, hd = q.shape
+    H x head]``, the blocks attended over ``[F]``, those of a slot
+    whole)``, with a window those with no window ``[F]``, and last the
+    query rows taken as projected: all ``F x T x H``, or, where a tile
+    holds too few events (``on_the_tile``), none: XLA then turns, rounds
+    and gates around the kernel, in the same order."""
+    q, cos, sin, gate, H = q.q, q.cos, q.sin, q.gate, q.heads
+    F, T, width = q.shape
+    hd = width // H
     G = cache.shape[1] // (2 * hd)
     R = H // G
-    o, *blocks = _attend(
-        [q.reshape(F, T, G, R, hd).transpose(0, 2, 1, 3, 4).reshape(
-            F, G, T * R, hd)],
-        cache, slot, p0, T=T, values_at=G, vd=hd, scale=scale,
-        interpret=interpret, window=window,
+    half = cos.shape[-1]
+    attend = functools.partial(
+        _attend, kt=cache, slot=slot, p0=p0, T=T, values_at=G, vd=hd,
+        scale=scale, interpret=interpret, window=window,
         name=("grouped" if window is None else "window") + "_attention_fused")
-    return (o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(
-        F, T, H, hd), *blocks)
+    if not on_the_tile(T, R, cache.shape[-1]):
+        from linkerd_tpu.models.grouped_attention import rotate
+        q = rotate(q.reshape(F, T, H, hd), cos, sin, 2 * half).astype(
+            jnp.bfloat16)
+        o, *blocks = attend([q.reshape(F, T, G, R, hd).transpose(
+            0, 2, 1, 3, 4).reshape(F, G, T * R, hd)])
+        o = o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(
+            F, T, H, hd)
+        if gate is not None:
+            o = (o.astype(jnp.float32) * gate[..., None]).astype(jnp.bfloat16)
+        return (o.reshape(F, T, width), *blocks, 0)
+    cos, sin = cos.reshape(F, T, half), sin.reshape(F, T, half)
+    passes = jnp.zeros((F, T, hd - 2 * half), jnp.float32)
+    times = turn_lanes(hd, R) // hd
+    cos = jnp.tile(jnp.concatenate([cos, cos, passes + 1], -1), times)
+    sin = jnp.tile(jnp.concatenate([-sin, sin, passes], -1), times)
+    if gate is not None:
+        gate = gate.reshape(F, T, G, R).transpose(0, 2, 1, 3)
+    return (*attend([q], turn=(half, cos, sin, gate)), F * T * H)
 
 
 def attention_kind(platform: str) -> str:

@@ -545,15 +545,21 @@ class TestScorerSelection:
             "        topology_name='v5e:2x2', platform='tpu')\n"
             "except Exception as e:\n"
             "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models.grouped_attention import Queries\n"
             "from linkerd_tpu.ops.flow_attention import (\n"
             "    grouped_attention_fused)\n"
             "sh = SingleDeviceSharding(topo.devices[0])\n"
             "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
             "bf = functools.partial(S, jnp.bfloat16)\n"
+            "f32 = functools.partial(S, jnp.float32)\n"
             "fn = jax.jit(functools.partial(grouped_attention_fused,\n"
             "                               scale=0.125))\n"
             "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 512)):\n"
-            "    text = fn.lower(bf(F, T, 32, 64), bf(512, 1024, 1024),\n"
+            "    # the queries as projected: the whole head of 64 turned on\n"
+            "    # the tile, two heads to its 128 lanes; no gate\n"
+            "    q = Queries(f32(F, T, 32 * 64), f32(F, T, 1, 32),\n"
+            "                f32(F, T, 1, 32), None, 32)\n"
+            "    text = fn.lower(q, bf(512, 1024, 1024),\n"
             "                    S(jnp.int32, F), S(jnp.int32, F)\n"
             "                    ).compile().as_text()\n"
             "    assert 'tpu_custom_call' in text, (F, T)\n"
@@ -657,6 +663,45 @@ class TestScorerSelection:
             "            op = 'bitcast'     # a fusion of a bitcast alone\n"
             "        print('WHOLE', op, name, typ[:80])\n"
             + EXPERTS_SEEN +
+            "# what touches an array as large as a layer's queries or its\n"
+            "# attention's output, [F, T, heads x head] elements, under an\n"
+            "# attention scope of the entry computation: QO scope opcode\n"
+            "# name reads writes (arrays of that size among its operands\n"
+            "# and in its result)\n"
+            "sizes = {4096 * h * cfg.head_dim for h in cfg.heads_per_layer}\n"
+            "shape = re.compile(r'(?:bf16|f32)\\[([\\d,]+)\\]')\n"
+            "def large(typ):\n"
+            "    n = 0\n"
+            "    for dims in shape.findall(typ):\n"
+            "        size = 1\n"
+            "        for d in dims.split(','): size *= int(d)\n"
+            "        n += size in sizes\n"
+            "    return n\n"
+            "types, entry = {}, []\n"
+            "for line in text[text.index('ENTRY'):].splitlines():\n"
+            "    name, eq, rest = line.strip().partition(' = ')\n"
+            "    if not eq or name.startswith('//'): continue\n"
+            "    name = name.replace('ROOT ', '')\n"
+            "    if rest.startswith('('):\n"
+            "        depth = 0\n"
+            "        for i, ch in enumerate(rest):\n"
+            "            depth += (ch == '(') - (ch == ')')\n"
+            "            if depth == 0: break\n"
+            "        typ, rest = rest[:i + 1], rest[i + 2:]\n"
+            "    else:\n"
+            "        typ, _, rest = rest.partition(' ')\n"
+            "    types[name] = typ\n"
+            "    entry.append((name, typ, rest))\n"
+            "for name, typ, rest in entry:\n"
+            "    scope = re.search(r'op_name=\"[^\"]*(layer\\d+\\.'\n"
+            "                      r'(?:full|window)_attention)', rest)\n"
+            "    if not scope: continue\n"
+            "    args = rest.split(', metadata=')[0].split(', calls=')[0]\n"
+            "    reads = sum(large(types.get(a, ''))\n"
+            "                for a in re.findall(r'%[\\w.\\-]+', args))\n"
+            "    if reads or large(typ):\n"
+            "        print('QO', scope.group(1), rest.split('(', 1)[0], name,\n"
+            "              reads, large(typ))\n"
             "print('COMPILED', topo.devices[0].device_kind)\n")
         proc = _run([sys.executable, "-c", code], timeout=900,
                     env=_clean_env(
@@ -668,6 +713,38 @@ class TestScorerSelection:
         assert proc.returncode == 0, proc.stderr[-2000:]
         assert "COMPILED TPU v5" in proc.stdout
         return proc.stdout
+
+    def test_laguna_step_passes_q_and_o_through_hbm_once_each(
+            self, laguna_step_compiled):
+        """Inside ``layer<l>.full_attention`` / ``layer<l>.window_attention``
+        three instructions touch an array of ``[F, T, heads x head]``
+        elements (25 M on a full layer, 34 M on a sliding one) and no
+        other does: ``wq``'s product writes ``q`` (float32, as the kernel
+        takes it), the kernel reads it and writes ``o``, ``wo``'s product
+        reads ``o``. RoPE's split and concatenation, the cast, the two
+        transposes to and from the kernel's rows, the gate's pass and
+        ``wo``'s cast, ten or so passes of 134 MB a layer in float32
+        until PR 37, are on the kernel's tile; a view of ``q`` by heads
+        is another tiling and a copy (0.67 ms a sliding layer by the
+        compiler's estimate), so the operator keeps it ``[F, T, heads x
+        head]``."""
+        seen = [line.split()[1:] for line in laguna_step_compiled.splitlines()
+                if line.startswith("QO")]
+        by_layer = {}
+        for scope, op, name, reads, writes in seen:
+            by_layer.setdefault(scope, []).append(
+                (op, int(reads), int(writes), name))
+        assert sorted(by_layer) == [
+            "layer0.full_attention", "layer1.window_attention",
+            "layer2.window_attention", "layer3.window_attention",
+            "layer4.full_attention"]
+        for scope, touched in by_layer.items():
+            kernel = "window" if "window" in scope else "grouped"
+            assert sorted(t[:3] for t in touched) == [
+                ("custom-call", 1, 1), ("fusion", 0, 1), ("fusion", 1, 0)
+            ], (scope, touched)
+            assert [name for op, *_, name in touched if op == "custom-call"
+                    ][0].startswith(f"%{kernel}_attention_fused"), touched
 
     def test_laguna_step_compiles_for_v5e_at_the_published_widths(
             self, laguna_step_compiled):
@@ -738,22 +815,29 @@ class TestScorerSelection:
             "from linkerd_tpu.ops.expert_product import (\n"
             "    add_rows_fused, column_block, row_block,\n"
             "    swiglu_tiles_fused)\n"
+            "from linkerd_tpu.models.grouped_attention import Queries\n"
             "from linkerd_tpu.ops.flow_attention import (\n"
             "    grouped_attention_fused)\n"
             "sh = SingleDeviceSharding(topo.devices[0])\n"
             "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
             "bf = functools.partial(S, jnp.bfloat16)\n"
+            "f32 = functools.partial(S, jnp.float32)\n"
+            "# the queries as projected, the angles of the rotated half of\n"
+            "# a head (32 of 64 pairs on a full layer), a gate a head\n"
+            "asked = lambda F, T, H, half: Queries(\n"
+            "    f32(F, T, H * 128), f32(F, T, 1, half), f32(F, T, 1, half),\n"
+            "    f32(F, T, H), H)\n"
             "full = jax.jit(functools.partial(grouped_attention_fused,\n"
             "                                 scale=128 ** -0.5))\n"
             "ring = jax.jit(functools.partial(grouped_attention_fused,\n"
             "                                 scale=128 ** -0.5, window=512))\n"
             "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 128)):\n"
-            "    text = full.lower(bf(F, T, 48, 128), bf(128, 2048, 4224),\n"
+            "    text = full.lower(asked(F, T, 48, 32), bf(128, 2048, 4224),\n"
             "                      S(jnp.int32, F), S(jnp.int32, F)\n"
             "                      ).compile().as_text()\n"
             "    assert 'tpu_custom_call' in text, (F, T)\n"
             "    assert '%grouped_attention_fused' in text, (F, T)\n"
-            "    text = ring.lower(bf(F, T, 64, 128), bf(128, 2048, 640),\n"
+            "    text = ring.lower(asked(F, T, 64, 64), bf(128, 2048, 640),\n"
             "                      S(jnp.int32, F), S(jnp.int32, F)\n"
             "                      ).compile().as_text()\n"
             "    assert 'tpu_custom_call' in text, (F, T)\n"

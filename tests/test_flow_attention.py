@@ -344,3 +344,218 @@ def test_three_calls_append_to_one_slot_as_the_gathered_slots_did(
         at = {f: at[f] + n[f] for f in at}
     # the third flow's last chunk ended at the slot's last position
     assert at[2] == P == int(stp[1][14])
+
+
+# -- grouped attention: the queries as projected, turned and gated on the tile
+
+def queries_of(seed: int, F: int, T: int, H: int, hd: int, p0, half: int,
+               gated: bool = True, rope_scale: float = 1.0):
+    """A layer's ``Queries`` as ``models.grouped_attention._apply`` hands
+    them to ``attend``: ``q [F, T, H x hd]`` float32 as a projection
+    leaves it, the cosines and sines of the events' positions (``p0[f] +
+    t``) at ``half`` frequencies times ``rope_scale``, a gate a head."""
+    from linkerd_tpu.models.grouped_attention import Queries
+    k = jax.random.split(jax.random.key(seed), 2)
+    cos, sin = lm.angles(
+        jnp.asarray(p0)[:, None] + jnp.arange(T)[None],
+        (10000.0 ** (-np.arange(half) / half)).astype(np.float32))
+    return Queries(
+        jax.random.normal(k[0], (F, T, H * hd), jnp.float32),
+        (cos * rope_scale)[:, :, None], (sin * rope_scale)[:, :, None],
+        jax.nn.sigmoid(jax.random.normal(k[1], (F, T, H), jnp.float32))
+        if gated else None, H)
+
+
+def unturned(q, H: int):
+    """``Queries`` of queries that are not to be turned (``cos`` 1, ``sin``
+    0: ``a x 1 - b x 0`` is ``a``) nor gated, ``q [F, T, H, hd]``."""
+    from linkerd_tpu.models.grouped_attention import Queries
+    F, T, _, hd = q.shape
+    one = jnp.ones((F, T, 1, hd // 2), jnp.float32)
+    return Queries(q.reshape(F, T, H * hd).astype(jnp.float32), one,
+                   0 * one, None, H)
+
+
+def as_the_step_did(q, cache, slot, p0, scale, window=None):
+    """The parent's order around the same kernel body, each pass an array
+    of its own: turn in float32, **round to bfloat16**, lay the rows
+    ``[F, G, T x R, head]``, attend (``_attend`` with no ``turn``), lay
+    them back, widen, times the gate in float32, round as ``wo``'s product
+    does."""
+    from linkerd_tpu.models.grouped_attention import rotate
+    F, T, width = q.q.shape
+    H, hd = q.heads, width // q.heads
+    G = cache.shape[1] // (2 * hd)
+    R = H // G
+    qb = rotate(q.q.reshape(F, T, H, hd), q.cos, q.sin,
+                2 * q.cos.shape[-1]).astype(jnp.bfloat16)
+    o, *blocks = fa._attend(
+        [qb.reshape(F, T, G, R, hd).transpose(0, 2, 1, 3, 4).reshape(
+            F, G, T * R, hd)], cache, slot, p0, T=T, values_at=G, vd=hd,
+        scale=scale, interpret=True, window=window, name="as_the_step_did")
+    o = o.reshape(F, G, T, R, hd).transpose(0, 2, 1, 3, 4).reshape(F, T, H, hd)
+    if q.gate is not None:
+        o = o.astype(jnp.float32) * q.gate[..., None]
+    return o.astype(jnp.bfloat16).reshape(F, T, width), *blocks
+
+
+def ulps_apart(a, b) -> np.ndarray:
+    """bfloat16 values' distance in units of the last place of the
+    larger, or of 1 where both are smaller (a weighted mean of values of
+    size 1 that cancels to nearly nothing is as exact as its terms)."""
+    a, b = (np.asarray(x, np.float32) for x in (a, b))
+    big = np.maximum(np.maximum(np.abs(a), np.abs(b)), 1.0)
+    return np.abs(a - b) / 2.0 ** (np.floor(np.log2(big)) - 7)
+
+
+# (F, T), heads, key/value heads, head, rotated half, RoPE's scale, gate,
+# positions a slot, window: each kind of layer at its published heads
+IN_TILE = {
+    # Laguna's full layer: 48 heads over 8, 64 of 128 values turned, cos and
+    # sin scaled, a gate; a tile is a flow's 16 events x 6 heads
+    "full": ((3, 16), 48, 8, 128, 32, 1.4158883, True, 512, None),
+    # the same over a slot of 4,096: 64 events come in two tiles of 32
+    "full-two-tiles": ((2, 64), 12, 2, 128, 32, 1.4158883, True, 4096, None),
+    # its sliding layer: 64 heads, all 128 turned, a ring that wraps
+    "sliding": ((4, 16), 64, 8, 128, 64, 1.0, True, 640, 512),
+    # LFM2's: a head of 64 (two to a lane tile), no gate (and a q_norm,
+    # which is XLA's, before the kernel)
+    "lfm2": ((3, 16), 32, 8, 64, 32, 1.0, False, 256, None),
+    # chunks of one event: too few to fill a tile's sublanes, so XLA turns
+    # and gates around the kernel (``on_the_tile``)
+    "one-event": ((8, 1), 16, 2, 128, 64, 1.0, True, 640, 512),
+    # the tests' tiny heads
+    "tiny": ((4, 16), 8, 2, 16, 4, 1.3, True, 88, 24),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(IN_TILE))
+def test_the_tile_turns_rounds_and_gates_as_the_step_did(kind):
+    """``grouped_attention_fused`` handed the queries as projected
+    (float32, unturned, ``[F, T, H x head]``), interpreted: **rounding for
+    rounding what the step did around the kernel** (``as_the_step_did``:
+    turn in float32, one rounding to bfloat16, the same kernel body, the
+    output rounded, widened, gated in float32, rounded): not one value
+    differs. Against ``attend_grouped_xla`` on the same ``Queries`` (a
+    softmax normalised before its weights are rounded, where the kernel
+    divides after) no value is further than two units of bfloat16's last
+    place (``ulps_apart``), a handful further than one, and more than
+    half are equal. (At chunks of one event the wrapper itself keeps that
+    order around the kernel: the same holds, and no row is counted as
+    taken on the tile.) The last flow is padding (its slot out of
+    range, read clipped); a tile's rows span all of a flow's events, or,
+    at 4,096 positions, half of them."""
+    from linkerd_tpu.models import grouped_attention as ga
+    (F, T), H, G, hd, half, rope_scale, gated, P, W = IN_TILE[kind]
+    rng = np.random.default_rng(P + T)
+    S, slot = slots_for(F)
+    p0 = (rng.integers(0, 4 * P, F) if W else flows_at(F, T, P)).astype(
+        np.int32)
+    q = queries_of(F + P, F, T, H, hd, p0, half, gated, rope_scale)
+    cache = jax.random.normal(jax.random.key(P), (S, 2 * G * hd, P),
+                              jnp.bfloat16)
+    scale = hd ** -0.5
+    got, *counted, in_tile = jax.jit(functools.partial(
+        fa.grouped_attention_fused, scale=scale, window=W, interpret=True))(
+            q, cache, slot, p0)
+    assert got.shape == (F, T, H * hd) and got.dtype == jnp.bfloat16
+    assert fa.on_the_tile(T, H // G, P) == (kind != "one-event")
+    assert int(in_tile) == (F * T * H if kind != "one-event" else 0)
+    was, *counted_then = jax.jit(functools.partial(
+        as_the_step_did, scale=scale, window=W))(q, cache, slot, p0)
+    assert (np.asarray(got, np.float32) == np.asarray(was, np.float32)).all()
+    for a, b in zip(counted, counted_then):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    want, *_, none = jax.jit(functools.partial(
+        ga.attend_grouped_xla, scale=scale, window=W))(q, cache, slot, p0)
+    assert want.shape == got.shape and int(none) == 0
+    assert want.dtype == (jnp.float32 if gated else jnp.bfloat16)
+    apart = ulps_apart(got, want.astype(jnp.bfloat16))
+    # (6 of the full layer's 294,912 values are two apart; none elsewhere)
+    assert apart.max() <= 2.0 and np.sum(apart > 1.0) <= 8, (
+        apart.max(), np.sum(apart > 1.0))
+    assert np.mean(apart > 0) < 0.5
+    if kind == "full-two-tiles":
+        assert fa._events_a_tile(T, H // G, P) == 32
+
+
+def test_a_head_is_turned_with_its_own_lanes():
+    """``turn_lanes``: a head of 128 is turned by itself; two heads of 64
+    share the 128 lanes, each rolled against its own half; the tests'
+    heads of 16 go as many as the group has."""
+    assert fa.turn_lanes(128, 6) == 128 == fa.turn_lanes(64, 4)
+    assert fa.turn_lanes(64, 1) == 64 and fa.turn_lanes(256, 2) == 256
+    assert fa.turn_lanes(16, 4) == 64 and fa.turn_lanes(16, 16) == 128
+    # three heads of 64 a group: no two of them divide it
+    assert fa.turn_lanes(64, 3) == 64 and fa.turn_lanes(32, 6) == 96
+    # on the tile from 16 events a tile (whole sublane tiles of bfloat16)
+    assert [fa.on_the_tile(T, 8, 640) for T in (1, 8, 16, 64, 128)] == [
+        False, False, True, True, True]
+    assert fa.on_the_tile(64, 6, 4224) and fa._events_a_tile(64, 6, 4224) == 32
+
+
+def parents_apply(layer, lp, cfg, cache, start_entry, h, call):
+    """The attention operator in the order the step ran before the queries
+    went to ``attend`` as projected, every pass an array of its own: turn
+    ``q`` and ``k`` in float32, **cast** ``q``, append, attend over
+    already-turned bfloat16 queries (``attend_grouped_xla`` handed a turn
+    by nothing and no gate), **the gate in float32** on the widened
+    output, ``wo``. Returns the output and the appended state."""
+    from linkerd_tpu.models import grouped_attention as ga
+    F, T, _ = h.shape
+    H, G, hd = layer.heads, layer.kv_heads, layer.head_dim
+    eps = cfg.rms_norm_eps
+    x = lm._rms(h, lp["operator_norm"], eps)
+    cos, sin = lm.angles(call.pos, layer.inv_freq)
+    if layer.rope_scale != 1.0:
+        cos, sin = cos * layer.rope_scale, sin * layer.rope_scale
+    cos, sin = cos[:, :, None], sin[:, :, None]
+    rotary = 2 * len(layer.inv_freq)
+    q = lm._mm(x, lp["wq"]).reshape(F, T, H, hd)
+    k = lm._mm(x, lp["wk"]).reshape(F, T, G, hd)
+    if "q_norm" in lp:
+        q, k = (lm._rms(q, lp["q_norm"], eps), lm._rms(k, lp["k_norm"], eps))
+    q, k = ga.rotate(q, cos, sin, rotary), ga.rotate(k, cos, sin, rotary)
+    entry = jnp.concatenate([k.reshape(F, T, G * hd), lm._mm(x, lp["wv"])],
+                            -1).astype(jnp.bfloat16)
+    cache, _ = lm.append_chunk(cache, entry, start_entry, call.slot, call.p0,
+                               call.count, call.begins, positions_last=True,
+                               ring=layer.window is not None)
+    o, *_ = ga.attend_grouped_xla(
+        unturned(q.astype(jnp.bfloat16), H), cache, call.slot, call.p0,
+        hd ** -0.5, window=layer.window)
+    assert o.dtype == jnp.bfloat16
+    o = o.reshape(F, T, H, hd)
+    if "wg" in lp:
+        o = o.astype(jnp.float32) * jax.nn.sigmoid(
+            lm._mm(x, lp["wg"]))[..., None]
+    return lm._mm(o.reshape(F, T, H * hd), lp["wo"]), cache
+
+
+def operator_and_parent(cfg, params, l: int, layer, seed: int = 0):
+    """Layer ``l``'s operator (``cfg.operator(l).apply``) and
+    ``parents_apply`` over the same call on XLA's attention: four flows'
+    next 8 events (one begins, one is deep in its slot, or several times
+    round its ring, one brings 3 events, one is padding) on a state of
+    random entries. Returns ``((y, state, counts), (y, state))``."""
+    from linkerd_tpu.models import grouped_attention as ga
+    op, lp = cfg.operator(l), params["layers"][l]
+    F, T = 4, 8
+    rng = np.random.default_rng(seed)
+    state = jax.random.normal(jax.random.key(seed), op.init(cfg).shape,
+                              jnp.bfloat16)
+    p0 = np.array([1, 200 if not op.ring else 5 * op.ring - 3, 17, 0],
+                  np.int32)
+    call = lm.Call(
+        slot=np.array([1, 3, 5, cfg.slots], np.int32), p0=p0,
+        count=np.array([T, T, 3, 0], np.int32),
+        begins=np.array([True, False, False, False]),
+        pos=jnp.asarray(p0[:, None] + np.arange(T)[None]),
+        attend=ga.attend_grouped_xla, experts=None)
+    h = jnp.asarray(rng.normal(size=(F, T, cfg.hidden_size)), jnp.float32)
+    start = state[0, :, 0]
+    mine = jax.jit(lambda lp, state, h: op.apply(
+        lp, cfg, state, start, h, call))(lp, state, h)
+    then = jax.jit(lambda lp, state, h: parents_apply(
+        layer, lp, cfg, state, start, h, call))(lp, state, h)
+    return mine, then

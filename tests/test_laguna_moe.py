@@ -31,6 +31,9 @@ from linkerd_tpu.telemetry.anomaly import (
     InProcessScorer, JaxAnomalyConfig, JaxAnomalyTelemeter,
 )
 from linkerd_tpu.telemetry.metrics import MetricsTree
+from tests.test_flow_attention import (
+    operator_and_parent, queries_of, unturned,
+)
 from tests.test_latent_moe import SEED, product_of, rows_of, run
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -293,6 +296,40 @@ def test_full_and_sliding_layers_take_their_own_heads_rotary_part_and_rope(
     assert lfm2.apply.__code__ is ops[0].apply.__code__
 
 
+@pytest.mark.parametrize("l", [0, 1], ids=["full", "sliding"])
+def test_on_xla_the_operator_is_the_parents_bit_for_bit(l, monkeypatch):
+    """On XLA's attention (every platform but the TPU) the operator that
+    hands ``attend`` the queries as projected computes **what it computed
+    when it turned, cast and gated them itself** (``parents_apply``: turn
+    -> cast -> attend -> gate in float32), on the seeded weights: a full
+    layer (6 heads, half a head turned, cos and sin scaled) and a sliding
+    one (8 heads, a ring passed five times): the output and the appended
+    state are equal, not close."""
+    seen = {}
+
+    def spy(layer):
+        seen[layer.kind] = layer
+        return ga.grouped_attention(layer)
+
+    monkeypatch.setattr(lg, "grouped_attention", spy)
+    CFG.operator(l)
+    s = scorer()
+    try:
+        (y, state, counts), (y_then, state_then) = operator_and_parent(
+            CFG, s.params, l, seen["full" if l in CACHES else "window"])
+    finally:
+        s.close()
+    assert "wg" in s.params["layers"][l] and y.dtype == jnp.float32
+    assert np.isfinite(np.asarray(y)).all() and np.asarray(y).std() > 0.01
+    assert (np.asarray(y) == np.asarray(y_then)).all()
+    assert (np.asarray(state, np.float32)
+            == np.asarray(state_then, np.float32)).all()
+    # one call's query rows by hand: 4 flows x 8 events x the layer's
+    # heads, none of them taken on a kernel's tile here
+    assert int(counts["attn.q_rows"]) == 4 * 8 * (6 if l in CACHES else 8)
+    assert int(counts["attn.q_rows_in_tile"]) == 0
+
+
 def test_the_rotary_part_turns_and_the_rest_passes():
     x = jax.random.normal(jax.random.key(1), (2, 3, 4, 16))
     pos = jnp.array([[0, 1, 2], [7, 8, 9]])
@@ -423,6 +460,9 @@ def test_a_call_counts_what_each_kind_of_layer_attended_and_holds(seqs):
     assert n["attn.window_blocks"] == 2 * len(RINGS) == n[
         "attn.window_blocks_unwindowed"]
     assert n["attn.kv_blocks"] == 2 * 4 == n["attn.kv_blocks_whole"]
+    # query rows: a layout of 2 flows x 8 events, 6 + 8 + 8 + 6 heads; XLA's
+    # attention takes none of them as projected on a kernel's tile
+    assert n["attn.q_rows"] == 2 * 8 * 28 and n["attn.q_rows_in_tile"] == 0
     assert n["state.window_rows"] == 8 * RING * len(RINGS)
     assert n["state.window_rows_as_cache"] == 8 * 256 * len(RINGS)
     assert n["cache.rows_written"] == 2 * 9 * 4
@@ -464,13 +504,16 @@ def test_the_spec_is_a_flow_model_over_the_whole_vocabulary():
 # -- the kernel over a ring, and at a head of 128 -----------------------------
 
 def ring_of(rng, F: int, T: int, H: int, G: int, hd: int, P: int, p0):
-    k = jax.random.split(jax.random.key(int(rng.integers(1 << 30))), 2)
+    """A layer's ``Queries`` (projected, still to be turned and gated),
+    its rings, the flows' slots and positions."""
+    seed = int(rng.integers(1 << 30))
     S = 2 * F + 1
     slot = (1 + 2 * rng.permutation(F)).astype(np.int32)
     slot[-1] = S        # a flow that brings nothing: read clipped
-    return (jax.random.normal(k[0], (F, T, H, hd), jnp.bfloat16),
-            jax.random.normal(k[1], (S, 2 * G * hd, P), jnp.bfloat16),
-            slot, np.asarray(p0, np.int32))
+    p0 = np.asarray(p0, np.int32)
+    return (queries_of(seed, F, T, H, hd, p0, hd // 2),
+            jax.random.normal(jax.random.key(seed), (S, 2 * G * hd, P),
+                              jnp.bfloat16), slot, p0)
 
 
 def blocks_by_hand(p0, T: int, R: int, P: int, window=None) -> tuple:
@@ -513,12 +556,15 @@ def test_the_window_kernel_is_xlas_attention_over_a_ring(layout, width):
     p0 = [1, W // 2, W - T // 2, P - T // 2, 3 * P + 5, 7 * P - T,
           2 * P - 1, 1][:F]
     q, cache, slot, p0 = ring_of(rng, F, T, H, G, hd, P, p0)
-    want, one, whole, plain = jax.jit(functools.partial(
+    want, one, whole, plain, none = jax.jit(functools.partial(
         ga.attend_grouped_xla, scale=0.25, window=W))(q, cache, slot, p0)
     assert np.asarray(one).tolist() == [1] * F == np.asarray(plain).tolist()
-    got, seen, whole, plain = jax.jit(functools.partial(
+    got, seen, whole, plain, in_tile = jax.jit(functools.partial(
         fa.grouped_attention_fused, scale=0.25, window=W, interpret=True))(
             q, cache, slot, p0)
+    assert (int(none), int(in_tile)) == (
+        0, F * T * H if fa.on_the_tile(T, H // G, P) else 0)
+    assert got.shape == want.shape == (F, T, H * hd)
     gap = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
     assert gap.max() < 0.06 and np.median(gap) < 4e-3, (gap.max(),
                                                         np.median(gap))
@@ -538,7 +584,7 @@ def test_a_window_sees_exactly_its_last_positions():
     P, W, hd = 24, 8, 16
     cache = np.zeros((1, 2 * hd, P), np.float32)
     cache[0, hd] = np.arange(P)             # value row 0: the index
-    q = jnp.ones((1, 1, 1, hd), jnp.bfloat16)
+    q = unturned(jnp.ones((1, 1, 1, hd)), 1)
     for p in (0, 3, 7, 8, 23, 24, 30, 100):
         for attend in (
                 functools.partial(ga.attend_grouped_xla, window=W),
@@ -548,8 +594,8 @@ def test_a_window_sees_exactly_its_last_positions():
                            np.zeros(1, np.int32), np.array([p], np.int32),
                            scale=1.0)
             seen = [i % P for i in range(max(0, p - W + 1), p + 1)]
-            assert float(o[0, 0, 0, 0]) == pytest.approx(np.mean(seen),
-                                                         rel=1e-2), p
+            assert float(o[0, 0, 0]) == pytest.approx(np.mean(seen),
+                                                      rel=1e-2), p
 
 
 # (heads, kv heads, head, positions): a slot of 4,096 at a head of 128,
@@ -566,11 +612,12 @@ def test_the_grouped_kernel_at_a_head_of_128_is_xlas_attention(layout, width):
     rng = np.random.default_rng(P + T)
     p0 = np.linspace(1, P - T, F).astype(np.int32)
     q, cache, slot, p0 = ring_of(rng, F, T, H, G, hd, P, p0)
-    want, _, _ = jax.jit(functools.partial(
+    want, *_ = jax.jit(functools.partial(
         ga.attend_grouped_xla, scale=0.09))(q, cache, slot, p0)
-    got, seen, whole = jax.jit(functools.partial(
+    got, seen, whole, in_tile = jax.jit(functools.partial(
         fa.grouped_attention_fused, scale=0.09, interpret=True))(
             q, cache, slot, p0)
+    assert int(in_tile) == (F * T * H if fa.on_the_tile(T, H // G, P) else 0)
     gap = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
     assert gap.max() < 0.06 and np.median(gap) < 4e-3
     by_hand = blocks_by_hand(p0, T, H // G, P)
@@ -595,8 +642,17 @@ def test_the_whole_step_on_the_kernels_is_the_reference(seqs, whole,
     short = {k: v[:48] for k, v in seqs.items()}
     s = scorer()
     try:
+        t0 = time.monotonic()
         got = in_chunks(s, short, 16)
+        t1 = time.monotonic()
     finally:
         s.close()
     for key, ids in short.items():
         close_to(got[key], whole[0][key][:len(ids)])
+    # every query row of every layer went to the kernel as projected: 3
+    # calls (the third of 2 flows in a layout of 2) of 16 events x 28 heads
+    recs = [c.counts for c in phases.records()
+            if c.kind == phases.SCORE and t0 <= c.t0 <= t1]
+    rows = sum(n["attn.q_rows"] for n in recs)
+    assert rows == sum(n["attn.q_rows_in_tile"] for n in recs)
+    assert rows == (4 + 4 + 2) * 16 * 28

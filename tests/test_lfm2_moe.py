@@ -13,11 +13,13 @@ import numpy as np
 import pytest
 
 from chipbench.reference import lfm2_moe as ref
+from linkerd_tpu.models import grouped_attention as ga
 from linkerd_tpu.models import latent_moe as lm
 from linkerd_tpu.models import lfm2_moe as lf
 from linkerd_tpu.models.spec import lfm2_moe
 from linkerd_tpu.ops import flow_attention as fa
 from linkerd_tpu.telemetry import phases
+from tests.test_flow_attention import operator_and_parent, queries_of
 from tests.test_latent_moe import (
     CFG_LFM2 as CFG, MODELS, SEED, TINY_LFM2 as TINY, close_to,
     reference_scores, rows_of, run, scorer,
@@ -127,6 +129,11 @@ def test_a_call_counts_the_state_it_wrote_of_each_kind(seqs):
     assert rec.counts["conv.state_rows"] == 2 * 2 * len(CONVS)
     assert rec.counts["cache.rows_written"] == 2 * 9 * len(ATTNS)
     assert rec.counts["cache.rows_whole"] == 2 * CFG.positions * len(ATTNS)
+    # query rows: a layout of 2 flows x 8 events x the heads, an attention
+    # layer; XLA's attention takes none as projected on a kernel's tile
+    assert rec.counts["attn.q_rows"] == 2 * 8 * CFG.num_attention_heads * len(
+        ATTNS)
+    assert rec.counts["attn.q_rows_in_tile"] == 0
     pairs = 16 * CFG.num_experts_per_tok * (CFG.layers - CFG.num_dense_layers)
     assert rec.counts["moe.local_pairs"] == pairs
     tiles = rec.counts["moe.tiles"]
@@ -198,20 +205,24 @@ def test_the_grouped_kernel_is_xlas_attention(layout, width):
     number, flows that begin, that end at the slot's last position, a
     padding flow (slot out of range, read clipped)."""
     (F, T), (H, G, hd, P) = layout, GROUPED[width]
-    k = jax.random.split(jax.random.key(F * T + P), 2)
-    q = jax.random.normal(k[0], (F, T, H, hd), jnp.bfloat16)
     S = 2 * F + 1
     slot = (1 + 2 * np.random.default_rng(F).permutation(F)).astype(np.int32)
     slot[-1] = S
-    cache = jax.random.normal(k[1], (S, 2 * G * hd, P), jnp.bfloat16)
+    cache = jax.random.normal(jax.random.key(F * T + P), (S, 2 * G * hd, P),
+                              jnp.bfloat16)
     p0 = np.linspace(1, P - T, F).astype(np.int32)
     p0[:2] = 1, 0
-    want, one, whole = jax.jit(functools.partial(
+    # as this model's layers hand them over: the whole head turned, no gate
+    q = queries_of(F * T + P, F, T, H, hd, p0, hd // 2, gated=False)
+    want, one, whole, none = jax.jit(functools.partial(
         lf.attend_grouped_xla, scale=0.25))(q, cache, slot, p0)
-    assert np.asarray(one).tolist() == [1] * F and whole == 1
-    got, seen, whole = jax.jit(functools.partial(
+    assert np.asarray(one).tolist() == [1] * F and whole == 1 and none == 0
+    got, seen, whole, in_tile = jax.jit(functools.partial(
         fa.grouped_attention_fused, scale=0.25, interpret=True))(
             q, cache, slot, p0)
+    assert got.shape == want.shape == (F, T, H * hd)
+    assert got.dtype == want.dtype == jnp.bfloat16
+    assert in_tile == (F * T * H if fa.on_the_tile(T, H // G, P) else 0)
     gap = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
     assert gap.max() < 0.05 and np.median(gap) < 4e-3, (gap.max(),
                                                         np.median(gap))
@@ -221,3 +232,34 @@ def test_the_grouped_kernel_is_xlas_attention(layout, width):
         -(-min(int(p) + T, P) // bk) for p in p0]
     assert fa.best_attention("tpu", True) is fa.grouped_attention_fused
     assert fa.best_attention("cpu", True) is lf.attend_grouped_xla
+
+
+def test_on_xla_the_operator_is_the_parents_bit_for_bit(monkeypatch):
+    """This model's attention layer (``q_norm`` and ``k_norm``, the whole
+    head turned, no gate) on XLA's attention: the operator that hands
+    ``attend`` the normed queries as projected computes what it computed
+    when it turned and cast them itself (``parents_apply``), output and
+    appended state equal, on the seeded weights."""
+    seen = []
+
+    def spy(layer):
+        seen.append(layer)
+        return ga.grouped_attention(layer)
+
+    monkeypatch.setattr(lf, "grouped_attention", spy)
+    l = ATTNS[0]
+    CFG.operator(l)
+    s = scorer(MODEL)
+    try:
+        lp = s.params["layers"][l]
+        (y, state, counts), (y_then, state_then) = operator_and_parent(
+            CFG, s.params, l, seen[-1])
+    finally:
+        s.close()
+    assert "q_norm" in lp and "wg" not in lp
+    assert np.isfinite(np.asarray(y)).all() and np.asarray(y).std() > 0.01
+    assert (np.asarray(y) == np.asarray(y_then)).all()
+    assert (np.asarray(state, np.float32)
+            == np.asarray(state_then, np.float32)).all()
+    assert int(counts["attn.q_rows"]) == 4 * 8 * CFG.num_attention_heads
+    assert int(counts["attn.q_rows_in_tile"]) == 0
