@@ -139,48 +139,55 @@ def _apply(layer: AttentionLayer, lp, cfg, cache, start_entry, h, call):
     if layer.window is not None and layer.window - 1 + T > P:
         raise ValueError(f"a chunk of {T} events behind a window of "
                          f"{layer.window} does not fit a ring of {P}")
-    x = _rms(h, lp["operator_norm"], eps)
-    cos, sin = angles(call.pos, layer.inv_freq)
-    if layer.rope_scale != 1.0:
-        cos, sin = cos * layer.rope_scale, sin * layer.rope_scale
-    cos, sin = cos[:, :, None], sin[:, :, None]
-    q = _mm(x, lp["wq"])
-    k = _mm(x, lp["wk"]).reshape(F, T, G, hd)
-    if "q_norm" in lp:
-        q = _rms(q.reshape(F, T, H, hd), lp["q_norm"], eps).reshape(F, T, -1)
-        k = _rms(k, lp["k_norm"], eps)
-    k = rotate(k, cos, sin, 2 * len(layer.inv_freq))
-    entry = jnp.concatenate([k.reshape(F, T, G * hd), _mm(x, lp["wv"])],
-                            -1).astype(jnp.bfloat16)
-    cache, written = append_chunk(cache, entry, start_entry, call.slot,
-                                  call.p0, call.count, call.begins,
-                                  positions_last=True,
-                                  ring=layer.window is not None)
-    q = Queries(q, cos, sin,    # an output gate a head, where the layer has
-                jax.nn.sigmoid(_mm(x, lp["wg"])) if "wg" in lp else None, H)
-    if layer.window is None:
-        o, blocks, whole, in_tile = call.attend(q, cache, call.slot, call.p0,
-                                                hd ** -0.5)
-        own = {"attn.{}_blocks_whole": F * whole}
-    else:
-        # a ring's rows are held whatever the flows' lengths: what is
-        # counted beside them is the same layer as a cache
-        o, blocks, whole, unwindowed, in_tile = call.attend(
-            q, cache, call.slot, call.p0, hd ** -0.5, window=layer.window)
-        own = {"attn.{}_blocks_unwindowed": unwindowed.sum(),
-               "state.{}_rows": jnp.int32(S * P),
-               "state.{}_rows_as_cache": jnp.int32(S * cfg.positions)}
-    counts = {"cache.rows_written": written,
-              "cache.rows_whole": (call.slot < S).sum() * P,
-              "attn.kv_blocks": blocks.sum(),
-              "attn.kv_blocks_whole": F * whole,
-              "attn.q_rows": jnp.int32(F * T * H),
-              "attn.q_rows_in_tile": jnp.int32(in_tile)}
-    if layer.kind:      # and under the kind's own names
-        own["attn.{}_blocks"] = blocks.sum()
-        counts.update({name.format(layer.kind): v
-                       for name, v in own.items()})
-    return _mm(o, lp["wo"]), cache, counts
+    with jax.named_scope("project"):
+        x = _rms(h, lp["operator_norm"], eps)
+        cos, sin = angles(call.pos, layer.inv_freq)
+        if layer.rope_scale != 1.0:
+            cos, sin = cos * layer.rope_scale, sin * layer.rope_scale
+        cos, sin = cos[:, :, None], sin[:, :, None]
+        q = _mm(x, lp["wq"])
+        k = _mm(x, lp["wk"]).reshape(F, T, G, hd)
+        if "q_norm" in lp:
+            q = _rms(q.reshape(F, T, H, hd), lp["q_norm"], eps).reshape(
+                F, T, -1)
+            k = _rms(k, lp["k_norm"], eps)
+        k = rotate(k, cos, sin, 2 * len(layer.inv_freq))
+        entry = jnp.concatenate([k.reshape(F, T, G * hd), _mm(x, lp["wv"])],
+                                -1).astype(jnp.bfloat16)
+    with jax.named_scope("append"):
+        cache, written = append_chunk(cache, entry, start_entry, call.slot,
+                                      call.p0, call.count, call.begins,
+                                      positions_last=True,
+                                      ring=layer.window is not None)
+    with jax.named_scope("project"):    # an output gate a head, where the
+        q = Queries(q, cos, sin,        # layer has one
+                    jax.nn.sigmoid(_mm(x, lp["wg"])) if "wg" in lp else None,
+                    H)
+    with jax.named_scope("attend"):
+        if layer.window is None:
+            o, blocks, whole, in_tile = call.attend(
+                q, cache, call.slot, call.p0, hd ** -0.5)
+            own = {"attn.{}_blocks_whole": F * whole}
+        else:
+            # a ring's rows are held whatever the flows' lengths: what is
+            # counted beside them is the same layer as a cache
+            o, blocks, whole, unwindowed, in_tile = call.attend(
+                q, cache, call.slot, call.p0, hd ** -0.5, window=layer.window)
+            own = {"attn.{}_blocks_unwindowed": unwindowed.sum(),
+                   "state.{}_rows": jnp.int32(S * P),
+                   "state.{}_rows_as_cache": jnp.int32(S * cfg.positions)}
+        counts = {"cache.rows_written": written,
+                  "cache.rows_whole": (call.slot < S).sum() * P,
+                  "attn.kv_blocks": blocks.sum(),
+                  "attn.kv_blocks_whole": F * whole,
+                  "attn.q_rows": jnp.int32(F * T * H),
+                  "attn.q_rows_in_tile": jnp.int32(in_tile)}
+        if layer.kind:      # and under the kind's own names
+            own["attn.{}_blocks"] = blocks.sum()
+            counts.update({name.format(layer.kind): v
+                           for name, v in own.items()})
+    with jax.named_scope("out"):
+        return _mm(o, lp["wo"]), cache, counts
 
 
 def grouped_attention(layer: AttentionLayer) -> Operator:
@@ -196,7 +203,7 @@ def grouped_attention(layer: AttentionLayer) -> Operator:
     return Operator(apply=apply, init=init,
                     start_of=lambda cache: cache[0, :, 0],
                     scope=f"{layer.kind}_attention".lstrip("_"), caches=True,
-                    ring=layer.ring)
+                    ring=layer.ring, parts=True)
 
 
 def attend_grouped_xla(q, cache, slot, p0, scale: float,

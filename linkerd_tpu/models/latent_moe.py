@@ -88,6 +88,7 @@ residual stream, the norms, the router and the softmaxes are float32.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import zlib
 from dataclasses import dataclass
@@ -223,22 +224,25 @@ class Operator(NamedTuple):
     operator's name in a device scope; ``caches``: whether the state
     holds positions of the flow (a row a position: ``cfg.positions`` of
     them, or where ``ring`` is set the newest ``ring``, at ``position mod
-    ring``)."""
+    ring``); ``parts``: whether ``apply`` opens the step's parts of an
+    attention operator inside its scope (``project``: norms, projections,
+    RoPE and the entry; ``append``; ``attend``; ``out``: the output's
+    projection, and the residual add that XLA fuses with it); where it
+    does not, the operator's scope is one part."""
     apply: Callable
     init: Callable
     start_of: Callable
     scope: str
     caches: bool
     ring: int = 0
+    parts: bool = False
 
 
 # what every flow model's step reports of its operators, nought where no
 # layer counts it: the blocks of positions attended over, those of the
-# slots whole, the cache rows written, those of the touched slots whole,
-# and the rows of fixed-size state written
+# slots whole, the cache rows written and those of the touched slots whole
 OPERATOR_COUNTS = ("attn.kv_blocks", "attn.kv_blocks_whole",
-                   "cache.rows_written", "cache.rows_whole",
-                   "conv.state_rows")
+                   "cache.rows_written", "cache.rows_whole")
 
 
 # -- weights from the seed ----------------------------------------------------
@@ -562,37 +566,48 @@ def _attention(lp, cfg, cache, start_entry, h, call):
     H, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                      cfg.qk_rope_head_dim)
     rank, eps = cfg.kv_lora_rank, cfg.rms_norm_eps
-    x = _rms(h, lp["attn_norm"], eps)
-    cos, sin = angles(call.pos, yarn_inv_freq(cfg))
-    q = _mm(_rms(_mm(x, lp["wdq"]), lp["q_norm"], eps), lp["wuq"]).reshape(
-        F, T, H, nope + rope)
-    q_rope = _rope(q[..., nope:], cos[:, :, None], sin[:, :, None])
-    ckr = _mm(x, lp["wdkv"])
-    entry = jnp.concatenate(
-        [_rms(ckr[..., :rank], lp["kv_norm"], eps),
-         _rope(ckr[..., rank:], cos, sin)], -1).astype(jnp.bfloat16)
-    cache, written = append_chunk(cache, entry, start_entry, call.slot,
-                                  call.p0, call.count, call.begins)
-    wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
-    q_abs = jnp.einsum("fthd,chd->fthc", q[..., :nope].astype(jnp.bfloat16),
-                       wukv[..., :nope], preferred_element_type=jnp.float32)
-    o, blocks, whole = call.attend(
-        q_abs.astype(jnp.bfloat16), q_rope.astype(jnp.bfloat16), cache,
-        call.slot, call.p0, softmax_scale(cfg))
-    o = jnp.einsum("fthc,chd->fthd", o, wukv[..., nope:],
-                   preferred_element_type=jnp.float32)
-    return (_mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"]), cache,
-            {"attn.kv_blocks": blocks.sum(), "attn.kv_blocks_whole": F * whole,
-             "cache.rows_written": written,
-             "cache.rows_whole": (call.slot < cfg.slots).sum()
-             * cfg.positions})
+    with jax.named_scope("project"):
+        x = _rms(h, lp["attn_norm"], eps)
+        cos, sin = angles(call.pos, yarn_inv_freq(cfg))
+        q = _mm(_rms(_mm(x, lp["wdq"]), lp["q_norm"], eps), lp["wuq"]
+                ).reshape(F, T, H, nope + rope)
+        q_rope = _rope(q[..., nope:], cos[:, :, None], sin[:, :, None])
+        ckr = _mm(x, lp["wdkv"])
+        entry = jnp.concatenate(
+            [_rms(ckr[..., :rank], lp["kv_norm"], eps),
+             _rope(ckr[..., rank:], cos, sin)], -1).astype(jnp.bfloat16)
+    with jax.named_scope("append"):
+        cache, written = append_chunk(cache, entry, start_entry, call.slot,
+                                      call.p0, call.count, call.begins)
+    with jax.named_scope("project"):      # the absorption of ``wukv`` into q
+        wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
+        q_abs = jnp.einsum("fthd,chd->fthc",
+                           q[..., :nope].astype(jnp.bfloat16),
+                           wukv[..., :nope],
+                           preferred_element_type=jnp.float32)
+        q_abs, q_rope = q_abs.astype(jnp.bfloat16), q_rope.astype(jnp.bfloat16)
+    with jax.named_scope("attend"):
+        o, blocks, whole = call.attend(q_abs, q_rope, cache, call.slot,
+                                       call.p0, softmax_scale(cfg))
+    with jax.named_scope("out"):
+        o = jnp.einsum("fthc,chd->fthd", o, wukv[..., nope:],
+                       preferred_element_type=jnp.float32)
+        y = _mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"])
+    with jax.named_scope("attend"):
+        return (y, cache,
+                {"attn.kv_blocks": blocks.sum(),
+                 "attn.kv_blocks_whole": F * whole,
+                 "cache.rows_written": written,
+                 "cache.rows_whole": (call.slot < cfg.slots).sum()
+                 * cfg.positions})
 
 
 LATENT_ATTENTION = Operator(
     apply=_attention,
     init=lambda cfg: jnp.zeros((cfg.slots, cfg.positions, cfg.entry_width),
                                jnp.bfloat16),
-    start_of=lambda cache: cache[0, 0], scope="attention", caches=True)
+    start_of=lambda cache: cache[0, 0], scope="attention", caches=True,
+    parts=True)
 
 
 def route(lp, cfg, x):
@@ -682,31 +697,32 @@ def routed_experts(lp, cfg, x, valid, experts=ExpertOps(), base=None):
     N, D = x.shape
     lo, hi = cfg.experts_held
     G, k, M = hi - lo, cfg.num_experts_per_tok, cfg.expert_tile
-    idx, w = route(lp, cfg, x)
-    local = (idx >= lo) & (idx < hi) & valid[:, None]
-    g = jnp.where(local, idx - lo, G).reshape(-1)           # [N * k]
-    order = jnp.argsort(g, stable=True)
-    g_sorted = g[order]
-    cnt = (g[:, None] == jnp.arange(G)[None]).sum(0).astype(jnp.int32)
-    tiles = (cnt + M - 1) // M
-    tile_end = jnp.cumsum(tiles)
-    # a group's rows start at a tile's edge; a pair's place is its group's
-    # start plus its rank among the group's pairs
-    ge = jnp.minimum(g_sorted, G - 1)
-    rank = jnp.arange(N * k) - (jnp.cumsum(cnt) - cnt)[ge]
-    most = N * min(k, G) // M + G                           # tiles at most
-    C = min(most, max(1, CHUNK_BYTES // (M * D * 4)))
-    R = -(-most // C) * C * M                               # rows: whole runs
-    dest = jnp.where(g_sorted < G, (tile_end - tiles)[ge] * M + rank, R)
-    dest_tok = jnp.full((R,), N, jnp.int32).at[dest].set(
-        (order // k).astype(jnp.int32), mode="drop")
-    dest_w = jnp.zeros((R,), jnp.float32).at[dest].set(
-        w.reshape(-1)[order], mode="drop")
-    tile_expert = jnp.minimum(
-        (jnp.arange(R // M)[:, None] >= tile_end[None]).sum(1), G - 1
-    ).astype(jnp.int32)
-    x_pad = jnp.concatenate(
-        [x.astype(jnp.bfloat16), jnp.zeros((1, D), jnp.bfloat16)])
+    with jax.named_scope("route"):     # and the sort into tiles
+        idx, w = route(lp, cfg, x)
+        local = (idx >= lo) & (idx < hi) & valid[:, None]
+        g = jnp.where(local, idx - lo, G).reshape(-1)           # [N * k]
+        order = jnp.argsort(g, stable=True)
+        g_sorted = g[order]
+        cnt = (g[:, None] == jnp.arange(G)[None]).sum(0).astype(jnp.int32)
+        tiles = (cnt + M - 1) // M
+        tile_end = jnp.cumsum(tiles)
+        # a group's rows start at a tile's edge; a pair's place is its group's
+        # start plus its rank among the group's pairs
+        ge = jnp.minimum(g_sorted, G - 1)
+        rank = jnp.arange(N * k) - (jnp.cumsum(cnt) - cnt)[ge]
+        most = N * min(k, G) // M + G                       # tiles at most
+        C = min(most, max(1, CHUNK_BYTES // (M * D * 4)))
+        R = -(-most // C) * C * M                           # rows: whole runs
+        dest = jnp.where(g_sorted < G, (tile_end - tiles)[ge] * M + rank, R)
+        dest_tok = jnp.full((R,), N, jnp.int32).at[dest].set(
+            (order // k).astype(jnp.int32), mode="drop")
+        dest_w = jnp.zeros((R,), jnp.float32).at[dest].set(
+            w.reshape(-1)[order], mode="drop")
+        tile_expert = jnp.minimum(
+            (jnp.arange(R // M)[:, None] >= tile_end[None]).sum(1), G - 1
+        ).astype(jnp.int32)
+        x_pad = jnp.concatenate(
+            [x.astype(jnp.bfloat16), jnp.zeros((1, D), jnp.bfloat16)])
 
     def run(c, carry):
         out, loads = carry
@@ -749,25 +765,36 @@ def _forward(params, cfg, operators, kept, starts, tok, call):
         with jax.named_scope(f"layer{l}.{op.scope}"):
             a, kept[l], layer_tally = op.apply(lp, cfg, kept[l], starts[l],
                                                h, call)
-            h = h + a
-            for name, v in layer_tally.items():
-                tally[name] = tally.get(name, 0) + v
+            with (jax.named_scope("out") if op.parts
+                  else contextlib.nullcontext()):
+                h = h + a
+        for name, v in layer_tally.items():
+            tally[name] = tally.get(name, 0) + v
         with jax.named_scope(f"layer{l}.ffn"):
-            x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
-            if "router" in lp:
-                flat = x.reshape(F * T, -1)
-                base = h.reshape(F * T, -1)
-                if "shared_gate" in lp:
-                    base = base + _swiglu(flat, lp["shared_gate"],
-                                          lp["shared_up"], lp["shared_down"])
+            routed = "router" in lp
+            with jax.named_scope("route" if routed else "dense"):
+                x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
+            if routed:
+                with jax.named_scope("dense"):
+                    flat = x.reshape(F * T, -1)
+                    base = h.reshape(F * T, -1)
+                    if "shared_gate" in lp:
+                        base = base + _swiglu(flat, lp["shared_gate"],
+                                              lp["shared_up"],
+                                              lp["shared_down"])
+                with jax.named_scope("route"):
+                    mine = valid.reshape(-1)
                 # the routed rows are added to the stream where it lies
                 y, cnt, loaded = routed_experts(
-                    lp, cfg, flat, valid.reshape(-1), call.experts, base)
+                    lp, cfg, flat, mine, call.experts, base)
                 counts.append(cnt)
-                loads = loads + loaded
-                h = y.reshape(F, T, -1)
+                with jax.named_scope("expert_tiles"):
+                    loads = loads + loaded
+                    h = y.reshape(F, T, -1)
             else:
-                h = h + _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
+                with jax.named_scope("dense"):
+                    h = h + _swiglu(x, lp["w_gate"], lp["w_up"],
+                                    lp["w_down"])
     G = cfg.experts_held[1] - cfg.experts_held[0]
     counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
     return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(kept),
@@ -815,7 +842,12 @@ def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
     or the kernel ``ops/flow_attention.best_attention`` gives for a TPU);
     ``experts``: the routed experts' grouped product and combine (XLA's,
     or the kernels ``ops/expert_product.best_expert_product`` gives).
-    Returns ``(scores [B] float32 in row order, state, counts)``."""
+    Returns ``(scores [B] float32 in row order, state, counts)``. Its
+    device scopes name the parts its time is read by (PERF.md section 3):
+    ``layer<l>.<operator's scope>`` (an attention operator's ``project``,
+    ``append``, ``attend`` and ``out`` inside), ``layer<l>.ffn``
+    (``route``, ``dense``, ``expert_tiles``) and ``head``; the rest (the
+    embedding, the state's bookkeeping) is in none."""
     kept, length, last_h, (starts, start_h) = state
     S, P = cfg.slots, cfg.positions
     B = rows.shape[0]

@@ -217,8 +217,7 @@ def _short_conv(lp, cfg, tail, start_tail, h, call):
     the slot is left the last ``K - 1`` rows of tail and chunk together
     (``count`` events of it: padding rows are not the flow's). A
     ``slot`` out of range reads clipped and writes nothing. Returns the
-    output, the tails, and the rows of tail written
-    (``conv.state_rows``)."""
+    output, the tails, and no counts."""
     F, T, D = h.shape
     S, K = cfg.slots, cfg.conv_L_cache
     x = _rms(h, lp["operator_norm"], cfg.rms_norm_eps)
@@ -234,8 +233,7 @@ def _short_conv(lp, cfg, tail, start_tail, h, call):
     left = jnp.take_along_axis(
         seq, (call.count[:, None] + jnp.arange(K - 1)[None])[..., None], 1)
     tail = tail.at[call.slot].set(left.astype(jnp.bfloat16), mode="drop")
-    return (_mm(gate_out * v, lp["out_proj"]), tail,
-            {"conv.state_rows": (call.slot < S).sum() * (K - 1)})
+    return _mm(gate_out * v, lp["out_proj"]), tail, {}
 
 
 SHORT_CONV = Operator(

@@ -105,6 +105,24 @@ def mlp36(recon_weight: float = 0.7) -> ModelSpec:
         make_step=make_step, score_path=score_path)
 
 
+def compiled_text(program: Callable, args: tuple, static: dict
+                  ) -> Callable[[], str]:
+    """A thunk that gives the optimised text of the jitted ``program`` as
+    it runs on ``args`` (``phases.program``'s ``describe``). It holds the
+    arguments' shapes, types and placement, not the arrays: a committed
+    array's sharding, nothing for an uncommitted one, so that the lowering
+    is the call's own and its compile a hit in JAX's caches."""
+    import jax
+
+    def like(a):
+        return jax.ShapeDtypeStruct(
+            np.shape(a), a.dtype, weak_type=getattr(a, "weak_type", False),
+            sharding=a.sharding if getattr(a, "committed", False) else None)
+
+    shapes = jax.tree_util.tree_map(like, args)
+    return lambda: program.lower(*shapes, **static).compile().as_text()
+
+
 def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
     """A flow model's spec: a row is int32 ``(stream key, restart flag,
     event id)``, laid out by ``FlowTable``; what the layers keep of a
@@ -120,6 +138,7 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
     import jax
 
     from linkerd_tpu.models import latent_moe as lm
+    from linkerd_tpu.telemetry import phases
     from linkerd_tpu.telemetry.flowstate import FlowTable
 
     built = {}      # what make_step chose, for describe
@@ -144,12 +163,18 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
             lm.flow_step,
             static_argnames=("cfg", "F", "T", "attend", "experts"),
             donate_argnums=(1, 2))
+        registered = set()      # the layouts whose program is registered
 
         def step(params, state, rows, n, layout):
             def run(state, rows, n):
-                return program(params, state, rows, np.int32(n), cfg=cfg,
-                               F=layout[0], T=layout[1], attend=attend,
-                               experts=experts)
+                args = (params, state, rows, np.int32(n))
+                static = {"cfg": cfg, "F": layout[0], "T": layout[1],
+                          "attend": attend, "experts": experts}
+                if (layout, rows.shape) not in registered:
+                    registered.add((layout, rows.shape))
+                    phases.program("jit_flow_step",
+                                   compiled_text(program, args, static))
+                return program(*args, **static)
             if state[-1] is None:
                 # once: the same program, on arguments like this call's
                 state = lm.with_start(run, cfg, state, rows)

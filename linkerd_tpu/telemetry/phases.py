@@ -49,15 +49,23 @@ byte moves) on the loop, before the coroutine first yields; THREAD_HOP,
 STEP x ``fit_steps`` (each train-step call, on the resident batch),
 LOSS_WAIT (the one wait: transfer, statistics and steps) on the worker
 thread; RETURN_HOP back onto the loop.
+
+Beside the calls, the compiled programs' scopes: a step that wants its
+device time read by part registers each variant it compiles (``program``:
+a name and a thunk that gives the optimised program's text), and a reader
+after the window gets, per variant, which device scope (``op_name``) each
+instruction of the program was made under (``program_scopes``): the thunk
+runs then, once, and never on the path that serves.
 """
 
 from __future__ import annotations
 
 import collections
 import copy
+import re
 import threading
 import time
-from typing import Dict, Iterator, List, Sequence, Tuple
+from typing import Callable, Dict, Iterator, List, Sequence, Tuple
 
 LOG_CAPACITY = 4096
 
@@ -123,3 +131,86 @@ def records() -> List[Call]:
     """The log as it stands, oldest first."""
     with _lock:
         return list(_log)
+
+
+# -- the compiled programs' scopes --------------------------------------------
+
+PROGRAM_VARIANTS = 16       # kept a name, the newest
+
+_HEADER = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=]+) = (.*)$")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+# a loop's computations, whose instructions run on the device as the
+# entry's do (a fusion's or a reduction's are inside one instruction)
+_LOOP = re.compile(r"\b(?:condition|body)=%?([\w.\-]+)")
+
+
+class _Program:
+    """One registered variant: its thunk until first read, then its map."""
+
+    __slots__ = ("describe", "scopes")
+
+    def __init__(self, describe: Callable[[], str]):
+        self.describe = describe
+        self.scopes: Dict[str, str] = {}
+
+
+_programs: Dict[str, "collections.deque[_Program]"] = {}
+
+
+def program(name: str, describe: Callable[[], str]) -> None:
+    """Register a variant of the compiled program ``name`` (the name a
+    device trace gives it, ``jit_flow_step``). ``describe()`` returns the
+    optimised program's text (``compile().as_text()``) and must hold no
+    array: it is kept after the program's owner is gone, and runs only
+    when the scopes are first read."""
+    with _lock:
+        _programs.setdefault(name, collections.deque(
+            maxlen=PROGRAM_VARIANTS)).append(_Program(describe))
+
+
+def program_scopes(name: str) -> List[Dict[str, str]]:
+    """Per registered variant of ``name``, oldest first: ``{instruction:
+    scope path}`` over the instructions that run as operations of the
+    program (``instruction_scopes``)."""
+    with _lock:
+        variants = list(_programs.get(name, ()))
+    for v in variants:
+        if v.describe is not None:
+            v.scopes, v.describe = instruction_scopes(v.describe()), None
+    return [v.scopes for v in variants]
+
+
+def instruction_scopes(text: str) -> Dict[str, str]:
+    """``{instruction: op_name}`` of an optimised HLO module's text: the
+    instructions of its entry computation and of every loop's body and
+    condition among them, each by its name as a device trace gives it
+    (without ``%``). An instruction that carries no scope takes its
+    loop's ("" in the entry)."""
+    bodies: Dict[str, List[Tuple[str, str]]] = {}
+    entry, current = None, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line[:1].isspace():
+            current = _HEADER.match(line).group(1)
+            bodies[current] = []
+            if line.startswith("ENTRY"):
+                entry = current
+            continue
+        m = _INSTRUCTION.match(line) if current is not None else None
+        if m:
+            bodies[current].append(m.groups())
+        elif line.startswith("}"):
+            current = None
+    scopes: Dict[str, str] = {}
+    todo = [(entry, "")] if entry is not None else []
+    seen = set()
+    while todo:
+        computation, outer = todo.pop()
+        if computation in seen or computation not in bodies:
+            continue
+        seen.add(computation)
+        for name, rest in bodies[computation]:
+            m = _OP_NAME.search(rest)
+            scopes[name] = m.group(1) if m else outer
+            todo.extend((c, scopes[name]) for c in _LOOP.findall(rest))
+    return scopes

@@ -12,6 +12,7 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -117,20 +118,33 @@ def main() -> int:
     args = ap.parse_args()
 
     os.makedirs(OUT_DIR, exist_ok=True)
-    header = os.path.join(HERE, "huffman_table.h")
-    with open(header, "w") as f:
+    fd, tmp = tempfile.mkstemp(dir=HERE, prefix=".huffman_table.")
+    with os.fdopen(fd, "w") as f:
         f.write(gen_table())
+    os.chmod(tmp, 0o644)
+    os.replace(tmp, os.path.join(HERE, "huffman_table.h"))
     if args.sanitize:
         return build_sanitized(args.sanitize)
+    # pytest's workers may build at once: each links into a file of its
+    # own and renames it into place, so none loads a half-written library
+    fd, tmp = tempfile.mkstemp(dir=OUT_DIR, prefix=".libl5d_native.",
+                               suffix=".so")
+    os.close(fd)
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-Wall",
         os.path.join(HERE, "l5d_native.cpp"),
         os.path.join(HERE, "fastpath.cpp"),
         os.path.join(HERE, "h2_fastpath.cpp"),
-        "-o", SO_PATH, "-lpthread", "-ldl",
+        "-o", tmp, "-lpthread", "-ldl",
     ]
     print(" ".join(cmd))
-    subprocess.check_call(cmd)
+    try:
+        subprocess.check_call(cmd)
+        os.chmod(tmp, 0o755)
+        os.replace(tmp, SO_PATH)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     print(f"built {SO_PATH}")
     return 0
 

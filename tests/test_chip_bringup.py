@@ -4,6 +4,7 @@ cache is placed from outside, and ``chip_smoke.py`` cannot pass without a
 chip. (Sorts before test_multicore.py on purpose: tier-1 is time-boxed.)"""
 
 import ast
+import re
 import os
 import subprocess
 import sys
@@ -702,6 +703,17 @@ class TestScorerSelection:
             "    if reads or large(typ):\n"
             "        print('QO', scope.group(1), rest.split('(', 1)[0], name,\n"
             "              reads, large(typ))\n"
+            "# the parts the benchmark reads the step's device time by: how\n"
+            "# many of them each instruction under a layer's scope lies in\n"
+            "from linkerd_tpu.telemetry import phases\n"
+            "with open('chipbench/metrics/flow_step.unattributed_pct.json'\n"
+            "          ) as f:\n"
+            "    parts = json.load(f)['scopes']\n"
+            "for name, path in phases.instruction_scopes(text).items():\n"
+            "    if re.search(r'(^|/)layer\\d+\\.', path):\n"
+            "        found = {p for c in path.split('/') for p in parts\n"
+            "                 if c == p or c.endswith('.' + p)}\n"
+            "        print('PART', len(found), name, path)\n"
             "print('COMPILED', topo.devices[0].device_kind)\n")
         proc = _run([sys.executable, "-c", code], timeout=900,
                     env=_clean_env(
@@ -745,6 +757,29 @@ class TestScorerSelection:
             ], (scope, touched)
             assert [name for op, *_, name in touched if op == "custom-call"
                     ][0].startswith(f"%{kernel}_attention_fused"), touched
+
+    def test_laguna_step_puts_each_layer_instruction_in_one_part(
+            self, laguna_step_compiled):
+        """Every instruction of the optimised step (its entry's and its
+        loops') made under a layer's scope lies in exactly one of the
+        parts the benchmark reads the step's device time by
+        (``chipbench/readers/program_scope_ms.py``): an attention layer's
+        ``project``, ``append``, ``attend``, ``out``; the feed-forward's
+        ``route``, ``dense``, ``expert_tiles``. None is in two, so no
+        device time is counted twice, and none in none, so what no part
+        holds is the embedding, the state's bookkeeping and what the
+        compiler adds with no scope."""
+        seen = [line.split(None, 3)[1:]
+                for line in laguna_step_compiled.splitlines()
+                if line.startswith("PART")]
+        assert len(seen) > 1000
+        assert not [s for s in seen if s[0] != "1"], [
+            s for s in seen if s[0] != "1"][:10]
+        layers = {re.search(r"layer\d+\.\w+", path).group(0)
+                  for _, _, path in seen}
+        assert layers == {"layer0.full_attention", "layer4.full_attention",
+                          *(f"layer{l}.window_attention" for l in (1, 2, 3)),
+                          *(f"layer{l}.ffn" for l in range(5))}
 
     def test_laguna_step_compiles_for_v5e_at_the_published_widths(
             self, laguna_step_compiled):

@@ -767,8 +767,8 @@ class TestFastpathNativeFeed:
         idle engine drains zero rows into a ring view and rejects
         non-contiguous/wrong-dtype buffers."""
         native = pytest.importorskip("linkerd_tpu.native")
-        if not native.available():
-            pytest.skip("native lib unavailable")
+        if not native.ensure_built():
+            pytest.skip("native toolchain unavailable")
         eng = native.FastPathEngine()
         try:
             ring = NativeFeatureRing(16)

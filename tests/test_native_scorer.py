@@ -39,7 +39,7 @@ from linkerd_tpu.testing.faults import EchoBackend
 native = pytest.importorskip("linkerd_tpu.native")
 
 pytestmark = pytest.mark.skipif(
-    not native.available(), reason="native lib unavailable")
+    not native.ensure_built(), reason="native toolchain unavailable")
 
 
 def run(coro):

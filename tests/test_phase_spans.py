@@ -133,9 +133,7 @@ class TestScorePath:
                 # the append: a window of T + 1 = 5 of a slot's 64
                 # positions a flow a layer
                 "cache.rows_written": 3 * 2 * 5,
-                "cache.rows_whole": 3 * 2 * 64,
-                # no layer of this model keeps a state of fixed size
-                "conv.state_rows": 0}
+                "cache.rows_whole": 3 * 2 * 64}
         assert timing["bytes"] == 8 * 3 * 4 + 8 * 4
         assert state["flow"]["layouts"] == {"2x4": 2}
         assert np.shape(state["flow"]["expert_tokens"]) == (2, 4)
@@ -455,6 +453,136 @@ def test_reader_returns_nothing_without_spans(hand_made_run, monkeypatch,
         {**hand_made_run, "trace": None}, {"spans": ["fit.step"]}) is None
 
 
+# two variants of one program, as a process that compiled it for two layouts
+# holds them: the instructions of the first run a loop whose body's kernel
+# carries no scope of its own (it takes the loop's), a copy no scope at all
+SCOPED_HLO = """HloModule jit_scoped_step, is_scheduled=true
+
+%fused_computation (param_0: f32[8]) -> f32[8] {
+  %param_0 = f32[8]{0} parameter(0)
+  ROOT %inside = f32[8]{0} multiply(%param_0, %param_0), metadata={op_name="jit(step)/nowhere"}
+}
+
+%body (p: (f32[8])) -> (f32[8]) {
+  %p = (f32[8]{0}) parameter(0)
+  %gte = f32[8]{0} get-tuple-element(%p), index=0
+  %fusion.1 = f32[8]{0} fusion(%gte), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/layer1.ffn/expert_tiles/while/body/mul"}
+  %custom-call.1 = f32[8]{0} custom-call(%fusion.1), custom_call_target="tpu_custom_call"
+  ROOT %tuple.1 = (f32[8]{0}) tuple(%custom-call.1)
+}
+
+%cond (q: (f32[8])) -> pred[] {
+  %q = (f32[8]{0}) parameter(0)
+  ROOT %constant.1 = pred[] constant(false)
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0), metadata={op_name="a"}
+  %fusion.2 = f32[8]{0} fusion(%a), kind=kOutput, calls=%fused_computation, metadata={op_name="jit(step)/layer0.attention/project/dot_general"}
+  %tuple.2 = (f32[8]{0}) tuple(%fusion.2)
+  %while.1 = (f32[8]{0}) while(%tuple.2), condition=%cond, body=%body, metadata={op_name="jit(step)/layer1.ffn/expert_tiles/while"}
+  %gte.2 = f32[8]{0} get-tuple-element(%while.1), index=0
+  %fusion.3 = f32[8]{0} fusion(%gte.2), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/head/reduce_max"}
+  %copy.1 = f32[8]{0} copy(%fusion.3)
+  ROOT %fusion.4 = f32[8]{0} fusion(%copy.1), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(step)/layer2.conv/mul"}
+}
+"""
+OTHER_LAYOUT_HLO = """HloModule jit_scoped_step
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %fusion.7 = f32[8]{0} fusion(%a), kind=kLoop, calls=%f, metadata={op_name="jit(step)/layer0.attention/project/mul"}
+  %fusion.8 = f32[8]{0} fusion(%fusion.7), kind=kLoop, calls=%f, metadata={op_name="jit(step)/layer0.attention/append/scatter"}
+  ROOT %custom-call.8 = f32[8]{0} custom-call(%fusion.8), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/layer0.attention/attend/pallas_call"}
+}
+"""
+PARTS = ["project", "out", "append", "attend", "conv", "route",
+         "expert_tiles", "dense", "head"]
+
+
+def _op(name, kind, a, b):
+    return {"plane": "/device:TPU:0", "name": f"%{name} = f32[8]{{0}} "
+            f"{kind}(%x), metadata={{}}", "start": a * 1e9,
+            "dur": (b - a) * 1e9}
+
+
+@pytest.fixture(scope="module")
+def scoped_run():
+    """Executions of three programs on one device, in seconds on the
+    trace's clock. ``jit_scoped_step`` (200-210, by the first variant):
+    ``project`` 200-202; the loop 202-207 holding its body's fusion
+    202.5-204 and kernel 204-206.5, both ``expert_tiles``, so 5 s of which
+    the loop's own is 1; ``head`` 207-208; a copy of no scope 208-208.5;
+    ``conv`` 208.5-209.5; idle 209.5-210. Again (300-310, by the second):
+    ``project`` 300-303, ``append`` 303-305, ``attend`` 305-309. Each has
+    10% in no part. ``jit_unmapped_step``: an operation its one variant
+    does not name. ``jit_unnamed_step``: no variant registered."""
+    phases.program("jit_scoped_step", lambda: SCOPED_HLO)
+    phases.program("jit_scoped_step", lambda: OTHER_LAYOUT_HLO)
+    phases.program("jit_unmapped_step", lambda: OTHER_LAYOUT_HLO)
+
+    def program(name, start, ops):
+        return {"plane": "/device:TPU:0", "name": name, "start": start * 1e9,
+                "dur": 10e9, "ops": ops}
+    yield {"trace": {"programs": [
+        program("jit_scoped_step", 200, [
+            _op("fusion.2", "fusion", 200, 202),
+            _op("while.1", "while", 202, 207),
+            _op("fusion.1", "fusion", 202.5, 204),
+            _op("custom-call.1", "custom-call", 204, 206.5),
+            _op("fusion.3", "fusion", 207, 208),
+            _op("copy.1", "copy", 208, 208.5),
+            _op("fusion.4", "fusion", 208.5, 209.5)]),
+        program("jit_scoped_step", 300, [
+            _op("fusion.7", "fusion", 300, 303),
+            _op("fusion.8", "fusion", 303, 305),
+            _op("custom-call.8", "custom-call", 305, 309)]),
+        program("jit_unmapped_step", 400, [
+            _op("fusion.7", "fusion", 400, 401),
+            _op("fusion.9", "fusion", 401, 402)]),
+        program("jit_unnamed_step", 500, [
+            _op("fusion.7", "fusion", 500, 501)])]}}
+
+
+SCOPE_READINGS = [
+    # the first execution's 2 s and the second's 3: the median of two
+    ("^jit_scoped_step$", ["project", "out"], "ms", 2500.0),
+    # the loop's own 1 s and its body's 4 (the kernel's by the loop's
+    # scope), not its 5 again: 5 s and 0
+    ("^jit_scoped_step$", ["expert_tiles"], "ms", 2500.0),
+    ("^jit_scoped_step$", ["conv"], "ms", 500.0),
+    ("^jit_scoped_step$", ["attend"], "ms", 2000.0),
+    ("^jit_scoped_step$", ["dense"], "ms", 0.0),
+    # every part: 9 s of each 10, and the copy, which no part names, and
+    # the idle half second the other tenth: the two make the step
+    ("^jit_scoped_step$", PARTS, "ms", 9000.0),
+    ("^jit_scoped_step$", PARTS, "outside_pct", 10.0),
+    ("^jit_scoped_step$", ["head"], "outside_pct", 95.0),
+    ("^jit_unmapped_step$", PARTS, "ms", None),
+    ("^jit_unnamed_step$", PARTS, "outside_pct", None),
+]
+
+
+@pytest.mark.parametrize("program,scopes,stat,want", SCOPE_READINGS)
+def test_program_scope_reader_on_a_hand_made_run(scoped_run, program, scopes,
+                                                 stat, want):
+    from chipbench import harness
+    got = harness.load_code("readers", "program_scope_ms").read(
+        scoped_run, {"program": program, "scopes": scopes, "stat": stat})
+    assert got == (want if want is None else pytest.approx(want, rel=1e-9))
+
+
+def test_program_scope_reader_reads_nothing_without_the_registry(
+        scoped_run, monkeypatch):
+    """As on the parent of PR 38, whose ``phases`` registers no program:
+    the driver lays this benchmark's files over it for a traced run."""
+    from chipbench import harness
+    monkeypatch.delattr(phases, "program_scopes")
+    assert harness.load_code("readers", "program_scope_ms").read(
+        {"trace": scoped_run["trace"]}, {"program": "^jit_scoped_step$",
+                                         "scopes": PARTS, "stat": "ms"}) is None
+
+
 def test_every_new_per_layer_entry_has_its_files_and_its_arrow():
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         manifest = json.load(f)
@@ -490,12 +618,20 @@ def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == [flow_cell]]
     assert len(mine) == 18     # 17 of PRs 28-31, ``moe.weight_loads_share``
-    assert not [m for m in manifest["per_layer"] if m not in mine
-                and flow_cell in m.get("workloads", [])]
+    # and PR 38's parts of the step, each over the flow cells that have it
+    shared = [m for m in manifest["per_layer"] if m not in mine
+              and flow_cell in m.get("workloads", [])]
+    assert [m["name"] for m in shared] == [
+        f"flow_step.{p}" for p in ("project_ms", "append_ms", "attend_ms",
+                                   "route_ms", "experts_ms", "dense_ms",
+                                   "head_ms", "unattributed_pct")]
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           "flow_step.unattributed_pct.json")) as f:
+        parts = json.load(f)["scopes"]
     with open(os.path.join(REPO, "linkerd_tpu", "telemetry",
                            "phases.py")) as f:
         documented = f.read()
-    for m in mine:
+    for m in mine + shared:
         assert m["moves"] in e2e
         with open(os.path.join(REPO, "chipbench", "metrics",
                                m["name"] + ".json")) as f:
@@ -508,6 +644,9 @@ def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
             assert count is None or f"``{count}``" in documented, count
         if "program" in how:
             assert how["program"] == "^jit_flow_step$"
+        # a part's names are among those the step opens (the
+        # ``_lowered_for_the_chip`` test below holds the step to them)
+        assert set(how.get("scopes", [])) <= set(parts)
 
 
 # -- names on the device ------------------------------------------------------
@@ -541,3 +680,115 @@ def test_lowered_programs_carry_their_scopes_and_the_kernel_its_name():
         scorer.close()
     for scope in ("normalize", "loss_grad", "adam"):
         assert f"jit(step)/{scope}/" in text, scope
+
+
+FLOW_CELLS = {"latent_moe": "kimi-k2-6-ep32.flows64x64",
+              "lfm2_moe": "lfm2-24b-a2b.flows64x64-fullvocab",
+              "laguna_moe": "laguna-xs.2.flows64x64-long"}
+
+
+def _tiny(model):
+    from tests.test_laguna_moe import CFG as LAGUNA
+    from tests.test_latent_moe import MODELS
+    return LAGUNA if model == "laguna_moe" else MODELS[model].cfg
+
+
+@pytest.mark.parametrize("model", sorted(FLOW_CELLS))
+def test_a_flow_step_lowered_for_the_chip_carries_the_scopes_its_cell_reads(
+        model):
+    """The step as a TPU gets it (both kernels), at a tiny size, opens
+    every scope that a ``program_scope_ms`` entry listing the model's cell
+    puts down a part to: ``layer<l>.conv`` for LFM2's convolutions."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from linkerd_tpu.models import latent_moe as lm
+    from linkerd_tpu.ops.expert_product import best_expert_product
+    from linkerd_tpu.ops.flow_attention import best_attention
+
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    wanted = set()
+    for m in manifest["per_layer"]:
+        with open(os.path.join(REPO, "chipbench", "metrics",
+                               m["name"] + ".json")) as f:
+            how = json.load(f)
+        if (how["reader"] == "program_scope_ms" and how["stat"] == "ms"
+                and FLOW_CELLS[model] in m["workloads"]):
+            wanted |= set(how["scopes"])
+    assert len(wanted) == (9 if model == "lfm2_moe" else 8)
+    cfg = _tiny(model)
+    params = jax.eval_shape(lambda: lm.init(jax.random.key(0), cfg))
+    state = jax.eval_shape(lambda: lm.init_state(cfg))[:3] + (
+        lm.start_shapes(cfg),)
+    text = jax.jit(lm.flow_step, static_argnames=(
+        "cfg", "F", "T", "attend", "experts")).trace(
+        params, state, jax.ShapeDtypeStruct((64, 3), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.int32), cfg=cfg, F=8, T=8,
+        attend=best_attention("tpu", model != "latent_moe"),
+        experts=best_expert_product("tpu")).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    opened = set(re.findall(r"[/.](\w+)(?=/)", text))
+    assert wanted <= opened, wanted - opened
+
+
+def test_the_flow_steps_registered_map_names_the_compiled_steps_instructions():
+    """A scorer registers its step once, on its first call; the thunk runs
+    when the scopes are read, compiles nothing (the lowering is the call's
+    own, in JAX's cache), and names exactly the instructions that run as
+    operations of the compiled step: its entry's and its loops'."""
+    import re
+
+    import jax
+
+    from linkerd_tpu.models import latent_moe as lm
+    from linkerd_tpu.models.spec import latent_moe
+    from tests.test_latent_moe import CFG, rows_of
+
+    async def go():
+        scorer = InProcessScorer(seed=1, spec=latent_moe(CFG),
+                                 devices=jax.devices()[:1])
+        try:
+            before = len(phases.program_scopes("jit_flow_step"))
+            rows = rows_of({5: [3, 4, 5], 6: [7, 8]})
+            for _ in range(3):
+                await scorer.score(rows)
+            return before, scorer.params, rows
+        finally:
+            scorer.close()
+
+    before, params, rows = run(go())
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _s, **_k: compiles.append(event))
+    maps = phases.program_scopes("jit_flow_step")
+    assert len(maps) == before + 1 and not [
+        e for e in compiles if "backend_compile" in e]
+    scopes = maps[-1]
+
+    # the same program compiled anew, its instructions listed apart: those
+    # of every computation but what runs inside one instruction (a
+    # fusion's, a reduction's or a sort's comparator, a custom call's)
+    state = jax.eval_shape(lambda: lm.init_state(CFG))[:3] + (
+        lm.start_shapes(CFG),)
+    text = jax.jit(lm.flow_step, static_argnames=(
+        "cfg", "F", "T", "attend", "experts"), donate_argnums=(1, 2)).lower(
+        params, state, jax.ShapeDtypeStruct((8, 3), rows.dtype),
+        np.int32(5), cfg=CFG, F=2, T=4, attend=lm.attend_xla,
+        experts=lm.ExpertOps()).compile().as_text()
+    inside = {name.strip().lstrip("%") for pair in re.findall(
+        r"(?:calls|to_apply)=%?([\w.\-]+)|called_computations=\{([^}]*)\}",
+        text) for names in pair for name in names.split(",") if name}
+    names, current = set(), None
+    for line in text.splitlines():
+        header = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if header:
+            current = header.group(1)
+        named = re.match(r"^\s+(?:ROOT )?%?([\w.\-]+) = ", line)
+        if named and current not in inside:
+            names.add(named.group(1))
+    assert set(scopes) == names
+    assert {p.split("/")[1] for p in scopes.values() if "/" in p} >= {
+        "layer0.attention", "layer1.ffn", "head"}

@@ -39,7 +39,8 @@ def run(coro):
 # ── C vs Python featurization parity ─────────────────────────────────────
 
 
-@pytest.mark.skipif(not native.available(), reason="needs libl5d_native")
+@pytest.mark.skipif(not native.ensure_built(),
+                    reason="native toolchain unavailable")
 class TestFeaturizationParity:
     """The Python tracker must reproduce the engines' float32 EWMA
     arithmetic BIT-FOR-BIT: the in-plane scorer and the Python-side
@@ -713,7 +714,8 @@ routers:
 # ── native engine config surface (no traffic) ────────────────────────────
 
 
-@pytest.mark.skipif(not native.available(), reason="needs libl5d_native")
+@pytest.mark.skipif(not native.ensure_built(),
+                    reason="native toolchain unavailable")
 class TestNativeStreamConfig:
     def test_stream_cfg_accepted_and_snapshot_enabled(self):
         eng = native.FastPathEngine()
