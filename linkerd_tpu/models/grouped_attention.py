@@ -19,8 +19,10 @@ by ``sigmoid(x wg)[i]`` before ``wo``.
 position's rotated keys and then its values, positions along the lanes, as
 the kernel of ``ops/flow_attention.py`` reads a slot (the compiler would
 store a multiple of 128 lanes entry-minor and transpose it, a copy of the
-layer, every call). ``append_chunk`` writes the call's entries in place,
-then the chunk attends over its flow's slot by the step's ``attend``,
+layer, every call). The step's ``append`` writes the call's entries in
+place (on a TPU a flow's whole lane tiles, a ring's wrap in the same
+pass: ``ops/cache_append.py``), then the chunk attends over its flow's
+slot by the step's ``attend``,
 which is handed ``q`` **as the projection left it** (``Queries``: float32,
 unturned, with the angles and the gate) and hands back what ``wo``
 multiplies: on a TPU the kernel turns, rounds and gates on its tile, and
@@ -55,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from linkerd_tpu.models.latent_moe import (
-    ATTENTION_BLOCK, Operator, _mm, _rms, _rope, angles, append_chunk,
+    ATTENTION_BLOCK, Operator, _mm, _rms, _rope, angles,
 )
 
 RING_BLOCK = 128    # a ring is whole blocks of the kernel's positions
@@ -155,10 +157,9 @@ def _apply(layer: AttentionLayer, lp, cfg, cache, start_entry, h, call):
         entry = jnp.concatenate([k.reshape(F, T, G * hd), _mm(x, lp["wv"])],
                                 -1).astype(jnp.bfloat16)
     with jax.named_scope("append"):
-        cache, written = append_chunk(cache, entry, start_entry, call.slot,
-                                      call.p0, call.count, call.begins,
-                                      positions_last=True,
-                                      ring=layer.window is not None)
+        cache, written, in_kernel = call.append(
+            cache, entry, start_entry, call.slot, call.p0, call.count,
+            call.begins, positions_last=True, ring=layer.window is not None)
     with jax.named_scope("project"):    # an output gate a head, where the
         q = Queries(q, cos, sin,        # layer has one
                     jax.nn.sigmoid(_mm(x, lp["wg"])) if "wg" in lp else None,
@@ -176,8 +177,11 @@ def _apply(layer: AttentionLayer, lp, cfg, cache, start_entry, h, call):
             own = {"attn.{}_blocks_unwindowed": unwindowed.sum(),
                    "state.{}_rows": jnp.int32(S * P),
                    "state.{}_rows_as_cache": jnp.int32(S * cfg.positions)}
+        live = (call.slot < S).sum()
         counts = {"cache.rows_written": written,
-                  "cache.rows_whole": (call.slot < S).sum() * P,
+                  "cache.rows_whole": live * P,
+                  "append.flows": live,
+                  "append.flows_in_kernel": in_kernel,
                   "attn.kv_blocks": blocks.sum(),
                   "attn.kv_blocks_whole": F * whole,
                   "attn.q_rows": jnp.int32(F * T * H),

@@ -29,10 +29,13 @@ Per layer ``h += Attn(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``:
   one rope key, so a flow's queries are one ``[events x heads, rank +
   rope]`` matrix against its slot ``[positions, rank + rope]``. **The
   cache is read and written where it lies** (PR 31). A layer first
-  appends the call's entries in place (``append_chunk``: a window of
-  ``T + 1`` positions a flow is read, the chunk's rows and, where the flow
-  begins, the start token's are set into it, and the window is written
-  back; no slot is gathered, merged and written back whole), then the
+  appends the call's entries in place by the step's ``append`` (on a TPU
+  ``ops/cache_append.py``'s kernel: a flow's one or two tiles of 128
+  positions read, merged and written back whole; elsewhere
+  ``append_chunk``: a window of ``T + 1`` positions a flow is read, the
+  chunk's rows and, where the flow begins, the start token's are set into
+  it, and the window is written back; no slot is gathered, merged and
+  written back whole), then the
   chunk attends over its flow's slot of the appended cache. *How that
   product runs is the step's ``attend``*, which takes the layer's cache
   whole and each flow's slot number, chosen by platform where the step is
@@ -197,9 +200,11 @@ class Call(NamedTuple):
     its ``slot`` (``slots`` where the flow brings nothing), the position
     ``p0`` its chunk is appended at, the chunk's ``count`` events and
     whether the flow ``begins`` here; ``pos [F, T]`` each event's
-    position; ``attend``: the attention over a slot, and ``experts``: the
-    routed experts' grouped product and combine (``ExpertOps``), that
-    the step was built with."""
+    position; ``attend``: the attention over a slot, ``experts``: the
+    routed experts' grouped product and combine (``ExpertOps``), and
+    ``append``: how a chunk's entries reach a layer's state
+    (``append_chunk``'s signature and result), that the step was built
+    with."""
     slot: Any
     p0: Any
     count: Any
@@ -207,6 +212,7 @@ class Call(NamedTuple):
     pos: Any
     attend: Callable
     experts: Any
+    append: Callable
 
 
 class Operator(NamedTuple):
@@ -240,9 +246,12 @@ class Operator(NamedTuple):
 
 # what every flow model's step reports of its operators, nought where no
 # layer counts it: the blocks of positions attended over, those of the
-# slots whole, the cache rows written and those of the touched slots whole
+# slots whole, the cache rows written and those of the touched slots whole,
+# the flows appended to (a slot in range, a layer) and of those the flows
+# a kernel appended
 OPERATOR_COUNTS = ("attn.kv_blocks", "attn.kv_blocks_whole",
-                   "cache.rows_written", "cache.rows_whole")
+                   "cache.rows_written", "cache.rows_whole",
+                   "append.flows", "append.flows_in_kernel")
 
 
 # -- weights from the seed ----------------------------------------------------
@@ -488,21 +497,24 @@ def append_chunk(cache, entry, start_entry, slot, p0, count, begins,
     slot, the entries are set into it and the window is written back over
     itself, so what the window holds besides is bit for bit what it was;
     with a donated cache that is ``F`` small updates in place (XLA makes
-    a loop of ``F`` reads and one of ``F`` writes of it: 0.63 ms a layer
-    at the benchmark's cell, where gathering and scattering whole slots
-    took 4.07; a scatter of single rows makes XLA relayout the whole
-    layer; a Pallas append of whole lane tiles took 0.15 but writes 256
-    positions a flow and is a second implementation; my chip runs, PR
-    31). A ``slot`` out of range (a flow of the layout that brings
-    nothing) reads clipped and writes nothing. ``positions_last``: the
+    a loop of ``F`` reads and one of ``F`` writes of it, each an
+    unaligned window: 0.63 ms a layer of the Kimi cell, 1.1-2.25 of the
+    Laguna cell's; a scatter of single rows makes XLA relayout the whole
+    layer). **This is the append of every platform but the TPU**, and
+    what ``ops/cache_append.cache_append_fused`` (the TPU's: whole lane
+    tiles by a Pallas kernel, both of a ring's windows in one pass) is
+    tested against and hands the shapes it does not serve. A ``slot`` out
+    of range (a flow of the layout that brings nothing) reads clipped and
+    writes nothing. ``positions_last``: the
     cache lies ``[slots, entry, positions]`` (as a kernel reads it where
     the compiler would not store it so of itself), and a window is
     ``[entry, W]`` of it. ``ring``: a slot is a ring of its positions,
     position ``p`` at ``p mod positions``: a chunk that passes the
     ring's end goes on at its start, by a second window there (read,
     set and written back likewise, for the flows whose chunk wraps).
-    Returns the cache and the rows written (``W`` a window of a slot in
-    range)."""
+    Returns the cache, the rows written (``W`` a window of a slot in
+    range) and the flows a kernel appended: none (``Call.append``'s
+    result)."""
     S, P, E = cache.shape
     if positions_last:
         P, E = E, P
@@ -533,12 +545,12 @@ def append_chunk(cache, entry, start_entry, slot, p0, count, begins,
 
     if not ring:
         return (put(cache, slot, jnp.clip(p0 - 1, 0, P - W)),
-                (slot < S).sum() * W)
+                (slot < S).sum() * W, jnp.int32(0))
     at = (p0 - 1) % P                       # the start token's, or the
     cache = put(cache, slot, jnp.minimum(at, P - W))    # position before
     wraps = jnp.where(at + W > P, slot, S)
     return (put(cache, wraps, jnp.zeros_like(at)),
-            ((slot < S).sum() + (wraps < S).sum()) * W)
+            ((slot < S).sum() + (wraps < S).sum()) * W, jnp.int32(0))
 
 
 def angles(pos, inv_freq):
@@ -551,7 +563,7 @@ def _attention(lp, cfg, cache, start_entry, h, call):
     """The latent attention as an ``Operator.apply``: ``h [F, T, hidden]``
     the residual stream, ``cache [slots, positions, entry]``
     this layer's, donated. The chunk's ``count`` entries are appended to
-    the cache in place *before* the layer attends (``append_chunk``: at
+    the cache in place *before* the layer attends (``call.append``: at
     ``(slot, p0 + t)``, and the start token's at position 0 where the
     flow ``begins``; a ``slot`` out of range writes nothing), then the
     chunk attends causally over its flow's slot by ``call.attend``
@@ -577,8 +589,9 @@ def _attention(lp, cfg, cache, start_entry, h, call):
             [_rms(ckr[..., :rank], lp["kv_norm"], eps),
              _rope(ckr[..., rank:], cos, sin)], -1).astype(jnp.bfloat16)
     with jax.named_scope("append"):
-        cache, written = append_chunk(cache, entry, start_entry, call.slot,
-                                      call.p0, call.count, call.begins)
+        cache, written, in_kernel = call.append(
+            cache, entry, start_entry, call.slot, call.p0, call.count,
+            call.begins)
     with jax.named_scope("project"):      # the absorption of ``wukv`` into q
         wukv = lp["wukv"].reshape(rank, H, nope + cfg.v_head_dim)
         q_abs = jnp.einsum("fthd,chd->fthc",
@@ -594,12 +607,14 @@ def _attention(lp, cfg, cache, start_entry, h, call):
                        preferred_element_type=jnp.float32)
         y = _mm(o.reshape(F, T, H * cfg.v_head_dim), lp["wo"])
     with jax.named_scope("attend"):
+        live = (call.slot < cfg.slots).sum()
         return (y, cache,
                 {"attn.kv_blocks": blocks.sum(),
                  "attn.kv_blocks_whole": F * whole,
                  "cache.rows_written": written,
-                 "cache.rows_whole": (call.slot < cfg.slots).sum()
-                 * cfg.positions})
+                 "cache.rows_whole": live * cfg.positions,
+                 "append.flows": live,
+                 "append.flows_in_kernel": in_kernel})
 
 
 LATENT_ATTENTION = Operator(
@@ -833,7 +848,7 @@ def event_scores(params, cfg, pred, tok):
 
 
 def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
-              attend=attend_xla, experts=ExpertOps()):
+              attend=attend_xla, experts=ExpertOps(), append=append_chunk):
     """One call, of this model or of any whose configuration gives its
     layers' operators (``cfg.operator``, ``cfg.tensors``: here and
     ``models/lfm2_moe.py``). ``rows [B, 3]`` int32 ``(cell, address,
@@ -841,7 +856,10 @@ def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
     over a slot that the model's attention layers call (``attend_xla``,
     or the kernel ``ops/flow_attention.best_attention`` gives for a TPU);
     ``experts``: the routed experts' grouped product and combine (XLA's,
-    or the kernels ``ops/expert_product.best_expert_product`` gives).
+    or the kernels ``ops/expert_product.best_expert_product`` gives);
+    ``append``: how the attention layers write a chunk into their state
+    (``append_chunk``, or the kernel ``ops/cache_append.best_append``
+    gives).
     Returns ``(scores [B] float32 in row order, state, counts)``. Its
     device scopes name the parts its time is read by (PERF.md section 3):
     ``layer<l>.<operator's scope>`` (an attention operator's ``project``,
@@ -865,7 +883,7 @@ def flow_step(params, state, rows, n, *, cfg, F: int, T: int,
     prev_h = jnp.where(begins[:, None], start_h[None],
                        last_h[jnp.minimum(slot, S - 1)])
     call = Call(slot, p0, count, begins, p0[:, None] + jnp.arange(T)[None],
-                attend, experts)
+                attend, experts, append)
     operators = [cfg.operator(l) for l in range(cfg.layers)]
     h, kept, expert_tokens, tally, loads = _forward(
         params, cfg, operators, kept, starts, tok, call)

@@ -31,8 +31,8 @@ side**. Per layer ``h += Op(RMSNorm(h))``, then ``h += FFN(RMSNorm(h))``;
   ``ops/flow_attention.py`` reads a slot (the latent cache reaches that
   layout by the compiler's own choice, its 576 being no multiple of 128
   lanes; 1,024 would be stored entry-minor and transposed, a copy of the
-  layer, every call). ``append_chunk`` writes the call's entries into it
-  in place, then the chunk attends over its flow's slot by the step's
+  layer, every call). The step's ``append`` writes the call's entries into
+  it in place, then the chunk attends over its flow's slot by the step's
   ``attend``: ``grouped_attention_fused`` on a TPU, ``attend_grouped_xla``
   elsewhere. The operator is ``models/grouped_attention.py``'s, which
   another model's layers take with other head counts, a rotary part and

@@ -131,10 +131,12 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
     flow_step`` over the configuration's layers, built with the attention
     its platform gets (``ops/flow_attention.best_attention``: the fused
     kernel on a TPU, XLA's elsewhere; ``grouped``: over keys and values
-    in groups of heads, else over the latent) and with the routed
-    experts' grouped product its platform gets
-    (``ops/expert_product.best_expert_product``, likewise), and
-    ``describe`` says which."""
+    in groups of heads, else over the latent), with the routed experts'
+    grouped product its platform gets
+    (``ops/expert_product.best_expert_product``, likewise) and with the
+    append of a chunk to a layer's state its platform gets
+    (``ops/cache_append.best_append``, likewise), and ``describe`` says
+    which."""
     import jax
 
     from linkerd_tpu.models import latent_moe as lm
@@ -145,13 +147,16 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
 
     def make_step(platform: str):
         # the kernel's module is imported where a step is built
+        from linkerd_tpu.ops.cache_append import append_kind, best_append
         from linkerd_tpu.ops.expert_product import (
             best_expert_product, expert_product_kind)
         from linkerd_tpu.ops.flow_attention import (
             attention_call, attention_kind, best_attention)
         attend = best_attention(platform, grouped)
         experts = best_expert_product(platform)
+        append = best_append(platform)
         built["attention"] = attention_kind(platform)
+        built["append"] = append_kind(platform)
         built["state"] = {
             op.scope: {"positions": op.ring or cfg.positions,
                        "call": attention_call(platform, grouped,
@@ -161,7 +166,7 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
         # the state and the staged rows are the program's to reuse
         program = jax.jit(
             lm.flow_step,
-            static_argnames=("cfg", "F", "T", "attend", "experts"),
+            static_argnames=("cfg", "F", "T", "attend", "experts", "append"),
             donate_argnums=(1, 2))
         registered = set()      # the layouts whose program is registered
 
@@ -169,7 +174,8 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
             def run(state, rows, n):
                 args = (params, state, rows, np.int32(n))
                 static = {"cfg": cfg, "F": layout[0], "T": layout[1],
-                          "attend": attend, "experts": experts}
+                          "attend": attend, "experts": experts,
+                          "append": append}
                 if (layout, rows.shape) not in registered:
                     registered.add((layout, rows.shape))
                     phases.program("jit_flow_step",
@@ -188,6 +194,7 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
             "experts_held": list(cfg.experts_held),
             "layer_share": cfg.layer_share,
             "attention": built.get("attention"),
+            "append": built.get("append"),
             # a kind of layer: the positions a slot keeps, the call made
             "state": built.get("state"),
             "expert_product": built.get("expert_product"),

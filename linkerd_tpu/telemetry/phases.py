@@ -31,9 +31,12 @@ flows and layers, ``attn.kv_blocks_whole``: what the slots whole would
 have been; XLA's attention runs over every slot whole as one block, so
 there the two are equal; ``cache.rows_written``: the rows of the cache
 the step wrote, a window of ``T + 1`` positions a live flow a layer
-since the append is in place, ``cache.rows_whole``: the live flows'
-slots whole, which a step that gathers the slots and scatters them back
-writes); ``fit.calls``,
+where XLA appends in place, the whole tiles of 128 positions the
+append's kernel writes back where it does, ``cache.rows_whole``: the
+live flows' slots whole, which a step that gathers the slots and
+scatters them back writes; ``append.flows``: the flows appended to, a
+live flow an attention layer, ``append.flows_in_kernel``: of those, the
+ones the append's kernel took); ``fit.calls``,
 ``fit.shipped_bytes`` (the padded host arrays a fit places on the
 device: rows, labels, mask and, where rows were padded, the row mask;
 once a fit, whatever ``fit_steps``).

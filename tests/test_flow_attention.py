@@ -3,7 +3,8 @@ the CPU, against XLA's ``attend_xla`` on the same inputs: alone, over
 layouts, widths and flows of every length, each flow's slot taken from
 the layer's cache by its number; inside ``flow_step``, where padding rows
 and empty flows meet it; and the append that goes before it
-(``models.latent_moe.append_chunk``), against the formulation it replaced
+(``models.latent_moe.append_chunk``, and the TPU's kernel of
+``ops/cache_append.py`` interpreted), against the formulation it replaced
 (gather the slots, ``where`` the chunk in, scatter them back whole), which
 is kept here as the oracle."""
 
@@ -15,6 +16,7 @@ import numpy as np
 import pytest
 
 from linkerd_tpu.models import latent_moe as lm
+from linkerd_tpu.ops import cache_append as ca
 from linkerd_tpu.ops import flow_attention as fa
 
 # heads, rank, rope, positions: the tiny preset's (one block of 64
@@ -113,6 +115,39 @@ def test_selection_is_by_platform_alone():
         assert fa.best_attention(platform) is lm.attend_xla
 
 
+def test_the_append_is_selected_by_platform_and_served_by_shape():
+    """``best_append``: the kernel on a TPU, XLA's form elsewhere; the
+    kernel serves a state whose positions are whole tiles of 128 lanes
+    and chunks that divide a tile (the three flow cells' caches and rings,
+    either layout), and hands any other shape to XLA's form itself."""
+    assert ca.append_kind("tpu") == "fused_pallas"
+    assert ca.best_append("tpu") is ca.cache_append_fused
+    for platform in ("cpu", "gpu"):
+        assert ca.append_kind(platform) == "xla"
+        assert ca.best_append(platform) is lm.append_chunk
+    for shape, last, ring in (((128, 2048, 4224), True, False),
+                              ((128, 2048, 640), True, True),
+                              ((512, 1024, 1024), True, False),
+                              ((512, 1024, 576), False, False)):
+        assert ca.serves(shape, 64, last, ring)
+        assert ca.serves(shape, 1, last, ring)
+        assert not ca.serves(shape, 256, last, ring)    # a chunk of 2 tiles
+        assert not ca.serves(shape, 96, last, ring)     # straddles a tile
+    assert not ca.serves((8, 64, 24), 8, True, True)    # no whole tiles
+    assert not ca.serves((8, 64, 88), 8, True, False)
+    # a ring of one tile: a window from its last position would take it
+    # twice
+    assert not ca.serves((8, 64, 128), 8, True, True)
+    assert ca.serves((8, 64, 128), 8, True, False)
+    # (the first tile, the tiles) of a window of 65 positions
+    p0 = jnp.array([1, 64, 65, 100, 4224 - 63, 0])
+    first, n = ca.window_tiles(p0, 4224, 65, False)
+    assert first.tolist() == [0, 0, 0, 0, 32, 0]
+    assert n.tolist() == [1, 1, 2, 2, 1, 1]
+    first, n = ca.window_tiles(jnp.array([0, 640, 641, 2020]), 640, 65, True)
+    assert first.tolist() == [4, 4, 0, 0] and n.tolist() == [2, 2, 1, 2]
+
+
 TINY = lm.LatentMoEConfig(
     hidden_size=64, num_attention_heads=4, q_lora_rank=32, kv_lora_rank=16,
     qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
@@ -205,7 +240,8 @@ def append_by_gather(cache, entry, start_entry, slot, p0, count, begins):
     """The formulation ``append_chunk`` replaced, as PRs 28-30 had it in
     ``_attention``: every flow's slot gathered whole, the chunk set into
     it by a ``where`` over all its positions, the slots scattered back
-    whole. Returns the cache and the rows it wrote (whole slots)."""
+    whole. Returns the cache, the rows it wrote (whole slots) and the flows
+    a kernel appended (none): ``Call.append``'s result."""
     S, P, _ = cache.shape
     T = entry.shape[1]
     kv = cache[jnp.minimum(slot, S - 1)]
@@ -215,7 +251,8 @@ def append_by_gather(cache, entry, start_entry, slot, p0, count, begins):
         entry, jnp.clip(t, 0, T - 1)[..., None], 1), kv)
     kv = kv.at[:, 0].set(jnp.where(begins[:, None], start_entry[None],
                                    kv[:, 0]))
-    return cache.at[slot].set(kv, mode="drop"), (slot < S).sum() * P
+    return (cache.at[slot].set(kv, mode="drop"), (slot < S).sum() * P,
+            jnp.int32(0))
 
 
 # (positions, T, [(slot, p0, count) a flow]); 8 slots, so slot 8 is a flow
@@ -231,13 +268,47 @@ APPENDS = {
     "a-chunk-as-long-as-the-slot": (16, 16, [(3, 1, 15), (7, 4, 12)]),
     "four-blocks-of-positions": (512, 32, [(0, 1, 32), (7, 120, 17),
                                            (3, 480, 32), (8, 1, 0)]),
+    # slots of whole tiles of 128 positions, where the kernel appends: a
+    # window inside one tile and one across two; a window pushed back at
+    # the slot's end (inside a tile, and across two at a chunk of a whole
+    # tile); flows that begin, of count 0, out of range
+    "one-tile": (256, 8, [(2, 9, 8), (5, 100, 8), (8, 40, 8), (1, 1, 0)]),
+    "across-two-tiles": (384, 64, [(1, 100, 64), (3, 127, 5), (6, 1, 64),
+                                   (4, 250, 20), (8, 1, 0)]),
+    "pushed-back-at-the-end": (256, 64, [(6, 250, 6), (0, 200, 56),
+                                         (2, 192, 64)]),
+    "a-tile-a-chunk": (256, 128, [(4, 200, 56), (2, 1, 127), (7, 128, 128),
+                                  (0, 0, 1)]),
+    "one-event-a-flow": (128, 1, [(3, 127, 1), (5, 1, 1), (0, 0, 1),
+                                  (8, 1, 0)]),
 }
 
 
+def tiles_by_hand(P: int, T: int, flows) -> int:
+    """Lane tiles of 128 positions a cache's windows of ``T + 1`` touch,
+    over the flows with a slot in range (8 slots)."""
+    W = min(T + 1, P)
+    w0 = [min(max(p - 1, 0), P - W) for s, p, _ in flows if s < 8]
+    return sum(-(-(w % 128 + W) // 128) for w in w0)
+
+
+@pytest.mark.parametrize("append", ["xla", "kernel"])
+@pytest.mark.parametrize("positions_last", [False, True],
+                         ids=["positions-minor", "positions-last"])
 @pytest.mark.parametrize("case", sorted(APPENDS))
-def test_the_append_touches_the_chunks_rows_and_no_other(case):
+def test_the_append_touches_the_chunks_rows_and_no_other(
+        case, positions_last, append):
+    """XLA's append (``append_chunk``) and the TPU's kernel
+    (``cache_append_fused``, interpreted) on a layer's cache laid either
+    way: the latent cache ``[slots, positions, entry]`` (the kernel takes
+    it as the bitcast view ``[slots, entry, positions]``) and the grouped
+    operator's positions last. Both are **bit for bit the gathered
+    slots'**, touch no row but the chunks' and the start token's, and
+    count what they write: windows of ``T + 1`` (XLA's), or whole tiles of
+    128 (the kernel's, where its shapes are served: whole tiles of
+    positions; elsewhere it hands the call to XLA's and counts no flow)."""
     P, T, flows = APPENDS[case]
-    S, E, F = 8, 24, len(flows)
+    S, E, F = 8, 32, len(flows)
     slot, p0, count = (np.array(c, np.int32) for c in zip(*flows))
     begins = (count > 0) & (p0 == 1)
     k = jax.random.split(jax.random.key(P + T + F), 3)
@@ -245,10 +316,18 @@ def test_the_append_touches_the_chunks_rows_and_no_other(case):
     entry = jax.random.normal(k[1], (F, T, E), jnp.bfloat16)
     start = jax.random.normal(k[2], (E,), jnp.bfloat16)
     args = (before, entry, start, slot, p0, count, begins)
-    got, written = jax.jit(lm.append_chunk)(*args)
-    want, whole = jax.jit(append_by_gather)(*args)
-    got, want, was = (np.asarray(a, np.float32) for a in (got, want, before))
-    np.testing.assert_array_equal(got, want)
+    laid = ((before.transpose(0, 2, 1),) + args[1:] if positions_last
+            else args)
+    fn = (functools.partial(ca.cache_append_fused, interpret=True)
+          if append == "kernel" else lm.append_chunk)
+    got, written, in_kernel = jax.jit(functools.partial(
+        fn, positions_last=positions_last))(*laid)
+    if positions_last:
+        got = got.transpose(0, 2, 1)
+    want, whole, _ = jax.jit(append_by_gather)(*args)
+    got, want, was = (np.asarray(a) for a in (got, want, before))
+    np.testing.assert_array_equal(got.view(np.uint16), want.view(np.uint16))
+    got, want, was = (a.astype(np.float32) for a in (got, want, was))
     # by hand: the rows of the chunk and the start token's, nothing else
     mine = np.zeros((S, P), bool)
     for f, (sl, at, n) in enumerate(flows):
@@ -263,26 +342,33 @@ def test_the_append_touches_the_chunks_rows_and_no_other(case):
     np.testing.assert_array_equal(got[~mine], was[~mine])
     changed = int((got != was).any(-1).sum())
     live = int((slot < S).sum())
-    assert changed <= mine.sum() <= int(written) == live * min(T + 1, P)
+    served = append == "kernel" and P % 128 == 0
+    assert served == (append == "kernel" and ca.serves(
+        laid[0].shape, T, positions_last, False))
+    assert int(in_kernel) == (live if served else 0)
+    assert changed <= mine.sum() <= int(written) == (
+        128 * tiles_by_hand(P, T, flows) if served else live * min(T + 1, P))
     assert int(whole) == live * P
 
 
-def test_three_calls_append_to_one_slot_as_the_gathered_slots_did(
-        monkeypatch):
+def test_three_calls_append_to_one_slot_as_the_gathered_slots_did():
     """Three calls in a row bring the same flows their next chunks (one of
     them begins in the first call; one call has a flow of fewer events and
     a flow that brings nothing). On XLA's attention the step with the
     append in place gives the scores and the cache of the step with the
-    gathered slots **bit for bit**; the kernel's step agrees to rounding;
-    after each call every row outside the chunks is what it was; and the
-    step counts the rows it wrote."""
+    gathered slots **bit for bit**; the TPU's step (both kernels,
+    interpreted: the append's over slots of two tiles, one flow's chunks
+    across a tile's edge in the second call) agrees to rounding; after
+    each call every row outside the chunks is what it was; and the step
+    counts the rows it wrote: windows of ``T + 1``, or the kernel's whole
+    tiles, and the flows the kernel appended."""
     F, T, P, S = 4, 8, TINY.positions, TINY.slots
     params = lm.init(jax.random.key(5), TINY)
     rng = np.random.default_rng(11)
 
-    def build(attend):
+    def build(attend, append):
         return jax.jit(functools.partial(lm.flow_step, cfg=TINY, F=F, T=T,
-                                         attend=attend))
+                                         attend=attend, append=append))
 
     def run(step, state, chunks):
         rows = device_rows(chunks, T)
@@ -290,17 +376,14 @@ def test_three_calls_append_to_one_slot_as_the_gathered_slots_did(
         staged[:len(rows)] = rows
         return step(params, state, jnp.asarray(staged), np.int32(len(rows)))
 
-    place = build(lm.attend_xla)
+    place = build(lm.attend_xla, lm.append_chunk)
     fused = build(functools.partial(fa.latent_attention_fused,
-                                    interpret=True))
-    gathered = build(lm.attend_xla)
-    with monkeypatch.context() as oracle:
-        # traced here, once, with the oracle's append: the start token's
-        # own call has the shapes of every call below
-        oracle.setattr(lm, "append_chunk", append_by_gather)
-        state = lm.with_start(
-            lambda s, r, n: gathered(params, s, jnp.asarray(r), np.int32(n)),
-            TINY, lm.init_state(TINY), jnp.zeros((F * T, 3), jnp.int32))
+                                    interpret=True),
+                  functools.partial(ca.cache_append_fused, interpret=True))
+    gathered = build(lm.attend_xla, append_by_gather)
+    state = lm.with_start(
+        lambda s, r, n: gathered(params, s, jnp.asarray(r), np.int32(n)),
+        TINY, lm.init_state(TINY), jnp.zeros((F * T, 3), jnp.int32))
     states = {"place": state, "gathered": state, "fused": state}
     at = {0: 1, 1: 120, 2: P - 3 * T}       # flow f's next position
     slot_of = {0: 9, 1: 2, 2: 14}
@@ -335,9 +418,15 @@ def test_three_calls_append_to_one_slot_as_the_gathered_slots_did(
             for new, old in zip(stp[0], states["place"][0]))
         wrote = TINY.layers * 3 * (T + 1)
         assert changed <= TINY.layers * int(mine.sum()) <= wrote
-        for c in (cp, cf):
-            assert int(c["cache.rows_written"]) == wrote
+        assert int(cp["cache.rows_written"]) == wrote
+        # a window of 9 from position 127 on touches both tiles of a slot
+        tiles = 3 + sum(at[f] - 1 in range(120, 128) for f in at)
+        assert tiles == (4 if call == 1 else 3)
+        assert int(cf["cache.rows_written"]) == TINY.layers * tiles * 128
+        for c, kernel in ((cp, 0), (cf, 3)):
             assert int(c["cache.rows_whole"]) == TINY.layers * 3 * P
+            assert int(c["append.flows"]) == TINY.layers * 3
+            assert int(c["append.flows_in_kernel"]) == TINY.layers * kernel
         assert (int(cg["cache.rows_written"]) == int(cg["cache.rows_whole"])
                 == TINY.layers * 3 * P)
         states = {"place": stp, "gathered": stg, "fused": stf}
@@ -518,9 +607,10 @@ def parents_apply(layer, lp, cfg, cache, start_entry, h, call):
     q, k = ga.rotate(q, cos, sin, rotary), ga.rotate(k, cos, sin, rotary)
     entry = jnp.concatenate([k.reshape(F, T, G * hd), lm._mm(x, lp["wv"])],
                             -1).astype(jnp.bfloat16)
-    cache, _ = lm.append_chunk(cache, entry, start_entry, call.slot, call.p0,
-                               call.count, call.begins, positions_last=True,
-                               ring=layer.window is not None)
+    cache, *_ = lm.append_chunk(cache, entry, start_entry, call.slot,
+                                call.p0, call.count, call.begins,
+                                positions_last=True,
+                                ring=layer.window is not None)
     o, *_ = ga.attend_grouped_xla(
         unturned(q.astype(jnp.bfloat16), H), cache, call.slot, call.p0,
         hd ** -0.5, window=layer.window)
@@ -551,7 +641,7 @@ def operator_and_parent(cfg, params, l: int, layer, seed: int = 0):
         count=np.array([T, T, 3, 0], np.int32),
         begins=np.array([True, False, False, False]),
         pos=jnp.asarray(p0[:, None] + np.arange(T)[None]),
-        attend=ga.attend_grouped_xla, experts=None)
+        attend=ga.attend_grouped_xla, experts=None, append=lm.append_chunk)
     h = jnp.asarray(rng.normal(size=(F, T, cfg.hidden_size)), jnp.float32)
     start = state[0, :, 0]
     mine = jax.jit(lambda lp, state, h: op.apply(

@@ -25,6 +25,7 @@ from linkerd_tpu.models import laguna_moe as lg
 from linkerd_tpu.models import latent_moe as lm
 from linkerd_tpu.models import lfm2_moe as lf
 from linkerd_tpu.models.spec import SPECS, laguna_moe
+from linkerd_tpu.ops import cache_append as ca
 from linkerd_tpu.ops import flow_attention as fa
 from linkerd_tpu.telemetry import phases
 from linkerd_tpu.telemetry.anomaly import (
@@ -200,18 +201,32 @@ WRAPS = {"ends-at-the-end": (24, 8, [17, 1], [7, 8]),
          "straddles": (24, 8, [20, 1], [8, 3]),
          "twice-round": (24, 16, [24 * 2 + 15, 1], [16, 16]),
          "one-event": (24, 1, [47, 1], [1, 1]),
-         "from-index-0": (16, 4, [32, 1], [4, 2])}
+         "from-index-0": (16, 4, [32, 1], [4, 2]),
+         # rings of whole tiles of 128, where the kernel appends: the
+         # published ring's last tile to its first, a window inside a tile
+         # and one across two several times round, one event at the end
+         "wraps-last-tile-to-first": (640, 64, [639, 1], [64, 64]),
+         "inside-a-tile": (640, 64, [640 * 3 + 130, 1], [64, 0]),
+         "across-two-tiles": (256, 64, [256 * 2 + 100, 1], [64, 30]),
+         "one-event-wraps": (256, 1, [256 * 3, 1], [1, 1])}
 
 
+@pytest.mark.parametrize("append", ["xla", "kernel"])
 @pytest.mark.parametrize("case", sorted(WRAPS))
-def test_a_chunk_that_passes_the_rings_end_goes_on_at_its_start(case):
-    """``append_chunk(ring=True)`` against the ring written position by
-    position by hand: ``entry[f, t]`` at ``(p0 + t) mod ring`` for ``t <
-    count``, the start token's at index 0 where the flow begins, a flow
-    that brings nothing (slot out of range) writes nothing, and every
-    other value of the layer is bit for bit what it was."""
+def test_a_chunk_that_passes_the_rings_end_goes_on_at_its_start(case,
+                                                                 append):
+    """``append_chunk(ring=True)`` and the TPU's kernel
+    (``cache_append_fused(ring=True)``, interpreted) against the ring
+    written position by position by hand: ``entry[f, t]`` at ``(p0 + t)
+    mod ring`` for ``t < count``, the start token's at index 0 where the
+    flow begins, a flow that brings nothing (slot out of range) writes
+    nothing, and every other value of the layer is bit for bit what it
+    was. XLA's takes a second window where a chunk wraps; the kernel
+    takes a ring's tiles modulo the ring, the wrap in the same pass, and
+    counts whole tiles (on a ring of whole tiles: elsewhere it hands the
+    call to XLA's)."""
     P, T, p0, count = WRAPS[case]
-    S, E, F = 5, 8, 3
+    S, E, F = 5, 16, 3
     k = jax.random.split(jax.random.key(P + T), 3)
     cache = jax.random.normal(k[0], (S, E, P), jnp.bfloat16)
     entry = jax.random.normal(k[1], (F, T, E), jnp.bfloat16)
@@ -220,8 +235,10 @@ def test_a_chunk_that_passes_the_rings_end_goes_on_at_its_start(case):
     p0 = np.array(p0 + [1], np.int32)
     count = np.array(count + [0], np.int32)
     begins = p0 == 1
-    got, written = jax.jit(functools.partial(
-        lm.append_chunk, positions_last=True, ring=True))(
+    fn = (functools.partial(ca.cache_append_fused, interpret=True)
+          if append == "kernel" else lm.append_chunk)
+    got, written, in_kernel = jax.jit(functools.partial(
+        fn, positions_last=True, ring=True))(
             cache, entry, start, slot, p0, count, begins)
     want = np.asarray(cache, np.float32)
     for f in range(2):
@@ -230,10 +247,16 @@ def test_a_chunk_that_passes_the_rings_end_goes_on_at_its_start(case):
         for t in range(count[f]):
             want[slot[f], :, (p0[f] + t) % P] = np.asarray(
                 entry[f, t], np.float32)
-    assert (np.asarray(got, np.float32) == want).all()
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint16),
+                                  want.astype(jnp.bfloat16).view(np.uint16))
     W = T + 1
+    if append == "kernel" and P % 128 == 0:
+        assert ca.serves(cache.shape, T, True, True)
+        tiles = sum(-(-((int(p) - 1) % P % 128 + W) // 128) for p in p0[:2])
+        assert (int(written), int(in_kernel)) == (tiles * 128, 2)
+        return
     wraps = sum((int(p) - 1) % P + W > P for p in p0[:2])
-    assert int(written) == (2 + wraps) * W
+    assert (int(written), int(in_kernel)) == ((2 + wraps) * W, 0)
 
 
 def test_full_and_sliding_layers_take_their_own_heads_rotary_part_and_rope(
@@ -630,13 +653,17 @@ def test_the_grouped_kernel_at_a_head_of_128_is_xlas_attention(layout, width):
 def test_the_whole_step_on_the_kernels_is_the_reference(seqs, whole,
                                                         monkeypatch):
     """The step built on the TPU's kernels, interpreted (both attention
-    calls, the grouped product, the combine), over calls of 16 events
-    that straddle the ring's end."""
+    calls, the grouped product, the combine, the append: over the caches
+    of 256 positions, two tiles; the rings of 24 are no whole tiles and
+    take XLA's append), over calls of 16 events that straddle the ring's
+    end."""
     from linkerd_tpu.ops import expert_product as ep
     monkeypatch.setattr(
         fa, "best_attention",
         lambda platform, grouped=False: functools.partial(
             fa.grouped_attention_fused, interpret=True))
+    monkeypatch.setattr(ca, "best_append", lambda platform: functools.partial(
+        ca.cache_append_fused, interpret=True))
     monkeypatch.setattr(ep, "best_expert_product",
                         lambda platform: product_of("fused"))
     short = {k: v[:48] for k, v in seqs.items()}
@@ -656,3 +683,9 @@ def test_the_whole_step_on_the_kernels_is_the_reference(seqs, whole,
     rows = sum(n["attn.q_rows"] for n in recs)
     assert rows == sum(n["attn.q_rows_in_tile"] for n in recs)
     assert rows == (4 + 4 + 2) * 16 * 28
+    # the live flows (3, 3, 2) of every layer appended to; by the kernel on
+    # the two full layers
+    flows = [n["append.flows"] for n in recs]
+    assert flows == [3 * 4, 3 * 4, 2 * 4]
+    assert [n["append.flows_in_kernel"] for n in recs] == [
+        f // 2 for f in flows]

@@ -157,10 +157,15 @@ def attention(request, monkeypatch):
     """The step built on each attention and each grouped product of the
     routed experts: XLA's, which this platform gets, and the TPU's
     kernels (``ops/flow_attention.py``, ``ops/expert_product.py``),
-    interpreted."""
+    interpreted; the TPU's append too (``ops/cache_append.py``), which
+    hands these slots of 64 positions, no whole tile, to XLA's."""
     if request.param == "fused":
+        from linkerd_tpu.ops import cache_append as ca
         from linkerd_tpu.ops import expert_product as ep
         from linkerd_tpu.ops import flow_attention as fa
+        monkeypatch.setattr(
+            ca, "best_append", lambda platform: functools.partial(
+                ca.cache_append_fused, interpret=True))
         monkeypatch.setattr(
             fa, "best_attention",
             lambda platform, grouped=False: functools.partial(
@@ -418,6 +423,8 @@ class TestState:
             # a layout of 8 events: windows of 9 positions
             wrote = CFG.layers * len(call) * 9
             assert changed <= wrote == rec.counts["cache.rows_written"]
+            assert rec.counts["append.flows"] == CFG.layers * len(call)
+            assert rec.counts["append.flows_in_kernel"] == 0
             assert rec.counts["cache.rows_whole"] == (
                 CFG.layers * len(call) * CFG.positions)
             before = after

@@ -133,9 +133,12 @@ class TestScorePath:
                 # the append: a window of T + 1 = 5 of a slot's 64
                 # positions a flow a layer
                 "cache.rows_written": 3 * 2 * 5,
-                "cache.rows_whole": 3 * 2 * 64}
+                "cache.rows_whole": 3 * 2 * 64,
+                # 2 flows a layer, none by the append's kernel (XLA's here)
+                "append.flows": 3 * 2, "append.flows_in_kernel": 0}
         assert timing["bytes"] == 8 * 3 * 4 + 8 * 4
         assert state["flow"]["layouts"] == {"2x4": 2}
+        assert state["flow"]["append"] == "xla"
         assert np.shape(state["flow"]["expert_tokens"]) == (2, 4)
 
     def test_third_dispatch_at_depth_two_waits_for_a_slot(self):
@@ -618,13 +621,15 @@ def test_every_flow_entry_has_its_files_and_reads_what_the_program_writes():
     mine = [m for m in manifest["per_layer"]
             if m.get("workloads") == [flow_cell]]
     assert len(mine) == 18     # 17 of PRs 28-31, ``moe.weight_loads_share``
-    # and PR 38's parts of the step, each over the flow cells that have it
+    # and the step's parts and the share of the flows the append's kernel
+    # took, each over the flow cells that have it
     shared = [m for m in manifest["per_layer"] if m not in mine
               and flow_cell in m.get("workloads", [])]
     assert [m["name"] for m in shared] == [
         f"flow_step.{p}" for p in ("project_ms", "append_ms", "attend_ms",
                                    "route_ms", "experts_ms", "dense_ms",
-                                   "head_ms", "unattributed_pct")]
+                                   "head_ms", "unattributed_pct")] + [
+        "append.in_kernel_share"]
     with open(os.path.join(REPO, "chipbench", "metrics",
                            "flow_step.unattributed_pct.json")) as f:
         parts = json.load(f)["scopes"]
@@ -696,7 +701,7 @@ def _tiny(model):
 @pytest.mark.parametrize("model", sorted(FLOW_CELLS))
 def test_a_flow_step_lowered_for_the_chip_carries_the_scopes_its_cell_reads(
         model):
-    """The step as a TPU gets it (both kernels), at a tiny size, opens
+    """The step as a TPU gets it (its kernels), at a tiny size, opens
     every scope that a ``program_scope_ms`` entry listing the model's cell
     puts down a part to: ``layer<l>.conv`` for LFM2's convolutions."""
     import re
@@ -705,6 +710,7 @@ def test_a_flow_step_lowered_for_the_chip_carries_the_scopes_its_cell_reads(
     import jax.numpy as jnp
 
     from linkerd_tpu.models import latent_moe as lm
+    from linkerd_tpu.ops.cache_append import best_append
     from linkerd_tpu.ops.expert_product import best_expert_product
     from linkerd_tpu.ops.flow_attention import best_attention
 
@@ -724,11 +730,12 @@ def test_a_flow_step_lowered_for_the_chip_carries_the_scopes_its_cell_reads(
     state = jax.eval_shape(lambda: lm.init_state(cfg))[:3] + (
         lm.start_shapes(cfg),)
     text = jax.jit(lm.flow_step, static_argnames=(
-        "cfg", "F", "T", "attend", "experts")).trace(
+        "cfg", "F", "T", "attend", "experts", "append")).trace(
         params, state, jax.ShapeDtypeStruct((64, 3), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32), cfg=cfg, F=8, T=8,
         attend=best_attention("tpu", model != "latent_moe"),
-        experts=best_expert_product("tpu")).lower(
+        experts=best_expert_product("tpu"),
+        append=best_append("tpu")).lower(
         lowering_platforms=("tpu",)).as_text(debug_info=True)
     opened = set(re.findall(r"[/.](\w+)(?=/)", text))
     assert wanted <= opened, wanted - opened
