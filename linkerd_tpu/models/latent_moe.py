@@ -234,7 +234,11 @@ class Operator(NamedTuple):
     attention operator inside its scope (``project``: norms, projections,
     RoPE and the entry; ``append``; ``attend``; ``out``: the output's
     projection, and the residual add that XLA fuses with it); where it
-    does not, the operator's scope is one part."""
+    does not, the operator's scope is one part. ``carries``: whether the
+    layer hands something to the layers after it (``models/hy4_moe.py``:
+    a selection of positions): ``apply`` then takes the carry the layer
+    before it handed on (None at the first) as a last argument and hands
+    back the one to pass on as a fourth result."""
     apply: Callable
     init: Callable
     start_of: Callable
@@ -242,6 +246,7 @@ class Operator(NamedTuple):
     caches: bool
     ring: int = 0
     parts: bool = False
+    carries: bool = False
 
 
 # what every flow model's step reports of its operators, nought where no
@@ -408,8 +413,14 @@ def _rms(x, gain, eps):
             * gain.astype(jnp.float32))
 
 
-def _swiglu(x, gate, up, down):
-    return _mm(jax.nn.silu(_mm(x, gate)) * _mm(x, up), down)
+def _swiglu(x, gate, up, down, limit=None):
+    """``silu(x gate) * (x up)`` through ``down``; with a ``limit`` (a
+    configuration's ``swiglu_limit``) ``silu(min(x gate, limit)) *
+    clip(x up, -limit, limit)``."""
+    if limit is None:
+        return _mm(jax.nn.silu(_mm(x, gate)) * _mm(x, up), down)
+    return _mm(jax.nn.silu(jnp.minimum(_mm(x, gate), limit))
+               * jnp.clip(_mm(x, up), -limit, limit), down)
 
 
 def yarn_frequencies(dim: int, base: float, factor: float, original: int,
@@ -644,14 +655,15 @@ def route(lp, cfg, x):
 CHUNK_BYTES = 192 * 2 ** 20  # the float32 rows a run of tiles gives back
 
 
-def swiglu_tiles_xla(xs, wt, tile_expert, live, gate, up, down):
+def swiglu_tiles_xla(xs, wt, tile_expert, live, gate, up, down, limit=None):
     """The grouped product as XLA does it, a loop over the tiles that
     slices the tile's expert out of ``gate``, ``up [G, D, I]`` and ``down
     [G, I, D]`` every trip: the path of every platform but the TPU, and
     what ``ops/expert_product.swiglu_tiles_fused`` is tested against.
     ``xs [tiles x M, D]`` bfloat16 the tiles' rows, ``wt [tiles x M]``
     their routing weights, ``tile_expert [tiles]`` each tile's held
-    expert, ``live`` how many tiles, the first, hold a pair. Returns ``(y
+    expert, ``live`` how many tiles, the first, hold a pair; ``limit``:
+    ``_swiglu``'s. Returns ``(y
     [tiles x M, D]`` float32 ``= wt * swiglu(xs)`` for the rows of the
     first ``live`` tiles (the others zero here, unspecified on the
     kernel), the whole-expert equivalents of weights read``: one a tile
@@ -663,7 +675,7 @@ def swiglu_tiles_xla(xs, wt, tile_expert, live, gate, up, down):
         w = jax.lax.dynamic_slice_in_dim(wt, t * M, M)
         return jax.lax.dynamic_update_slice_in_dim(
             y, _swiglu(jax.lax.dynamic_slice_in_dim(xs, t * M, M),
-                       gate[e], up[e], down[e]) * w[:, None], t * M, 0)
+                       gate[e], up[e], down[e], limit) * w[:, None], t * M, 0)
 
     return (jax.lax.fori_loop(0, live, tile,
                               jnp.zeros(xs.shape, jnp.float32)),
@@ -708,8 +720,11 @@ def routed_experts(lp, cfg, x, valid, experts=ExpertOps(), base=None):
     **so what moves follows the tiles that hold a pair and not ``R`` or
     ``N x k``** (where 12 of 384 experts are held ``R`` is 34,304 rows
     for some 1,000 pairs). ``C`` is as many tiles as give back
-    ``CHUNK_BYTES`` of float32 rows."""
+    ``CHUNK_BYTES`` of float32 rows. Where the configuration has a
+    ``swiglu_limit``, ``experts.product`` is handed it as ``limit``."""
     N, D = x.shape
+    limit = getattr(cfg, "swiglu_limit", None)
+    clamp = {} if limit is None else {"limit": limit}
     lo, hi = cfg.experts_held
     G, k, M = hi - lo, cfg.num_experts_per_tok, cfg.expert_tile
     with jax.named_scope("route"):     # and the sort into tiles
@@ -747,7 +762,7 @@ def routed_experts(lp, cfg, x, valid, experts=ExpertOps(), base=None):
             x_pad[rows],
             jax.lax.dynamic_slice_in_dim(dest_w, c * C * M, C * M),
             jax.lax.dynamic_slice_in_dim(tile_expert, c * C, C), live,
-            lp["exp_gate"], lp["exp_up"], lp["exp_down"])
+            lp["exp_gate"], lp["exp_up"], lp["exp_down"], **clamp)
         return experts.combine(y, rows.reshape(C, M), live, out), loads + n
 
     with jax.named_scope("expert_tiles"):
@@ -770,50 +785,141 @@ def _forward(params, cfg, operators, kept, starts, tok, call):
     layers' state with the chunks applied, tokens per held expert
     ``[expert layers, G]``, the operators' counts (``Operator``), summed
     over flows and layers, and the expert layers' ``weight_loads``
-    (``routed_experts``), summed."""
+    (``routed_experts``), summed.
+
+    Operators that carry (``Operator.carries``) are handed what the one
+    before them handed on. **Where the configuration has ``hc_mult``
+    streams** (more than one; ``models/hy4_moe.py``) the residual is ``X``,
+    ``streams`` arrays ``[F, T, hidden]`` float32 (the embedding in every
+    stream; arrays of their own, so that no pass relays a stream out of a
+    wider array), and
+    each of a layer's two sublayers is wrapped by a hyper-connection:
+    its input is the streams mixed by ``hyper_in``, and ``hyper_out``
+    mixes the streams and adds its output to each (scope ``hyper``); the
+    final hidden is the norm of the streams' sum. Every SwiGLU takes the
+    configuration's ``swiglu_limit`` where it has one. Both are read at
+    trace time: a configuration with neither traces as it did."""
     F, T = tok.shape
+    streams = getattr(cfg, "hc_mult", 1)
+    limit = getattr(cfg, "swiglu_limit", None)
     h = params["embed"][tok].astype(jnp.float32)
+    if streams > 1:
+        h = (h,) * streams
     valid = jnp.arange(T)[None] < call.count[:, None]
     counts, kept, tally = [], list(kept), {}
     loads = jnp.int32(0)
+    carry = None
     for l, (lp, op) in enumerate(zip(params["layers"], operators)):
         with jax.named_scope(f"layer{l}.{op.scope}"):
-            a, kept[l], layer_tally = op.apply(lp, cfg, kept[l], starts[l],
-                                               h, call)
-            with (jax.named_scope("out") if op.parts
-                  else contextlib.nullcontext()):
-                h = h + a
+            u, mix = (hyper_in(lp, "hc_attn", cfg, h) if streams > 1
+                      else (h, None))
+            if op.carries:
+                a, kept[l], layer_tally, carry = op.apply(
+                    lp, cfg, kept[l], starts[l], u, call, carry)
+            else:
+                a, kept[l], layer_tally = op.apply(lp, cfg, kept[l],
+                                                   starts[l], u, call)
+            if streams > 1:
+                h = hyper_out(h, a, mix)
+            else:
+                with (jax.named_scope("out") if op.parts
+                      else contextlib.nullcontext()):
+                    h = h + a
         for name, v in layer_tally.items():
             tally[name] = tally.get(name, 0) + v
         with jax.named_scope(f"layer{l}.ffn"):
+            u, mix = (hyper_in(lp, "hc_ffn", cfg, h) if streams > 1
+                      else (h, None))
             routed = "router" in lp
             with jax.named_scope("route" if routed else "dense"):
-                x = _rms(h, lp["ffn_norm"], cfg.rms_norm_eps)
+                x = _rms(u, lp["ffn_norm"], cfg.rms_norm_eps)
             if routed:
                 with jax.named_scope("dense"):
                     flat = x.reshape(F * T, -1)
-                    base = h.reshape(F * T, -1)
+                    # the routed rows are added where the sum lies: the
+                    # residual stream, or with several streams the shared
+                    # expert's output alone (nought where there is none)
+                    base = None if streams > 1 else h.reshape(F * T, -1)
                     if "shared_gate" in lp:
-                        base = base + _swiglu(flat, lp["shared_gate"],
-                                              lp["shared_up"],
-                                              lp["shared_down"])
+                        shared = _swiglu(flat, lp["shared_gate"],
+                                         lp["shared_up"], lp["shared_down"],
+                                         limit)
+                        base = shared if base is None else base + shared
                 with jax.named_scope("route"):
                     mine = valid.reshape(-1)
-                # the routed rows are added to the stream where it lies
                 y, cnt, loaded = routed_experts(
                     lp, cfg, flat, mine, call.experts, base)
                 counts.append(cnt)
                 with jax.named_scope("expert_tiles"):
                     loads = loads + loaded
-                    h = y.reshape(F, T, -1)
+                    y = y.reshape(F, T, -1)
+                h = y if streams == 1 else hyper_out(h, y, mix)
             else:
                 with jax.named_scope("dense"):
-                    h = h + _swiglu(x, lp["w_gate"], lp["w_up"],
-                                    lp["w_down"])
+                    y = _swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"],
+                                limit)
+                    if streams == 1:
+                        h = h + y
+                if streams > 1:
+                    h = hyper_out(h, y, mix)
     G = cfg.experts_held[1] - cfg.experts_held[0]
     counts = (jnp.stack(counts) if counts else jnp.zeros((0, G), jnp.int32))
+    if streams > 1:
+        h = sum(h)
     return (_rms(h, params["final_norm"], cfg.rms_norm_eps), tuple(kept),
             counts, tally, loads)
+
+
+def sinkhorn(m, iterations: int, eps: float):
+    """``m [..., n, n]`` positive, its rows and then its columns divided by
+    their sums (plus ``eps``), ``iterations`` times: doubly stochastic."""
+    for _ in range(iterations):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        m = m / (m.sum(-2, keepdims=True) + eps)
+    return m
+
+
+def hyper_coefficients(lp, name: str, cfg, X):
+    """A hyper-connection's coefficients for the streams ``X`` (``n``
+    arrays ``[F, T, hidden]`` float32; mHC's form): ``x = RMSNorm(vec X)``
+    (no gain), ``a = alpha * (x phi) + bias`` with ``phi [n x hidden, n
+    (n + 2)]`` (``<name>_phi``: stream ``i``'s rows ``i x hidden ..``) and
+    ``alpha`` three scalars, one for each group of ``a``
+    (``<name>_alpha``); ``pre = sigmoid(a[:n])``, ``post = hc_magnitude x
+    sigmoid(a[n:2n])``, ``res = sinkhorn(exp(a[2n:]) as [n, n])``.
+    Returns ``(pre [F, T, n], post [F, T, n], res [F, T, n, n])``."""
+    n, C = len(X), X[0].shape[-1]
+    scale = jax.lax.rsqrt(sum(jnp.mean(x * x, -1, keepdims=True) for x in X)
+                          / n + cfg.rms_norm_eps)
+    phi = lp[name + "_phi"]
+    alpha = jnp.repeat(lp[name + "_alpha"].astype(jnp.float32),
+                       np.array([n, n, n * n]),
+                       total_repeat_length=n * (n + 2))
+    a = sum(_mm(x * scale, phi[i * C:(i + 1) * C]) for i, x in enumerate(X)
+            ) * alpha + lp[name + "_bias"].astype(jnp.float32)
+    return (jax.nn.sigmoid(a[..., :n]),
+            cfg.hc_magnitude * jax.nn.sigmoid(a[..., n:2 * n]),
+            sinkhorn(jnp.exp(a[..., 2 * n:].reshape(*a.shape[:-1], n, n)),
+                     cfg.hc_sinkhorn_iterations, cfg.hc_eps))
+
+
+def hyper_in(lp, name: str, cfg, X):
+    """A sublayer's input ``u = sum_i pre[i] X_i [F, T, hidden]``, and what
+    ``hyper_out`` needs: ``(post, res)``. In float32, element by element
+    (a product on the MXU would round the streams to bfloat16)."""
+    with jax.named_scope("hyper"):
+        pre, post, res = hyper_coefficients(lp, name, cfg, X)
+        return (sum(pre[..., i, None] * x for i, x in enumerate(X)),
+                (post, res))
+
+
+def hyper_out(X, y, mix):
+    """The streams after a sublayer whose output is ``y [F, T, hidden]``:
+    ``X'_i = sum_j res[i, j] X_j + post[i] y``."""
+    post, res = mix
+    with jax.named_scope("hyper"):
+        return tuple(sum(res[..., i, j, None] * x for j, x in enumerate(X))
+                     + post[..., i, None] * y for i in range(len(X)))
 
 
 HEAD_LOGITS_BYTES = 384 * 2 ** 20   # a block of float32 logits, at most
@@ -826,16 +932,26 @@ def event_scores(params, cfg, pred, tok):
     or the embedding where the two are tied (no ``head`` among the
     parameters). Where the call's logits pass ``HEAD_LOGITS_BYTES`` (4,096
     events over a vocabulary of 65,536 are 1 GiB of float32) they are
-    formed in blocks of rows, one after the other."""
+    formed in blocks of rows, one after the other. Where the
+    configuration's ``head_fp32`` is set the product is float32 from
+    float32 operands (``Precision.HIGHEST``), not bfloat16's."""
     N, vocab = tok.size, cfg.vocab_slice
     rows = N
     while rows * vocab * 4 > HEAD_LOGITS_BYTES and rows % 2 == 0:
         rows //= 2
+    wide = getattr(cfg, "head_fp32", False)
+    if wide:
+        head = (params["head"] if "head" in params
+                else params["embed"].T).astype(jnp.float32)
 
     def block(args):
         x, ids = args
-        logits = _mm(x, params["head"] if "head" in params
-                     else params["embed"].T)
+        if wide:
+            logits = jnp.dot(x.astype(jnp.float32), head,
+                             precision=jax.lax.Precision.HIGHEST)
+        else:
+            logits = _mm(x, params["head"] if "head" in params
+                         else params["embed"].T)
         nll = (jax.nn.logsumexp(logits, -1)
                - jnp.take_along_axis(logits, ids[..., None], -1)[..., 0])
         return 1.0 - jnp.exp(-nll / math.log(vocab))

@@ -24,7 +24,7 @@ that lays a call's rows out for the step (``map`` -> a plan with ``rows``,
 ``keyed``: its calls apply in the order they were made, and ``describe``
 reports the table and the newest call's counts for ``device_state()``.
 
-Four instances: ``mlp36`` (the 36-column autoencoder + classifier: its
+Five instances: ``mlp36`` (the 36-column autoencoder + classifier: its
 state is the normalisation triple ``(mu, var, initialised)``, which a fit
 repoints and a score step only reads; trains online; sharded over a mesh)
 and the flow models ``latent_moe`` (latent attention over a per-flow
@@ -32,7 +32,9 @@ cache, routed experts beside a shared one), ``lfm2_moe`` (short
 convolutions among grouped-query attention layers, so two kinds of
 per-flow state, routed experts alone) and ``laguna_moe`` (window and full
 attention layers mixed: a ring of the newest positions beside a cache of
-them all, routed experts beside a shared one): keyed, frozen,
+them all, routed experts beside a shared one) and ``hy4_moe`` (latent
+attention over an indexer's selection, whose choice layers hand on, and a
+residual stream four wide): keyed, frozen,
 single-device, one step (``models/latent_moe.flow_step``) over any's
 layers.
 """
@@ -123,7 +125,8 @@ def compiled_text(program: Callable, args: tuple, static: dict
     return lambda: program.lower(*shapes, **static).compile().as_text()
 
 
-def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
+def _flow_model(name: str, cfg, grouped: bool,
+                sparse: bool = False) -> ModelSpec:
     """A flow model's spec: a row is int32 ``(stream key, restart flag,
     event id)``, laid out by ``FlowTable``; what the layers keep of a
     flow, the flows' lengths and the start token's constants are the
@@ -131,7 +134,8 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
     flow_step`` over the configuration's layers, built with the attention
     its platform gets (``ops/flow_attention.best_attention``: the fused
     kernel on a TPU, XLA's elsewhere; ``grouped``: over keys and values
-    in groups of heads, else over the latent), with the routed experts'
+    in groups of heads, else over the latent; ``sparse``: over the latent,
+    a selection of each event's positions), with the routed experts'
     grouped product its platform gets
     (``ops/expert_product.best_expert_product``, likewise) and with the
     append of a chunk to a layer's state its platform gets
@@ -152,7 +156,8 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
             best_expert_product, expert_product_kind)
         from linkerd_tpu.ops.flow_attention import (
             attention_call, attention_kind, best_attention)
-        attend = best_attention(platform, grouped)
+        attend = (best_attention(platform, sparse=True) if sparse
+                  else best_attention(platform, grouped))
         experts = best_expert_product(platform)
         append = best_append(platform)
         built["attention"] = attention_kind(platform)
@@ -160,7 +165,7 @@ def _flow_model(name: str, cfg, grouped: bool) -> ModelSpec:
         built["state"] = {
             op.scope: {"positions": op.ring or cfg.positions,
                        "call": attention_call(platform, grouped,
-                                              bool(op.ring))}
+                                              bool(op.ring), sparse)}
             for op in map(cfg.operator, range(cfg.layers)) if op.caches}
         built["expert_product"] = expert_product_kind(platform)
         # the state and the staged rows are the program's to reuse
@@ -240,5 +245,16 @@ def laguna_moe(cfg=None) -> ModelSpec:
                        cfg if cfg is not None else LagunaMoEConfig(), True)
 
 
+def hy4_moe(cfg=None) -> ModelSpec:
+    """The flow model of ``models/hy4_moe.py``: latent attention over an
+    indexer's selection of each event's positions (a second array of
+    state on the layers that index), a sink and a gate, a residual stream
+    four wide, routed experts beside a shared one."""
+    from linkerd_tpu.models.hy4_moe import Hy4MoEConfig
+    return _flow_model("hy4_moe",
+                       cfg if cfg is not None else Hy4MoEConfig(), False,
+                       sparse=True)
+
+
 SPECS = {"mlp36": mlp36, "latent_moe": latent_moe, "lfm2_moe": lfm2_moe,
-         "laguna_moe": laguna_moe}
+         "laguna_moe": laguna_moe, "hy4_moe": hy4_moe}

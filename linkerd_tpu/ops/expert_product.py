@@ -115,12 +115,13 @@ def fetches(tile_expert, live, blocks: int):
 
 def _kernel(fresh_ref, slot_ref, ahead_ref, first_ref, x_ref, wt_ref,
             gate_hbm, up_hbm, down_hbm, o_ref, gate_v, up_v, down_v, sem,
-            *acc):
+            *acc, limit=None):
     """One tile of ``M`` rows of one expert, one block of ``bi``
     intermediate columns. The weights stay in HBM and come by the kernel's
     own copies into two VMEM slots: a step that needs a fresh block waits
     for it (it was started by the fresh step before it, or just now by
-    the first step) and starts the next one."""
+    the first step) and starts the next one. ``limit``: the SwiGLU's clamp
+    (``models.latent_moe._swiglu``), or None."""
     blocks, bi = pl.num_programs(1), gate_v.shape[-1]
     j = pl.program_id(1)
     s = pl.program_id(0) * blocks + j
@@ -157,6 +158,8 @@ def _kernel(fresh_ref, slot_ref, ahead_ref, first_ref, x_ref, wt_ref,
     x = x_ref[...]
     g = jnp.dot(x, gate_v[slot], preferred_element_type=jnp.float32)
     u = jnp.dot(x, up_v[slot], preferred_element_type=jnp.float32)
+    if limit is not None:
+        g, u = jnp.minimum(g, limit), jnp.clip(u, -limit, limit)
     y = jnp.dot((jax.nn.silu(g) * u).astype(x.dtype), down_v[slot],
                 preferred_element_type=jnp.float32)
     if not acc:             # the expert is one block
@@ -177,10 +180,10 @@ def _kernel(fresh_ref, slot_ref, ahead_ref, first_ref, x_ref, wt_ref,
         o_ref[...] = acc_ref[...] * wt_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "budget"))
+@functools.partial(jax.jit, static_argnames=("interpret", "budget", "limit"))
 def swiglu_tiles_fused(xs, wt, tile_expert, live, gate, up, down,
                        interpret: bool = False,
-                       budget: int = WEIGHT_BLOCK_BYTES):
+                       budget: int = WEIGHT_BLOCK_BYTES, limit=None):
     """``xs [tiles x M, D]`` bfloat16, ``wt [tiles x M]`` float32,
     ``tile_expert [tiles]`` int32 (in ``0 .. G - 1``), ``live`` int32: the
     first ``live`` tiles hold a pair; ``gate``, ``up [G, D, I]``, ``down
@@ -188,7 +191,8 @@ def swiglu_tiles_fused(xs, wt, tile_expert, live, gate, up, down,
     rows of the first ``live`` tiles computed and weighed and the others
     **unspecified** (no grid step writes them), the whole-expert
     equivalents of weights fetched``: the schedule's fresh steps over the
-    blocks an expert comes in)``. Jitted, so that a step of several expert
+    blocks an expert comes in)``. ``limit``: the SwiGLU's clamp
+    (``models.latent_moe._swiglu``), or None. Jitted, so that a step of several expert
     layers traces and lowers the kernel once."""
     rows, D = xs.shape
     tiles = tile_expert.shape[0]
@@ -198,7 +202,7 @@ def swiglu_tiles_fused(xs, wt, tile_expert, live, gate, up, down,
     tile_expert = tile_expert.astype(jnp.int32)
     fresh, slot, ahead = fetches(tile_expert, live, blocks)
     y = pl.pallas_call(
-        _kernel,
+        _kernel if limit is None else functools.partial(_kernel, limit=limit),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(live, blocks),

@@ -529,28 +529,187 @@ def grouped_attention_fused(q, cache, slot, p0, scale: float,
     return (*attend([q], turn=(half, cos, sin, gate)), F * T * H)
 
 
+SELECT_EVENTS = 16      # events a tile of the selection's kernel, at most
+SELECT_VMEM_LIMIT = 100 * 2 ** 20   # of a v5e's 128 MiB: 45 MiB at 6,144
+
+
+def _selected_kernel(slot_ref, p0_ref, qa_ref, qr_ref, sink_ref, idx_ref,
+                     thr_ref, tie_ref, k_ref, o_ref, s_ref, m_ref, l_ref,
+                     acc_ref, *, scale: float, heads: int):
+    """One flow, one tile of its events, all heads, over **the positions
+    each event's selection holds**: ``_kernel``'s two loops with no mask
+    but the selection's and a sink in the softmax's sum. ``qa_ref``,
+    ``qr_ref [1, rows, rank | rope]`` the tile's queries, a row an (event,
+    head), event-major; ``sink_ref [rows, 1]`` each row's head's sink
+    logit (``MASKED``: none); ``idx_ref [1, events, P]`` the events'
+    index scores, ``thr_ref`` and ``tie_ref [1, events, 1]`` their
+    thresholds: position ``s`` is selected iff its score is over the
+    threshold, or equal to it and ``s <= tie``; ``k_ref [1, rank + rope,
+    P]`` the flow's slot, positions along the lanes."""
+    del slot_ref    # the block spec's alone: which slot the keys are of
+    f, i = pl.program_id(0), pl.program_id(1)
+    rows, P = s_ref.shape[0], k_ref.shape[-1]
+    events, bk, rank = rows // heads, m_ref.shape[1], acc_ref.shape[1]
+    first = p0_ref[f] + i * events      # position of the tile's first event
+    last = blocks_seen(first, events, P)
+    qa, qr = qa_ref[0], qr_ref[0]
+    thr, tie = thr_ref[0], tie_ref[0]
+
+    def score(j, _):
+        at = pl.multiple_of(j * bk, bk)
+        s = (jnp.dot(qa, k_ref[0, :rank, pl.ds(at, bk)],
+                     preferred_element_type=jnp.float32)
+             + jnp.dot(qr, k_ref[0, rank:, pl.ds(at, bk)],
+                       preferred_element_type=jnp.float32)) * scale
+        got = idx_ref[0, :, pl.ds(at, bk)]                  # [events, bk]
+        col = at + jax.lax.broadcasted_iota(jnp.int32, got.shape, 1)
+        chosen = (got > thr) | ((got == thr) & (col <= tie))
+        # an event's row of the mask for each of its heads' rows
+        off = jnp.broadcast_to(
+            jnp.where(chosen, 0.0, MASKED)[:, None, :],
+            (events, heads, bk)).reshape(rows, bk)
+        s = s + off
+        s_ref[:, pl.ds(at, bk)] = s
+        m_ref[...] = jnp.maximum(m_ref[...], s)     # lane by lane
+
+    # the sink takes part in the maximum as in the sum
+    m_ref[...] = jnp.broadcast_to(sink_ref[...], m_ref.shape)
+    jax.lax.fori_loop(0, last, score, None)
+    m_ref[...] = jnp.broadcast_to(m_ref[...].max(-1, keepdims=True),
+                                  m_ref.shape)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def weigh(j, _):
+        at = pl.multiple_of(j * bk, bk)
+        p = jnp.exp(s_ref[:, pl.ds(at, bk)] - m_ref[...])
+        l_ref[...] += p
+        ct = k_ref[0, :rank, pl.ds(at, bk)]
+        acc_ref[...] += jax.lax.dot_general(
+            p.astype(ct.dtype), ct, POSITION_AXES,
+            preferred_element_type=jnp.float32)
+
+    jax.lax.fori_loop(0, last, weigh, None)
+    total = (l_ref[...].sum(-1, keepdims=True)
+             + jnp.exp(sink_ref[...] - m_ref[:, :1]))
+    o_ref[0] = (acc_ref[...] * (1.0 / total)).astype(o_ref.dtype)
+
+
+def selection_tile(T: int) -> int:
+    """Events a tile of ``sparse_latent_attention_fused`` holds: ``T``
+    halved until it is ``SELECT_EVENTS`` or fewer (16 at chunks of 64)."""
+    events = T
+    while events > SELECT_EVENTS and events % 2 == 0:
+        events //= 2
+    return events
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def sparse_latent_attention_fused(q_abs, q_rope, cache, slot, p0,
+                                  scale: float, selection, sink=None,
+                                  interpret: bool = False):
+    """The latent attention over **a selection of each event's positions**
+    (``models/hy4_moe.py``: an indexer's top ``k``), with a sink: what
+    ``models.hy4_moe.attend_selected_xla`` returns. ``q_abs``, ``q_rope``
+    and ``cache`` as ``latent_attention_fused`` takes them;
+    ``selection``: ``(scores [F, T, P]`` float32, ``threshold [F, T]``
+    float32, ``tie [F, T]`` int32``)``: event ``(f, t)`` attends over the
+    positions whose score is over its threshold, or equal to it at or
+    before ``tie`` (a score past the event is ``-inf``, never selected);
+    ``sink [H]``, a logit a head added to the softmax's sum (None: none).
+
+    A grid cell is a flow and a tile of ``selection_tile(T)`` events (16
+    at chunks of 64: 1,024 rows at 64 heads, whatever the slot's length):
+    the tile's scores ``[rows, P]`` stay in VMEM whole (24 MiB at 6,144
+    positions), as ``_kernel``'s do, so that the softmax takes two loops
+    over blocks and one reduction across lanes; both loops end at the last
+    block an event of the tile may see (``blocks_seen``), and every block
+    is masked by the selection, which the kernel reads from the scores'
+    rows of the tile's events. Returns ``(o [F, T, H, rank]`` bfloat16,
+    the blocks attended over ``[F]``, those of a slot whole)``."""
+    F, T, H, rank = q_abs.shape
+    scores, threshold, tie = selection
+    kt = cache.transpose(0, 2, 1)
+    S, E, P = kt.shape
+    bk, events = kv_block(P), selection_tile(T)
+    rows, tiles = events * H, T // events
+    sink = (jnp.full((H,), MASKED, jnp.float32) if sink is None
+            else sink.astype(jnp.float32))
+    o = pl.pallas_call(
+        functools.partial(_selected_kernel, scale=scale, heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(F, tiles),
+            in_specs=[
+                pl.BlockSpec((1, rows, rank),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                pl.BlockSpec((1, rows, E - rank),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                pl.BlockSpec((rows, 1), lambda f, i, slot, p0: (0, 0)),
+                pl.BlockSpec((1, events, P),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                pl.BlockSpec((1, events, 1),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                pl.BlockSpec((1, events, 1),
+                             lambda f, i, slot, p0: (f, i, 0)),
+                pl.BlockSpec((1, E, P),
+                             lambda f, i, slot, p0: (slot[f], 0, 0))],
+            out_specs=pl.BlockSpec((1, rows, rank),
+                                   lambda f, i, slot, p0: (f, i, 0)),
+            scratch_shapes=[pltpu.VMEM((rows, P), jnp.float32),
+                            pltpu.VMEM((rows, bk), jnp.float32),
+                            pltpu.VMEM((rows, bk), jnp.float32),
+                            pltpu.VMEM((rows, rank), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((F, T * H, rank), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=SELECT_VMEM_LIMIT),
+        interpret=interpret,
+        name="sparse_latent_attention_fused",
+    )(jnp.minimum(slot, S - 1).astype(jnp.int32), p0.astype(jnp.int32),
+      q_abs.reshape(F, T * H, rank), q_rope.reshape(F, T * H, -1),
+      jnp.tile(sink, events)[:, None], scores,
+      threshold.astype(jnp.float32)[..., None],
+      tie.astype(jnp.int32)[..., None], kt)
+    attended = sum(blocks_seen(p0 + i * events, events, P)
+                   for i in range(tiles))
+    return o.reshape(F, T, H, rank), attended, tiles * (P // bk)
+
+
 def attention_kind(platform: str) -> str:
     """Which attention ``best_attention`` hands the flow step on
     ``platform``: ``"fused_pallas"`` on a TPU, ``"xla"`` elsewhere."""
     return "fused_pallas" if platform == "tpu" else "xla"
 
 
-def attention_call(platform: str, grouped: bool, windowed: bool) -> str:
+def attention_call(platform: str, grouped: bool, windowed: bool,
+                   sparse: bool = False) -> str:
     """The name of the call a layer's attention makes on ``platform``."""
     if attention_kind(platform) != "fused_pallas":
+        if sparse:
+            return "attend_selected_xla"
         return "attend_grouped_xla" if grouped else "attend_xla"
+    if sparse:
+        return "sparse_latent_attention_fused"
     if not grouped:
         return "latent_attention_fused"
     return ("window" if windowed else "grouped") + "_attention_fused"
 
 
-def best_attention(platform: str, grouped: bool = False):
+def best_attention(platform: str, grouped: bool = False,
+                   sparse: bool = False):
     """The flow step's ``attend`` for parameters living on ``platform``:
     the fused kernel on ``tpu``, the XLA path elsewhere (an interpreted
     kernel is far too slow to serve); ``grouped``: for grouped-query
     attention over keys and values (``models/grouped_attention.py``: a
-    layer with a window hands it ``window=``), else for the latent
-    attention (``models/latent_moe.py``)."""
+    layer with a window hands it ``window=``), ``sparse``: for the latent
+    attention over a selection of positions (``models/hy4_moe.py``), else
+    for the latent attention (``models/latent_moe.py``)."""
+    if sparse:
+        if attention_kind(platform) == "fused_pallas":
+            return sparse_latent_attention_fused
+        from linkerd_tpu.models.hy4_moe import attend_selected_xla
+        return attend_selected_xla
     if attention_kind(platform) == "fused_pallas":
         return grouped_attention_fused if grouped else latent_attention_fused
     if grouped:

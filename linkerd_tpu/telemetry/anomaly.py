@@ -850,7 +850,9 @@ class JaxAnomalyConfig:
     # (models/lfm2_moe.py: short convolutions among attention layers;
     # models/laguna_moe.py: window and full attention layers mixed, a
     # ring of the newest positions beside a cache of them all), by the
-    # same step, table and dispatcher.
+    # same step, table and dispatcher; "hy4_moe" (models/hy4_moe.py:
+    # latent attention over an indexer's selection of positions, a
+    # residual stream four wide) likewise.
     model: str = "mlp36"
     # line-rate micro-batcher: drain is size- and deadline-triggered —
     # a batch dispatches when maxBatch rows are pending OR the oldest

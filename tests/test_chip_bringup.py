@@ -77,7 +77,7 @@ def appended_by_the_kernel(compiled: str, layers: int) -> None:
     assert f"NAMED append {layers}" in compiled
     made = [line.split()[1:] for line in compiled.splitlines()
             if line.startswith("APPEND")]
-    assert sum(op == "custom-call" for op, _ in made) == layers, made
+    assert sum(m[0] == "custom-call" for m in made) == layers, made
     assert not [m for m in made if m[0] in APPEND_LOOPS], made
 
 
@@ -948,6 +948,243 @@ class TestScorerSelection:
         assert "COMPILED TPU v5" in proc.stdout
         assert proc.stdout.count("LAYOUT") == 5
         assert proc.stdout.count("RUN") == 2
+
+    @pytest.fixture(scope="class")
+    def hy4_step_compiled(self):
+        """The same step over the fifth flow model's layers
+        (``models/hy4_moe.py``) as the benchmark's cell
+        ``hy4-preview-ep16.flows64x64-6k`` runs it: the published widths
+        (hidden 6,144, 64 heads of latent attention, an indexer of 32 x
+        128, four streams, 16 of 256 experts held beside a shared one, an
+        eighth of the vocabulary), 5 layers with two kinds of state (5
+        latent caches ``[128, 6144, 576]``, 2 index-key arrays ``[128,
+        128, 6144]``), 64 flows x 64 events, the attention a TPU gets. The
+        child prints the program's bytes, its kernels by name, every
+        instruction that makes an array of a layer's state's, the
+        embedding's or the head's size, and the parts each instruction
+        under a layer's scope lies in."""
+        code = (
+            "import json, re, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models import latent_moe as lm\n"
+            "from linkerd_tpu.models import hy4_moe as hy\n"
+            "from linkerd_tpu.ops.cache_append import best_append\n"
+            "from linkerd_tpu.ops.expert_product import (\n"
+            "    best_expert_product)\n"
+            "from linkerd_tpu.ops.flow_attention import best_attention\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "with open('chipbench/configs/hy4-preview-ep16.json') as f:\n"
+            "    cfg = hy.Hy4MoEConfig.from_config(json.load(f))\n"
+            "assert cfg == hy.Hy4MoEConfig()\n"
+            "held = cfg.experts_held[1] - cfg.experts_held[0]\n"
+            "params = {'layers': [{} for _ in range(cfg.layers)]}\n"
+            "for name, (shape, _, _, each) in cfg.tensors().items():\n"
+            "    a = S(jnp.bfloat16, *((held,) if each else ()), *shape)\n"
+            "    p = name.split('.')\n"
+            "    if p[0] == 'layers': params['layers'][int(p[1])][p[2]] = a\n"
+            "    else: params[name] = a\n"
+            "place = lambda t: jax.tree_util.tree_map(\n"
+            "    lambda a: S(a.dtype, *a.shape), t)\n"
+            "state = place(jax.eval_shape(\n"
+            "    lambda: lm.init_state(cfg)))[:3] + (\n"
+            "    place(lm.start_shapes(cfg)),)\n"
+            "step = jax.jit(lm.flow_step, donate_argnums=(1, 2),\n"
+            "               static_argnames=('cfg', 'F', 'T', 'attend',\n"
+            "                                'experts', 'append'))\n"
+            "c = step.lower(params, state, S(jnp.int32, 4096, 3),\n"
+            "               S(jnp.int32), cfg=cfg, F=64, T=64,\n"
+            "               attend=best_attention('tpu', sparse=True),\n"
+            "               experts=best_expert_product('tpu'),\n"
+            "               append=best_append('tpu')).compile()\n"
+            "m = c.memory_analysis()\n"
+            "print('BYTES', m.argument_size_in_bytes, m.temp_size_in_bytes,\n"
+            "      m.alias_size_in_bytes)\n"
+            "text = c.as_text()\n"
+            "print('KERNELS', text.count(\n"
+            "    'custom_call_target=\"tpu_custom_call\"'))\n"
+            + APPEND_NAMED +
+            "print('NAMED sparse', len(re.findall(\n"
+            "    r'^\\s*%sparse_latent_attention_fused[\\w.]* = ', text,\n"
+            "    re.M)))\n"
+            + EXPERTS_PATTERNS +
+            "S_, P, E, V = (cfg.slots, cfg.positions, cfg.entry_width,\n"
+            "               cfg.vocab_slice)\n"
+            "DH = cfg.index_head_dim\n"
+            "# a layer's latent or index keys, or a part of every slot's\n"
+            "# keys (XLA split the copy a gather of slots made in three)\n"
+            "whole = re.compile(rf'bf16\\[{S_},(\\d+),{E}\\]|'\n"
+            "                   rf'bf16\\[{S_},(\\d+),{P}\\]|'\n"
+            "                   rf'bf16\\[{S_},{DH},(\\d+)\\]|'\n"
+            "                   rf'(?:bf16|f32)\\[{V},(\\d+)\\]|'\n"
+            "                   rf'(?:bf16|f32)\\[(\\d+),{V}\\]')\n"
+            "for line in text.splitlines():\n"
+            "    name, eq, rest = line.strip().partition(' = ')\n"
+            "    if not eq or name.startswith('//'): continue\n"
+            "    if rest.startswith('('):\n"
+            "        depth = 0\n"
+            "        for i, ch in enumerate(rest):\n"
+            "            depth += (ch == '(') - (ch == ')')\n"
+            "            if depth == 0: break\n"
+            "        typ, rest = rest[:i + 1], rest[i + 2:]\n"
+            "    else:\n"
+            "        typ, _, rest = rest.partition(' ')\n"
+            "    if any(int(next(x for x in g if x)) >= 128\n"
+            "           for g in whole.findall(typ)):\n"
+            "        op = rest.split('(', 1)[0]\n"
+            "        if op == 'fusion' and 'calls=%bitcast_fusion' in rest:\n"
+            "            op = 'bitcast'     # a fusion of a bitcast alone\n"
+            "        print('WHOLE', op, name, typ[:80])\n"
+            + EXPERTS_SEEN +
+            "from linkerd_tpu.telemetry import phases\n"
+            "with open('chipbench/metrics/flow_step.unattributed_pct.json'\n"
+            "          ) as f:\n"
+            "    parts = json.load(f)['scopes'] + ['index', 'hyper']\n"
+            "for name, path in phases.instruction_scopes(text).items():\n"
+            "    if re.search(r'(^|/)layer\\d+\\.', path):\n"
+            "        found = {p for c in path.split('/') for p in parts\n"
+            "                 if c == p or c.endswith('.' + p)}\n"
+            "        inner = [c for c in path.split('/')\n"
+            "                 if any(c == p or c.endswith('.' + p)\n"
+            "                        for p in parts)]\n"
+            "        print('PART', len(found), inner[-1] if inner else '-',\n"
+            "              name, path)\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=900,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        return proc.stdout
+
+    def test_hy4_step_compiles_for_v5e_at_the_published_widths(
+            self, hy4_step_compiled):
+        """It fits one v5e with all 5 layers' weights and both kinds of
+        state as arguments, under 15 GiB with its temporaries, the state
+        updated in place (aliased); every layer attends by the selection's
+        kernel, and appends its latent, and a ``full`` layer its index
+        keys, by the append's kernel: 5 + 7 + 4 x 2 experts' kernels."""
+        args, temp, alias = (int(v) for v in next(
+            line for line in hy4_step_compiled.splitlines()
+            if line.startswith("BYTES")).split()[1:])
+        # weights 8.90 GB; 5 latent caches of 0.91 GB, 2 index-key arrays
+        # of 0.20 GB
+        assert 13.7e9 < args < 13.9e9 and alias > 4.9e9
+        # the four streams are arrays of their own: with one array of
+        # [F, T, 4, hidden] the compiler relaid it for every
+        # hyper-connection and held 2.76 GiB of temporaries
+        assert temp < 1.75 * 2 ** 30 and args + temp < 15 * 2 ** 30
+        assert "KERNELS 20" in hy4_step_compiled
+        assert "NAMED sparse 5" in hy4_step_compiled
+        assert "NAMED append 7" in hy4_step_compiled
+
+    def test_hy4_step_puts_each_layer_instruction_in_one_part(
+            self, hy4_step_compiled):
+        """Every instruction of the optimised step made under a layer's
+        scope lies in exactly one of the parts the benchmark reads the
+        step's device time by: the flow cells' (``project``, ``append``,
+        ``attend``, ``out``; ``route``, ``dense``, ``expert_tiles``) and
+        this model's ``index`` (the indexer's projections, scores and
+        selection) and ``hyper`` (the hyper-connections); both are
+        read."""
+        seen = [line.split(None, 4)[1:]
+                for line in hy4_step_compiled.splitlines()
+                if line.startswith("PART")]
+        assert len(seen) > 500
+        assert not [s for s in seen if s[0] != "1"], [
+            s for s in seen if s[0] != "1"][:10]
+        inner = {s[1] for s in seen}
+        assert {"index", "hyper", "attend", "append", "project",
+                "out"} <= inner
+        layers = {re.search(r"layer\d+\.\w+", path).group(0)
+                  for *_, path in seen}
+        assert layers == {f"layer{l}.{part}" for l in range(5)
+                          for part in ("attention", "ffn")}
+        index = {re.search(r"layer\d+", path).group(0)
+                 for _, part, _, path in seen if part == "index"}
+        assert index == {"layer0", "layer1"}       # the full layers alone
+
+    def test_hy4_step_makes_no_copy_of_a_layers_state(
+            self, hy4_step_compiled):
+        """A latent cache and an index-key array lie as the model keeps
+        them and the optimised program names them only to pass them on
+        (the selection's kernel reads a slot where it lies; the indexer's
+        product reads the flows' keys a few slots at a time); no array of
+        the embedding's or the head's size is made but the head widened to
+        float32, which its product reads (``head_fp32``). Each of the seven
+        arrays is appended by one call of the kernel, in place."""
+        seen = [line.split()[1:] for line in hy4_step_compiled.splitlines()
+                if line.startswith("WHOLE")]
+        passes_on = {"parameter", "get-tuple-element", "bitcast", "tuple",
+                     "while", "custom-call"}
+        made = [s for s in seen if s[0] not in passes_on]
+        assert len(made) <= 1 and all("f32[6144,15104]" in s[2]
+                                      for s in made), made[:10]
+        appended_by_the_kernel(hy4_step_compiled, 7)
+
+    def test_hy4_kernels_compile_for_v5e_at_every_kind_of_layout(self):
+        """The selection's kernel alone at the published sizes (64 heads
+        over a latent of 512 + 64, slots of 6,144, index scores ``[F, T,
+        6144]``) in the layouts ``FlowTable`` makes: a tile of 16 events
+        (1,024 rows, 24 MiB of scores in VMEM) where a chunk has 16 or
+        more, the chunk whole where fewer; and the grouped product with
+        the SwiGLU's clamp at this model's widths."""
+        code = (
+            "import functools, jax, jax.numpy as jnp\n"
+            "from jax.experimental import topologies\n"
+            "from jax.sharding import SingleDeviceSharding\n"
+            "try:\n"
+            "    topo = topologies.get_topology_desc(\n"
+            "        topology_name='v5e:2x2', platform='tpu')\n"
+            "except Exception as e:\n"
+            "    print('NO_TOPOLOGY', repr(e)); raise SystemExit(0)\n"
+            "from linkerd_tpu.models.hy4_moe import Selection\n"
+            "from linkerd_tpu.ops.expert_product import swiglu_tiles_fused\n"
+            "from linkerd_tpu.ops.flow_attention import (\n"
+            "    sparse_latent_attention_fused)\n"
+            "sh = SingleDeviceSharding(topo.devices[0])\n"
+            "S = lambda dt, *s: jax.ShapeDtypeStruct(s, dt, sharding=sh)\n"
+            "bf = functools.partial(S, jnp.bfloat16)\n"
+            "f32 = functools.partial(S, jnp.float32)\n"
+            "fn = jax.jit(functools.partial(sparse_latent_attention_fused,\n"
+            "                               scale=256 ** -0.5))\n"
+            "P = 6144\n"
+            "for F, T in ((64, 64), (64, 1), (1, 64), (2, 8), (8, 128)):\n"
+            "    sel = Selection(f32(F, T, P), f32(F, T),\n"
+            "                    S(jnp.int32, F, T))\n"
+            "    text = fn.lower(bf(F, T, 64, 512), bf(F, T, 64, 64),\n"
+            "                    bf(128, P, 576), S(jnp.int32, F),\n"
+            "                    S(jnp.int32, F), selection=sel,\n"
+            "                    sink=f32(64)).compile().as_text()\n"
+            "    assert '%sparse_latent_attention_fused' in text, (F, T)\n"
+            "    print('LAYOUT', F, T)\n"
+            "G, D, I = 16, 6144, 2048\n"
+            "text = swiglu_tiles_fused.lower(\n"
+            "    bf(4 * 128, D), S(jnp.float32, 4 * 128), S(jnp.int32, 4),\n"
+            "    S(jnp.int32), bf(G, D, I), bf(G, D, I), bf(G, I, D),\n"
+            "    limit=10.0).compile().as_text()\n"
+            "assert 'tpu_custom_call' in text\n"
+            "print('COMPILED', topo.devices[0].device_kind)\n")
+        proc = _run([sys.executable, "-c", code], timeout=600,
+                    env=_clean_env(
+                        JAX_PLATFORMS="cpu",
+                        TPU_ACCELERATOR_TYPE="v5litepod-4",
+                        TPU_WORKER_HOSTNAMES="localhost", PYTHONPATH=REPO))
+        if "NO_TOPOLOGY" in proc.stdout:
+            pytest.skip("no compile-only TPU client in this installation")
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "COMPILED TPU v5" in proc.stdout
+        assert proc.stdout.count("LAYOUT") == 5
 
 
 class TestFailLoud:

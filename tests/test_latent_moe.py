@@ -513,8 +513,8 @@ class TestState:
             assert "flow" not in s.device_state()
         finally:
             s.close()
-        assert sorted(SPECS) == ["laguna_moe", "latent_moe", "lfm2_moe",
-                                 "mlp36"]
+        assert sorted(SPECS) == ["hy4_moe", "laguna_moe", "latent_moe",
+                                 "lfm2_moe", "mlp36"]
         assert mlp36(0.5).cfg.recon_weight == 0.5
 
 
